@@ -6,8 +6,8 @@ of a guess (r4 verdict #2): for each K it measures
 - host:        native/python fold of K ciphertexts mod n^2 (the path
                small aggregates take today);
 - device-lat:  ONE blocking device fold (dispatch + fetch) — what a lone
-               below-crossover request would pay; on tunneled platforms
-               this is floored by the link round-trip;
+               below-crossover request would pay, host<->device round
+               trip included;
 - device-sus:  sustained per-fold time with R pipelined dispatches —
                what concurrent serving pays per request;
 - coalesced:   per-request time when R concurrent K-wide folds share one

@@ -57,39 +57,10 @@ def main(argv=None):
     resident = jax.device_put(bn.ints_to_batch(cs, ctx.L))
     jax.block_until_ready(resident)
 
-    # v2 with the fused in-kernel Karatsuba product: the mode is threaded
-    # through the jit cache keys (unlike DDS_PROD_TB), so switching the
-    # env var in-process measures the real third variant. Save/restore the
-    # caller's flag and restore it even if an assert raises.
-    import contextlib
-    import os
-
-    @contextlib.contextmanager
-    def karatsuba_env(value: str | None):
-        prior = os.environ.get("DDS_KARATSUBA")
-        try:
-            if value is None:
-                os.environ.pop("DDS_KARATSUBA", None)
-            else:
-                os.environ["DDS_KARATSUBA"] = value
-            yield
-        finally:
-            if prior is None:
-                os.environ.pop("DDS_KARATSUBA", None)
-            else:
-                os.environ["DDS_KARATSUBA"] = prior
-
-    with karatsuba_env("2"):
-        gotf = bn.batch_to_ints(np.asarray(mx.reduce_mul2(mctx, sb)))[0]
-        assert gotf == want, "v2-fused-karatsuba fold wrong on device"
-
     rows = []
-    with karatsuba_env(None):
-        t1 = sustained_device(lambda: pm.reduce_mul(ctx, resident), repeats=args.repeats)
-        t2 = sustained_device(lambda: mx.reduce_mul2(mctx, resident), repeats=args.repeats)
-    with karatsuba_env("2"):
-        tf = sustained_device(lambda: mx.reduce_mul2(mctx, resident), repeats=args.repeats)
-    for name, t in (("v1-cios", t1), ("v2-mxu", t2), ("v2-kfused", tf)):
+    t1 = sustained_device(lambda: pm.reduce_mul(ctx, resident), repeats=args.repeats)
+    t2 = sustained_device(lambda: mx.reduce_mul2(mctx, resident), repeats=args.repeats)
+    for name, t in (("v1-cios", t1), ("v2-mxu", t2)):
         rows.append(
             emit(
                 f"fold kernel {name} @ {args.bits}-bit Paillier (mod n^2)",

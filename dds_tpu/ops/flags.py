@@ -10,19 +10,16 @@ import os
 def karatsuba_mode() -> str | bool:
     """DDS_KARATSUBA: "" / 0 -> off (plain schoolbook, the measured
     default), 1 -> the composed k1 variant (XLA-side combine; kept as the
-    negative-result record), 2 / "fused" -> the fully in-kernel variant.
-    Returns a mode usable as a jit cache key; unknown values fail loudly
-    (a typo silently running the recorded-negative k1 variant would
-    mislead every number downstream)."""
+    negative-result record). Returns a mode usable as a jit cache key;
+    unknown values fail loudly (a typo silently running the
+    recorded-negative k1 variant would mislead every number downstream)."""
     flag = os.environ.get("DDS_KARATSUBA", "").strip().lower()
     if not flag or flag in ("0", "false", "off", "no"):
         return False
-    if flag in ("2", "fused"):
-        return "fused"
     if flag in ("1", "true", "on", "yes", "k1"):
         return "k1"
     raise ValueError(
-        f"unknown DDS_KARATSUBA value {flag!r} (use 0, 1/k1, or 2/fused)"
+        f"unknown DDS_KARATSUBA value {flag!r} (use 0 or 1/k1)"
     )
 
 

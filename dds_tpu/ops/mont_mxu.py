@@ -186,9 +186,8 @@ def _prod3_call(h: int, B: int, TB: int, interpret: bool):
 
 
 def _karatsuba_combine(z0c, z2c, z1, sa, ca, sb, cb, h: int, L: int):
-    """The proof-carrying Karatsuba recombination, shared by the composed
-    (prod_lm_k1, XLA values) and fused (_make_kfused_kernel, in-kernel
-    values) variants — ONE copy of the borrow-free complement-add math.
+    """The proof-carrying Karatsuba recombination of prod_lm_k1: the
+    borrow-free complement-add math, on XLA values.
 
     Inputs: canonical half products z0c/z2c (2h, B); redundant middle
     product z1 (2h, B) of the normalized half sums sa/sb (h, B) with
@@ -213,80 +212,6 @@ def _karatsuba_combine(z0c, z2c, z1, sa, ca, sb, cb, h: int, L: int):
     T = T.at[h : h + rows].add(mid)
     T = T.at[2 * h :].add(z2c)
     return T
-
-
-def _make_kfused_kernel(L: int, TB: int):
-    """FULLY fused Karatsuba product: the three half-size schoolbook
-    products AND the recombination (carry normalizations, complement-add
-    middle term, shifted assembly) all inside ONE kernel, VMEM-resident.
-
-    This is the lever the measured prod_lm_k1 verdict names: the composed
-    variant's 25% multiply saving was eaten by the combine's XLA-side HBM
-    passes; here the combine's carry_norm/assembly arithmetic runs on
-    in-register values, so only (a, b) in and T out touch HBM — the same
-    traffic as the plain schoolbook kernel. Math and digit bounds are
-    identical to prod_lm_k1 (see its docstring); `carry_norm` is pure
-    jnp shifts/masks and traces inside Pallas unchanged."""
-    h = L // 2
-
-    def kernel(a_ref, b_ref, out_ref, acc_ref, sa_ref):
-        # normalized half sums + their 0/1 overflow bits. Only the a-side
-        # operand of a product needs a ref (dynamic per-row reads inside
-        # the accumulate loop); b-sides are consumed whole as values, so
-        # sb never round-trips VMEM.
-        sa, ca = carry_norm(a_ref[0:h, :] + a_ref[h:L, :])
-        sb, cb = carry_norm(b_ref[0:h, :] + b_ref[h:L, :])
-        sa_ref[:, :] = sa
-
-        def prod(a_read, b):
-            acc_ref[:, :] = jnp.zeros((2 * h + GROUP, TB), jnp.uint32)
-            _accumulate_prod(a_read, b, acc_ref, h, TB)
-            return acc_ref[0 : 2 * h, :]
-
-        z0 = prod(lambda i: a_ref[pl.ds(i, 1), :], b_ref[0:h, :])
-        z0c, _ = carry_norm(z0)
-        z2 = prod(lambda i: a_ref[pl.ds(h + i, 1), :], b_ref[h:L, :])
-        z2c, _ = carry_norm(z2)
-        z1 = prod(lambda i: sa_ref[pl.ds(i, 1), :], sb)
-
-        out_ref[:, :] = _karatsuba_combine(z0c, z2c, z1, sa, ca, sb, cb, h, L)
-
-    return kernel
-
-
-@functools.lru_cache(maxsize=None)
-def _kfused_call(L: int, B: int, TB: int, interpret: bool):
-    h = L // 2
-    kernel = _make_kfused_kernel(L, TB)
-    spec = pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // TB,),
-        in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((2 * L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((2 * L, B), jnp.uint32),
-        scratch_shapes=[
-            pltpu.VMEM((2 * h + GROUP, TB), jnp.uint32),
-            pltpu.VMEM((h, TB), jnp.uint32),
-        ],
-        interpret=interpret,
-    )
-
-
-def prod_lm_kf(a, b, TB: int | None = None, interpret: bool | None = None):
-    """Fused-Karatsuba full product, limbs-major (L,B)x(L,B)->(2L,B).
-    Same contract as prod_lm/prod_lm_k1; requires L even with L/2 a
-    multiple of GROUP (falls back to prod_lm otherwise)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    L = a.shape[0]
-    if TB is None:
-        TB = _tb_for(L)
-    if L % 2 or (L // 2) % GROUP:
-        return prod_lm(a, b, TB, interpret)
-    a, B = _pad_lanes(a, TB)
-    b, _ = _pad_lanes(b, TB)
-    return _kfused_call(L, a.shape[1], TB, interpret)(a, b)[:, :B]
 
 
 def _pad_lanes(x, TB: int):
@@ -386,8 +311,7 @@ def prod_lm_k1(a, b, TB: int | None = None, interpret: bool | None = None):
 def _use_karatsuba() -> str | bool:
     """DDS_KARATSUBA mode (see ops/flags.karatsuba_mode — jax-free so
     validators need not import this module): False = plain schoolbook
-    (the measured default), "k1" = composed variant, "fused" = the fully
-    in-kernel variant (_make_kfused_kernel)."""
+    (the measured default), "k1" = the composed variant."""
     from dds_tpu.ops.flags import karatsuba_mode
 
     return karatsuba_mode()
@@ -577,12 +501,10 @@ def mul2_lm(mctx: MxuCtx, a, b, interpret: bool | None = None,
 
     `karatsuba` must be passed EXPLICITLY by traced callers (their jit
     caches key on it); None reads the DDS_KARATSUBA env flag. Modes:
-    False = schoolbook, "k1"/True = composed Karatsuba, "fused" =
-    in-kernel Karatsuba (see _use_karatsuba)."""
+    False = schoolbook, "k1"/True = composed Karatsuba (see
+    _use_karatsuba)."""
     mode = _use_karatsuba() if karatsuba is None else karatsuba
-    if mode == "fused":
-        T = prod_lm_kf(a, b, interpret=interpret)
-    elif mode:  # "k1" or legacy True
+    if mode:  # "k1" or legacy True
         T = prod_lm_k1(a, b, interpret=interpret)
     else:
         T = prod_lm(a, b, interpret=interpret)

@@ -336,7 +336,7 @@ def _reduce_fn(ctx: ModCtx, P2: int, interpret: bool):
 def _fold_fix(ctx: ModCtx, K: int):
     """Device-resident R^K mod n fixup for a K-term fold (cached: the proxy
     folds the same store size repeatedly, and the host modexp + transfer
-    otherwise costs milliseconds per aggregate on tunneled platforms)."""
+    would otherwise be paid on every aggregate)."""
     R = 1 << (LIMB_BITS * ctx.L)
     return jax.device_put(bn.int_to_limbs(pow(R % ctx.n, K, ctx.n), ctx.L))
 

@@ -8,6 +8,11 @@ Run a self-contained node + workload:
 
     python -m dds_tpu.run --ops 100 --backend tpu
     python -m dds_tpu.run --config configs/default.toml
+
+`--backend tpu` needs a TPU (or `JAX_PLATFORMS=cpu`, asking for the CPU on
+purpose), and folds reach the device only from 1,024 stored rows up, so
+this 100-op workload folds on the host: `chip_smoke.py` at the repo root
+is the run that serves from the chip.
 """
 
 from __future__ import annotations
@@ -75,6 +80,17 @@ class Deployment:
 
         chronoscope.detach()
         chronoscope.reset()
+
+
+def _log_backend(server: DDSRestServer) -> None:
+    """Say once where the proxy's ciphertext math runs: the tpu backend
+    records the device it found at construction (and refuses a silent CPU
+    fallback there); cpu/native run on the host."""
+    be = server.backend
+    log.info(
+        "crypto backend %s: platform=%s device_kind=%s", be.name,
+        getattr(be, "platform", "host"), getattr(be, "device_kind", "-"),
+    )
 
 
 async def launch(cfg: DDSConfig | None = None) -> Deployment:
@@ -455,6 +471,7 @@ async def launch(cfg: DDSConfig | None = None) -> Deployment:
         slo=SloEngine.from_obs(cfg.obs),
     )
     await server.start()
+    _log_backend(server)
 
     # Merkle anti-entropy loops: one pull agent per local replica, on a
     # jittered timer so the fleet's rounds spread out instead of thundering
@@ -737,6 +754,7 @@ async def _launch_constellation(cfg: DDSConfig, net, stoppables,
         reshard=ConstellationReshard(const),
     )
     await server.start()
+    _log_backend(server)
 
     if cfg.helmsman.enabled:
         from dds_tpu.fleet import Helmsman

@@ -70,26 +70,19 @@ def compile_cache_bypass():
     loses a little warm-start time; the converse one writes secret-leg
     executables to disk. Fail-safe direction chosen accordingly.
     """
+    from jax._src import compilation_cache as _cc
+
     with _BYPASS_LOCK:
         prev = jax.config.jax_compilation_cache_dir
         try:
-            from jax._src import compilation_cache as _cc
-
-            reset = _cc.reset_cache
-        except Exception:  # pragma: no cover - private API drift
-            reset = None
-        try:
-            if reset is not None:
-                reset()
+            _cc.reset_cache()
             jax.config.update("jax_compilation_cache_dir", None)
             yield
         finally:
+            # exactly what was in effect: the operator's directory or the
+            # in-checkout default (dds_tpu/__init__), never a new one
             jax.config.update("jax_compilation_cache_dir", prev)
-            if reset is not None:
-                try:
-                    reset()
-                except Exception:  # pragma: no cover
-                    pass
+            _cc.reset_cache()
 
 
 def _fused_crt_raw(bases, N, n0inv, R2, one_mont, digits):

@@ -55,11 +55,11 @@ def test_fold_many_cache_keys_on_karatsuba_mode_and_interpret(monkeypatch):
     monkeypatch.delenv("DDS_KARATSUBA", raising=False)
     foldmany._fold_many_fn(ctx, "v2", 2)
     keys_off = {k for k in foldmany._FN_CACHE if k[0] == ctx.n}
-    monkeypatch.setenv("DDS_KARATSUBA", "2")
+    monkeypatch.setenv("DDS_KARATSUBA", "1")
     foldmany._fold_many_fn(ctx, "v2", 2)
-    keys_fused = {k for k in foldmany._FN_CACHE if k[0] == ctx.n}
-    assert keys_fused != keys_off  # a NEW entry was compiled, not reused
-    assert any(k[-1] == "fused" for k in keys_fused - keys_off)
+    keys_k1 = {k for k in foldmany._FN_CACHE if k[0] == ctx.n}
+    assert keys_k1 != keys_off  # a NEW entry was compiled, not reused
+    assert any(k[-1] == "k1" for k in keys_k1 - keys_off)
 
 
 def test_prod_tb_env_flag_validated_loudly(monkeypatch):

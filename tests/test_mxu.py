@@ -191,43 +191,3 @@ def test_reduce_mul2_karatsuba_flag(monkeypatch):
     for c in cs:
         want = want * c % n
     assert bn.limbs_to_int(np.asarray(out)[0]) == want
-
-
-def test_prod_lm_kf_fused_karatsuba_matches_int():
-    """The fully in-kernel Karatsuba product (three half products + the
-    whole recombination in ONE Pallas kernel) must equal python ints."""
-    import random
-
-    rng = random.Random(91)
-    for bits in (256, 512):
-        L = bits // 16
-        xs = [rng.getrandbits(bits) for _ in range(4)]
-        ys = [rng.getrandbits(bits) for _ in range(4)]
-        T = np.asarray(
-            mx.prod_lm_kf(bn.ints_to_batch(xs, L).T, bn.ints_to_batch(ys, L).T)
-        )
-        for i in range(4):
-            val = sum(int(d) << (16 * k) for k, d in enumerate(T[:, i]))
-            assert val == xs[i] * ys[i]
-
-
-def test_reduce_mul2_fused_karatsuba_flag(monkeypatch):
-    """DDS_KARATSUBA=2 routes mul2 through the fused kernel with
-    identical results (incl. the modexp ladder)."""
-    import random
-
-    monkeypatch.setenv("DDS_KARATSUBA", "2")
-    rng = random.Random(92)
-    n = rng.getrandbits(512) | (1 << 511) | 1
-    ctx = ModCtx.make(n)
-    mctx = mx.MxuCtx.make(ctx)
-    cs = [rng.randrange(n) for _ in range(11)]
-    out = mx.reduce_mul2(mctx, bn.ints_to_batch(cs, ctx.L))
-    want = 1
-    for c in cs:
-        want = want * c % n
-    assert bn.limbs_to_int(np.asarray(out)[0]) == want
-    bases = [rng.randrange(n) for _ in range(4)]
-    exp = rng.getrandbits(40)
-    got = mx.pow_mod2(mctx, bn.ints_to_batch(bases, ctx.L), exp)
-    assert bn.batch_to_ints(np.asarray(got)) == [pow(b, exp, n) for b in bases]

@@ -724,26 +724,3 @@ def test_flight_index_lines_and_prune_rewrite(tmp_path):
     assert {e["path"] for e in idx} == files
     assert all({"ts", "kind", "trace_id", "path"} <= set(e) for e in idx)
     assert [e["kind"] for e in idx] == ["kind_2", "kind_3"]
-
-
-# ----------------------------------------------------- bench.py satellite
-
-
-def test_bench_probe_failure_classification():
-    import bench
-
-    d = bench._classify_failure(None, "", "WARNING: platform experimental\n")
-    assert d["kind"] == "hang_timeout" and d["rc"] is None
-
-    d = bench._classify_failure(
-        1, "", "RuntimeError: UNAVAILABLE: TPU backend setup error\n"
-    )
-    assert d["kind"] == "unavailable"
-    assert any("UNAVAILABLE" in l for l in d["tail"])
-
-    err = "WARNING: noise\nTraceback (most recent call last):\nValueError: boom\n"
-    d = bench._classify_failure(2, "", err)
-    assert d["kind"] == "crash"
-    # error-ish lines beat the warning noise that used to clip the detail
-    assert any("ValueError" in l for l in d["tail"])
-    assert not any(l.startswith("WARNING") for l in d["tail"])
