@@ -59,6 +59,7 @@ from dds_tpu.models.backend import CryptoBackend, get_backend
 from dds_tpu.obs import context as obs_context
 from dds_tpu.obs.flight import flight
 from dds_tpu.obs.metrics import SIZE_BUCKETS, metrics
+from dds_tpu.obs.runtime import LoopSampler
 from dds_tpu.obs.slo import SloEngine
 from dds_tpu.obs.watchtower import watchtower
 from dds_tpu.utils import sigs
@@ -272,6 +273,20 @@ class ProxyConfig:
     ssl_client_context: object = None
 
 
+def _fold_after_wait(fold, operands: list[int], modulus: int,
+                     t_call: float, t_done: list):
+    """The worker thread's side of `DDSRestServer._fold_on_worker`: note
+    how long the call waited for this thread, fold, and leave in
+    `t_done[0]` the instant the result was ready."""
+    t_run = time.perf_counter()
+    tracer.record("dispatch.thread_wait", (t_run - t_call) * 1e3,
+                  _ctx=obs_context.child(), _t_end=t_run)
+    try:
+        return fold(operands, modulus)
+    finally:
+        t_done[0] = time.perf_counter()
+
+
 async def _cancel_task(task: asyncio.Task) -> None:
     """Cancel a background task and swallow its CancelledError."""
     task.cancel()
@@ -337,6 +352,7 @@ class DDSRestServer:
             handler_timeout=self.cfg.handler_timeout,
         )
         self._tasks: list[asyncio.Task] = []
+        self._loop_sampler: LoopSampler | None = None
         self._keys_dirty = False
         self._keys_saver: asyncio.Task | None = None
         # modulus -> [(enqueue_t, operands, future, waiter trace ctx)];
@@ -518,6 +534,10 @@ class DDSRestServer:
         self._load_keys()
         await self._http.start()
         self.cfg.port = self._http.port  # resolve OS-assigned port 0
+        # the loop this proxy serves on: its lag, the collector's pauses
+        # and a stall are the host runtime's share of every latency
+        self._loop_sampler = LoopSampler()
+        self._loop_sampler.start()
         if self.cfg.key_sync_enabled and self.cfg.peers:
             await self._bootstrap_keys_from_peers()
             self._tasks.append(supervised_task(self._key_sync_loop(),
@@ -563,6 +583,9 @@ class DDSRestServer:
     async def stop(self) -> None:
         if self.heliograph is not None:
             self.heliograph.stop()
+        if self._loop_sampler is not None:
+            await self._loop_sampler.stop()
+            self._loop_sampler = None
         for t in self._tasks:
             t.cancel()
         for t in self._tasks:
@@ -905,13 +928,44 @@ class DDSRestServer:
         memo = self._agg_memo
         if memo is not None and memo[0] == state:
             return memo
-        keys = sorted(self.stored_keys)
-        cached = [k for k in keys if k in self._cache]
-        cached_tags = [self._cache[k][0] for k in cached]
-        digest = sigs.key_from_set(cached)
-        fp = sigs.tags_fingerprint(cached_tags)
+        with tracer.span("assembly.state") as sm:
+            keys = sorted(self.stored_keys)
+            cached = [k for k in keys if k in self._cache]
+            cached_tags = [self._cache[k][0] for k in cached]
+            digest = sigs.key_from_set(cached)
+            fp = sigs.tags_fingerprint(cached_tags)
+            sm["k"], sm["cached"] = len(keys), len(cached)
         self._agg_memo = (state, keys, cached, digest, fp, cached_tags)
         return self._agg_memo
+
+    def _validate_tags(self, cached: list[str], tags, fresh: dict,
+                       fresh_tags: dict) -> None:
+        """Fill `fresh` with the cached entries the tag round confirmed:
+        all of them (`tags` None: every vote said "unchanged"), or those
+        whose quorum-max tag equals the cached one."""
+        with tracer.span("assembly.validate_tags", k=len(cached)) as vm:
+            if tags is None:
+                for k in cached:
+                    ct, cv = self._cache[k]
+                    fresh[k] = cv
+                    fresh_tags[k] = ct
+            else:
+                for k, t in zip(cached, tags):
+                    ct, cv = self._cache[k]
+                    if t == ct:
+                        fresh[k] = cv
+                        fresh_tags[k] = ct
+            vm["stale"] = len(cached) - len(fresh)
+
+    async def _reread(self, keys: list[str], audit: int) -> list:
+        """Full ABD re-reads of `keys`, gathered (`audit` of them are the
+        audit's sample, the rest stale); exceptions come back in place."""
+        with tracer.span("assembly.reread", stale=len(keys) - audit,
+                         audit=audit):
+            return await asyncio.gather(
+                *(self._fetch_tagged(k) for k in keys),
+                return_exceptions=True,
+            )
 
     async def _fetch_tagged(self, key: str, exclude=()):
         dl = self._request_deadline()
@@ -1384,16 +1438,9 @@ class DDSRestServer:
                             return pm[1]
                         # audit flushed the cache: rebuild from quorum reads
                     else:
-                        for k in cached:
-                            ct, cv = self._cache[k]
-                            fresh[k] = cv
-                            fresh_tags[k] = ct
+                        self._validate_tags(cached, None, fresh, fresh_tags)
                 else:
-                    for k, t in zip(cached, tags):
-                        ct, cv = self._cache[k]
-                        if t == ct:
-                            fresh[k] = cv
-                            fresh_tags[k] = ct
+                    self._validate_tags(cached, tags, fresh, fresh_tags)
             except Exception as e:  # validation trouble => plain full fetch
                 log.debug("tag validation failed (%s); full refetch", e)
 
@@ -1405,13 +1452,13 @@ class DDSRestServer:
         # re-read — but the newer tag is reported by the audited read
         # itself, so it is corroborated by an independent re-read before
         # being exempted from the flush.
-        audit = random.sample(
-            sorted(fresh), min(self.cfg.aggregate_cache_audit, len(fresh))
-        )
-        stale = [k for k in keys if k not in fresh or k in audit]
-        results = await asyncio.gather(
-            *(self._fetch_tagged(k) for k in stale), return_exceptions=True
-        )
+        with tracer.span("assembly.pick_stale", k=len(keys)) as pm:
+            audit = random.sample(
+                sorted(fresh), min(self.cfg.aggregate_cache_audit, len(fresh))
+            )
+            stale = [k for k in keys if k not in fresh or k in audit]
+            pm["stale"], pm["audit"] = len(stale) - len(audit), len(audit)
+        results = await self._reread(stale, len(audit)) if stale else []
         fetched = {}
         for k, r in zip(stale, results):
             if isinstance(r, Exception):
@@ -1431,24 +1478,22 @@ class DDSRestServer:
             self._flush_cache()
             fresh.clear()  # serve only quorum-read data this round
             remaining = [k for k in keys if k not in fetched]
-            more = await asyncio.gather(
-                *(self._fetch_tagged(k) for k in remaining),
-                return_exceptions=True,
-            )
+            more = await self._reread(remaining, 0)
             for k, r in zip(remaining, more):
                 if isinstance(r, Exception):
                     raise r
                 fetched[k] = r
-        out = []
-        for k in keys:
-            v = fetched[k][0] if k in fetched else fresh[k]
-            if v is not None:
-                out.append((k, v))
-        # memoize the materialized pairs only if the (stored, cache) state
-        # did not move while this round was in flight — the next fully-
-        # unchanged round can then serve `out` after audit alone
-        if (self._stored_version, self._cache_version) == state:
-            self._pairs_memo = (state, out)
+        with tracer.span("assembly.pairs", k=len(keys)):
+            out = []
+            for k in keys:
+                v = fetched[k][0] if k in fetched else fresh[k]
+                if v is not None:
+                    out.append((k, v))
+            # memoize the materialized pairs only if the (stored, cache)
+            # state did not move while this round was in flight — the next
+            # fully-unchanged round can then serve `out` after audit alone
+            if (self._stored_version, self._cache_version) == state:
+                self._pairs_memo = (state, out)
         return out
 
     async def _audit_verdict(
@@ -1504,9 +1549,7 @@ class DDSRestServer:
         if not audit:
             return True
         pre = {k: self._cache[k] for k in audit}
-        results = await asyncio.gather(
-            *(self._fetch_tagged(k) for k in audit), return_exceptions=True
-        )
+        results = await self._reread(audit, len(audit))
         fetched = {}
         for k, r in zip(audit, results):
             if isinstance(r, Exception):
@@ -1649,9 +1692,10 @@ class DDSRestServer:
             with tracer.span(f"http.{req.method}.{route or 'root'}", _ctx=ctx):
                 if adm_ms is not None:
                     # decided before the trace root existed — backdate it
-                    # into the tree as the admission stage
+                    # into the tree as the admission stage, at its true end
                     tracer.record("proxy.admission", adm_ms,
-                                  _ctx=obs_context.child())
+                                  _ctx=obs_context.child(),
+                                  _t_end=t_adm + adm_ms / 1e3)
                 resp = await self._route(req)
             status = resp.status
             return resp
@@ -2094,15 +2138,13 @@ class DDSRestServer:
 
             case ("GET", "_trace") if self.cfg.trace_route_enabled:
                 # live observability (SURVEY §5.5): per-span timing summary
-                # (count/total/mean/p50/p95 ms) from utils/trace, counters
-                # under their OWN key (they are occurrences, not durations —
-                # mixing them into span counts skewed every mean/percentile).
+                # (count/total/mean/p50/p95 ms) from utils/trace; occurrence
+                # counts are obs.metrics' (GET /metrics).
                 # Config-gated (reveals workload shape); no ciphertexts or
                 # keys leave — span metadata is aggregate timing only.
                 return Response.json(
                     {
                         "spans": tracer.summary(),
-                        "counters": tracer.counters(),
                         "stored_keys": len(self.stored_keys),
                     }
                 )
@@ -2423,14 +2465,17 @@ class DDSRestServer:
         pos = self._pos(req)
         mod = req.query.get(modparam)
         pairs = await self._fetch_visible()
-        memo = self._operand_memo
-        if memo is not None and memo[0] is pairs and memo[1] == pos:
-            # identity match: _fetch_stored returned its memoized pairs
-            # list, so the extracted column is unchanged too
-            operands = memo[2]
-        else:
-            operands = [int(v[pos]) for _, v in pairs if pos < len(v)]
-            self._operand_memo = (pairs, pos, operands)
+        with tracer.span("assembly.operands") as om:
+            memo = self._operand_memo
+            hit = memo is not None and memo[0] is pairs and memo[1] == pos
+            if hit:
+                # identity match: _fetch_stored returned its memoized pairs
+                # list, so the extracted column is unchanged too
+                operands = memo[2]
+            else:
+                operands = [int(v[pos]) for _, v in pairs if pos < len(v)]
+                self._operand_memo = (pairs, pos, operands)
+            om["k"], om["memo"] = len(operands), hit
         if not operands:
             return Response(404)
         metrics.observe(
@@ -2629,7 +2674,7 @@ class DDSRestServer:
         ):
             self._folds_inflight += 1
             try:
-                return await asyncio.to_thread(fold, operands, modulus)
+                return await self._fold_on_worker(fold, operands, modulus)
             finally:
                 self._folds_inflight -= 1
         loop = asyncio.get_running_loop()
@@ -2644,6 +2689,24 @@ class DDSRestServer:
             self._fold_drainer = supervised_task(self._drain_folds(),
                                                  name="proxy.fold_drainer")
         return await fut
+
+    @staticmethod
+    async def _fold_on_worker(fold, operands: list[int], modulus: int):
+        """`fold` on a worker thread, with the two waits of the hop as
+        spans: for a free thread (`dispatch.thread_wait`, recorded by the
+        worker at its first line; `to_thread` copies the context, so it
+        lands in the request's tree) and for the event loop to take the
+        coroutine up again once the worker returned
+        (`dispatch.resume_wait`)."""
+        t_call = time.perf_counter()
+        t_done = [t_call]
+        try:
+            return await asyncio.to_thread(
+                _fold_after_wait, fold, operands, modulus, t_call, t_done)
+        finally:
+            t_back = time.perf_counter()
+            tracer.record("dispatch.resume_wait", (t_back - t_done[0]) * 1e3,
+                          _ctx=obs_context.child(), _t_end=t_back)
 
     def _coalesce_window(self) -> float:
         """The gather window for this drain cycle: adaptive (sized from

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import logging
 import os
 import threading
@@ -63,6 +64,7 @@ log = logging.getLogger("dds.chronoscope")
 # the downstream calls) is the "response" stage.
 STAGES = (
     "admission",                # backpressure decision at the front door
+    "assemble",                 # aggregate operand assembly (memo-miss work)
     "coalesce-wait",            # sat in the proxy fold coalescer window
     "serialize",                # message -> wire frame (+ MAC/sig)
     "quorum-rtt",               # ABD round: on the wire + remote queueing
@@ -74,8 +76,11 @@ STAGES = (
     "tier-demote",              # Stratum eviction: HBM -> warm -> segments
     "tier-cold-read",           # segment read + HMAC re-verify from disk
     "trace-compile",            # one-time jit trace+compile (cold call)
+    "queue-wait",               # fold waited for a worker thread / the loop
     "dispatch",                 # host-side dispatch orchestration
     "device-execute",           # on-device kernel time
+    "device-to-host",           # result copy back + limb decode
+    "runtime",                  # event loop held / collector pause
     "response",                 # proxy host work around the calls
     "other",                    # unclassified — counts against coverage
 )
@@ -85,6 +90,11 @@ _EPS = 1e-9
 
 def classify(name: str, *, root: bool = False) -> str:
     """Map a span name to its pipe stage (see STAGES)."""
+    return _stage_of(name)
+
+
+@functools.lru_cache(maxsize=1024)   # route names come off the wire: bounded
+def _stage_of(name: str) -> str:
     if name == "proxy.admission":
         return "admission"
     if name == "proxy.coalesce_wait":
@@ -97,8 +107,20 @@ def classify(name: str, *, root: bool = False) -> str:
         return "quorum-rtt"
     if name == "ingest.queue_wait":
         return "ingest-queue-wait"
-    if name == "ingest.h2d":
+    if name == "ingest.h2d" or name.startswith("residency."):
         return "host-to-device-transfer"
+    if name.startswith("assembly."):
+        return "assemble"
+    if name in ("dispatch.thread_wait", "dispatch.resume_wait"):
+        return "queue-wait"
+    if name == "dispatch.d2h":
+        return "device-to-host"
+    if name.startswith("dispatch."):
+        return "dispatch"
+    if name == "xla.compile":
+        return "trace-compile"
+    if name.startswith("runtime."):
+        return "runtime"
     if name == "tier.promote":
         return "tier-promote"
     if name == "tier.demote":
@@ -128,8 +150,12 @@ class _Node:
 
     def __init__(self, rec: SpanRecord):
         self.rec = rec
-        self.end = rec.ts
-        self.start = rec.ts - max(0.0, rec.dur_ms) / 1e3
+        # placed by the span's true end on perf_counter (utils/trace's
+        # rule); records stitched from other hosts carry only `ts`, and a
+        # stitched tree is rebuilt from wire dicts as a whole, so one tree
+        # never mixes the two clocks
+        self.end = rec.end
+        self.start = self.end - max(0.0, rec.dur_ms) / 1e3
         self.children: list["_Node"] = []
         self.events: list[SpanRecord] = []
 
@@ -153,10 +179,26 @@ def _build_nodes(records: Iterable[SpanRecord]):
     return nodes, order, events
 
 
+def _subtree(kids: dict, root: SpanRecord) -> list:
+    """`root` and every record below it, by a parent -> children index."""
+    out, stack = [root], [root.span_id]
+    while stack:
+        for r in kids.get(stack.pop(), ()):
+            out.append(r)
+            if r.kind == "span" and r.span_id is not None:
+                stack.append(r.span_id)
+    return out
+
+
 def critical_path(records: Iterable[SpanRecord], *,
                   root_span_id: Optional[str] = None,
-                  orphans_to_root: bool = True) -> Optional[dict]:
+                  orphans_to_root: bool = True,
+                  with_path: bool = True) -> Optional[dict]:
     """Extract the blocking chain and per-stage self-times of one trace.
+
+    `with_path=False` leaves the waterfall (`path`) out and sums the
+    stages only: what every trace needs, where the waterfall is wanted of
+    the few that become exemplars.
 
     Without `root_span_id` the longest parent-less span wins the root.
     With `orphans_to_root`, spans whose parent never arrived (Panopticon
@@ -191,7 +233,7 @@ def critical_path(records: Iterable[SpanRecord], *,
             holder.events.append(ev)
 
     stages: dict[str, float] = {}
-    path: list[dict] = []
+    path: Optional[list] = [] if with_path else None
     _attribute(root, root.start, root.end, 0, stages, path, root.start)
     wall_ms = (root.end - root.start) * 1e3
     named = sum(v for k, v in stages.items() if k != "other")
@@ -201,12 +243,12 @@ def critical_path(records: Iterable[SpanRecord], *,
         "wall_ms": round(wall_ms, 3),
         "coverage": round(min(1.0, named / wall_ms), 4) if wall_ms else 1.0,
         "stages": {k: round(v, 3) for k, v in stages.items() if v > 0},
-        "path": path,
+        "path": path if with_path else [],
     }
 
 
 def _attribute(node: _Node, w_start: float, w_end: float, depth: int,
-               stages: dict, path: list, t0: float) -> None:
+               stages: dict, path: Optional[list], t0: float) -> None:
     """Claim non-overlapping child windows back-to-front inside
     [w_start, w_end]; the unclaimed remainder is this node's self-time.
     Overlapping siblings keep only the tail the later-ending one left
@@ -224,22 +266,23 @@ def _attribute(node: _Node, w_start: float, w_end: float, depth: int,
     self_s = max(0.0, window - sum(e - s for _, s, e in claimed))
     stage = classify(node.rec.name, root=depth == 0)
     stages[stage] = stages.get(stage, 0.0) + self_s * 1e3
-    entry = {
-        "name": node.rec.name,
-        "stage": stage,
-        "depth": depth,
-        "start_ms": round((w_start - t0) * 1e3, 3),
-        "dur_ms": round(window * 1e3, 3),
-        "self_ms": round(self_s * 1e3, 3),
-    }
-    if node.rec.meta:
-        entry["meta"] = dict(node.rec.meta)
-    if node.events:
-        entry["events"] = [
-            {"name": ev.name, **({"meta": ev.meta} if ev.meta else {})}
-            for ev in node.events[:8]
-        ]
-    path.append(entry)
+    if path is not None:
+        entry = {
+            "name": node.rec.name,
+            "stage": stage,
+            "depth": depth,
+            "start_ms": round((w_start - t0) * 1e3, 3),
+            "dur_ms": round(window * 1e3, 3),
+            "self_ms": round(self_s * 1e3, 3),
+        }
+        if node.rec.meta:
+            entry["meta"] = dict(node.rec.meta)
+        if node.events:
+            entry["events"] = [
+                {"name": ev.name, **({"meta": ev.meta} if ev.meta else {})}
+                for ev in node.events[:8]
+            ]
+        path.append(entry)
     if depth >= 64:
         return
     for c, s, e in reversed(claimed):  # chronological order
@@ -360,12 +403,15 @@ class Chronoscope:
                     return
                 buf = self._traces.get(tid)
                 if buf is None:
-                    buf = self._traces[tid] = {"records": [], "roots": set()}
+                    buf = self._traces[tid] = {"records": [], "roots": set(),
+                                               "kids": {}}
                     while len(self._traces) > self.MAX_TRACES:
                         self._traces.popitem(last=False)
                         self.traces_evicted += 1
                 if len(buf["records"]) < self.MAX_TRACE_SPANS:
                     buf["records"].append(rec)
+                    if rec.parent_id is not None:
+                        buf["kids"].setdefault(rec.parent_id, []).append(rec)
             if rec.kind != "span":
                 return
             if rec.parent_id is None and rec.name.startswith("http."):
@@ -382,11 +428,11 @@ class Chronoscope:
                     if buf is None:
                         return
                     buf["roots"].add(rec.span_id)
-                    records = list(buf["records"])
-                res = critical_path(records, root_span_id=rec.span_id,
-                                    orphans_to_root=False)
-                if res is not None:
-                    self._absorb(res)
+                    # its own subtree only (children record before their
+                    # parent): a handler's analysis must not grow with
+                    # the rest of the request's trace
+                    records = _subtree(buf["kids"], rec)
+                self._profile(records, rec.span_id, orphans_to_root=False)
         except Exception:  # noqa: BLE001 — observers never break observed paths
             log.exception("chronoscope ingest failed")
 
@@ -407,20 +453,29 @@ class Chronoscope:
             and r.name.startswith("http.")
         ]
         for root in roots:
-            res = critical_path(records, root_span_id=root.span_id)
-            if res is not None:
-                self._absorb(res)
+            self._profile(records, root.span_id, orphans_to_root=True)
         for r in records:
             if (r.kind == "span" and r.name == "replica.handle"
                     and r.span_id not in done_roots):
-                res = critical_path(records, root_span_id=r.span_id,
-                                    orphans_to_root=False)
-                if res is not None:
-                    self._absorb(res)
+                self._profile(records, r.span_id, orphans_to_root=False)
+
+    def _profile(self, records: list, root_span_id: str, *,
+                 orphans_to_root: bool) -> None:
+        """Stage sums of one (sub)tree into the aggregates; the waterfall
+        is built only if the trace is kept as an exemplar."""
+        res = critical_path(records, root_span_id=root_span_id,
+                            orphans_to_root=orphans_to_root, with_path=False)
+        if res is not None:
+            self._absorb(res, lambda: critical_path(
+                records, root_span_id=root_span_id,
+                orphans_to_root=orphans_to_root))
 
     # ---------------------------------------------------------- aggregation
 
-    def _absorb(self, res: dict) -> None:
+    def _absorb(self, res: dict, with_waterfall=None) -> None:
+        """Fold one trace's stage sums in. `with_waterfall()` gives the
+        same result with its `path`, for the trace that is kept as an
+        exemplar (`res` itself is kept when it is not given)."""
         route, wall = res["route"], res["wall_ms"]
         if wall <= 0:
             return
@@ -470,6 +525,8 @@ class Chronoscope:
                 st["ex_start"] = now
             cur = st["ex_cur"]
             if len(cur) < self.exemplars or wall > cur[-1][0]:
+                if with_waterfall is not None:
+                    res = with_waterfall() or res
                 cur.append((wall, res))
                 cur.sort(key=lambda t: -t[0])
                 del cur[self.exemplars:]

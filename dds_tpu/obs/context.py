@@ -23,7 +23,7 @@ collision-safe for a per-process ring of 64k spans.
 from __future__ import annotations
 
 import contextvars
-import secrets
+import random
 from dataclasses import dataclass
 from typing import Optional
 
@@ -46,7 +46,9 @@ _current: contextvars.ContextVar[Optional[SpanContext]] = contextvars.ContextVar
 
 
 def new_id() -> str:
-    return secrets.token_hex(8)
+    # telemetry labels, not secrets: the module's generator (seeded from
+    # the OS, reseeded in a forked child) costs a third of an OS read
+    return f"{random.getrandbits(64):016x}"
 
 
 def current() -> Optional[SpanContext]:

@@ -5,7 +5,7 @@ opening, a request budget exhausting (`DeadlineExceededError`), a
 Trudy/Nemesis attack firing — the in-memory telemetry that explains it is
 about to be overwritten by the span ring. The flight recorder freezes it:
 one JSONL incident file per fault with a header record (fault kind, info,
-live counters, span summary) followed by the faulting trace's full span
+span summary) followed by the faulting trace's full span
 tree and the tail of the span ring. Every chaos-suite failure becomes
 self-describing instead of un-reproducible.
 
@@ -144,7 +144,6 @@ class FlightRecorder:
             "trace_id": trace_id,
             **self.identity,
             "info": info,
-            "counters": tracer.counters(),
             "summary": tracer.summary(),
             "trace_spans": len(faulting),
             "ring_tail": len(tail),

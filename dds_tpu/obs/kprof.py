@@ -5,7 +5,9 @@ from per-phase bottleneck accounting, and GPU HE accelerators (GME, arxiv
 2309.11001) report compile-vs-execute splits per kernel. JAX hides the
 boundary: calling a jitted fn returns as soon as the work is ENQUEUED
 (having traced+compiled first on a cache miss), and only
-`block_until_ready` exposes device time. `profiled()` separates the two
+`block_until_ready` waits for the device (the host's wait, which holds
+the device's time plus queueing and the wake-up; device time proper
+comes from a profiler trace). `profiled()` separates the two
 into distinct tracer spans and metrics histograms; `cache_event`/`counted`
 account compile-cache hits vs misses for the manual dict caches
 (ops/foldmany) and `functools.lru_cache`d builders (ops/mont_mxu).
@@ -17,6 +19,17 @@ records as `kernel.<name>.compile` INSTEAD of `.dispatch` — so dispatch
 stats stay warm-only and Chronoscope's dispatch stage is never polluted
 by one-time trace+compile time (which gets its own trace-compile stage).
 
+Each phase is recorded at its own end (the tracer's rule): the host phase
+at the instant the call returned, before `block_until_ready`, the execute
+phase after it. A subscriber therefore never sees the dispatch phase
+drawn over the execute phase.
+
+`kernel.*.compile` counts the kernels' own builder caches and cannot see
+XLA compilations made elsewhere (the pool's small gather and placement
+programs). The first `profiled()` call therefore registers ONE
+`jax.monitoring` listener for `backend_compile_duration`: every XLA
+compilation of the process becomes an `xla.compile` span under the
+request that was compiling, and counts in `dds_xla_compile_total`.
 
 `kernel_summary()` condenses both for benchmark records
 (benchmarks/common.emit attaches it to every row in results.json).
@@ -31,7 +44,8 @@ from dds_tpu.obs import context as obs_context
 from dds_tpu.obs.metrics import metrics
 from dds_tpu.utils.trace import tracer
 
-__all__ = ["cache_event", "counted", "profiled", "kernel_summary", "reset"]
+__all__ = ["cache_event", "counted", "profiled", "kernel_summary", "reset",
+           "watch_xla_compiles"]
 
 _lock = threading.Lock()
 _cache_stats: dict[str, list[int]] = {}  # cache name -> [hits, misses]
@@ -39,6 +53,33 @@ _cache_stats: dict[str, list[int]] = {}  # cache name -> [hits, misses]
 # caches fire BEFORE the dispatch (the builder returns the jitted fn),
 # so the miss is remembered until the matching kernel dispatches
 _pending_compile: set[str] = set()
+_xla_listener = False   # the jax.monitoring listener is registered once
+
+
+def _on_jax_duration(name: str, seconds: float, **_kw) -> None:
+    if not name.endswith("backend_compile_duration"):
+        return
+    cur = obs_context.current()
+    tracer.record(
+        "xla.compile", seconds * 1e3,
+        _ctx=obs_context.child(cur) if cur is not None else None,
+    )
+    metrics.inc("dds_xla_compile_total",
+                help="XLA compilations of this process, as jax reports them")
+
+
+def watch_xla_compiles() -> None:
+    """Register the listener, once. Lazily, by whoever is about to use
+    jax (`profiled()`, a resident pool): replicas never import it, and
+    jax keeps a listener for the life of the process."""
+    global _xla_listener
+    with _lock:
+        if _xla_listener:
+            return
+        _xla_listener = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def cache_event(cache: str, hit: bool) -> None:
@@ -66,8 +107,8 @@ def counted(cache: str, lru_fn, *args):
 
 def profiled(kernel: str, dispatch, **meta):
     """Run `dispatch()` (enqueue device work, return jax arrays) and time
-    its two phases separately: the host-side call and the
-    `block_until_ready` device execution. A cold call — its builder cache
+    its two phases separately: the host-side call and the host's wait
+    in `block_until_ready`. A cold call — its builder cache
     missed (by name) since the last dispatch, or any cache miss landed
     DURING the dispatch window — records its host phase as
     `kernel.<name>.compile`; warm calls record `.dispatch`. Both pair
@@ -75,6 +116,8 @@ def profiled(kernel: str, dispatch, **meta):
     the (ready) dispatch result."""
     import jax
 
+    if not _xla_listener:
+        watch_xla_compiles()
     with _lock:
         compiled = kernel in _pending_compile
         _pending_compile.discard(kernel)
@@ -82,8 +125,6 @@ def profiled(kernel: str, dispatch, **meta):
     t0 = time.perf_counter()
     out = dispatch()
     t1 = time.perf_counter()
-    jax.block_until_ready(out)
-    t2 = time.perf_counter()
     with _lock:
         compiled = compiled or (
             sum(m for _, m in _cache_stats.values()) > misses0
@@ -93,11 +134,13 @@ def profiled(kernel: str, dispatch, **meta):
     cur = obs_context.current()
     phase = "compile" if compiled else "dispatch"
     tracer.record(
-        f"kernel.{kernel}.{phase}", (t1 - t0) * 1e3,
+        f"kernel.{kernel}.{phase}", (t1 - t0) * 1e3, _t_end=t1,
         _ctx=obs_context.child(cur) if cur is not None else None, **meta,
     )
+    jax.block_until_ready(out)
+    t2 = time.perf_counter()
     tracer.record(
-        f"kernel.{kernel}.execute", (t2 - t1) * 1e3,
+        f"kernel.{kernel}.execute", (t2 - t1) * 1e3, _t_end=t2,
         _ctx=obs_context.child(cur) if cur is not None else None, **meta,
     )
     if compiled:
@@ -113,7 +156,8 @@ def profiled(kernel: str, dispatch, **meta):
         )
     metrics.observe(
         "dds_kernel_execute_seconds", t2 - t1, kernel=kernel,
-        help="device execute time (block_until_ready)",
+        help="host wait in block_until_ready after the dispatch returned "
+             "(queueing, execution and the wake-up; not device time)",
     )
     return out
 

@@ -83,6 +83,7 @@ class ResidentPool:
     _count: int = field(init=False, default=0, repr=False)
 
     def __post_init__(self):
+        kprof.watch_xla_compiles()   # the pool's own small programs too
         self._ctx = ModCtx.make(self.modulus)
         if self.reduce is None:
             self.reduce = self._ctx.reduce_mul
@@ -327,11 +328,13 @@ class ResidentPool:
         with self._lock:
             return [c in self._index for c in cs]
 
-    def ensure(self, cs: list[int], pre: dict | None = None) -> np.ndarray | None:
+    def ensure(self, cs: list[int], pre: dict | None = None,
+               path: str = "fold") -> np.ndarray | None:
         """Ingest any unseen ciphertexts; return row indices for all of cs.
         Caller must hold `_lock`. `pre` optionally maps ciphertext -> already
         limb-converted row (fold() precomputes these OUTSIDE the lock so the
-        CPU-heavy conversion never serializes concurrent folds).
+        CPU-heavy conversion never serializes concurrent folds). The
+        placement is one `ingest.h2d` span (`path` says who asked).
 
         Returns None when the distinct operands cannot fit even after a
         reset (aggregate wider than max_rows) — callers fall back to a
@@ -353,9 +356,16 @@ class ResidentPool:
                     [c % self.modulus for c in missing], self._ctx.L
                 )
             start = self._count
-            self._buf = self._place(jax.lax.dynamic_update_slice(
-                self._buf, jnp.asarray(rows), (start, 0)
-            ))
+            moved = len(missing) * self._ctx.L * 4
+            with tracer.span("ingest.h2d", path=path, rows=len(missing),
+                             bytes=moved, shard=self.gid or "-"):
+                self._buf = self._place(jax.lax.dynamic_update_slice(
+                    self._buf, jnp.asarray(rows), (start, 0)
+                ))
+            metrics.inc(
+                "dds_ingest_h2d_bytes_total", moved, shard=self.gid or "-",
+                help="bytes placed into device-resident pools (rows*L*4)",
+            )
             for i, c in enumerate(missing):
                 self._index[c] = start + i
             self._count += len(missing)
@@ -374,28 +384,14 @@ class ResidentPool:
             [c % self.modulus for c in missing], self._ctx.L
         )
         pre = {c: converted[i] for i, c in enumerate(missing)}
-        t_h2d = time.perf_counter()
         with self._lock:
             missing_now = [c for c in missing if c not in self._index]
-            self.ensure(missing, pre)
+            self.ensure(missing, pre, path="write")
             # count placements, not the buffer delta: an eviction wave in
             # the same ensure() can shrink _count while rows still land
             grew = sum(1 for c in missing_now if c in self._index)
         self._flush_spill()
         if grew:
-            # Chronoscope's host-to-device-transfer stage + bytes-moved
-            # accounting: each placed row is L limbs of 4 bytes on device
-            moved = grew * self._ctx.L * 4
-            cur = obs_context.current()
-            tracer.record(
-                "ingest.h2d", (time.perf_counter() - t_h2d) * 1e3,
-                _ctx=obs_context.child(cur) if cur is not None else None,
-                rows=grew, bytes=moved, shard=self.gid or "-",
-            )
-            metrics.inc(
-                "dds_ingest_h2d_bytes_total", moved, shard=self.gid or "-",
-                help="bytes placed into device-resident pools (rows*L*4)",
-            )
             metrics.inc(
                 "dds_resident_ingest_total", grew, shard=self.gid or "-",
                 path="write",
@@ -434,38 +430,56 @@ class ResidentPool:
         plane's fused multi-group dispatch and Prism's resident MatVec
         gather. Returns None when the distinct operands cannot fit even
         after a reset (callers fall back to direct marshaling). Accounts
-        resident/ingested operands as a side effect."""
-        with self._lock:
-            m = self._idx_memo
-            if m is not None and m[0] is cs and m[1] == self._epoch:
-                self._account(len(cs), 0, 0)
-                return self._buf, m[2]
-            missing = sorted({c for c in cs if c not in self._index})
-            if not missing:
-                idx = np.asarray(
-                    [self._index[c] for c in cs], dtype=np.int32
-                )
-                self._idx_memo = (cs, self._epoch, idx)
-                self._account(len(cs), 0, 0)
-                return self._buf, idx  # immutable jax array: safe outside
+        resident/ingested operands as a side effect.
+
+        Spans, one each per call and never per row: `residency.lookup`
+        over each of the two locked stretches (`stretch`; the wait for
+        the lock included, and named in `lock_wait_ms`), `residency.convert` over the limb
+        conversion between them, `ingest.h2d` (from `ensure`) inside the
+        second."""
+        with tracer.span("residency.lookup", k=len(cs), stretch=1) as lm:
+            t_ask = time.perf_counter()
+            with self._lock:
+                lm["lock_wait_ms"] = (time.perf_counter() - t_ask) * 1e3
+                m = self._idx_memo
+                lm["memo"] = (m is not None and m[0] is cs
+                              and m[1] == self._epoch)
+                if lm["memo"]:
+                    lm["missing"] = 0
+                    self._account(len(cs), 0, 0)
+                    return self._buf, m[2]
+                missing = sorted({c for c in cs if c not in self._index})
+                lm["missing"] = len(missing)
+                if not missing:
+                    idx = np.asarray(
+                        [self._index[c] for c in cs], dtype=np.int32
+                    )
+                    self._idx_memo = (cs, self._epoch, idx)
+                    self._account(len(cs), 0, 0)
+                    return self._buf, idx  # immutable jax array: safe outside
         # limb-convert the unseen operands OUTSIDE the lock (the
         # CPU-heavy part); placement/index update stays serialized.
         # Entries are only ever added, so `missing` can only shrink in
         # between; ensure() recomputes it under the lock (and converts
         # inline in the rare capacity-reset case where `pre` is short).
-        converted = bn.ints_to_batch(
-            [c % self.modulus for c in missing], self._ctx.L
-        )
-        pre = {c: converted[i] for i, c in enumerate(missing)}
-        with self._lock:
-            idx = self.ensure(cs, pre)
-            if idx is None:
-                self._account(0, 0, len(cs))
-                out = None
-            else:
-                self._idx_memo = (cs, self._epoch, idx)
-                self._account(len(cs) - len(missing), len(missing), 0)
-                out = (self._buf, idx)
+        with tracer.span("residency.convert", rows=len(missing)):
+            converted = bn.ints_to_batch(
+                [c % self.modulus for c in missing], self._ctx.L
+            )
+            pre = {c: converted[i] for i, c in enumerate(missing)}
+        with tracer.span("residency.lookup", k=len(cs), stretch=2,
+                         memo=False, missing=len(missing)) as lm:
+            t_ask = time.perf_counter()
+            with self._lock:
+                lm["lock_wait_ms"] = (time.perf_counter() - t_ask) * 1e3
+                idx = self.ensure(cs, pre)
+                if idx is None:
+                    self._account(0, 0, len(cs))
+                    out = None
+                else:
+                    self._idx_memo = (cs, self._epoch, idx)
+                    self._account(len(cs) - len(missing), len(missing), 0)
+                    out = (self._buf, idx)
         # deliver any eviction wave to the tier sink outside the lock
         self._flush_spill()
         return out
@@ -484,12 +498,14 @@ class ResidentPool:
             resident = False
         else:
             buf, idx = got
-            rows = jnp.take(buf, jnp.asarray(idx), axis=0)
+            with tracer.span("dispatch.gather", k=len(cs)):
+                rows = jnp.take(buf, jnp.asarray(idx), axis=0)
             resident = True
         with tracer.span("kernel.fold", k=len(cs), resident=resident):
-            # dispatch (trace/compile) timed apart from block_until_ready
-            # device execution (obs/kprof) — the split the flat span hid
+            # dispatch (trace/compile) timed apart from the wait in
+            # block_until_ready (obs/kprof) — the split the flat span hid
             out = kprof.profiled(
                 "store.reduce", lambda: self.reduce(rows), k=len(cs),
             )
-            return bn.limbs_to_int(np.asarray(out)[0])
+            with tracer.span("dispatch.d2h"):
+                return bn.limbs_to_int(np.asarray(out)[0])

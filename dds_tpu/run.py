@@ -80,6 +80,9 @@ class Deployment:
 
         chronoscope.detach()
         chronoscope.reset()
+        from dds_tpu.obs import runtime
+
+        runtime.remove_gc()
 
 
 def _log_backend(server: DDSRestServer) -> None:
@@ -558,6 +561,11 @@ async def launch(cfg: DDSConfig | None = None) -> Deployment:
     from dds_tpu.obs.chronoscope import chronoscope
 
     chronoscope.attach()
+    # the collector's pauses: noted lock-free, drained by the proxy's loop
+    # sampler into dds_gc_pause_seconds_total and runtime.gc spans
+    from dds_tpu.obs import runtime
+
+    runtime.install_gc()
     return dep
 
 

@@ -1,4 +1,4 @@
-"""Structured tracing: causally-linked spans + point events + counters.
+"""Structured tracing: causally-linked spans + point events.
 
 The reference's only observability is three debug flags gating `println`s
 and a client ops/s printout (SURVEY.md §5.1, `dds-system.conf:61-62`,
@@ -10,6 +10,17 @@ tree — HTTP route -> quorum round -> per-replica handler -> crypto kernel —
 instead of an anonymous flat ring. Point `event`s (chaos injections, retry
 attempts, breaker transitions, attacks) annotate the same tree with zero
 duration. Overhead is one perf_counter pair and a deque append per span.
+Counts of occurrences live in `obs.metrics`, not here.
+
+One clock, true placement. A record carries two ends: `ts` on the wall
+clock (for people and for cross-host stitching) and `t_end` on
+`time.perf_counter()`, the clock that can be mapped onto a device trace;
+`tid` is the thread that recorded it. The rule every caller keeps: a span
+is recorded WHEN IT ENDS, or passes its true end — a span measured after
+the fact (`record(name, dur_ms, _t_end=...)`) hands over the
+`perf_counter` instant at which it ended, and both ends are set back by
+the same amount. Subscribers can therefore place a span at
+`[t_end - dur_ms, t_end]` without knowing who recorded it.
 
 Usage:
 
@@ -17,16 +28,14 @@ Usage:
     with tracer.span("abd.fetch", key=key) as meta:
         meta["coordinator"] = coord      # annotate mid-span
     tracer.event("breaker.open", target=coord)
-    tracer.count("abd.suspect")
-    print(tracer.summary())              # span stats only
-    print(tracer.counters())             # counters, separately
+    tracer.record("proxy.admission", ms, _t_end=t_decided)   # backdated
+    print(tracer.summary())              # span stats
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
-import json
 import logging
 import math
 import os
@@ -48,6 +57,39 @@ class SpanRecord:
     span_id: Optional[str] = None
     parent_id: Optional[str] = None
     kind: str = "span"  # "span" (timed) | "event" (zero-duration annotation)
+    # the span's end on time.perf_counter() and the recording thread; None
+    # on records rebuilt from another process's wire dicts (their clock is
+    # not ours), which are placed by `ts`
+    t_end: Optional[float] = None
+    tid: Optional[int] = None
+
+    @property
+    def end(self) -> float:
+        """Where the span ends, on the best clock the record has."""
+        return self.t_end if self.t_end is not None else self.ts
+
+
+class _Span:
+    """`Tracer.span`'s context manager: a class, not a generator, because
+    a served request opens a few dozen of them."""
+
+    __slots__ = ("_tracer", "_name", "_ctx", "_meta", "_token", "_t0")
+
+    def __init__(self, tracer, name, ctx, meta):
+        self._tracer, self._name, self._ctx, self._meta = (
+            tracer, name, ctx, meta)
+
+    def __enter__(self) -> dict:
+        self._token = obs_context.attach(self._ctx)
+        self._t0 = time.perf_counter()
+        return self._meta
+
+    def __exit__(self, *_exc) -> bool:
+        t1 = time.perf_counter()
+        obs_context.detach(self._token)
+        self._tracer.record(self._name, (t1 - self._t0) * 1e3,
+                            _ctx=self._ctx, _t_end=t1, **self._meta)
+        return False
 
 
 def _percentile(sorted_durs: list[float], q: float) -> float:
@@ -64,14 +106,12 @@ class Tracer:
     max_events: int = 65536
     enabled: bool = True
     _events: collections.deque = field(init=False, repr=False)
-    _counters: collections.Counter = field(init=False, repr=False)
     _lock: threading.Lock = field(init=False, repr=False)
     _subscribers: list = field(init=False, repr=False)
     _notifying: threading.local = field(init=False, repr=False)
 
     def __post_init__(self):
         self._events = collections.deque(maxlen=self.max_events)
-        self._counters = collections.Counter()
         self._lock = threading.Lock()
         self._subscribers = []
         self._notifying = threading.local()
@@ -109,29 +149,25 @@ class Tracer:
         finally:
             self._notifying.active = False
 
-    @contextlib.contextmanager
     def span(self, name: str, /, _ctx: Optional[obs_context.SpanContext] = None,
              **meta):
-        """Timed span. Yields the (mutable) meta dict so callers can
-        annotate facts learned mid-span (the chosen coordinator, a batch
-        size). Installs a child trace context for the duration, so spans
-        recorded inside — including ones in tasks spawned inside (asyncio
-        copies contextvars at task creation) — become children."""
+        """Timed span, as a context manager. Yields the (mutable) meta
+        dict so callers can annotate facts learned mid-span (the chosen
+        coordinator, a batch size). Installs a child trace context for the
+        duration, so spans recorded inside — including ones in tasks
+        spawned inside (asyncio copies contextvars at task creation) —
+        become children."""
         if not self.enabled:
-            yield meta
-            return
-        ctx = _ctx if _ctx is not None else obs_context.child()
-        token = obs_context.attach(ctx)
-        t0 = time.perf_counter()
-        try:
-            yield meta
-        finally:
-            obs_context.detach(token)
-            self.record(name, (time.perf_counter() - t0) * 1e3, _ctx=ctx, **meta)
+            return contextlib.nullcontext(meta)
+        return _Span(self, name,
+                     _ctx if _ctx is not None else obs_context.child(), meta)
 
     def record(self, name: str, dur_ms: float, /,
                _ctx: Optional[obs_context.SpanContext] = None,
-               _kind: str = "span", **meta) -> None:
+               _kind: str = "span", _t_end: Optional[float] = None,
+               **meta) -> None:
+        """Record a span that ends now, or, with `_t_end` (an instant of
+        `time.perf_counter()`), one that ended then."""
         if not self.enabled:
             return
         ctx = _ctx if _ctx is not None else obs_context.current()
@@ -139,7 +175,13 @@ class Tracer:
             (ctx.trace_id, ctx.span_id, ctx.parent_id) if ctx is not None
             else (None, None, None)
         )
-        rec = SpanRecord(time.time(), name, dur_ms, meta, tid, sid, pid, _kind)
+        ts = time.time()
+        if _t_end is None:
+            _t_end = time.perf_counter()
+        else:
+            ts -= max(0.0, time.perf_counter() - _t_end)
+        rec = SpanRecord(ts, name, dur_ms, meta, tid, sid, pid, _kind,
+                         _t_end, threading.get_ident())
         with self._lock:
             self._events.append(rec)
         if self._subscribers:
@@ -156,12 +198,6 @@ class Tracer:
         ctx = obs_context.child(cur) if cur is not None else None
         self.record(name, 0.0, _ctx=ctx, _kind="event", **meta)
 
-    def count(self, name: str, n: int = 1) -> None:
-        if not self.enabled:
-            return
-        with self._lock:
-            self._counters[name] += n
-
     # ------------------------------------------------------------- reporting
 
     def events(self, name: str | None = None) -> list[SpanRecord]:
@@ -175,15 +211,9 @@ class Tracer:
             evs = list(self._events)
         return [e for e in evs if e.trace_id == trace_id]
 
-    def counters(self) -> dict[str, int]:
-        with self._lock:
-            return dict(self._counters)
-
     def summary(self) -> dict[str, dict]:
         """Per-span-name {count, total_ms, mean_ms, p50_ms, p95_ms} over
-        TIMED spans only. Counters are a different quantity (occurrences,
-        not durations) and zero-duration events would deflate the means —
-        both are reported separately (`counters()`, the /_trace route)."""
+        TIMED spans only: zero-duration events would deflate the means."""
         groups: dict[str, list[float]] = collections.defaultdict(list)
         for e in self.events():
             if e.kind == "span":
@@ -207,6 +237,11 @@ class Tracer:
         span recorded with meta named `name`/`ts`/`dur_ms` can never
         shadow the record fields."""
         rec = {"ts": e.ts, "name": e.name, "dur_ms": e.dur_ms, "kind": e.kind}
+        if e.t_end is not None:
+            # this process's perf_counter: places the span among its
+            # neighbours of one incident file; never read across hosts
+            rec["t_end"] = e.t_end
+            rec["tid"] = e.tid
         if e.trace_id is not None:
             rec["trace_id"] = e.trace_id
             rec["span_id"] = e.span_id
@@ -215,17 +250,9 @@ class Tracer:
             rec["meta"] = e.meta
         return rec
 
-    def dump_jsonl(self, path: str) -> int:
-        evs = self.events()
-        with open(path, "w") as f:
-            for e in evs:
-                f.write(json.dumps(self.event_dict(e), default=str) + "\n")
-        return len(evs)
-
     def reset(self) -> None:
         with self._lock:
             self._events.clear()
-            self._counters.clear()
 
 
 def _default_tracer() -> Tracer:

@@ -17,14 +17,9 @@ def test_tracer_spans_and_summary():
     for _ in range(3):
         with t.span("abd.fetch", key="k"):
             pass
-    t.count("abd.suspect", 2)
     s = t.summary()
     assert s["abd.fetch"]["count"] == 3
     assert s["abd.fetch"]["p95_ms"] >= 0
-    # counters are occurrences, not durations: reported via counters(),
-    # never mixed into the span summary (PR 2 split the two)
-    assert "abd.suspect" not in s
-    assert t.counters()["abd.suspect"] == 2
     assert len(t.events("abd.fetch")) == 3
 
 
@@ -32,17 +27,17 @@ def test_tracer_disabled_records_nothing():
     t = Tracer(enabled=False)
     with t.span("x"):
         pass
-    t.count("y")
-    assert t.summary() == {}
+    t.record("y", 1.0)
+    t.event("z")
+    assert t.summary() == {} and t.events() == []
 
 
-def test_tracer_dump_jsonl(tmp_path):
+def test_tracer_event_dict_is_json_safe():
     t = Tracer()
     with t.span("a", foo=1):
         pass
-    p = tmp_path / "trace.jsonl"
-    assert t.dump_jsonl(str(p)) == 1
-    rec = json.loads(p.read_text().strip())
+    (e,) = t.events()
+    rec = json.loads(json.dumps(Tracer.event_dict(e)))
     # meta lives under its own key so span meta can never shadow the
     # record's fields (PR 2 namespaced it)
     assert rec["name"] == "a" and rec["meta"]["foo"] == 1

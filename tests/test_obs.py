@@ -51,13 +51,11 @@ def test_summary_excludes_counters_and_zero_duration_events():
     t = Tracer()
     for d in (1.0, 2.0, 3.0):
         t.record("op", d)
-    t.count("op")  # same NAME as the span family — must not inflate count
-    t.count("occurrences", 5)
+    t.event("op")  # same NAME as the span family — must not inflate count
     t.event("annotation")
     s = t.summary()
     assert s["op"]["count"] == 3 and s["op"]["mean_ms"] == 2.0
-    assert "occurrences" not in s and "annotation" not in s
-    assert t.counters() == {"op": 1, "occurrences": 5}
+    assert "annotation" not in s
 
 
 def test_percentiles_nearest_rank_small_k():
@@ -78,27 +76,31 @@ def test_thread_safety_under_concurrent_record_and_count():
     t = Tracer(max_events=100_000)
     n_threads, per = 8, 500
 
+    idents = set()
+
     def work():
+        idents.add(threading.get_ident())
         for i in range(per):
             t.record("op", float(i))
-            t.count("hits")
+            t.event("hits")
 
     threads = [threading.Thread(target=work) for _ in range(n_threads)]
     for th in threads:
         th.start()
     for th in threads:
         th.join()
-    assert t.counters()["hits"] == n_threads * per
+    assert len(t.events("hits")) == n_threads * per
     assert t.summary()["op"]["count"] == n_threads * per
+    # every record names the thread that made it
+    assert {e.tid for e in t.events("op")} == idents
 
 
-def test_dump_jsonl_namespaces_meta(tmp_path):
+def test_event_dict_namespaces_meta():
     t = Tracer()
     # hostile meta: keys that collide with the record's own fields
     t.record("real-name", 42.0, name="shadow", ts=-1, dur_ms=0.0)
-    path = tmp_path / "spans.jsonl"
-    assert t.dump_jsonl(str(path)) == 1
-    rec = json.loads(path.read_text().splitlines()[0])
+    (e,) = t.events()
+    rec = json.loads(json.dumps(Tracer.event_dict(e)))
     assert rec["name"] == "real-name" and rec["dur_ms"] == 42.0
     assert rec["meta"] == {"name": "shadow", "ts": -1, "dur_ms": 0.0}
 
@@ -397,12 +399,11 @@ def test_metrics_route_serves_parseable_prometheus_text():
     assert any(k.startswith("dds_breaker_state") for k in parsed)
 
 
-def test_trace_route_reports_counters_separately():
+def test_trace_route_reports_span_summary_only():
     async def go():
         net, server, _ = await _obs_rest_stack()
         try:
             tracer.reset()
-            tracer.count("standalone.counter", 3)
             await _call(server, "POST", "/PutSet", {"contents": ["y"]})
             status, body = await _call(server, "GET", "/_trace")
             assert status == 200
@@ -412,8 +413,8 @@ def test_trace_route_reports_counters_separately():
             await server.stop()
 
     out = run(go())
-    assert out["counters"]["standalone.counter"] == 3
-    assert "standalone.counter" not in out["spans"]
+    # occurrences are counted in obs.metrics (GET /metrics), not here
+    assert set(out) == {"spans", "stored_keys"}
     assert "http.POST.PutSet" in out["spans"]
 
 

@@ -1,0 +1,388 @@
+"""Spans placed at their true ends, inside the aggregate, round the thread
+hop and on the event loop (utils/trace, obs/kprof, obs/chronoscope,
+obs/runtime, http/server, resident/pool)."""
+
+import asyncio
+import gc
+import json
+import logging
+import threading
+import time
+
+import pytest
+
+from dds_tpu.obs import kprof, runtime
+from dds_tpu.obs.chronoscope import STAGES, classify, critical_path
+from dds_tpu.obs.metrics import metrics
+from dds_tpu.utils.trace import Tracer, tracer
+
+pytestmark = pytest.mark.obs
+
+
+# ------------------------------------------------------------- one clock
+
+
+def test_a_record_carries_its_end_on_perf_counter_and_its_thread():
+    t = Tracer()
+    before = time.perf_counter()
+    with t.span("a"):
+        time.sleep(0.002)
+    after = time.perf_counter()
+    (rec,) = t.events()
+    assert before <= rec.t_end - rec.dur_ms / 1e3 <= rec.t_end <= after
+    assert rec.tid == threading.get_ident()
+    assert rec.end == rec.t_end
+    d = json.loads(json.dumps(Tracer.event_dict(rec)))
+    assert d["t_end"] == rec.t_end and d["tid"] == rec.tid
+
+
+def test_a_span_measured_after_the_fact_is_set_back_on_both_clocks():
+    t = Tracer()
+    seen = []
+    t.subscribe(seen.append)
+    t_done = time.perf_counter()
+    wall_done = time.time()
+    time.sleep(0.03)
+    t.record("late", 5.0, _t_end=t_done)
+    t.record("now", 5.0)
+    late, now = t.events()
+    assert late.t_end == t_done
+    assert abs(late.ts - wall_done) < 0.01      # not 30 ms after the fact
+    assert now.t_end >= t_done + 0.03 and now.ts >= wall_done + 0.029
+    assert [r.name for r in seen] == ["late", "now"]
+
+
+def test_a_record_rebuilt_from_the_wire_is_placed_by_its_wall_clock():
+    from dds_tpu.obs.panopticon import record_from_dict
+
+    t = Tracer()
+    t.record("shipped", 4.0)
+    (rec,) = t.events()
+    far = record_from_dict(Tracer.event_dict(rec))
+    # another process's perf_counter means nothing here
+    assert far.t_end is None and far.end == rec.ts
+
+
+# ------------------------------------------------- kprof: each phase's end
+
+
+def test_profiled_records_the_dispatch_phase_before_the_execute_phase():
+    import jax.numpy as jnp
+
+    seen = []
+    tracer.subscribe(seen.append)
+    try:
+        with tracer.span("kernel.fold") as _:
+            kprof.profiled("tracing.test", lambda: jnp.arange(8) * 2)
+    finally:
+        tracer.unsubscribe(seen.append)
+    names = [r.name for r in seen]
+    i = names.index("kernel.tracing.test.execute")
+    host = seen[i - 1]
+    assert host.name in ("kernel.tracing.test.dispatch",
+                         "kernel.tracing.test.compile")
+    execute = seen[i]
+    # each was notified at its own end: the host phase ends where the
+    # execute phase starts, and neither is drawn over the other
+    assert host.t_end <= execute.t_end
+    assert abs((execute.t_end - execute.dur_ms / 1e3) - host.t_end) < 1e-4
+    res = critical_path([r for r in seen
+                         if r.trace_id == execute.trace_id])
+    by = {p["name"]: p for p in res["path"]}
+    h, e = by[host.name], by[execute.name]
+    assert h["start_ms"] + h["dur_ms"] <= e["start_ms"] + 1e-3
+    assert h["dur_ms"] == pytest.approx(host.dur_ms, abs=0.01)
+    assert e["dur_ms"] == pytest.approx(execute.dur_ms, abs=0.01)
+
+
+def test_every_xla_compilation_becomes_a_span_and_a_count():
+    import jax
+    import jax.numpy as jnp
+
+    kprof.watch_xla_compiles()
+    kprof.watch_xla_compiles()   # registered once
+    before = metrics.value("dds_xla_compile_total") or 0.0
+    n_spans = len(tracer.events("xla.compile"))
+    with tracer.span("http.GET.Compiling") as _:
+        # a shape nothing else in the suite compiles
+        jax.jit(lambda x: (x * 3 + 1).sum())(jnp.ones((3, 17, 5)))
+        inside = tracer.events("xla.compile")[n_spans:]
+    assert len(inside) >= 1
+    assert (metrics.value("dds_xla_compile_total") or 0.0) \
+        == before + len(inside)
+    root = tracer.events("http.GET.Compiling")[-1]
+    assert all(r.parent_id == root.span_id for r in inside)
+
+
+# ------------------------------------------------------- the closed taxonomy
+
+NEW_NAMES = {
+    "assembly.state": "assemble", "assembly.validate_tags": "assemble",
+    "assembly.pick_stale": "assemble", "assembly.reread": "assemble",
+    "assembly.pairs": "assemble", "assembly.operands": "assemble",
+    "residency.lookup": "host-to-device-transfer",
+    "residency.convert": "host-to-device-transfer",
+    "ingest.h2d": "host-to-device-transfer",
+    "dispatch.thread_wait": "queue-wait",
+    "dispatch.resume_wait": "queue-wait",
+    "dispatch.gather": "dispatch", "dispatch.d2h": "device-to-host",
+    "xla.compile": "trace-compile",
+    "runtime.loop_blocked": "runtime", "runtime.gc": "runtime",
+}
+
+
+@pytest.mark.parametrize("name,stage", sorted(NEW_NAMES.items()))
+def test_classify_places_every_new_span(name, stage):
+    assert classify(name) == stage and stage in STAGES and stage != "other"
+
+
+# --------------------------------------------- one aggregate's span tree
+
+
+def _small_cfg():
+    from dds_tpu.utils.config import DDSConfig
+
+    cfg = DDSConfig()
+    cfg.replicas.endpoints = [f"replica-{i}" for i in range(4)]
+    cfg.replicas.sentinent = []
+    cfg.replicas.byz_quorum_size = 3
+    cfg.replicas.byz_max_faults = 1
+    cfg.recovery.enabled = False
+    cfg.proxy.port = 0
+    cfg.proxy.crypto_backend = "tpu"
+    return cfg
+
+
+def _uncovered_ms(parent, kids) -> float:
+    lo, hi = parent.t_end - parent.dur_ms / 1e3, parent.t_end
+    at, bare = lo, 0.0
+    for a, b in sorted((max(lo, k.t_end - k.dur_ms / 1e3), min(hi, k.t_end))
+                       for k in kids):
+        if a > at:
+            bare += a - at
+        at = max(at, b)
+    return (bare + max(0.0, hi - at)) * 1e3
+
+
+def test_one_sumall_after_a_write_yields_one_span_per_step(monkeypatch):
+    from dds_tpu.http.miniserver import http_request
+    from dds_tpu.run import launch
+
+    monkeypatch.setenv("DDS_TPU_MIN_BATCH", "0")   # tiny folds reach the pool
+    rows = 24
+    nsqr = ((1 << 61) - 1) ** 2
+
+    async def go():
+        cfg = _small_cfg()
+        dep = await launch(cfg)
+        host, port = cfg.proxy.host, dep.server.cfg.port
+        try:
+            keys = []
+            for i in range(rows):
+                st, body = await http_request(
+                    host, port, "POST", "/PutSet",
+                    json.dumps({"contents": [str(i), "x", str(1000 + i)]}
+                               ).encode())
+                assert st == 200
+                keys.append(body.decode())
+            target = f"/SumAll?position=2&nsqr={nsqr}"
+            for _ in range(2):   # ingest the rows, compile every shape
+                st, first = await http_request(host, port, "GET", target)
+                assert st == 200
+            st, _b = await http_request(
+                host, port, "PUT", f"/WriteElement/{keys[3]}?position=2",
+                json.dumps({"value": "777"}).encode())
+            assert st == 200
+            st, _b = await http_request(host, port, "GET", target)
+            st, _b = await http_request(
+                host, port, "PUT", f"/WriteElement/{keys[5]}?position=2",
+                json.dumps({"value": "778"}).encode())
+            seen = []
+            tracer.subscribe(seen.append)
+            try:
+                st, body = await http_request(host, port, "GET", target)
+            finally:
+                tracer.unsubscribe(seen.append)
+            assert st == 200 and body != first
+            st, prof = await http_request(host, port, "GET", "/profile")
+            return seen, json.loads(prof)
+        finally:
+            await dep.stop()
+
+    seen, prof = asyncio.run(go())
+    root = next(r for r in seen if r.name == "http.GET.SumAll")
+    tree = [r for r in seen if r.trace_id == root.trace_id
+            and r.kind == "span"]
+    named: dict[str, list] = {}
+    for r in tree:
+        named.setdefault(r.name, []).append(r)
+    once = ["assembly.state", "assembly.validate_tags", "assembly.pick_stale",
+            "assembly.reread", "assembly.pairs", "assembly.operands",
+            "residency.convert", "ingest.h2d", "dispatch.thread_wait",
+            "dispatch.gather", "dispatch.d2h", "dispatch.resume_wait"]
+    assert {n: len(named.get(n, [])) for n in once} == dict.fromkeys(once, 1)
+    # one per locked stretch of the pool
+    assert [r.meta["stretch"] for r in named["residency.lookup"]] == [1, 2]
+    # counts, never a span per row
+    assert named["assembly.state"][0].meta["k"] == rows
+    assert named["assembly.validate_tags"][0].meta == {"k": rows, "stale": 0}
+    assert named["assembly.pairs"][0].meta["k"] == rows
+    assert named["assembly.operands"][0].meta == {"k": rows, "memo": False}
+    assert named["assembly.reread"][0].meta == {"stale": 0, "audit": 2}
+    assert all(r.meta["k"] == rows for r in named["residency.lookup"])
+    assert named["residency.lookup"][0].meta["missing"] == 1
+    assert named["residency.convert"][0].meta["rows"] == 1
+    h2d = named["ingest.h2d"][0].meta
+    assert (h2d["path"], h2d["rows"]) == ("fold", 1) and h2d["bytes"] > 0
+    # the hop's two waits are recorded on either side of it
+    assert named["dispatch.thread_wait"][0].tid != root.tid
+    assert named["dispatch.resume_wait"][0].tid == root.tid
+    # what no child covers is under a tenth of either container (or under
+    # a millisecond: at this size the spans' own cost is what is left)
+    for name in ("proxy.fetch_stored", "proxy.fold"):
+        (parent,) = named[name]
+        kids = [r for r in tree if r.parent_id == parent.span_id]
+        left = _uncovered_ms(parent, kids)
+        assert left <= max(0.10 * parent.dur_ms, 1.0), (name, left, parent)
+    stages = prof["routes"]["http.GET.SumAll"]["stages"]
+    assert "other" not in stages
+    assert {"assemble", "queue-wait", "host-to-device-transfer"} <= set(stages)
+
+
+# ------------------------------------------------------- the host runtime
+
+
+async def _with_sampler(body, **kw):
+    sampler = runtime.LoopSampler(**kw)
+    sampler.start()
+    try:
+        await asyncio.sleep(0.05)   # let it tick
+        return await body(sampler)
+    finally:
+        await sampler.stop()
+
+
+def test_a_sleep_on_the_loop_is_one_loop_blocked_span():
+    async def body(_sampler):
+        n = len(tracer.events("runtime.loop_blocked"))
+        lag = metrics.histogram_stats("dds_event_loop_lag_seconds")["count"]
+        t0 = time.perf_counter()
+        time.sleep(0.06)
+        t1 = time.perf_counter()
+        await asyncio.sleep(0.05)
+        spans = tracer.events("runtime.loop_blocked")[n:]
+        assert metrics.histogram_stats(
+            "dds_event_loop_lag_seconds")["count"] > lag
+        return spans, t0, t1
+
+    spans, t0, t1 = asyncio.run(_with_sampler(body))
+    assert len(spans) == 1
+    (s,) = spans
+    # the timer was due up to one tick into the sleep
+    assert 40.0 - 1.0 <= s.dur_ms <= 80.0
+    # placed truly: inside the sleep, ending as the loop came back
+    assert t0 <= s.t_end - s.dur_ms / 1e3 and t1 <= s.t_end <= t1 + 0.02
+    assert s.trace_id is None   # belongs to no request
+
+
+def test_a_collection_is_one_gc_span_and_a_count_and_stop_removes_the_hook():
+    from dds_tpu.run import launch
+
+    async def go():
+        dep = await launch(_small_cfg())
+        try:
+            assert runtime._on_gc in gc.callbacks
+            await asyncio.sleep(0.05)
+            n = len(tracer.events("runtime.gc"))
+            before = metrics.value("dds_gc_pause_seconds_total",
+                                   generation="2") or 0.0
+            gc.collect()
+            await asyncio.sleep(0.08)   # the sampler drains the note
+            spans = tracer.events("runtime.gc")[n:]
+            after = metrics.value("dds_gc_pause_seconds_total",
+                                  generation="2")
+            return spans, before, after
+        finally:
+            await dep.stop()
+
+    spans, before, after = asyncio.run(go())
+    full = [s for s in spans if s.meta["generation"] == 2]
+    assert len(full) == 1
+    assert after == pytest.approx(before + full[0].dur_ms / 1e3)
+    assert full[0].dur_ms > 0 and "collected" in full[0].meta
+    assert runtime._on_gc not in gc.callbacks
+
+
+def test_a_stalled_loop_is_reported_once_with_its_stack(tmp_path, caplog):
+    from dds_tpu.obs.flight import flight
+
+    def the_culprit():
+        time.sleep(1.2)
+
+    async def body(sampler):
+        with caplog.at_level(logging.WARNING, logger="dds.runtime"):
+            the_culprit()
+            await asyncio.sleep(0.05)
+        return sampler.stalls_reported
+
+    was = flight.min_interval
+    flight.configure(dir=str(tmp_path), min_interval=0.0)
+    try:
+        reported = asyncio.run(_with_sampler(body))
+    finally:
+        flight.configure(dir="", min_interval=was)
+    assert reported == 1    # at most one a minute
+    warned = [r.getMessage() for r in caplog.records
+              if r.name == "dds.runtime"]
+    assert len(warned) == 1 and "the_culprit" in warned[0]
+    (incident,) = tmp_path.glob("incident-*-loop_stall.jsonl")
+    head = json.loads(incident.read_text().splitlines()[0])
+    assert head["incident"] == "loop_stall"
+    assert "the_culprit" in head["info"]["stack"]
+    assert head["info"]["silent_s"] >= 1.0
+    assert "counters" not in head
+
+
+# ------------------------------------------------ what a span costs a trace
+
+
+def test_a_handler_is_analysed_over_its_own_subtree_and_only_exemplars_keep_a_waterfall():
+    from dds_tpu.obs import chronoscope as cs
+    from dds_tpu.obs.metrics import Registry
+    from dds_tpu.utils.trace import SpanRecord
+
+    def rec(name, start, end, sid, parent=None):
+        return SpanRecord(ts=end, name=name, dur_ms=(end - start) * 1e3,
+                          meta={}, trace_id="t", span_id=sid,
+                          parent_id=parent)
+
+    seen = []
+    real = cs.critical_path
+
+    def counting(records, **kw):
+        records = list(records)
+        seen.append((kw.get("root_span_id"), len(records),
+                     kw.get("with_path", True)))
+        return real(records, **kw)
+
+    scope = cs.Chronoscope(Registry(), exemplars=1)
+    cs.critical_path = counting
+    try:
+        for i in range(30):   # a long request before the handler reports
+            scope.on_record(rec("assembly.state", 0.01, 0.02, f"a{i}", "r"))
+        scope.on_record(rec("net.serialize", 0.031, 0.032, "s", "h"))
+        scope.on_record(rec("replica.handle", 0.03, 0.04, "h", "q"))
+        scope.on_record(rec("abd.fetch", 0.025, 0.05, "q", "r"))
+        scope.on_record(rec("http.GET.SumAll", 0.0, 0.1, "r"))
+    finally:
+        cs.critical_path = real
+    handler = [s for s in seen if s[0] == "h"]
+    # the handler and its one child, whatever else the trace holds; the
+    # stage sums need no waterfall, the kept exemplar gets one built
+    assert handler[0][1:] == (2, False)
+    assert [s for s in seen if s[0] == "r"][0][2] is False
+    prof = scope.profile()["routes"]
+    assert prof["replica.handle"]["stages"]["serialize"]["p50_ms"] == 1.0
+    (ex,) = prof["http.GET.SumAll"]["exemplars"]
+    assert [e["name"] for e in ex["path"]][:1] == ["http.GET.SumAll"]
+    assert "other" not in prof["http.GET.SumAll"]["stages"]
