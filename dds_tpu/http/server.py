@@ -55,6 +55,7 @@ from dds_tpu.core.tenant import (CANARY_TENANT, DEFAULT_TENANT, TenantError,
 from dds_tpu.http import json_protocol as J
 from dds_tpu.utils.tasks import supervised_task
 from dds_tpu.http.miniserver import HttpServer, Request, Response, http_request
+from dds_tpu.http.operand_table import OperandTable
 from dds_tpu.models.backend import CryptoBackend, get_backend
 from dds_tpu.obs import context as obs_context
 from dds_tpu.obs.flight import flight
@@ -62,6 +63,7 @@ from dds_tpu.obs.metrics import SIZE_BUCKETS, metrics
 from dds_tpu.obs.runtime import LoopSampler
 from dds_tpu.obs.slo import SloEngine
 from dds_tpu.obs.watchtower import watchtower
+from dds_tpu.resident.pool import Operands
 from dds_tpu.utils import sigs
 from dds_tpu.utils.retry import (
     Deadline,
@@ -296,6 +298,23 @@ async def _cancel_task(task: asyncio.Task) -> None:
         pass
 
 
+def _pick_outside(n: int, gone, want: int) -> list[int]:
+    """Up to `want` distinct positions of range(n) outside the set `gone`,
+    uniformly: the audit's sample among the entries the tag round
+    confirmed, without listing them when few are gone."""
+    import random
+
+    want = min(want, n - len(gone))
+    if 2 * len(gone) > n:
+        return random.sample([i for i in range(n) if i not in gone], want)
+    out: set[int] = set()
+    while len(out) < want:
+        i = random.randrange(n)
+        if i not in gone:
+            out.add(i)
+    return list(out)
+
+
 class DDSRestServer:
     def __init__(self, abd: AbdClient, config: ProxyConfig | None = None,
                  local_replicas: dict | None = None,
@@ -336,17 +355,17 @@ class DDSRestServer:
         # written to a full quorum — the invariant the tag-validation read
         # path relies on for linearizability.
         self._cache: dict[str, tuple] = {}
-        # versions + memos for the aggregate hot path: between writes the
-        # per-request O(K) bookkeeping (sorted keys, digests, fingerprints,
-        # pairs/operand lists) is identical, so it is computed once per
-        # (stored_keys, cache) state and reused. The tag-validation quorum
-        # round and the audit still run on EVERY aggregate — the memos skip
+        # the aggregate hot path's state (http/operand_table): sorted keys,
+        # the (tag, value) entry per key, parsed operand columns and their
+        # pool rows, kept across aggregates and patched by the keys in
+        # `_dirty` (those whose cache entry moved since the table last took
+        # them in). A change of the key set or a cache flush drops it; the
+        # next aggregate builds it again. The tag-validation quorum round
+        # and the audit still run on EVERY aggregate: the table skips
         # recomputation, never revalidation.
         self._stored_version = 0   # bumps on stored_keys add/discard/sync
-        self._cache_version = 0    # bumps when a cached (tag, value) changes
-        self._agg_memo: tuple | None = None    # state -> keys/cached/digest/fp
-        self._pairs_memo: tuple | None = None  # state -> [(key, value)] result
-        self._operand_memo: tuple | None = None  # pairs identity -> operands
+        self._table: OperandTable | None = None
+        self._dirty: set[str] = set()
         self._http = HttpServer(
             self.cfg.host, self.cfg.port, self.handle, self.cfg.ssl_server_context,
             handler_timeout=self.cfg.handler_timeout,
@@ -787,11 +806,15 @@ class DDSRestServer:
         cur = self._cache.get(key)
         if cur is None or cur[0] < tag:
             self._cache[key] = (tag, value)
-            self._cache_version += 1
+            if self._table is not None:
+                # a mark, no parse and no hash of the row: the next
+                # aggregate takes the entry into its operand table
+                self._dirty.add(key)
 
     def _flush_cache(self) -> None:
         self._cache.clear()
-        self._cache_version += 1
+        self._table = None   # its entries were the cache's: build anew
+        self._dirty.clear()
         if self._search is not None:
             # the search index inherits the cache's completed-op trust
             # argument, so an audit-triggered flush voids it too: the next
@@ -873,37 +896,35 @@ class DDSRestServer:
                 )
         return None
 
+    def _full_view(self) -> bool:
+        """Whether this request's tenant sees every stored record. Without
+        Bastion that is everyone but the Heliograph canary, as long as no
+        canary key is stored: the canary tenant sees exactly its own
+        population (what makes decrypt-and-compare exact) and everyone
+        else everything BUT it."""
+        return (not self._tenancy_enabled and not self._canary_keys
+                and _REQ_TENANT.get() != CANARY_TENANT)
+
     def _tenant_pairs(self, pairs: list[tuple[str, list]]) -> list:
         """The aggregate/search view filtered to the request tenant's own
-        records (tenancy off = the full view, same list identity — every
+        records (`_full_view`: the same list identity, so every
         downstream pairs-identity memo stays warm). Memoized per (tenant,
         pairs identity): between writes each tenant's filtered view is
-        state-identical, and its stable identity is what the operand and
-        column memos key on."""
+        state-identical, and its stable identity is what the column and
+        partition memos key on."""
+        if self._full_view():
+            return pairs
         tenant = _REQ_TENANT.get()
-        if not self._tenancy_enabled:
-            # Heliograph scoping without Bastion: the canary tenant sees
-            # exactly its own population (what makes decrypt-and-compare
-            # exact) and everyone else sees everything BUT it. With no
-            # canary keys stored this is the identical list object —
-            # every pre-Heliograph memo identity stays warm.
-            if tenant != CANARY_TENANT and not self._canary_keys:
-                return pairs
-            memo = self._tenant_pairs_memo.get(tenant)
-            if memo is not None and memo[0] is pairs:
-                return memo[1]
-            ck = self._canary_keys
-            if tenant == CANARY_TENANT:
-                filtered = [(k, v) for k, v in pairs if k in ck]
-            else:
-                filtered = [(k, v) for k, v in pairs if k not in ck]
-            self._tenant_pairs_memo[tenant] = (pairs, filtered)
-            return filtered
         memo = self._tenant_pairs_memo.get(tenant)
         if memo is not None and memo[0] is pairs:
             return memo[1]
-        own = self._key_tenant
-        filtered = [(k, v) for k, v in pairs if own(k) == tenant]
+        if self._tenancy_enabled:
+            own = self._key_tenant
+            filtered = [(k, v) for k, v in pairs if own(k) == tenant]
+        else:
+            ck = self._canary_keys
+            mine = tenant == CANARY_TENANT
+            filtered = [(k, v) for k, v in pairs if (k in ck) == mine]
         self._tenant_pairs_memo[tenant] = (pairs, filtered)
         return filtered
 
@@ -921,41 +942,43 @@ class DDSRestServer:
         own = self._key_tenant
         return sorted(k for k in self.stored_keys if own(k) == tenant)
 
-    def _agg_state(self):
-        """(state, keys, cached, digest, fingerprint, cached_tags) for the
-        current aggregate view, memoized per (stored, cache) version."""
-        state = (self._stored_version, self._cache_version)
-        memo = self._agg_memo
-        if memo is not None and memo[0] == state:
-            return memo
+    def _sync_table(self) -> OperandTable:
+        """The operand table an aggregate starts on: the current one with
+        every cache entry that moved since it last looked taken in (O(moved
+        keys)), or one built anew (a sort of K keys and K cache probes)
+        when the key set changed, the cache was flushed or the cache is
+        off. `assembly.state` times it, with the tag list and fingerprint
+        of the round to come, whenever there is something to do."""
+        table = self._table
+        build = (table is None
+                 or table.stored_version != self._stored_version
+                 or not self.cfg.aggregate_cache)
+        if not build and not self._dirty:
+            return table
         with tracer.span("assembly.state") as sm:
-            keys = sorted(self.stored_keys)
-            cached = [k for k in keys if k in self._cache]
-            cached_tags = [self._cache[k][0] for k in cached]
-            digest = sigs.key_from_set(cached)
-            fp = sigs.tags_fingerprint(cached_tags)
-            sm["k"], sm["cached"] = len(keys), len(cached)
-        self._agg_memo = (state, keys, cached, digest, fp, cached_tags)
-        return self._agg_memo
-
-    def _validate_tags(self, cached: list[str], tags, fresh: dict,
-                       fresh_tags: dict) -> None:
-        """Fill `fresh` with the cached entries the tag round confirmed:
-        all of them (`tags` None: every vote said "unchanged"), or those
-        whose quorum-max tag equals the cached one."""
-        with tracer.span("assembly.validate_tags", k=len(cached)) as vm:
-            if tags is None:
-                for k in cached:
-                    ct, cv = self._cache[k]
-                    fresh[k] = cv
-                    fresh_tags[k] = ct
+            if build:
+                table = self._table = OperandTable(
+                    sorted(self.stored_keys), self._cache,
+                    self._stored_version,
+                )
+                self._dirty.clear()
             else:
-                for k, t in zip(cached, tags):
-                    ct, cv = self._cache[k]
-                    if t == ct:
-                        fresh[k] = cv
-                        fresh_tags[k] = ct
-            vm["stale"] = len(cached) - len(fresh)
+                self._take_dirty(table)
+            table.round_args()
+            sm["k"] = len(table.keys)
+            sm["cached"] = len(table.keys) - table.uncached
+        return table
+
+    def _take_dirty(self, table: OperandTable) -> None:
+        """Patch `table` by the keys whose cache entry moved, if it is
+        still the current table: an aggregate that began on a key set
+        since replaced finishes on its own, which `_cache_put` no longer
+        marks for."""
+        if table is self._table and self._dirty:
+            index, cache = table.index, self._cache
+            table.apply([(index[k], cache[k]) for k in self._dirty
+                         if k in index and k in cache])
+            self._dirty.clear()
 
     async def _reread(self, keys: list[str], audit: int) -> list:
         """Full ABD re-reads of `keys`, gathered (`audit` of them are the
@@ -1377,7 +1400,15 @@ class DDSRestServer:
         return self._tenant_pairs(await self._fetch_stored())
 
     async def _fetch_stored(self) -> list[tuple[str, list]]:
-        """Every stored (key, value), for the aggregate/search routes.
+        """Every stored (key, value), for the search/order/analytics
+        routes: `_fetch_table` validates the view, then one O(K) pass over
+        references lists it (`OperandTable.pairs`, the same list object
+        until an entry moves). The aggregates do not come through here:
+        they fold the table's operand column."""
+        return (await self._fetch_table()).pairs()
+
+    async def _fetch_table(self) -> OperandTable:
+        """The operand table, validated for this request.
 
         With the aggregate cache on, ONE batched tag-only quorum round
         (`AbdClient.read_tags`) validates all cached entries: a cached value
@@ -1398,51 +1429,64 @@ class DDSRestServer:
         The reference re-reads every set through full quorums per aggregate
         (`DDSRestServer.scala:397-446`); this replaces K 2-round-trip reads
         with 1 light round + reads for just the stale keys.
+
+        What it costs. O(K): a copy of the tag list and a join and hash
+        of its kept fields when an entry moved since the last round, the
+        replicas' side of the round, and one pass of tag comparisons when
+        the quorum saw a tag move. O(rows that moved): everything else.
+        Entries the proxy's own completed operations changed are taken
+        from the cache by key (`_sync_table`), stale and audited keys are
+        re-read through full quorums, and only those rows are parsed into
+        the operand columns and looked up in the pool. O(K) of parsing
+        comes back, under `assembly.state` and `assembly.operands`, when
+        the table is built anew: a change of the key set (`PutSet` of a new
+        key, `RemoveSet`, key sync), a cache flush after a forged audit, a
+        first request for a column.
         """
-        import random
-
         with tracer.span("proxy.fetch_stored"):
-            return await self._fetch_stored_traced()
+            return await self._fetch_table_traced()
 
-    async def _fetch_stored_traced(self) -> list[tuple[str, list]]:
-        import random
-
-        state, keys, cached, digest, fp, cached_tags = self._agg_state()
+    async def _fetch_table_traced(self) -> OperandTable:
+        table = self._sync_table()
+        keys = table.keys
         if not keys:
-            return []
-        fresh: dict[str, object] = {}
-        fresh_tags: dict[str, object] = {}
-        if self.cfg.aggregate_cache and cached:
+            return table
+        reply = None
+        sent = table.round_args() if self.cfg.aggregate_cache else None
+        if sent is not None:
+            _, _, cached, digest, fp, cached_tags = sent
             try:
                 dl = self._request_deadline()
-                tags = await self._retry(
+                reply = await self._retry(
                     lambda: self.abd.read_tags(
                         cached, digest=digest, fingerprint=fp,
                         cached_tags=cached_tags, deadline=dl,
                     ),
                     dl,
                 )
-                if tags is cached_tags:
-                    # identity return: every quorum vote said "unchanged",
-                    # so the whole cache is fresh. With a memoized pairs
-                    # list for this exact state only the audit remains —
-                    # the steady-state aggregate does O(1) bookkeeping.
-                    pm = self._pairs_memo
-                    if pm is not None and pm[0] == state:
-                        if await self._audit_cached(cached):
-                            metrics.inc(
-                                "dds_tag_cache_total", len(cached),
-                                outcome="hit",
-                                help="aggregate tag-cache keys by outcome",
-                            )
-                            return pm[1]
-                        # audit flushed the cache: rebuild from quorum reads
-                    else:
-                        self._validate_tags(cached, None, fresh, fresh_tags)
-                else:
-                    self._validate_tags(cached, tags, fresh, fresh_tags)
             except Exception as e:  # validation trouble => plain full fetch
                 log.debug("tag validation failed (%s); full refetch", e)
+                sent = None
+        # identity return: every quorum vote said "unchanged". If no entry
+        # moved since an aggregate last settled this table either, only the
+        # audit remains: the steady-state aggregate does O(1) bookkeeping.
+        steady = (sent is not None and reply is sent[5]
+                  and not table.uncached
+                  and table.version == table.settled and not self._dirty)
+        if steady:
+            stale: list[int] = []
+            audit = _pick_outside(len(keys), (), self.cfg.aggregate_cache_audit)
+        else:
+            with tracer.span("assembly.validate_tags", k=len(keys)) as vm:
+                # a write completed during the round: take it in first, so
+                # that its entry is held to the round's tag like any other
+                self._take_dirty(table)
+                stale = table.stale(sent, reply)
+                vm["stale"] = len(stale)
+            with tracer.span("assembly.pick_stale", k=len(keys)) as pm:
+                audit = _pick_outside(len(keys), set(stale),
+                                      self.cfg.aggregate_cache_audit)
+                pm["stale"], pm["audit"] = len(stale), len(audit)
 
         # audit sample: re-read a few cache-served keys through a full
         # quorum under a (random) coordinator. A value mismatch at the SAME
@@ -1452,54 +1496,57 @@ class DDSRestServer:
         # re-read — but the newer tag is reported by the audited read
         # itself, so it is corroborated by an independent re-read before
         # being exempted from the flush.
-        with tracer.span("assembly.pick_stale", k=len(keys)) as pm:
-            audit = random.sample(
-                sorted(fresh), min(self.cfg.aggregate_cache_audit, len(fresh))
-            )
-            stale = [k for k in keys if k not in fresh or k in audit]
-            pm["stale"], pm["audit"] = len(stale) - len(audit), len(audit)
-        results = await self._reread(stale, len(audit)) if stale else []
+        pre = {keys[i]: table.entries[i] for i in audit}
+        reread = [keys[i] for i in stale] + list(pre)
+        results = await self._reread(reread, len(pre)) if reread else []
         fetched = {}
-        for k, r in zip(stale, results):
+        for k, r in zip(reread, results):
             if isinstance(r, Exception):
                 raise r
             fetched[k] = r  # (value, tag, coordinator)
         # cache effectiveness: keys served from the tag-validated cache vs
         # re-read through full quorums (audit re-reads count as misses —
-        # they cost a full ABD round either way)
-        metrics.inc("dds_tag_cache_total", max(0, len(keys) - len(stale)),
+        # they cost a full ABD round either way — except in the steady
+        # aggregate, which counts every key a hit)
+        miss = 0 if steady else len(reread)
+        metrics.inc("dds_tag_cache_total", len(keys) - miss,
                     outcome="hit", help="aggregate tag-cache keys by outcome")
-        metrics.inc("dds_tag_cache_total", len(stale), outcome="miss",
-                    help="aggregate tag-cache keys by outcome")
-        pre = {k: (fresh_tags[k], fresh[k]) for k in audit}
-        forged = await self._audit_verdict(audit, pre, fetched)
+        if not steady:
+            metrics.inc("dds_tag_cache_total", miss, outcome="miss",
+                        help="aggregate tag-cache keys by outcome")
+        forged = await self._audit_verdict(list(pre), pre, fetched)
         if forged:
             log.warning("aggregate cache audit mismatch: flushing cache")
             self._flush_cache()
-            fresh.clear()  # serve only quorum-read data this round
+            # serve only quorum-read data this round
             remaining = [k for k in keys if k not in fetched]
             more = await self._reread(remaining, 0)
             for k, r in zip(remaining, more):
                 if isinstance(r, Exception):
                     raise r
                 fetched[k] = r
-        with tracer.span("assembly.pairs", k=len(keys)):
-            out = []
-            for k in keys:
-                v = fetched[k][0] if k in fetched else fresh[k]
-                if v is not None:
-                    out.append((k, v))
-            # memoize the materialized pairs only if the (stored, cache)
-            # state did not move while this round was in flight — the next
-            # fully-unchanged round can then serve `out` after audit alone
-            if (self._stored_version, self._cache_version) == state:
-                self._pairs_memo = (state, out)
-        return out
+        elif steady and table.version == table.settled and not self._dirty:
+            return table
+        # patch: the rows re-read, as the cache holds them now (the newest
+        # completed op on the key: this round's read or a later one), and
+        # whatever else completed meanwhile. No await from here to the
+        # caller's snapshot of the column.
+        with tracer.span("assembly.pairs", k=len(keys)) as am:
+            index, cache = table.index, self._cache
+            am["patched"] = table.apply([
+                # a read the cache does not keep (no tag, cache off) is
+                # served this round and is stale for the next
+                (index[k], cache.get(k) or (None, r[0]))
+                for k, r in fetched.items()
+            ])
+            self._take_dirty(table)
+            table.settled = table.version
+        return table
 
     async def _audit_verdict(
         self, audit: list[str], pre: dict, fetched: dict
     ) -> list[str]:
-        """Shared forged/suspect classification for both audit paths.
+        """Forged/suspect classification of the audit's sample.
 
         `pre[k] = (tag, value)` is what the cache served; `fetched[k] =
         (value, tag, coordinator)` is the audit's full quorum re-read. A
@@ -1535,32 +1582,6 @@ class DDSRestServer:
                 if isinstance(r, Exception) or r[:2] != fetched[k][:2]:
                     forged.append(k)
         return forged
-
-    async def _audit_cached(self, cached: list[str]) -> bool:
-        """Audit a fully-cache-served aggregate round (the steady-state
-        fast path): re-read a sample through full quorums and flush on a
-        non-corroborated mismatch. Returns False when the cache was
-        flushed."""
-        import random
-
-        audit = random.sample(
-            cached, min(self.cfg.aggregate_cache_audit, len(cached))
-        )
-        if not audit:
-            return True
-        pre = {k: self._cache[k] for k in audit}
-        results = await self._reread(audit, len(audit))
-        fetched = {}
-        for k, r in zip(audit, results):
-            if isinstance(r, Exception):
-                raise r
-            fetched[k] = r
-        forged = await self._audit_verdict(audit, pre, fetched)
-        if forged:
-            log.warning("aggregate cache audit mismatch: flushing cache")
-            self._flush_cache()
-            return False
-        return True
 
     # -------------------------------------------------------------- routing
 
@@ -2464,18 +2485,25 @@ class DDSRestServer:
         """
         pos = self._pos(req)
         mod = req.query.get(modparam)
-        pairs = await self._fetch_visible()
+        table = await self._fetch_table()
         with tracer.span("assembly.operands") as om:
-            memo = self._operand_memo
-            hit = memo is not None and memo[0] is pairs and memo[1] == pos
-            if hit:
-                # identity match: _fetch_stored returned its memoized pairs
-                # list, so the extracted column is unchanged too
-                operands = memo[2]
+            if self._full_view():
+                # the table's column: the list the last aggregate folded,
+                # or a copy with the rows that moved since parsed in
+                operands, outcome = table.column(pos)
             else:
+                # a tenant's view: parsed per request (the filtered pairs
+                # list itself is memoized per tenant)
+                pairs = self._tenant_pairs(table.pairs())
                 operands = [int(v[pos]) for _, v in pairs if pos < len(v)]
-                self._operand_memo = (pairs, pos, operands)
-            om["k"], om["memo"] = len(operands), hit
+                outcome = "rebuilt"
+            metrics.inc(
+                "dds_operand_table_total", outcome=outcome,
+                help="aggregates by what their operand column cost: reused "
+                     "as it was, patched by the rows that moved, or parsed "
+                     "whole",
+            )
+            om["k"], om["memo"] = len(operands), outcome == "reused"
         if not operands:
             return Response(404)
         metrics.observe(
@@ -2485,6 +2513,13 @@ class DDSRestServer:
         if mod:
             modulus = self._parse_modulus(mod, modparam)
             result = None
+            # the per-owner partitions still read whole rows: listed when a
+            # plane or a shard map asks, before anything is awaited
+            pairs = (
+                self._tenant_pairs(table.pairs())
+                if self._resident is not None or self._shards is not None
+                else None
+            )
             if (
                 self._resident is not None
                 and len(operands) >= self._resident_min_fold
@@ -2560,13 +2595,13 @@ class DDSRestServer:
         """(keys, ciphertexts) of every stored record holding position
         `pos`, in sorted-key order — the operand column order the analytics
         routes expose (and echo back as `keys` so clients can line their
-        weight matrices up). Memoized per pairs-identity like the flat
-        operand memo."""
+        weight matrices up). Memoized per pairs-identity; the ciphertexts
+        are an `Operands` list, which keeps its pool rows with it."""
         memo = self._column_memo
         if memo is not None and memo[0] is pairs and memo[1] == pos:
             return memo[2], memo[3]
         keys = [k for k, v in pairs if pos < len(v)]
-        ciphers = [int(v[pos]) for _, v in pairs if pos < len(v)]
+        ciphers = Operands(int(v[pos]) for _, v in pairs if pos < len(v))
         self._column_memo = (pairs, pos, keys, ciphers)
         return keys, ciphers
 
@@ -2613,10 +2648,10 @@ class DDSRestServer:
     def _owner_operands(self, pairs, pos: int) -> list[tuple[str, list[int]]]:
         """Aggregate operands partitioned by owning shard group, with the
         group id attached (the Lodestone pool key). Unsharded proxies get
-        one anonymous group. Memoized per pairs-identity like the flat
-        operand memo — between writes the partition is state-identical,
-        and the stable operand-list identities are what the pools' row-
-        index memos key on."""
+        one anonymous group. Memoized per pairs-identity: between writes
+        the partition is state-identical, and each group's `Operands` list
+        carries the rows its pool resolved it to, so a warm aggregate
+        looks nothing up."""
         memo = self._owner_memo
         if memo is not None and memo[0] is pairs and memo[1] == pos:
             return memo[2]
@@ -2625,14 +2660,14 @@ class DDSRestServer:
             if pos < len(v):
                 gid = self.abd.owner(k) if self._shards is not None else ""
                 groups.setdefault(gid, []).append(int(v[pos]))
-        out = [(gid, g) for gid, g in groups.items() if g]
+        out = [(gid, Operands(g)) for gid, g in groups.items() if g]
         self._owner_memo = (pairs, pos, out)
         return out
 
     def _shard_operands(self, pairs, pos: int) -> list[list[int]]:
         """Aggregate operands partitioned by owning shard group (memoized
-        per pairs-identity like the flat operand memo — between writes the
-        partition is state-identical)."""
+        per pairs-identity — between writes the partition is
+        state-identical)."""
         memo = self._scatter_memo
         if memo is not None and memo[0] is pairs and memo[1] == pos:
             return memo[2]

@@ -23,7 +23,7 @@ warm tier (coldest-first order from `evict_rank`, the directory's
 decayed popularity) and keeps serving the fused fast path for the rows
 that stay — the fast path degrades gradually instead of cliff-dropping.
 Without a sink the legacy RESET remains (entries re-ingest on demand,
-`epoch` bumps, every row-index memo invalidates) — simple, and an
+`epoch` bumps, every kept row-index array is void) — simple, and an
 aggregate after a reset pays exactly the one-time ingest cost again,
 never wrong results; the reset now also files a `resident_reset` flight
 incident and stamps `last_reset_ts` so /health surfaces the silent
@@ -52,6 +52,37 @@ from dds_tpu.ops.montgomery import ModCtx
 from dds_tpu.utils.trace import tracer
 
 log = logging.getLogger("dds.resident")
+
+
+class RowTrack:
+    """Where one operand column's rows are in the pools that folded it.
+
+    The column's owner appends to `log` every position it changes (append
+    only, so a version of the column is a length of `log`); `rows` keeps,
+    per pool, the index array one version was last resolved to. A pool
+    resolves another version by looking up the positions logged between
+    the two and no others. Appends and slices are atomic under the
+    interpreter lock; a stored triple is always right for its version."""
+
+    __slots__ = ("log", "rows")
+
+    def __init__(self):
+        self.log: list[int] = []
+        self.rows: dict[int, tuple] = {}  # id(pool) -> (pool, epoch, version, idx)
+
+
+class Operands(list):
+    """One version of an operand column as a fold receives it: a list
+    nobody mutates once it is handed out, with the `track` of its column
+    and its `version` there. A list made alone gets a track of its own, so
+    folding the same object again finds its rows without a look-up."""
+
+    __slots__ = ("track", "version")
+
+    def __init__(self, cs=(), track: RowTrack | None = None):
+        super().__init__(cs)
+        self.track = track if track is not None else RowTrack()
+        self.version = len(self.track.log)
 
 
 @dataclass
@@ -89,12 +120,8 @@ class ResidentPool:
             self.reduce = self._ctx.reduce_mul
         self._buf = self._place_zeros(self.initial_rows)
         self._index = {}
-        # (cs-list identity, epoch, idx array): aggregates pass the same
-        # operand list object while the proxy's caches validate unchanged,
-        # so the O(K) big-int index lookups run once per distinct list.
-        # The strong ref keeps the keyed list alive (identity stays unique);
-        # epoch invalidates across capacity resets.
-        self._idx_memo: tuple | None = None
+        # bumps whenever rows move (reset, eviction): index arrays kept by
+        # callers (`RowTrack.rows`) are good for the epoch they name only
         self._epoch = 0
         self._resets = 0
         self._last_reset_ts: float | None = None
@@ -192,7 +219,7 @@ class ResidentPool:
             )
             self._index.clear()
             self._count = 0
-            self._epoch += 1  # row indices changed: invalidate idx memos
+            self._epoch += 1  # row indices changed: kept index arrays are void
             self._resets += 1
             self._last_reset_ts = time.time()
             metrics.inc(
@@ -292,7 +319,7 @@ class ResidentPool:
         self._buf = self._place(jnp.asarray(newbuf))
         self._index = {c: i for i, c in enumerate(survivors)}
         self._count = len(survivors)
-        self._epoch += 1  # row indices changed: invalidate idx memos
+        self._epoch += 1  # row indices changed: kept index arrays are void
         self._spill_out.append(spilled)
         metrics.inc(
             "dds_resident_evictions_total", len(victims),
@@ -424,39 +451,36 @@ class ResidentPool:
             metrics.inc("dds_cipher_store_total", n_direct,
                         outcome="direct", help=help_)
 
-    def rows_for(self, cs: list[int]):
-        """(buffer snapshot, row indices) for `cs`, ingesting any unseen
-        operands first — the gather half of `fold`, shared with the
-        plane's fused multi-group dispatch and Prism's resident MatVec
-        gather. Returns None when the distinct operands cannot fit even
-        after a reset (callers fall back to direct marshaling). Accounts
-        resident/ingested operands as a side effect.
+    def _rows(self, cs: list[int], k: int, epoch: int | None = None):
+        """(buffer snapshot, row index per element of `cs`, epoch),
+        ingesting what is unseen; `k` is the width of the column `cs`
+        belongs to (all of it, or the changed part). None when the distinct
+        operands cannot fit even after a reset or, with `epoch` given,
+        when the pool's rows are or become another epoch's.
 
         Spans, one each per call and never per row: `residency.lookup`
         over each of the two locked stretches (`stretch`; the wait for
-        the lock included, and named in `lock_wait_ms`), `residency.convert` over the limb
-        conversion between them, `ingest.h2d` (from `ensure`) inside the
-        second."""
-        with tracer.span("residency.lookup", k=len(cs), stretch=1) as lm:
+        the lock included, and named in `lock_wait_ms`; `looked_up` is
+        len(cs), `memo` says nothing had to be looked up),
+        `residency.convert` over the limb conversion between them,
+        `ingest.h2d` (from `ensure`) inside the second."""
+        with tracer.span("residency.lookup", k=k, stretch=1,
+                         looked_up=len(cs), memo=not cs) as lm:
             t_ask = time.perf_counter()
             with self._lock:
                 lm["lock_wait_ms"] = (time.perf_counter() - t_ask) * 1e3
-                m = self._idx_memo
-                lm["memo"] = (m is not None and m[0] is cs
-                              and m[1] == self._epoch)
-                if lm["memo"]:
+                if epoch is not None and epoch != self._epoch:
                     lm["missing"] = 0
-                    self._account(len(cs), 0, 0)
-                    return self._buf, m[2]
+                    return None
                 missing = sorted({c for c in cs if c not in self._index})
                 lm["missing"] = len(missing)
                 if not missing:
-                    idx = np.asarray(
+                    rows = np.asarray(
                         [self._index[c] for c in cs], dtype=np.int32
                     )
-                    self._idx_memo = (cs, self._epoch, idx)
-                    self._account(len(cs), 0, 0)
-                    return self._buf, idx  # immutable jax array: safe outside
+                    self._account(k, 0, 0)
+                    # immutable jax array: safe outside the lock
+                    return self._buf, rows, self._epoch
         # limb-convert the unseen operands OUTSIDE the lock (the
         # CPU-heavy part); placement/index update stays serialized.
         # Entries are only ever added, so `missing` can only shrink in
@@ -467,22 +491,83 @@ class ResidentPool:
                 [c % self.modulus for c in missing], self._ctx.L
             )
             pre = {c: converted[i] for i, c in enumerate(missing)}
-        with tracer.span("residency.lookup", k=len(cs), stretch=2,
-                         memo=False, missing=len(missing)) as lm:
+        with tracer.span("residency.lookup", k=k, stretch=2,
+                         looked_up=len(cs), memo=False,
+                         missing=len(missing)) as lm:
             t_ask = time.perf_counter()
             with self._lock:
                 lm["lock_wait_ms"] = (time.perf_counter() - t_ask) * 1e3
-                idx = self.ensure(cs, pre)
-                if idx is None:
-                    self._account(0, 0, len(cs))
+                rows = None
+                if epoch in (None, self._epoch):
+                    rows = self.ensure(cs, pre)
+                if epoch not in (None, self._epoch):
+                    # rows moved under the caller's index array (`ensure`
+                    # itself may have reset the pool): what it placed
+                    # stays, and the whole column is resolved again
+                    if rows is not None:
+                        self._account(0, len(missing), 0)
+                    out = None
+                elif rows is None:
+                    if epoch is None:   # else the caller resolves it whole
+                        self._account(0, 0, k)
                     out = None
                 else:
-                    self._idx_memo = (cs, self._epoch, idx)
-                    self._account(len(cs) - len(missing), len(missing), 0)
-                    out = (self._buf, idx)
+                    self._account(k - len(missing), len(missing), 0)
+                    out = (self._buf, rows, self._epoch)
         # deliver any eviction wave to the tier sink outside the lock
         self._flush_spill()
         return out
+
+    def patch_rows(self, idx: np.ndarray, epoch: int, positions: list[int],
+                   ciphers: list[int]):
+        """(buffer snapshot, row indices) of a column whose rows were `idx`
+        at `epoch` and whose `positions` now hold `ciphers`: those
+        ciphertexts alone are looked up and, where unseen, converted and
+        placed, so the cost is O(len(positions)) under the lock whatever
+        the column's width. `idx` is left as it is. None when the pool's
+        rows have moved since `epoch` (reset, eviction): `rows_for` then
+        resolves the whole column."""
+        got = self._rows(ciphers, len(idx), epoch)
+        if got is None:
+            return None
+        if positions:
+            idx = idx.copy()
+            idx[positions] = got[1]
+        return got[0], idx
+
+    def rows_for(self, cs: list[int]):
+        """(buffer snapshot, row indices) for `cs`, ingesting any unseen
+        operands first: the gather half of `fold`, shared with the
+        plane's fused multi-group dispatch and Prism's resident MatVec
+        gather. Returns None when the distinct operands cannot fit even
+        after a reset (callers fall back to direct marshaling). Accounts
+        resident/ingested operands as a side effect.
+
+        O(K) big-int dictionary probes under the lock for a plain list and
+        for an `Operands` this pool has not resolved at its current epoch.
+        An `Operands` whose track holds this pool's rows for some version
+        costs O(positions logged between that version and this one)
+        through `patch_rows`: nothing for the same object again, the rows
+        that changed for a column patched since."""
+        track = getattr(cs, "track", None)
+        known = track.rows.get(id(self)) if track is not None else None
+        if known is not None and known[0] is self:
+            _, epoch, version, idx = known
+            lo, hi = sorted((version, cs.version))
+            positions = list(dict.fromkeys(track.log[lo:hi]))
+            got = self.patch_rows(idx, epoch, positions,
+                                  [cs[p] for p in positions])
+            if got is not None:
+                if cs.version >= version:
+                    track.rows[id(self)] = (self, epoch, cs.version, got[1])
+                return got
+        got = self._rows(cs, len(cs))
+        if got is None:
+            return None
+        buf, idx, epoch = got
+        if track is not None:
+            track.rows[id(self)] = (self, epoch, cs.version, idx)
+        return buf, idx
 
     def fold(self, cs: list[int]) -> int:
         """prod(cs) mod modulus, gathering resident rows on-device."""

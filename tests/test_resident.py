@@ -7,7 +7,7 @@ IDENTICAL ciphertexts, exactly one kernel.resident_fold dispatch span
 per warm aggregate), write-path incremental ingest (a warm fleet's first
 post-write aggregate pays zero ingest; ingest racing an aggregate over
 the same values stays bit-for-bit and deadlock-free), the concurrency
-races around capacity resets and `_idx_memo` epoch invalidation, the
+races around capacity resets and the epoch that voids kept rows, the
 direct-fallback metric accounting fix, the /metrics + /health surface,
 and the sentry `resident fold` record contract.
 """
@@ -68,22 +68,29 @@ def test_direct_fallback_accounts_direct_not_resident():
     assert pool.hit_ratio() == 0.0
 
 
-def test_epoch_invalidates_idx_memo_across_reset():
-    """A capacity reset must invalidate row-index memos minted against
-    the old placement: the SAME operand-list object folds correctly after
-    rows were evicted and re-placed."""
+def test_epoch_invalidates_kept_rows_across_reset():
+    """A capacity reset must void row indices resolved against the old
+    placement: `patch_rows` refuses an index array of another epoch, and
+    the SAME operand list folds correctly after rows were dropped and
+    re-placed, its track then naming the new epoch."""
+    from dds_tpu.resident.pool import Operands
+
     pool = ResidentPool(MODULUS, initial_rows=4, max_rows=8)
-    cs = [rng.randrange(1, MODULUS) for _ in range(4)]
+    cs = Operands(rng.randrange(1, MODULUS) for _ in range(4))
     assert pool.fold(cs) == pyfold(cs)
-    assert pool._idx_memo is not None and pool._idx_memo[0] is cs
+    kept = cs.track.rows[id(pool)]
     epoch0 = pool.epoch
+    assert kept[0] is pool and kept[1] == epoch0
     # overflow with fresh values: forces the reset path, bumping the epoch
     flood = [rng.randrange(1, MODULUS) for _ in range(7)]
     assert pool.fold(flood) == pyfold(flood)
     assert pool.epoch > epoch0 and pool.resets >= 1
-    # same list object again: the stale memo must NOT serve old indices
+    # the old index array must NOT be patched or served
+    assert pool.patch_rows(kept[3], epoch0, [], []) is None
+    assert pool.patch_rows(kept[3], epoch0, [2], [cs[2]]) is None
+    # same list object again: resolved whole against the new placement
     assert pool.fold(cs) == pyfold(cs)
-    assert pool._idx_memo[1] == pool.epoch
+    assert cs.track.rows[id(pool)][1] == pool.epoch
 
 
 def test_capacity_reset_racing_concurrent_folds():

@@ -143,6 +143,20 @@ def test_tags_blob_packing_is_injective():
     assert S.tags_fingerprint(a) != S.tags_fingerprint(b)
 
 
+def test_a_fingerprint_from_kept_fields_is_the_vectors_own():
+    """The proxy's operand table keeps one `tag_field` per key and joins
+    them: what the replicas hash from their own tags, delimiters in ids
+    and the empty vector included."""
+    from dds_tpu.utils import sigs as S
+
+    for tags in ((), (M.ABDTag(0, ""),),
+                 (M.ABDTag(1, "x;9:y"), M.ABDTag(2, "z"), M.ABDTag(7, ":;")),
+                 tuple(M.ABDTag(i, f"proxy-{i % 3}") for i in range(50))):
+        fields = [S.tag_field(t) for t in tags]
+        assert ";".join(fields).encode() == S.tags_blob(tags)
+        assert S.fields_fingerprint(fields) == S.tags_fingerprint(tags)
+
+
 def test_read_tags_fingerprint_fast_path_identity():
     """Steady state: when every quorum vote is `unchanged`, read_tags
     returns the caller's cached_tags list BY IDENTITY (the all-fresh
