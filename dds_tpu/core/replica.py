@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import logging
 import random
+import time
 from dataclasses import dataclass, field
 
 from dds_tpu.core import messages as M
@@ -72,6 +73,61 @@ class _Outgoing:
     tag_to_reply: object = None  # tag returned to the proxy (read max / written)
 
 
+# key sets whose tag vector a replica keeps (one per distinct aggregate
+# key set a proxy revalidates); past it the oldest goes
+MAX_TAG_VECTORS = 8
+# stores a kept vector may trail before it is dropped instead of patched:
+# a key set nobody asked about for that long is cheaper built anew, and
+# the log of stored keys stays bounded
+MAX_TAG_VECTOR_LAG = 1 << 16
+
+
+class _TagVector:
+    """What the ReadTagBatch reply for one key set is made of, kept across
+    repository changes and patched by the keys stored since: the keys'
+    digest (a function of the keys alone), key -> position, the tag per
+    position and its `sigs.tag_field`, the joined blob the MAC covers and
+    its fingerprint. `seen` is how far into the replica's log of stored
+    keys the vector has been brought."""
+
+    __slots__ = ("digest", "index", "tags", "fields", "reply_tags", "blob",
+                 "fingerprint", "seen")
+
+    def __init__(self, keys: tuple, digest: str, repository: dict,
+                 blank: tuple, seen: int):
+        self.digest = digest
+        self.index = {k: i for i, k in enumerate(keys)}
+        # read without materializing default entries in the repository
+        self.tags = [repository.get(k, blank)[0] for k in keys]
+        self.fields = [sigs.tag_field(t) for t in self.tags]
+        self.seen = seen
+        self.seal()
+
+    def seal(self) -> None:
+        """One copy, one join, one hash: the reply's parts of the tags and
+        fields as they now stand."""
+        self.reply_tags = tuple(self.tags)
+        self.blob = sigs.fields_blob(self.fields)
+        self.fingerprint = sigs.blob_fingerprint(self.blob)
+
+    def patch(self, stored: list, repository: dict) -> int:
+        """Take in the keys `stored` since `seen` (the log's tail): replace
+        the tag and field of those in the set whose tag moved, then seal
+        once. Returns how many were replaced."""
+        index, tags, fields = self.index, self.tags, self.fields
+        changed = 0
+        for key in stored:
+            i = index.get(key)
+            if i is not None and tags[i] is not (tag := repository[key][0]):
+                tags[i] = tag
+                fields[i] = sigs.tag_field(tag)
+                changed += 1
+        self.seen += len(stored)
+        if changed:
+            self.seal()
+        return changed
+
+
 class BFTABDNode:
     """One replica endpoint. `addr` must appear in `replicas`."""
 
@@ -102,12 +158,20 @@ class BFTABDNode:
         self.incoming: dict[int, bool] = {}  # nonce -> expired
         self.siblings = TrustedNodesList(replicas)
         # bumped on every observable repository change (stored Write, Sleep
-        # reseed, Kill wipe, snapshot restore); versions the tag-batch cache
+        # reseed, Kill wipe, snapshot restore)
         self.repo_version = 0
-        # keys-tuple -> (repo_version, digest, tags, fingerprint): memoizes
-        # the per-key-set tag vector + its MAC inputs between repository
-        # changes, making repeat ReadTagBatch rounds O(1) instead of O(K)
-        self._tagbatch_cache: dict[tuple, tuple] = {}
+        # keys-tuple -> _TagVector: what each key set's ReadTagBatch reply
+        # is made of, kept across stored writes. `_store` logs the key it
+        # changed (`_stored_since`, only while a vector is kept) and a round
+        # patches its vector by the keys logged since it last looked:
+        # O(keys written since) per round, nothing when none was. The
+        # vectors answer for `_vector_version`, which moves with
+        # `repo_version` on every change that names its key; a change that
+        # names none (reseed, wipe, prune, a bare `repo_version` bump) leaves
+        # it behind, and the vectors are dropped before they are next read
+        self._tag_vectors: dict[tuple, _TagVector] = {}
+        self._stored_since: list[str] = []
+        self._vector_version = 0
         # Aegis: incremental (key -> tag, value-digest) hash index — the
         # source of StateDigest manifests and the anti-entropy tree
         self.merkle = MerkleIndex()
@@ -160,18 +224,29 @@ class BFTABDNode:
             self._send(sibling, msg)
 
     def _store(self, key: str, tag: M.ABDTag, value) -> None:
-        """The ONLY place stored tags change: bump the version so cached
-        tag-batch vectors (and their fingerprints) invalidate."""
+        """The ONLY place stored tags change: bump the version and name
+        the key, so that kept tag vectors patch it in before their next
+        reply."""
         self.repository[key] = (tag, value)
         self.repo_version += 1
+        self._vector_version += 1
+        if self._tag_vectors:
+            self._stored_since.append(key)
         self.merkle.update(key, tag, value)
+
+    def _drop_tag_vectors(self) -> None:
+        """The repository changed in a way that names no key: nothing kept
+        describes it. The next round of each key set builds anew."""
+        self._tag_vectors.clear()
+        self._stored_since.clear()
+        self._vector_version = self.repo_version
 
     def _install_repository(self, repository: dict) -> None:
         """Replace the whole repository (reseed / snapshot restore): bump
-        the version, drop memo caches, rebuild the Merkle index."""
+        the version, drop the kept tag vectors, rebuild the Merkle index."""
         self.repository = repository
         self.repo_version += 1
-        self._tagbatch_cache.clear()
+        self._drop_tag_vectors()
         self.merkle.rebuild(repository)
 
     def _wipe(self) -> None:
@@ -179,7 +254,7 @@ class BFTABDNode:
         self.outgoing = {}
         self.incoming = {}
         self.repo_version += 1
-        self._tagbatch_cache.clear()
+        self._drop_tag_vectors()
         self.merkle.rebuild({})
         self._recovery_sessions.clear()
 
@@ -226,21 +301,42 @@ class BFTABDNode:
                      epoch=epoch, sent_epoch=sent_epoch, msg=what)
         self._send(dest, M.WrongShard(key, epoch, nonce, sig))
 
-    def _tag_batch_fill(self, keys: tuple, digest: str) -> tuple[tuple, bytes]:
-        """(tag vector, fingerprint) for an AUTHENTICATED ReadTagBatch,
-        memoized per keys-tuple until the repository changes. Aggregates
-        revalidate the same key set every round; between writes this makes
-        the replica side O(1) instead of O(K). The digest stored with a hit
-        was computed from these exact keys when the entry was filled (the
-        tuple is the cache key), so it still authenticates them on probe."""
-        # read without materializing default entries in the repository
-        blank = (M.ABDTag(0, self.name), None)
-        tags = tuple(self.repository.get(k, blank)[0] for k in keys)
-        fp = sigs.tags_fingerprint(tags)
-        if len(self._tagbatch_cache) > 8:  # distinct key-sets stay bounded
-            self._tagbatch_cache.clear()
-        self._tagbatch_cache[keys] = (self.repo_version, digest, tags, fp)
-        return tags, fp
+    def _tag_vector(self, keys: tuple, vec: _TagVector | None,
+                    digest: str) -> tuple[_TagVector, str, int]:
+        """(vector, outcome, tags replaced) for an AUTHENTICATED
+        ReadTagBatch: `vec`, the vector the probe found kept for this key
+        set (None when it found none), brought up to the repository.
+        `reused` when no key of the set was stored since it last looked,
+        `patched` when those that were had their tag and field replaced
+        (and one join and one hash redone), `rebuilt` when there was none
+        to patch: a first round for the key set, or one after a change
+        that named no key."""
+        if self._vector_version != self.repo_version:
+            self._drop_tag_vectors()
+            vec = None
+        log = self._stored_since
+        if vec is None:
+            while len(self._tag_vectors) >= MAX_TAG_VECTORS:
+                del self._tag_vectors[next(iter(self._tag_vectors))]
+            vec = self._tag_vectors[keys] = _TagVector(
+                keys, digest, self.repository,
+                (M.ABDTag(0, self.name), None), len(log),
+            )
+            return vec, "rebuilt", 0
+        if vec.seen == len(log):
+            return vec, "reused", 0
+        changed = vec.patch(log[vec.seen:], self.repository)
+        # the log is needed only as far back as the vector furthest behind;
+        # one that fell too far behind goes instead of holding it
+        behind = [ks for ks, v in self._tag_vectors.items()
+                  if v.seen < len(log)]
+        if not behind or len(log) > MAX_TAG_VECTOR_LAG:
+            for ks in behind:
+                del self._tag_vectors[ks]
+            log.clear()
+            for v in self._tag_vectors.values():
+                v.seen = 0
+        return vec, "patched" if changed else "reused", changed
 
     # ------------------------------------------------------------- dispatch
 
@@ -341,16 +437,15 @@ class BFTABDNode:
                 # coordinator: authenticate the request BEFORE burning an
                 # anti-replay nonce, or unauthenticated traffic could both
                 # enumerate tags (write-activity oracle) and grow the nonce
-                # set without bound. The memo cache is PROBED read-only here
-                # (a hit skips the O(K) digest recompute) but only FILLED
-                # after the MAC verifies — pre-auth traffic must not be able
-                # to evict the hot entry or grow the cache
-                hit = self._tagbatch_cache.get(keys)
-                if hit is not None and hit[0] == self.repo_version:
-                    digest = hit[1]
-                else:
-                    hit = None
-                    digest = sigs.key_from_set(list(keys))
+                # set without bound. The kept vectors are PROBED read-only
+                # here (the digest of a key set already asked about is kept
+                # with its vector: it is a function of the keys alone) and
+                # only built, patched or evicted after the MAC verifies —
+                # pre-auth traffic must not be able to evict the hot vector
+                # or grow the table
+                kept = self._tag_vectors.get(keys)
+                digest = (kept.digest if kept is not None
+                          else sigs.key_from_set(list(keys)))
                 if not sigs.validate_proxy_signature(
                     cfg.proxy_mac_secret, digest, nonce, psig
                 ):
@@ -371,31 +466,46 @@ class BFTABDNode:
                             sender, bad, nonce, msg.epoch, "ReadTagBatch"
                         )
                         return
-                if hit is not None:
-                    tags, fp = hit[2], hit[3]
-                else:
-                    tags, fp = self._tag_batch_fill(keys, digest)
+                t0 = time.perf_counter()
+                vec, outcome, changed = self._tag_vector(keys, kept, digest)
+                fp = vec.fingerprint
                 # tag-only phase: no Write follows, so the nonce is spent now
                 self.incoming[nonce] = True
                 if pfp is not None and pfp == fp:
                     # steady-state fast path: assert vector equality by
                     # fingerprint instead of shipping/MACing all K tags
-                    sig = sigs.abd_batch_unchanged_signature(
-                        cfg.abd_mac_secret, fp, digest, nonce
-                    )
-                    self._send(
-                        sender,
-                        M.TagBatchReply((), digest, sig, nonce,
-                                        unchanged=True, fingerprint=fp),
+                    reply = M.TagBatchReply(
+                        (), digest,
+                        sigs.abd_batch_unchanged_signature(
+                            cfg.abd_mac_secret, fp, digest, nonce),
+                        nonce, unchanged=True, fingerprint=fp,
                     )
                 else:
-                    sig = sigs.abd_batch_signature(
-                        cfg.abd_mac_secret, tags, digest, nonce
+                    # the MAC covers the kept blob: no tag is formatted
+                    reply = M.TagBatchReply(
+                        vec.reply_tags, digest,
+                        sigs.abd_batch_blob_signature(
+                            cfg.abd_mac_secret, vec.blob, digest, nonce),
+                        nonce, fingerprint=fp,
                     )
-                    self._send(
-                        sender,
-                        M.TagBatchReply(tags, digest, sig, nonce, fingerprint=fp),
+                metrics.inc(
+                    "dds_replica_tag_vector_total", outcome=outcome,
+                    help="authenticated ReadTagBatch rounds by what the "
+                         "kept tag vector needed",
+                )
+                if changed:
+                    metrics.inc(
+                        "dds_replica_tag_vector_keys_total", changed,
+                        outcome="patched",
+                        help="tags replaced in a kept tag vector",
                     )
+                if outcome != "reused":
+                    tracer.record(
+                        "replica.tag_vector",
+                        (time.perf_counter() - t0) * 1e3, replica=self.name,
+                        k=len(keys), changed=changed, outcome=outcome,
+                    )
+                self._send(sender, reply)
 
             case M.TagReply(tag, key, value, signature, nonce):
                 if not sigs.validate_abd_signature(
@@ -996,7 +1106,7 @@ class BFTABDNode:
             del self.repository[k]
         if doomed:
             self.repo_version += 1
-            self._tagbatch_cache.clear()
+            self._drop_tag_vectors()
             self.merkle.rebuild(self.repository)
         return len(doomed)
 

@@ -71,7 +71,9 @@ def tags_blob(tags) -> bytes:
     charset-checked — without the prefix, delimiter characters inside an
     id would make the packing non-injective and two distinct vectors
     could share one MAC. ~6x cheaper than canonical JSON at K=8192,
-    which matters — it sits on the per-aggregate hot path."""
+    which matters where it still runs once per aggregate: the proxy's
+    verify of each full reply. The replica's reply and the proxy's request
+    are made from kept `tag_field`s (`fields_blob`) and format no tag."""
     return ";".join(f"{t.seq}:{len(t.id)}:{t.id}" for t in tags).encode()
 
 
@@ -82,23 +84,40 @@ def tag_field(tag) -> str:
     return f"{tag.seq}:{len(tag.id)}:{tag.id}"
 
 
+def fields_blob(fields) -> bytes:
+    """`tags_blob` of the vector whose `tag_field`s these are: one join."""
+    return ";".join(fields).encode()
+
+
+def blob_fingerprint(blob: bytes) -> bytes:
+    """`tags_fingerprint` of the vector whose `tags_blob` this is."""
+    return hashlib.sha256(blob).digest()
+
+
 def fields_fingerprint(fields) -> bytes:
     """`tags_fingerprint` of the vector whose `tag_field`s these are."""
-    return hashlib.sha256(";".join(fields).encode()).digest()
+    return blob_fingerprint(fields_blob(fields))
 
 
 def tags_fingerprint(tags) -> bytes:
     """Order-sensitive digest of a tag vector. Equal fingerprints (within
     one key-set request order) mean equal per-key tags — the whole-vector
     freshness check behind the unchanged-reply fast path of ReadTagBatch."""
-    return hashlib.sha256(tags_blob(tags)).digest()
+    return blob_fingerprint(tags_blob(tags))
 
 
 def abd_batch_signature(secret: bytes, tags, digest: str, nonce: int) -> bytes:
     """Intranet replica signature over a ReadTagBatch reply (tag vector +
     requested-keys digest + nonce) — the batched analogue of abd_signature."""
-    content = tags_blob(tags) + f"|{digest}|{nonce}".encode()
-    return _mac(secret, content)
+    return abd_batch_blob_signature(secret, tags_blob(tags), digest, nonce)
+
+
+def abd_batch_blob_signature(
+    secret: bytes, blob: bytes, digest: str, nonce: int
+) -> bytes:
+    """`abd_batch_signature` of the vector whose `tags_blob` this is, for
+    a signer that keeps the blob."""
+    return _mac(secret, blob + f"|{digest}|{nonce}".encode())
 
 
 def validate_abd_batch_signature(

@@ -284,18 +284,25 @@ def test_unauthenticated_tag_batch_is_ignored():
 
 
 def test_unauthenticated_tag_batch_cannot_evict_memo_cache():
-    """The replica's tag-batch memo cache is probed read-only before the
-    proxy MAC verifies and filled only after: unauthenticated traffic with
-    rotating bogus key sets must neither grow the cache nor evict the hot
-    entry of the legitimate aggregate."""
+    """The replica's kept tag vectors are probed read-only before the
+    proxy MAC verifies and built, patched or evicted only after:
+    unauthenticated traffic with rotating bogus key sets must neither grow
+    the table nor evict or patch the hot vector of the legitimate
+    aggregate."""
 
     async def go():
         c = Cluster()
         await c.client.write_set("k", [1])
-        await c.client.read_tags(["k"])  # fills each replica's memo
+        await c.client.read_tags(["k"])  # builds each replica's vector
+        await c.client.write_set("k", [2])  # logged, not yet patched in
+        await c.net.quiesce()
         target = c.replicas["replica-0"]
-        before = dict(target._tagbatch_cache)
-        assert before  # the legit entry is resident
+        before = dict(target._tag_vectors)
+        assert before  # the legit vector is resident
+        vec = before[("k",)]
+        state = (vec.seen, vec.tags[:], vec.fingerprint,
+                 target._stored_since[:])
+        assert state[3] == ["k"]
         c.net.register("intruder", lambda s, m: asyncio.sleep(0))
         for i in range(12):  # > the cache's eviction bound
             c.net.send(
@@ -303,7 +310,9 @@ def test_unauthenticated_tag_batch_cannot_evict_memo_cache():
                 M.ReadTagBatch((f"bogus-{i}",) * 4, 1000 + i, b"bad"),
             )
         await c.net.quiesce()
-        assert target._tagbatch_cache == before
+        assert target._tag_vectors == before
+        assert (vec.seen, vec.tags, vec.fingerprint,
+                target._stored_since) == state
 
     run(go())
 
