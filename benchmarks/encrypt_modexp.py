@@ -10,7 +10,6 @@ bits, L=256) at batch B for:
 
 - v2:      MXU band-REDC ladder (mont_mxu.pow_mod2) — sustained + single
            dispatch;
-- v1:      fused CIOS Pallas ladder (pallas_mont.pow_mod);
 - native:  host C++ CIOS (dds_tpu.native.powmod_batch);
 - python:  CPython pow() loop (the CPU baseline);
 - DJN:     the 448-bit short-exponent host path (what per-op encryption
@@ -51,7 +50,7 @@ def main(argv=None):
     from dds_tpu import native
     from dds_tpu.bench_key import bench_paillier_key
     from dds_tpu.ops import bignum as bn
-    from dds_tpu.ops import mont_mxu, pallas_mont
+    from dds_tpu.ops import mont_mxu
     from dds_tpu.ops.montgomery import ModCtx
 
     key = bench_paillier_key()
@@ -82,7 +81,7 @@ def main(argv=None):
     t_nat = best_of(lambda: native.powmod_batch(rs[: max(8, B // 32)], n, n2), repeats=2)
     nat_ops = max(8, B // 32) / t_nat
 
-    # v2 / v1 device ladders
+    # v2 device ladder
     v2_sus = sustained_device(lambda: mont_mxu.pow_mod2(mctx, dev, n), R=args.pipelined)
 
     def v2_block():
@@ -90,8 +89,6 @@ def main(argv=None):
 
     v2_block()
     v2_lat = best_of(v2_block, repeats=2)
-
-    v1_sus = sustained_device(lambda: pallas_mont.pow_mod(ctx, dev, n), R=args.pipelined)
 
     # batched CRT decrypt: Sanctum device path (both half-width legs
     # fused into one dispatch, secret moduli never in the shared caches
@@ -121,7 +118,6 @@ def main(argv=None):
         exp_bits=n.bit_length(),
         v2_sustained_ops=round(B / v2_sus, 1),
         v2_single_dispatch_ops=round(B / v2_lat, 1),
-        v1_sustained_ops=round(B / v1_sus, 1),
         native_host_ops=round(nat_ops, 1),
         python_pow_ops=round(py_ops, 1),
         djn_short_exp_host_ops=round(djn_ops, 1),

@@ -1,4 +1,5 @@
-"""Microprofile of the Pallas CIOS building blocks (dev tool, not a config).
+"""Microprofile of the v2 kernel's building blocks: VPU and MXU probes and
+the v2 roofline (dev tool, not a config).
 
 All timed functions return a scalar reduction of their output so only 4
 bytes cross the host<->device link per call while the full computation
@@ -12,11 +13,8 @@ import time
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from dds_tpu.bench_key import bench_paillier_key
-from dds_tpu.ops import pallas_mont as pm
 from dds_tpu.ops.montgomery import ModCtx
 
 
@@ -28,37 +26,6 @@ def timeit(fn, *args, repeats=5):
         np.asarray(fn(*args))
         ts.append(time.perf_counter() - t0)
     return min(ts)
-
-
-def make_nofinal_mul(L, Lt, TB):
-    """Same CIOS loop, but skip finalize: emit redundant t rows directly."""
-
-    def kernel(n0_ref, a_ref, b_ref, nbx_ref, out_ref):
-        n0 = n0_ref[0, 0]
-        b = b_ref[:, :]
-        nb = nbx_ref[0:L, :]
-        t = pm._cios_loop(
-            lambda i: a_ref[pl.ds(i, 1), :], b, nb, n0,
-            jnp.zeros((Lt, TB), jnp.uint32), L,
-        )
-        out_ref[:, :] = t[0:L, :]
-
-    def call(B):
-        return pl.pallas_call(
-            kernel,
-            grid=(B // TB,),
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-                pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-                pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-                pl.BlockSpec((Lt, TB), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((L, B), jnp.uint32),
-            interpret=pm._interpret_default(),
-        )
-
-    return call
 
 
 def vpu_mul_rate() -> float:
@@ -139,22 +106,8 @@ def roofline_report(bits_list=(1024, 2048, 4096)):
 def main():
     key = bench_paillier_key()
     ctx = ModCtx.make(key.nsquare)
-    L, TB = ctx.L, pm.MUL_TB
-    Lt = pm._pad_rows(L)
-    B = 8192
+    L = ctx.L
     rng = np.random.default_rng(0)
-    a = jnp.asarray(rng.integers(0, 1 << 16, size=(L, B), dtype=np.uint32))
-    b = jnp.asarray(rng.integers(0, 1 << 16, size=(L, B), dtype=np.uint32))
-
-    f = jax.jit(lambda a, b: pm.mul_lm(ctx, a, b).sum())
-    t_full = timeit(f, a, b)
-    print(f"mul_lm       B={B}: {t_full*1e3:8.2f} ms  -> {t_full/B*1e9:7.1f} ns/modmul")
-
-    nf = make_nofinal_mul(L, Lt, TB)(B)
-    g = jax.jit(lambda a, b: nf(pm._n0(ctx), a, b, pm._nbx(ctx, TB)).sum())
-    t_nf = timeit(g, a, b)
-    print(f"no-finalize  B={B}: {t_nf*1e3:8.2f} ms  -> {t_nf/B*1e9:7.1f} ns/modmul")
-    print(f"finalize share: {(t_full-t_nf)/t_full*100:.1f}%")
 
     # VPU elementwise throughput probes (32 chained ops on a 32M tile)
     x = jnp.asarray(rng.integers(0, 1 << 16, size=(512, 65536), dtype=np.uint32))

@@ -86,9 +86,10 @@ def sweep_one(bits: int, K: int, B: int, repeats: int = 3) -> list[dict]:
     batch = jax.device_put(bn.ints_to_batch(bases, ctx.L))
     jax.block_until_ready(batch)
     if tpu.pallas:
-        from dds_tpu.ops import pallas_mont
+        from dds_tpu.ops import mont_mxu
 
-        run = lambda: pallas_mont.pow_mod(ctx, batch, k_scalar)
+        mctx = mont_mxu.MxuCtx.make(ctx)
+        run = lambda: mont_mxu.pow_mod2(mctx, batch, k_scalar)
     else:
         run = lambda: ctx.pow_mod(batch, k_scalar)
     tpu_s = sustained_device(run, R=8, repeats=repeats)

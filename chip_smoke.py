@@ -8,11 +8,10 @@ one chip, three phases, any failure a non-zero exit.
 - `device`:  jax found a TPU (anything else exits here, before any result
              is printed), the native host bignum library built, and the
              versions and compile-cache directory in effect.
-- `kernels`: every Pallas kernel a flag or a key size can select compiles
-             for the chip (not interpret mode) and is exact against python
-             ints: fold and modexp through `TpuBackend` at RSA-1024/2048
-             and Paillier-2048/4096 widths on v2, v1 at Paillier-2048, and
-             each Karatsuba mode of the v2 multiply.
+- `kernels`: every Pallas kernel a key size can select compiles for the
+             chip (not interpret mode) and is exact against python ints:
+             fold and modexp through `TpuBackend` (the v2 family) at
+             RSA-1024/2048 and Paillier-2048/4096 widths.
 - `serve`:   the main path at deployment size through `run.launch` and the
              REST routes — 4 in-process replicas (f=1, quorum 3), K
              client-encrypted 8-column rows loaded by `POST /PutSet`, then
@@ -96,14 +95,14 @@ def _moduli() -> list[tuple[str, int]]:
             ("paillier-2048", k2.nsquare), ("paillier-4096", k4.nsquare)]
 
 
-def _backend_exact(rng, label: str, n: int, kernel: str, fold_k: int,
-                   pow_b: int) -> None:
+def _backend_exact(rng, label: str, n: int, fold_k: int, pow_b: int) -> None:
     """Fold and modexp through TpuBackend on the device, against python."""
     from dds_tpu.models.backend import CpuBackend, TpuBackend
 
-    be = TpuBackend(min_device_batch=0, kernel=kernel)
-    check(be.pallas is True, f"{label}/{kernel}: TpuBackend chose the jnp "
-          "path, not the compiled Pallas kernels")
+    be = TpuBackend(min_device_batch=0)
+    kernel = be.fold_kernel()
+    check(be.pallas is True and kernel == "v2", f"{label}/{kernel}: "
+          "TpuBackend chose the jnp path, not the compiled Pallas kernels")
     cpu = CpuBackend()
     cs = [rng.randrange(1, n) for _ in range(fold_k)]
     t0 = time.perf_counter()
@@ -122,41 +121,11 @@ def _backend_exact(rng, label: str, n: int, kernel: str, fold_k: int,
         fold_first_call_s=round(fold_s, 2), pow_first_call_s=round(pow_s, 2))
 
 
-def _mul2_exact(rng, label: str, n: int, mode, width: int) -> None:
-    """One v2 Montgomery multiply in Karatsuba mode `mode`, compiled."""
-    import jax
-    import numpy as np
-
-    from dds_tpu.ops import bignum as bn
-    from dds_tpu.ops import mont_mxu
-    from dds_tpu.ops.montgomery import ModCtx
-
-    ctx = ModCtx.make(n)
-    mctx = mont_mxu.MxuCtx.make(ctx)
-    xs = [rng.randrange(n) for _ in range(width)]
-    ys = [rng.randrange(n) for _ in range(width)]
-    fn = jax.jit(lambda a, b: mont_mxu.mul2_lm(mctx, a, b, False, mode))
-    t0 = time.perf_counter()
-    out = np.asarray(fn(bn.ints_to_batch(xs, ctx.L).T,
-                        bn.ints_to_batch(ys, ctx.L).T))
-    first_s = time.perf_counter() - t0
-    rinv = pow(1 << (16 * ctx.L), -1, n)
-    check(bn.batch_to_ints(out.T) == [x * y * rinv % n
-                                      for x, y in zip(xs, ys)],
-          f"{label}: mul2_lm(karatsuba={mode!r}) differs from python ints")
-    say("kernels", modulus=label, L=ctx.L, kernel="v2.mul2_lm",
-        karatsuba=mode, first_call_s=round(first_s, 2))
-
-
 def phase_kernels(seed: int, fold_k: int = FOLD_K, pow_b: int = POW_B) -> None:
     rng = random.Random(seed)
     moduli = _moduli()
     for label, n in moduli:
-        _backend_exact(rng, label, n, "v2", fold_k, pow_b)
-    label, n2 = moduli[2]
-    _backend_exact(rng, label, n2, "v1", fold_k, pow_b)
-    for mode in (False, "k1"):
-        _mul2_exact(rng, label, n2, mode, pow_b)
+        _backend_exact(rng, label, n, fold_k, pow_b)
 
 
 # ------------------------------------------------------------------- serve

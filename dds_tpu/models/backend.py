@@ -116,55 +116,26 @@ def _device() -> tuple[str, str]:
     return platform, jax.devices()[0].device_kind
 
 
-def _use_pallas(platform: str) -> bool:
-    """Compiled Pallas kernels on a TPU; jnp reference path elsewhere.
-
-    Override with DDS_PALLAS=1 (force, incl. interpret mode on CPU) or
-    DDS_PALLAS=0 (force the jnp path even on TPU).
-    """
-    import os
-
-    flag = os.environ.get("DDS_PALLAS", "").strip().lower()
-    if flag:
-        return flag not in ("0", "false", "off", "no")
-    return platform == "tpu"
-
-
 class TpuBackend:
     """Batched limb-tensor backend on the tier-0 Montgomery kernels.
 
-    On a TPU the compiled Pallas kernels run (ops/mont_mxu,
-    ops/pallas_mont); on a CPU the process asked for (tests), the portable
-    jnp path. Constructing it anywhere else raises (see `_device`).
-    Compiled kernels are cached per modulus via ModCtx.make's lru_cache.
+    Two kernel families (ops/kernel): on a TPU the compiled Pallas "v2"
+    kernels run (ops/mont_mxu); on a CPU the process asked for (tests),
+    the portable "jnp" path (ops/montgomery). `pallas=None` takes what is
+    observed (Pallas on a TPU); tests pass `pallas=True` to run the v2
+    kernels in interpret mode on the CPU. Constructing it anywhere else
+    raises (see `_device`). Compiled kernels are cached per modulus via
+    ModCtx.make's lru_cache.
     """
 
     name = "tpu"
 
-    def __init__(self, pallas: bool | None = None, min_device_batch: int | None = None,
-                 kernel: str | None = None, mesh=None):
+    def __init__(self, pallas: bool | None = None,
+                 min_device_batch: int | None = None, mesh=None):
         import os
 
         self.platform, self.device_kind = _device()
-        self.pallas = _use_pallas(self.platform) if pallas is None else pallas
-        # Kernel family for folds AND batch modexp: "v2" = schoolbook
-        # product + MXU band-matmul REDC (ops/mont_mxu), "v1" = fused CIOS
-        # (ops/pallas_mont). v2 wins both ops on TPU hardware (see
-        # benchmarks/kernel_compare.py); DDS_KERNEL overrides both.
-        self.kernel = (
-            kernel if kernel is not None else os.environ.get("DDS_KERNEL", "v2")
-        ).strip().lower()
-        if self.kernel not in ("v1", "v2"):
-            raise ValueError(
-                f"unknown fold kernel {self.kernel!r} (must be v1 or v2)"
-            )
-        if self.pallas and self.kernel == "v2":
-            # surface a bogus DDS_KARATSUBA at construction, not deep
-            # inside the first traced fold; only v2 consults it, and
-            # ops.flags is jax-free (no pallas import on this path)
-            from dds_tpu.ops.flags import karatsuba_mode
-
-            karatsuba_mode()
+        self.pallas = self.platform == "tpu" if pallas is None else pallas
         # Adaptive dispatch: below this fold width the flat device-dispatch
         # latency loses to a host fold, so small aggregates stay on host.
         # 1024 is the crossover of an earlier installation and has not been
@@ -224,19 +195,13 @@ class TpuBackend:
         # one multiply: a device round-trip can never win
         return c1 * c2 % modulus
 
-    def _mesh_kernel(self) -> str:
-        """The single kernel-family rule for every composite fold path —
-        mesh-sharded (parallel/mesh.py), coalesced (ops/foldmany) AND
-        resident-fused (dds_tpu/resident): the SAME family the
-        single-chip path would use (v1/v2 when pallas is on, the portable
-        jnp scans otherwise), so scale-out and batching never silently
-        run a slower kernel."""
-        return self.kernel if self.pallas else "jnp"
-
     def fold_kernel(self) -> str:
-        """Public alias of the composite-fold kernel rule — what the
-        Lodestone ResidentPlane builds its fused dispatch on."""
-        return self._mesh_kernel()
+        """The single kernel-family rule (a family of ops/kernel) for the
+        flat fold and every composite one — mesh-sharded (parallel/mesh),
+        coalesced (ops/foldmany) and resident-fused (dds_tpu/resident):
+        v2 when pallas is on, the portable jnp scans otherwise, so
+        scale-out and batching never silently run a slower kernel."""
+        return "v2" if self.pallas else "jnp"
 
     def resident_plane(self, initial_rows: int = 256,
                        max_rows: int = 1 << 20):
@@ -276,16 +241,12 @@ class TpuBackend:
             from dds_tpu.parallel import mesh as pm
 
             return pm.sharded_reduce_mul_fixed(
-                ctx, batch, mesh, kernel=self._mesh_kernel()
+                ctx, batch, mesh, kernel=self.fold_kernel()
             )
         if self.pallas:
-            if self.kernel == "v2":
-                from dds_tpu.ops import mont_mxu
+            from dds_tpu.ops import mont_mxu
 
-                return mont_mxu.reduce_mul2(mont_mxu.MxuCtx.make(ctx), batch)
-            from dds_tpu.ops import pallas_mont
-
-            return pallas_mont.reduce_mul(ctx, batch)
+            return mont_mxu.reduce_mul2(mont_mxu.MxuCtx.make(ctx), batch)
         return ctx.reduce_mul(batch)
 
     def modmul_fold(self, cs: list[int], modulus: int) -> int:
@@ -302,7 +263,7 @@ class TpuBackend:
         aggregates that individually sit below min_device_batch."""
         from dds_tpu.ops import foldmany
 
-        return foldmany.fold_many(folds, modulus, kernel=self._mesh_kernel())
+        return foldmany.fold_many(folds, modulus, kernel=self.fold_kernel())
 
     def matvec(
         self, cs: list[int], weights: list[list[int]], modulus: int,
@@ -322,7 +283,7 @@ class TpuBackend:
         from dds_tpu.ops import foldmany
 
         return foldmany.fold_weighted(
-            cs, weights, modulus, kernel=self._mesh_kernel(), rows=rows
+            cs, weights, modulus, kernel=self.fold_kernel(), rows=rows
         )
 
     def powmod_batch(self, bases: list[int], exp: int, modulus: int) -> list[int]:
@@ -343,21 +304,13 @@ class TpuBackend:
                 one[:, 0] = 1
                 batch = jnp.concatenate([jnp.asarray(batch), jnp.asarray(one)], 0)
             out = pm.sharded_pow_mod(
-                ctx, batch, _exp_to_digits(exp), mesh, kernel=self._mesh_kernel()
+                ctx, batch, _exp_to_digits(exp), mesh, kernel=self.fold_kernel()
             )
             return bn.batch_to_ints(np.asarray(out)[:B])
         if self.pallas:
-            if self.kernel == "v2":
-                # v2 wins modexp in both regimes (benchmarks/kernel_compare,
-                # back-to-back on a v5e: sustained 7.5 vs 12.7 ms, single
-                # dispatch 48 vs 84 ms @ B=256/L=256/64-bit exp)
-                from dds_tpu.ops import mont_mxu
+            from dds_tpu.ops import mont_mxu
 
-                out = mont_mxu.pow_mod2(mont_mxu.MxuCtx.make(ctx), batch, exp)
-            else:
-                from dds_tpu.ops import pallas_mont
-
-                out = pallas_mont.pow_mod(ctx, batch, exp)
+            out = mont_mxu.pow_mod2(mont_mxu.MxuCtx.make(ctx), batch, exp)
         else:
             out = ctx.pow_mod(batch, exp)
         return bn.batch_to_ints(np.asarray(out))

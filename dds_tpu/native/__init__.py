@@ -5,7 +5,7 @@ the reference's closed-source crypto jar (`hlib.hj.mlib`, `lib/README.txt:1`)
 on the host side: Paillier/RSA modexp and modmul for the principals that
 hold private keys (clients: encrypt/decrypt, `clt/DDSHttpClient.scala:131-134`
 trust model) and for accelerator-less hosts. The TPU Pallas kernels in
-`ops/pallas_mont.py` remain the batched data-plane path.
+`ops/mont_mxu.py` remain the batched data-plane path.
 
 The C++ source ships in-package and compiles once on first use with g++
 (-O3, native __uint128 CIOS, no external dependencies); the .so is cached

@@ -5,7 +5,7 @@
 // see lib/README.txt:1 and utils/SJHomoLibProvider.scala:33-101): all
 // host-side Paillier/RSA hot math (client-side encrypt, CRT decrypt, CPU
 // replica-side folds) runs here instead of interpreter big-ints. The TPU
-// Pallas kernels (ops/pallas_mont.py) remain the data-plane compute path;
+// Pallas kernels (ops/mont_mxu.py) remain the data-plane compute path;
 // this library serves the principals that hold private keys and hosts
 // without an accelerator.
 //

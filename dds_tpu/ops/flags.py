@@ -7,22 +7,6 @@ from __future__ import annotations
 import os
 
 
-def karatsuba_mode() -> str | bool:
-    """DDS_KARATSUBA: "" / 0 -> off (plain schoolbook, the measured
-    default), 1 -> the composed k1 variant (XLA-side combine; kept as the
-    negative-result record). Returns a mode usable as a jit cache key;
-    unknown values fail loudly (a typo silently running the
-    recorded-negative k1 variant would mislead every number downstream)."""
-    flag = os.environ.get("DDS_KARATSUBA", "").strip().lower()
-    if not flag or flag in ("0", "false", "off", "no"):
-        return False
-    if flag in ("1", "true", "on", "yes", "k1"):
-        return "k1"
-    raise ValueError(
-        f"unknown DDS_KARATSUBA value {flag!r} (use 0 or 1/k1)"
-    )
-
-
 def analytics_max_rows(default: int = 256) -> int:
     """Per-request weight-row cap for the Prism analytics routes (MatVec
     rows / GroupBySum groups): DDS_ANALYTICS_MAX_ROWS when set, else
@@ -82,7 +66,7 @@ def prod_tb() -> int | None:
     when unset. Validated HERE — int, positive, multiple of the 128-lane
     width — so a typo fails loudly at flag-read time with an actionable
     message instead of an opaque ValueError (or a mis-shaped kernel) deep
-    inside a trace (mirrors karatsuba_mode's loud-validation policy)."""
+    inside a trace."""
     env = os.environ.get("DDS_PROD_TB", "").strip()
     if not env:
         return None

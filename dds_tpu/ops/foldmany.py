@@ -28,75 +28,29 @@ kernel-family selection, and Montgomery contexts with fold_many.
 
 from __future__ import annotations
 
-import functools
-import threading
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from dds_tpu.obs import kprof
 from dds_tpu.ops import bignum as bn
-from dds_tpu.ops.flags import karatsuba_mode
-from dds_tpu.ops.montgomery import ModCtx, _mont_mul_raw
-
-_FN_CACHE: dict = {}
-_FN_CACHE_MAX = 64
-_FN_CACHE_LOCK = threading.Lock()
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _mul_bm(ctx: ModCtx, kernel: str, interpret: bool):
-    """Batch-major (B, L) Montgomery multiply for the kernel family
-    (mirrors parallel/mesh._local_fold_fn's selection)."""
-    if kernel == "v2":
-        from dds_tpu.ops import mont_mxu
-
-        mctx = mont_mxu.MxuCtx.make(ctx)
-        karatsuba = mont_mxu._use_karatsuba()
-        return lambda a, b: mont_mxu.mul2_lm(mctx, a.T, b.T, interpret, karatsuba).T
-    if kernel == "v1":
-        from dds_tpu.ops import pallas_mont
-
-        return lambda a, b: pallas_mont.mul_lm(ctx, a.T, b.T, interpret=interpret).T
-    N = jnp.asarray(ctx.N)
-    n0inv = jnp.uint32(ctx.n0inv)
-    return lambda a, b: _mont_mul_raw(a, b, N, n0inv)
+from dds_tpu.ops.kernel import fn_cache, halving_tree, interpret_default, mont_mul
+from dds_tpu.ops.montgomery import ModCtx
 
 
 def _fold_many_fn(ctx: ModCtx, kernel: str, R: int):
-    # the karatsuba mode and interpret flag are captured at build time by
-    # _mul_bm, so they MUST be in the cache key (mirroring mont_mxu's
-    # per-call karatsuba keying) — otherwise flipping DDS_KARATSUBA or the
-    # backend mid-process would silently serve a stale compiled function
-    interpret = _interpret_default()
-    kmode = karatsuba_mode() if kernel == "v2" else None
-    key = (ctx.n, kernel, R, interpret, kmode)
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("foldmany", hit=fn is not None)
-    if fn is not None:
-        return fn
-    mul = _mul_bm(ctx, kernel, interpret)
+    # interpret is baked into the multiply at trace time, so it is in the
+    # key: a backend flipped mid-process must not be served a stale trace
+    interpret = interpret_default()
 
     def run(arr, fixes):
         # arr: (P2*R, L) elem-major plain-domain; fixes: (R, L) = R^K_r
-        w = arr.shape[0] // R
-        x = arr
-        while w > 1:
-            h = w // 2
-            x = mul(x[: h * R], x[h * R : 2 * h * R])
-            w = h
-        return mul(x, fixes)                       # (R, L) plain domain
+        mul = mont_mul(ctx, kernel, interpret)
+        return mul(halving_tree(mul, arr, width=R), fixes)  # (R, L) plain
 
-    fn = jax.jit(run)
-    with _FN_CACHE_LOCK:
-        while len(_FN_CACHE) >= _FN_CACHE_MAX:
-            _FN_CACHE.pop(next(iter(_FN_CACHE)), None)
-        _FN_CACHE[key] = fn
-    return fn
+    return fn_cache(
+        "foldmany", (ctx.n, kernel, R, interpret), lambda: jax.jit(run)
+    )
 
 
 _WINDOW = 4  # digit width of the weighted fold's ladder (16-entry tables)
@@ -105,21 +59,9 @@ _WINDOW = 4  # digit width of the weighted fold's ladder (16-entry tables)
 def _fold_weighted_fn(ctx: ModCtx, kernel: str):
     """Compiled weighted-fold kernel for (ctx, kernel family): shapes are
     NOT in the cache key — jit retraces per (P2, Rp, D) input shape under
-    one entry, like mesh's "reduce" keys — but the karatsuba/interpret
-    flags are, for the same stale-executable reason as _fold_many_fn."""
-    interpret = _interpret_default()
-    kmode = karatsuba_mode() if kernel == "v2" else None
-    key = ("weighted", ctx.n, kernel, interpret, kmode)
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("fold_weighted", hit=fn is not None)
-    if fn is not None:
-        return fn
-    mul = _mul_bm(ctx, kernel, interpret)
-    one_mont = jnp.asarray(ctx.one_mont)
-    R2 = jnp.asarray(ctx.R2)
-    one_plain = np.zeros((ctx.L,), np.uint32)
-    one_plain[0] = 1
-    one_plain = jnp.asarray(one_plain)
+    one entry, like mesh's "reduce" keys — but the interpret flag is, for
+    the same stale-trace reason as _fold_many_fn."""
+    interpret = interpret_default()
     L = ctx.L
 
     def run(cs, digits):
@@ -127,9 +69,12 @@ def _fold_weighted_fn(ctx: ModCtx, kernel: str):
         # MSB-first 4-bit windows of each (row, operand) weight. Everything
         # runs in the Montgomery domain (entry via R2, exit via 1), so no
         # R-power bookkeeping is needed: mont_mul is closed over x~ = xR.
+        mul = mont_mul(ctx, kernel, interpret)
+        one_mont = jnp.asarray(ctx.one_mont)
+        one_plain = jnp.asarray(bn.ones_batch(1, L)[0])
         P2 = cs.shape[0]
         Rp = digits.shape[1]
-        cs_m = mul(cs, jnp.broadcast_to(R2, cs.shape))
+        cs_m = mul(cs, jnp.broadcast_to(jnp.asarray(ctx.R2), cs.shape))
         # table[d, k] = cs[k]^d for d in [0, 16): row-independent, so the
         # per-digit gather below serves every output row from one table
         tab = [jnp.broadcast_to(one_mont, cs.shape), cs_m]
@@ -138,30 +83,23 @@ def _fold_weighted_fn(ctx: ModCtx, kernel: str):
         table = jnp.stack(tab, axis=0)             # (16, P2, L)
         kidx = jnp.arange(P2)[None, :]
 
+        def mul_slabs(a, b):                       # (Rp, h, L) operands
+            return mul(a.reshape(-1, L), b.reshape(-1, L)).reshape(a.shape)
+
         def step(acc, dig):                        # acc (Rp, L); dig (Rp, P2)
             for _ in range(_WINDOW):
                 acc = mul(acc, acc)
             sel = table[dig, kidx]                 # (Rp, P2, L)
-            w = P2
-            x = sel
-            while w > 1:                           # tree fold over operands
-                h = w // 2
-                x = mul(
-                    x[:, :h].reshape(-1, L), x[:, h : 2 * h].reshape(-1, L)
-                ).reshape(Rp, h, L)
-                w = h
-            return mul(acc, x[:, 0]), None
+            # tree fold over the operand axis
+            return mul(acc, halving_tree(mul_slabs, sel, axis=1)[:, 0]), None
 
         acc0 = jnp.broadcast_to(one_mont, (Rp, L))
         acc, _ = jax.lax.scan(step, acc0, digits)
         return mul(acc, jnp.broadcast_to(one_plain, acc.shape))
 
-    fn = jax.jit(run)
-    with _FN_CACHE_LOCK:
-        while len(_FN_CACHE) >= _FN_CACHE_MAX:
-            _FN_CACHE.pop(next(iter(_FN_CACHE)), None)
-        _FN_CACHE[key] = fn
-    return fn
+    return fn_cache(
+        "fold_weighted", (ctx.n, kernel, interpret), lambda: jax.jit(run)
+    )
 
 
 def fold_weighted(

@@ -1,11 +1,11 @@
-"""Hybrid VPU+MXU Montgomery multiply (kernel v2).
+"""Hybrid VPU+MXU Montgomery multiply (kernel family "v2", the one a TPU
+serves; ops/montgomery's jnp scans are the portable reference).
 
-The CIOS kernel in `pallas_mont` interleaves the schoolbook product with
-the Montgomery reduction, so both halves of the work (2 L^2 limb products
-per multiply) run as uint32 VPU multiplies — the measured bottleneck
-(~60% of kernel time; u32 multiply throughput is ~8x below add/logic
-throughput on TPU VPUs). v2 separates the two halves and exploits that
-the *modulus is shared across the batch*:
+A fused CIOS multiply interleaves the schoolbook product with the
+Montgomery reduction, so both halves of the work (2 L^2 limb products per
+multiply) run as uint32 VPU multiplies, and u32 multiply throughput is
+~8x below add/logic throughput on TPU VPUs. v2 separates the two halves
+and exploits that the *modulus is shared across the batch*:
 
 - the a*b schoolbook product keeps the only varying*varying math on the
   VPU as a Pallas kernel (L^2 u32 multiplies — half of CIOS), producing a
@@ -17,17 +17,23 @@ the *modulus is shared across the batch*:
   band matrices of the modulus digits in base 2^8 — int8 MXU work that is
   ~free next to the VPU product;
 - carry normalization between stages is Kogge-Stone carry-lookahead in
-  plain XLA: O(log L) full-width vector passes instead of the O(L)
-  sequential scans of the v1 finalize.
+  plain XLA: O(log L) full-width vector passes instead of an O(L)
+  sequential carry scan.
 
 int8 matmuls need inputs in [-128, 127]; digit vectors/matrices live in
 [0, 255], so both are split as x = x' + 128*mask (x' signed, mask the 0/1
 support): M @ d = M'@d' + 128*(mask_M@d') + (128*M'@1 + 2^14*mask_M@1),
 i.e. two int8 matmuls plus a precomputed per-row constant.
 
-Replaces the same reference semantics as `pallas_mont` (the
-`HomoAdd.sum` / `HomoMult.multiply` folds of
-`dds/http/DDSRestServer.scala:385,423,479,518`); exactness is validated
+The product is plain schoolbook. A fused CIOS family (v1) and a
+one-level Karatsuba product (k1) were both built and lost at the served
+width on a v5e (L = 256: 2.1 times and 1.7 % slower); no cell or default
+ever reached them and PR 31 deleted them. v1 was ahead at L = 64 and k1
+at L = 512: PERF.md section 6 has the timings, ROADMAP D2 says when to
+recover them from commit a99dd7f.
+
+Replaces the reference's `HomoAdd.sum` / `HomoMult.multiply` folds
+(`dds/http/DDSRestServer.scala:385,423,479,518`); exactness is validated
 against python int arithmetic in tests/test_mxu.py.
 """
 
@@ -43,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dds_tpu.ops import bignum as bn
+from dds_tpu.ops.kernel import fold_fix, halving_tree, interpret_default
 from dds_tpu.ops.montgomery import ModCtx
 
 LIMB_BITS = bn.LIMB_BITS          # 16
@@ -76,10 +83,6 @@ def _tb_for(L: int) -> int:
     if L <= 128:
         return 256
     return PROD_TB
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -148,72 +151,6 @@ def _prod_call(L: int, B: int, TB: int, interpret: bool):
     )
 
 
-def _make_prod3_kernel(h: int, TB: int):
-    """Three independent (h, TB) x (h, TB) schoolbook products in ONE
-    kernel dispatch, outputs stacked as (6h, TB): the fused Karatsuba
-    product (z0 | z2 | z1-of-half-sums) without the per-product dispatch
-    + HBM round-trips that sank the composed variant. Same digit bounds
-    as _make_prod_kernel at half the row count."""
-
-    def kernel(a0_ref, b0_ref, a1_ref, b1_ref, sa_ref, sb_ref, out_ref, acc_ref):
-        for idx, (a_ref, b_ref) in enumerate(
-            ((a0_ref, b0_ref), (a1_ref, b1_ref), (sa_ref, sb_ref))
-        ):
-            acc_ref[:, :] = jnp.zeros((2 * h + GROUP, TB), jnp.uint32)
-            _accumulate_prod(
-                lambda i, r=a_ref: r[pl.ds(i, 1), :], b_ref[:, :], acc_ref, h, TB
-            )
-            out_ref[pl.ds(idx * 2 * h, 2 * h), :] = acc_ref[0 : 2 * h, :]
-
-    return kernel
-
-
-@functools.lru_cache(maxsize=None)
-def _prod3_call(h: int, B: int, TB: int, interpret: bool):
-    kernel = _make_prod3_kernel(h, TB)
-    spec = pl.BlockSpec((h, TB), lambda i: (0, i), memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // TB,),
-        in_specs=[spec] * 6,
-        out_specs=pl.BlockSpec(
-            (6 * h, TB), lambda i: (0, i), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((6 * h, B), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((2 * h + GROUP, TB), jnp.uint32)],
-        interpret=interpret,
-    )
-
-
-def _karatsuba_combine(z0c, z2c, z1, sa, ca, sb, cb, h: int, L: int):
-    """The proof-carrying Karatsuba recombination of prod_lm_k1: the
-    borrow-free complement-add math, on XLA values.
-
-    Inputs: canonical half products z0c/z2c (2h, B); redundant middle
-    product z1 (2h, B) of the normalized half sums sa/sb (h, B) with
-    overflow bits ca/cb (1, B) in {0,1}. Returns the (2L, B) redundant
-    accumulator T = z0 + [z1_full - z0 - z2]*X + z2*X^2 (see prod_lm_k1's
-    docstring for the digit bounds and the exactly-2 carry-out proof)."""
-    rows = 2 * h + 1
-    # z1_full over `rows` digits: cross terms of the overflow bits
-    z1f = jnp.pad(z1, ((0, 1), (0, 0)))
-    z1f = z1f.at[h : 2 * h].add(sb * ca)
-    z1f = z1f.at[h : 2 * h].add(sa * cb)
-    z1f = z1f.at[2 * h].add((ca * cb)[0])
-    # borrow-free middle term: complement-add the canonicalized z0/z2
-    comp0 = jnp.pad(MASK16 - z0c, ((0, 1), (0, 0)), constant_values=0xFFFF)
-    comp2 = jnp.pad(MASK16 - z2c, ((0, 1), (0, 0)), constant_values=0xFFFF)
-    t = z1f + comp0 + comp2
-    t = t.at[0:1].add(2)
-    mid, _ = carry_norm(t)   # carry-out is exactly 2; digits carry the value
-    B = z1.shape[1]
-    T = jnp.zeros((2 * L, B), jnp.uint32)
-    T = T.at[0 : 2 * h].add(z0c)
-    T = T.at[h : h + rows].add(mid)
-    T = T.at[2 * h :].add(z2c)
-    return T
-
-
 def _pad_lanes(x, TB: int):
     B = x.shape[1]
     Bp = max(TB, ((B + TB - 1) // TB) * TB)
@@ -230,7 +167,7 @@ def prod_lm(a, b, TB: int | None = None, interpret: bool | None = None):
     output is sliced back to 2L rows (the padded product's top rows are
     provably zero). TB=None picks the measured per-L lane tile (_tb_for)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     L = a.shape[0]
     if TB is None:
         TB = _tb_for(L)
@@ -241,80 +178,6 @@ def prod_lm(a, b, TB: int | None = None, interpret: bool | None = None):
     a, B = _pad_lanes(a, TB)
     b, _ = _pad_lanes(b, TB)
     return _prod_call(Lp, a.shape[1], TB, interpret)(a, b)[: 2 * L, :B]
-
-
-def prod_lm_k1(a, b, TB: int | None = None, interpret: bool | None = None):
-    """One Karatsuba level over prod_lm: 3 half-size schoolbook products
-    instead of 1 full-size one — 25% fewer VPU u32 multiplies, the v2
-    kernel's dominant cost. Composed entirely from existing primitives:
-
-        a = a0 + a1*X, b = b0 + b1*X  with X = 2^(16h), h = L/2
-        T = z0 + [z1 - z0 - z2]*X + z2*X^2,  z1 = (a0+a1)(b0+b1)
-
-    The half sums are carry-normalized into canonical h-limb digits plus a
-    0/1 overflow bit each (the bit's cross terms are cheap masked adds), so
-    the half-size products stay within prod_lm's 16-bit-digit contract.
-    The middle-term subtraction runs borrow-free as a complement add: with
-    rows = 2h+1 and canonical z0c/z2c,
-        t = z1_full + comp(z0c) + comp(z2c) + 2
-          = mid + 2*2^(16*rows)
-    so after carry_norm the carry-out is exactly 2 and the canonical
-    digits ARE the middle term. Digit bounds: every accumulated vector
-    stays < 2^27, far under carry_norm's 2^31 input bound.
-
-    Returns the same (2L, B) redundant accumulator shape as prod_lm; only
-    the digit decomposition differs, which _redc's carry normalization
-    absorbs. Requires L even with L/2 a multiple of GROUP (all supported
-    key sizes; falls back to prod_lm otherwise).
-
-    MEASURED VERDICT (v5e, sustained fold): the 25% multiply saving does
-    not survive the XLA-side combine — ~4% SLOWER at L=256 (17.0 vs
-    16.4 ms @ K=32768) and only ~3.5% faster at L=512 (14.0 vs 14.5 ms
-    @ K=8192), and fusing all three half-products into ONE dispatch
-    (_prod3_call, used here) moved those numbers by <1% vs the composed
-    three-dispatch form — so the cost is the combine's HBM passes
-    (2 carry_norms + complement adds + assembly over (2h..2L, B) arrays),
-    not dispatch overhead. Kept flag-gated (DDS_KARATSUBA=1) as a
-    correctness-tested experiment and as the record of why the default
-    stays plain schoolbook; the VMEM-combine variant this verdict calls
-    for exists as DDS_KARATSUBA=2 (`prod_lm_kf`, fully in-kernel)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    L = a.shape[0]
-    if TB is None:
-        TB = _tb_for(L)
-    if L % 2 or (L // 2) % GROUP:
-        return prod_lm(a, b, TB, interpret)
-    h = L // 2
-    a0, a1 = a[:h], a[h:]
-    b0, b1 = b[:h], b[h:]
-    sa, ca = carry_norm(a0 + a1)                           # (h,B), (1,B) in {0,1}
-    sb, cb = carry_norm(b0 + b1)
-    ap0, B0 = _pad_lanes(a0, TB)
-    bp0, _ = _pad_lanes(b0, TB)
-    ap1, _ = _pad_lanes(a1, TB)
-    bp1, _ = _pad_lanes(b1, TB)
-    sap, _ = _pad_lanes(sa, TB)
-    sbp, _ = _pad_lanes(sb, TB)
-    out = _prod3_call(h, ap0.shape[1], TB, interpret)(
-        ap0, bp0, ap1, bp1, sap, sbp
-    )
-    z0 = out[0 : 2 * h, :B0]                               # (2h, B)
-    z2 = out[2 * h : 4 * h, :B0]
-    z1 = out[4 * h :, :B0]
-    # products < 2^(32h): the carries past 2h rows are provably zero
-    z0c, _ = carry_norm(z0)
-    z2c, _ = carry_norm(z2)
-    return _karatsuba_combine(z0c, z2c, z1, sa, ca, sb, cb, h, L)
-
-
-def _use_karatsuba() -> str | bool:
-    """DDS_KARATSUBA mode (see ops/flags.karatsuba_mode — jax-free so
-    validators need not import this module): False = plain schoolbook
-    (the measured default), "k1" = the composed variant."""
-    from dds_tpu.ops.flags import karatsuba_mode
-
-    return karatsuba_mode()
 
 
 # ---------------------------------------------------------------------------
@@ -495,20 +358,9 @@ def _redc(mctx: MxuCtx, T):
     return jnp.where(take_diff, diff, t)
 
 
-def mul2_lm(mctx: MxuCtx, a, b, interpret: bool | None = None,
-            karatsuba: bool | str | None = None):
-    """Montgomery product a*b*R^-1 mod n, limbs-major (L, B) canonical.
-
-    `karatsuba` must be passed EXPLICITLY by traced callers (their jit
-    caches key on it); None reads the DDS_KARATSUBA env flag. Modes:
-    False = schoolbook, "k1"/True = composed Karatsuba (see
-    _use_karatsuba)."""
-    mode = _use_karatsuba() if karatsuba is None else karatsuba
-    if mode:  # "k1" or legacy True
-        T = prod_lm_k1(a, b, interpret=interpret)
-    else:
-        T = prod_lm(a, b, interpret=interpret)
-    return _redc(mctx, T)
+def mul2_lm(mctx: MxuCtx, a, b, interpret: bool | None = None):
+    """Montgomery product a*b*R^-1 mod n, limbs-major (L, B) canonical."""
+    return _redc(mctx, prod_lm(a, b, interpret=interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -516,18 +368,17 @@ def mul2_lm(mctx: MxuCtx, a, b, interpret: bool | None = None,
 # ---------------------------------------------------------------------------
 
 
-def _pow2_body(mctx: MxuCtx, E: int, interpret: bool, karatsuba: bool):
+def _pow2_body(mctx: MxuCtx, E: int, interpret: bool):
     """The traced ladder body (un-jitted): callers that already run under a
     transform (jit in _pow2_fn, shard_map in parallel/mesh.py) close over
     this directly."""
     ctx = mctx.ctx
-    mul = functools.partial(mul2_lm, karatsuba=karatsuba)
 
     def run(bases, digits):
         x = bases.T                                           # (L, B)
         shape = x.shape
         r2 = jnp.broadcast_to(jnp.asarray(ctx.R2)[:, None], shape)
-        xm = mul(mctx, x, r2, interpret)                  # to mont
+        xm = mul2_lm(mctx, x, r2, interpret)                  # to mont
         onem = jnp.broadcast_to(
             jnp.asarray(ctx.one_mont)[:, None], shape
         ).astype(jnp.uint32)
@@ -536,14 +387,14 @@ def _pow2_body(mctx: MxuCtx, E: int, interpret: bool, karatsuba: bool):
         # scan body stays branch-free)
         tab = [onem, xm]
         for _ in range(2, 16):
-            tab.append(mul(mctx, tab[-1], xm, interpret))
+            tab.append(mul2_lm(mctx, tab[-1], xm, interpret))
         table = jnp.stack(tab, axis=0)                        # (16, L, B)
         acc = jnp.take(table, digits[0], axis=0)
 
         def step(acc, d):
             for _ in range(4):                                # window bits
-                acc = mul(mctx, acc, acc, interpret)
-            acc = mul(mctx, acc, jnp.take(table, d, axis=0), interpret)
+                acc = mul2_lm(mctx, acc, acc, interpret)
+            acc = mul2_lm(mctx, acc, jnp.take(table, d, axis=0), interpret)
             return acc, None
 
         if E > 1:
@@ -558,47 +409,37 @@ def _pow2_body(mctx: MxuCtx, E: int, interpret: bool, karatsuba: bool):
 
 
 @functools.lru_cache(maxsize=None)
-def _pow2_fn(mctx: MxuCtx, E: int, interpret: bool, karatsuba: bool):
-    return jax.jit(_pow2_body(mctx, E, interpret, karatsuba))
+def _pow2_fn(mctx: MxuCtx, E: int, interpret: bool):
+    return jax.jit(_pow2_body(mctx, E, interpret))
 
 
 def pow_mod2(mctx: MxuCtx, bases, exp: int, interpret: bool | None = None):
     """Plain-domain bases^exp mod n via the v2 multiply; (B, L) in/out.
-    Contract identical to pallas_mont.pow_mod / ModCtx.pow_mod.
-
-    vs the v1 fused ladder (back-to-back on a v5e @ B=256, L=256, 64-bit
-    exp, benchmarks/kernel_compare): ~1.7x faster sustained (7.5 vs
-    12.7 ms/batch) and ~1.75x lower single-dispatch latency (48 vs 84 ms)
-    — the MXU REDC removes most of the VPU multiply work, which outweighs
-    the per-multiply HBM round-trips v1 avoids by keeping its chain
-    VMEM-resident. The serving backend uses this variant whenever folds
-    use v2 (the TPU default)."""
+    Contract identical to ModCtx.pow_mod."""
     from dds_tpu.ops.montgomery import _exp_to_digits
 
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     if exp == 0:
         return jnp.asarray(bn.ones_batch(bases.shape[0], mctx.ctx.L))
     digits = jnp.asarray(_exp_to_digits(exp).astype(np.int32))
     from dds_tpu.obs import kprof
 
     fn = kprof.counted(
-        "mont_mxu.pow2", _pow2_fn,
-        mctx, int(digits.shape[0]), interpret, _use_karatsuba(),
+        "mont_mxu.pow2", _pow2_fn, mctx, int(digits.shape[0]), interpret
     )
     return fn(jnp.asarray(bases), digits)
 
 
 @functools.lru_cache(maxsize=None)
-def _reduce2_fn(mctx: MxuCtx, P2: int, interpret: bool, karatsuba: bool):
+def _reduce2_fn(mctx: MxuCtx, P2: int, interpret: bool):
+    # the served fold: the yardstick reads its device time by the name
+    # `jit_run`, so the jitted function stays `run`
     def run(cs, fix):
-        x = cs.T
-        w = P2
-        while w > 1:
-            h = w // 2
-            x = mul2_lm(mctx, x[:, :h], x[:, h : 2 * h], interpret, karatsuba)
-            w = h
-        x = mul2_lm(mctx, x[:, :1], fix[:, None], interpret, karatsuba)
+        x = halving_tree(
+            lambda a, b: mul2_lm(mctx, a, b, interpret), cs.T, axis=1
+        )
+        x = mul2_lm(mctx, x[:, :1], fix[:, None], interpret)
         return x[:, :1].T
 
     return jax.jit(run)
@@ -607,11 +448,9 @@ def _reduce2_fn(mctx: MxuCtx, P2: int, interpret: bool, karatsuba: bool):
 def reduce_mul2(mctx: MxuCtx, cs, interpret: bool | None = None):
     """v2 modular product of all K rows of cs ((K, L) plain domain).
 
-    Contract identical to pallas_mont.reduce_mul / ModCtx.reduce_mul."""
-    from dds_tpu.ops.pallas_mont import _fold_fix
-
+    Contract identical to ModCtx.reduce_mul."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     ctx = mctx.ctx
     cs = jnp.asarray(cs)
     K = cs.shape[0]
@@ -621,7 +460,5 @@ def reduce_mul2(mctx: MxuCtx, cs, interpret: bool | None = None):
         cs = jnp.concatenate([cs, pad], axis=0)
     from dds_tpu.obs import kprof
 
-    fn = kprof.counted(
-        "mont_mxu.reduce2", _reduce2_fn, mctx, P2, interpret, _use_karatsuba()
-    )
-    return fn(cs, _fold_fix(ctx, K))
+    fn = kprof.counted("mont_mxu.reduce2", _reduce2_fn, mctx, P2, interpret)
+    return fn(cs, fold_fix(ctx, K))

@@ -49,6 +49,7 @@ from dds_tpu.ops.bignum import (
     normalize,
     cond_sub,
 )
+from dds_tpu.ops.kernel import pairwise_tree
 
 WINDOW = 4  # modexp window size (16-entry table)
 
@@ -181,18 +182,6 @@ def _mont_exp_rowdigits_raw(base, exp_digits, one_mont, N, n0inv):
     return r
 
 
-def _tree_reduce_raw(cs, N, n0inv):
-    """Binary-tree modular product of cs (K, L), K a power of two.
-
-    Inputs in *plain* domain; output = prod(cs) * R^-(K-1) mod n — the caller
-    multiplies by R^K mod n via one mont_mul to fix the domain.
-    """
-    t = cs
-    while t.shape[0] > 1:
-        t = _mont_mul_raw(t[0::2], t[1::2], N, n0inv)
-    return t
-
-
 def _exp_to_digits(exp: int) -> np.ndarray:
     """Python int -> MSB-first 4-bit digit array (at least one digit)."""
     if exp < 0:
@@ -302,8 +291,12 @@ class ModCtx:
 
     @functools.cached_property
     def _jit_tree_reduce(self):
+        """Binary-tree modular product of cs (K, L), K a power of two (so
+        the tree never pads): prod(cs) * R^-(K-1) mod n."""
         N, n0inv = jnp.asarray(self.N), jnp.uint32(self.n0inv)
-        return jax.jit(lambda cs: _tree_reduce_raw(cs, N, n0inv))
+        return jax.jit(lambda cs: pairwise_tree(
+            lambda a, b: _mont_mul_raw(a, b, N, n0inv), cs, None
+        ))
 
     @functools.cached_property
     def _jit_to_mont(self):

@@ -27,25 +27,21 @@ uint32):
   does, via hmac.compare_digest) to keep results bit-for-bit equal to the
   legacy scan.
 
-Dispatch discipline matches ops/foldmany: one module-level `_FN_CACHE`
-keyed by op family (shapes retrace under a single entry), lookups
-accounted via `kprof.cache_event("predicate", ...)`, every dispatch
-timed through `kprof.profiled("predicate", ...)` so `kernel.predicate.*`
-spans and histograms line up with the fold kernels'.
+Dispatch discipline matches ops/foldmany: jitted callables live in
+`ops/kernel.fn_cache` keyed by op family (shapes retrace under a single
+entry), lookups accounted as compile-cache events of "predicate", every
+dispatch timed through `kprof.profiled("predicate", ...)` so
+`kernel.predicate.*` spans and histograms line up with the fold kernels'.
 """
 
 from __future__ import annotations
 
 import hashlib
-import threading
 
 import numpy as np
 
 from dds_tpu.obs import kprof
-
-_FN_CACHE: dict = {}
-_FN_CACHE_MAX = 64
-_FN_CACHE_LOCK = threading.Lock()
+from dds_tpu.ops.kernel import fn_cache
 
 # 52-bit OPE ciphertexts split into two 26-bit lanes (see module docstring)
 LANE_BITS = 26
@@ -83,16 +79,6 @@ def pack_digests(values) -> tuple[np.ndarray, np.ndarray]:
     return hi, lo
 
 
-def _fn_cache_put(key, fn) -> None:
-    """foldmany's eviction discipline: FIFO-capped insert under the lock.
-    Shapes are NOT in the key — jit retraces per input shape under one
-    entry per op family."""
-    with _FN_CACHE_LOCK:
-        while len(_FN_CACHE) >= _FN_CACHE_MAX:
-            _FN_CACHE.pop(next(iter(_FN_CACHE)), None)
-        _FN_CACHE[key] = fn
-
-
 def _lex_gt(hi, lo, thi, tlo):
     return (hi > thi) | ((hi == thi) & (lo > tlo))
 
@@ -111,17 +97,12 @@ def compare_mask(hi: np.ndarray, lo: np.ndarray, op: str,
     import jax
     import jax.numpy as jnp
 
-    key = ("cmp", op)
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("predicate", hit=fn is not None)
-    if fn is None:
-        def run(hi, lo, thi, tlo):
-            ge = _lex_ge(hi, lo, thi, tlo)
-            gt = _lex_gt(hi, lo, thi, tlo)
-            return {"gt": gt, "ge": ge, "lt": ~ge, "le": ~gt}[op]
+    def run(hi, lo, thi, tlo):
+        ge = _lex_ge(hi, lo, thi, tlo)
+        gt = _lex_gt(hi, lo, thi, tlo)
+        return {"gt": gt, "ge": ge, "lt": ~ge, "le": ~gt}[op]
 
-        fn = jax.jit(run)
-        _fn_cache_put(key, fn)
+    fn = fn_cache("predicate", ("cmp", op), lambda: jax.jit(run))
     thi = np.uint32(threshold >> LANE_BITS)
     tlo = np.uint32(threshold & LANE_MASK)
     out = kprof.profiled(
@@ -139,15 +120,10 @@ def range_mask(hi: np.ndarray, lo: np.ndarray, lo_bound: int,
     import jax
     import jax.numpy as jnp
 
-    key = ("cmp", "range")
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("predicate", hit=fn is not None)
-    if fn is None:
-        def run(hi, lo, ahi, alo, bhi, blo):
-            return _lex_ge(hi, lo, ahi, alo) & ~_lex_gt(hi, lo, bhi, blo)
+    def run(hi, lo, ahi, alo, bhi, blo):
+        return _lex_ge(hi, lo, ahi, alo) & ~_lex_gt(hi, lo, bhi, blo)
 
-        fn = jax.jit(run)
-        _fn_cache_put(key, fn)
+    fn = fn_cache("predicate", ("cmp", "range"), lambda: jax.jit(run))
     out = kprof.profiled(
         "predicate",
         lambda: fn(
@@ -166,12 +142,12 @@ def eq_mask(dhi: np.ndarray, dlo: np.ndarray, query: str) -> np.ndarray:
     import jax
     import jax.numpy as jnp
 
-    key = ("digest", "eq")
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("predicate", hit=fn is not None)
-    if fn is None:
-        fn = jax.jit(lambda dhi, dlo, qhi, qlo: (dhi == qhi) & (dlo == qlo))
-        _fn_cache_put(key, fn)
+    fn = fn_cache(
+        "predicate", ("digest", "eq"),
+        lambda: jax.jit(
+            lambda dhi, dlo, qhi, qlo: (dhi == qhi) & (dlo == qlo)
+        ),
+    )
     qhi, qlo = digest_lanes(query)
     out = kprof.profiled(
         "predicate",
@@ -194,25 +170,20 @@ def entry_mask(dhi: np.ndarray, dlo: np.ndarray, valid: np.ndarray,
     import jax
     import jax.numpy as jnp
 
-    key = ("entry", mode)
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("predicate", hit=fn is not None)
-    if fn is None:
-        def run(dhi, dlo, valid, qhi, qlo):
-            # (N, C, Q) element-vs-query digest equality, masked to real
-            # (non-padding) elements
-            m = (
-                (dhi[:, :, None] == qhi[None, None, :])
-                & (dlo[:, :, None] == qlo[None, None, :])
-                & valid[:, :, None]
-            )
-            per_query = m.any(axis=1)  # (N, Q): query matched in row
-            if mode == "all":
-                return per_query.all(axis=1)
-            return per_query.any(axis=1)
+    def run(dhi, dlo, valid, qhi, qlo):
+        # (N, C, Q) element-vs-query digest equality, masked to real
+        # (non-padding) elements
+        m = (
+            (dhi[:, :, None] == qhi[None, None, :])
+            & (dlo[:, :, None] == qlo[None, None, :])
+            & valid[:, :, None]
+        )
+        per_query = m.any(axis=1)  # (N, Q): query matched in row
+        if mode == "all":
+            return per_query.all(axis=1)
+        return per_query.any(axis=1)
 
-        fn = jax.jit(run)
-        _fn_cache_put(key, fn)
+    fn = fn_cache("predicate", ("entry", mode), lambda: jax.jit(run))
     pairs = [digest_lanes(q) for q in queries]
     qhi = np.asarray([p[0] for p in pairs], np.uint32)
     qlo = np.asarray([p[1] for p in pairs], np.uint32)
@@ -232,24 +203,19 @@ def sort_perm(hi: np.ndarray, lo: np.ndarray, descending: bool) -> np.ndarray:
     import jax
     import jax.numpy as jnp
 
-    key = ("sort", descending)
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("predicate", hit=fn is not None)
-    if fn is None:
-        def run(hi, lo):
-            if descending:
-                # complementing both 26-bit lanes reverses the
-                # lexicographic order while the stable sort keeps ties in
-                # ascending row order — exactly sorted(reverse=True)
-                hi = LANE_MASK - hi
-                lo = LANE_MASK - lo
-            idx = jnp.arange(hi.shape[0], dtype=jnp.int32)
-            _, _, perm = jax.lax.sort((hi, lo, idx), num_keys=2,
-                                      is_stable=True)
-            return perm
+    def run(hi, lo):
+        if descending:
+            # complementing both 26-bit lanes reverses the
+            # lexicographic order while the stable sort keeps ties in
+            # ascending row order — exactly sorted(reverse=True)
+            hi = LANE_MASK - hi
+            lo = LANE_MASK - lo
+        idx = jnp.arange(hi.shape[0], dtype=jnp.int32)
+        _, _, perm = jax.lax.sort((hi, lo, idx), num_keys=2,
+                                  is_stable=True)
+        return perm
 
-        fn = jax.jit(run)
-        _fn_cache_put(key, fn)
+    fn = fn_cache("predicate", ("sort", descending), lambda: jax.jit(run))
     out = kprof.profiled(
         "predicate",
         lambda: fn(jnp.asarray(hi), jnp.asarray(lo)),

@@ -14,12 +14,12 @@ data-parallel batched ciphertext arithmetic sharded over a device mesh
   reduction.
 
 The shard-local math runs the SAME kernel family the single-chip path
-uses (`kernel=`): "v2" = VPU product + MXU band-REDC (ops/mont_mxu),
-"v1" = fused CIOS Pallas (ops/pallas_mont), "jnp" = the portable scan
-kernels — so N chips mean N x the fast kernel, not N x the portable one.
-Only the O(D) combine (D-1 multiplies of one residue each) stays on the
-portable `_mont_mul_raw`: a Pallas dispatch per single-row multiply would
-pad 1 lane to a full tile and cost more than it saves.
+uses (`kernel=`, a family of ops/kernel: "v2" = VPU product + MXU
+band-REDC, "jnp" = the portable scan kernels) — so N chips mean N x the
+fast kernel, not N x the portable one. Only the O(D) combine (D-1
+multiplies of one residue each) stays on the portable jnp multiply: a
+Pallas dispatch per single-row multiply would pad 1 lane to a full tile
+and cost more than it saves.
 
 Works identically on a real TPU slice and on the test fabric
 (`--xla_force_host_platform_device_count`, Pallas in interpret mode).
@@ -27,18 +27,17 @@ Works identically on a real TPU slice and on the test fabric
 
 from __future__ import annotations
 
-import functools
-import threading
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dds_tpu.ops import bignum as bn
-from dds_tpu.ops.montgomery import ModCtx, _mont_mul_raw, _mont_exp_raw, _tree_reduce_raw
-
-KERNELS = ("jnp", "v1", "v2")
+from dds_tpu.ops.kernel import (
+    check_family, fn_cache, fold_fix, halving_tree, interpret_default,
+    mont_mul, pairwise_tree,
+)
+from dds_tpu.ops.montgomery import ModCtx, _mont_exp_raw
 
 
 def make_mesh(n_devices: int | None = None, axis: str = "batch") -> Mesh:
@@ -46,12 +45,6 @@ def make_mesh(n_devices: int | None = None, axis: str = "batch") -> Mesh:
     if n_devices is not None:
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
-
-
-def _check_kernel(kernel: str) -> str:
-    if kernel not in KERNELS:
-        raise ValueError(f"unknown mesh kernel {kernel!r} (have {KERNELS})")
-    return kernel
 
 
 def group_sharding(mesh: Mesh | None, index: int, axis: str = "batch"):
@@ -68,91 +61,10 @@ def group_sharding(mesh: Mesh | None, index: int, axis: str = "batch"):
     return NamedSharding(Mesh(np.array([dev]), (axis,)), P())
 
 
-# jitted shard_map executables, keyed by (op, modulus, mesh, axis, kernel):
-# the serving path calls these per aggregate request, and rebuilding the
-# closure each call would defeat jax.jit's trace cache (jit keys on
-# function identity + shapes). Bounded FIFO (like ModCtx.make's lru_cache):
-# on the serving path the modulus comes from the client-supplied `nsqr`
-# query param, and each new modulus costs an XLA compile + retained
-# executable — unbounded growth would be a client-driven memory/compile DoS.
-_FN_CACHE: dict = {}
-_FN_CACHE_MAX = 64
-# folds are dispatched from proxy worker threads (asyncio.to_thread), so
-# eviction + insert must be atomic or two threads can pop the same FIFO key
-_FN_CACHE_LOCK = threading.Lock()
-
-
-def _fn_cache_put(key, fn) -> None:
-    with _FN_CACHE_LOCK:
-        while len(_FN_CACHE) >= _FN_CACHE_MAX:
-            _FN_CACHE.pop(next(iter(_FN_CACHE)), None)
-        _FN_CACHE[key] = fn
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _halving_tree_lm(mul_lm, x):
-    """Power-of-two tree fold over the lane axis of limbs-major x (L, W):
-    repeatedly multiply the left half by the right half with `mul_lm`
-    until one lane remains. Shared by both Pallas kernel families here
-    (and the same shape as mont_mxu._reduce2_fn's in-jit tree)."""
-    w = x.shape[1]
-    while w > 1:
-        h = w // 2
-        x = mul_lm(x[:, :h], x[:, h : 2 * h])
-        w = h
-    return x
-
-
-def _local_fold_fn(ctx: ModCtx, kernel: str, interpret: bool):
-    """Shard-local tree fold: (P2, L) batch-major -> (1, L) partial product
-    (times R^-(P2-1)), on the configured kernel family."""
-    if kernel == "v2":
-        from dds_tpu.ops import mont_mxu
-
-        mctx = mont_mxu.MxuCtx.make(ctx)
-        karatsuba = mont_mxu._use_karatsuba()
-        mul = lambda a, b: mont_mxu.mul2_lm(mctx, a, b, interpret, karatsuba)
-        return lambda local: _halving_tree_lm(mul, local.T).T
-    if kernel == "v1":
-        from dds_tpu.ops import pallas_mont
-
-        mul = lambda a, b: pallas_mont.mul_lm(ctx, a, b, interpret=interpret)
-        return lambda local: _halving_tree_lm(mul, local.T).T
-
-    N = jnp.asarray(ctx.N)
-    n0inv = jnp.uint32(ctx.n0inv)
-    one_mont = jnp.asarray(ctx.one_mont)
-
-    def fold(local):
-        return _tree_reduce_local(local, N, n0inv, one_mont)
-
-    return fold
-
-
-def _tree_reduce_local(cs, N, n0inv, one_mont):
-    """Tree reduction (shard-local, no collectives), any leaf count.
-
-    Odd levels are padded with the Montgomery identity R mod n. The R-power
-    accounting is structure-independent: a tree over K real leaves plus any
-    number of identity pads yields prod * R^-(K-1) (each pad contributes a
-    factor R, each internal mont_mul a factor R^-1, and pads - internals =
-    -(K-1) always).
-    """
-    t = cs
-    while t.shape[0] > 1:
-        if t.shape[0] % 2:
-            t = jnp.concatenate([t, one_mont[None, :]], axis=0)
-        t = _mont_mul_raw(t[0::2], t[1::2], N, n0inv)
-    return t
-
-
 def combine_partials(partials, modulus: int) -> int:
     """Modular-product tail combine over already-reduced partials — the
     host-integer twin of the replicated log2(D) tree `sharded_reduce_mul`
-    runs over gathered per-device partials (`_tree_reduce_local`). The
+    runs over gathered per-device partials (`ops/kernel.pairwise_tree`). The
     Constellation scatter-gather path (http/server._fold_aggregate) uses
     it to merge per-shard aggregate folds: every shard group shares one
     Paillier modulus, and the modular product is associative/commutative,
@@ -193,7 +105,7 @@ def sharded_reduce_mul(ctx: ModCtx, cs, mesh: Mesh, axis: str = "batch",
       that wins when per-device payloads are large enough that an
       all_gather would burst-buffer D copies at once.
     """
-    _check_kernel(kernel)
+    check_family(kernel)
     D = mesh.devices.size
     K = cs.shape[0]
     shard = -(-K // D)
@@ -202,56 +114,50 @@ def sharded_reduce_mul(ctx: ModCtx, cs, mesh: Mesh, axis: str = "batch",
     if total != K:
         pad = jnp.broadcast_to(jnp.asarray(ctx.one_mont), (total - K, ctx.L))
         cs = jnp.concatenate([jnp.asarray(cs), pad], axis=0)
+    interpret = interpret_default()
 
-    # NOT keyed on P2: jit retraces per input shape under one cache entry,
-    # and nothing in the closure bakes the shard width — keying on it would
-    # fragment the bounded FIFO per request size and churn compiles
-    key = ("reduce", ctx.n, mesh, axis, ring, kernel)
-    fn = _FN_CACHE.get(key)
-    if fn is None:
-        N = jnp.asarray(ctx.N)
-        n0inv = jnp.uint32(ctx.n0inv)
-        one_mont = jnp.asarray(ctx.one_mont)
-        perm = [(d, (d + 1) % D) for d in range(D)]
-        local_fold = _local_fold_fn(ctx, kernel, _interpret_default())
+    def step(local):
+        # local: (P2, L) on each device -> (1, L) partial, times R^-(P2-1)
+        lm = mont_mul(ctx, kernel, interpret, layout="lm")
+        partial = halving_tree(lm, local.T, axis=1).T
+        mul = mont_mul(ctx, "jnp", interpret)
+        if ring:
+            perm = [(d, (d + 1) % D) for d in range(D)]
 
-        def step(local):
-            # local: (P2, L) on each device
-            partial = local_fold(local)                           # (1, L)
-            if ring:
-                def hop(_, acc_msg):
-                    acc, msg = acc_msg
-                    msg = jax.lax.ppermute(msg, axis, perm)
-                    return _mont_mul_raw(acc, msg, N, n0inv), msg
+            def hop(_, acc_msg):
+                acc, msg = acc_msg
+                msg = jax.lax.ppermute(msg, axis, perm)
+                return mul(acc, msg), msg
 
-                acc, _ = jax.lax.fori_loop(
-                    0, D - 1, hop, (partial, partial)
-                )
-                return acc  # equal on every device after D-1 hops
-            partials = jax.lax.all_gather(partial, axis, tiled=True)  # (D, L)
-            return _tree_reduce_local(partials, N, n0inv, one_mont)   # (1, L) replicated
+            acc, _ = jax.lax.fori_loop(0, D - 1, hop, (partial, partial))
+            return acc  # equal on every device after D-1 hops
+        partials = jax.lax.all_gather(partial, axis, tiled=True)  # (D, L)
+        # (1, L), replicated
+        return pairwise_tree(mul, partials, jnp.asarray(ctx.one_mont))
 
-        fn = jax.jit(
-            jax.shard_map(
-                step,
-                mesh=mesh,
-                in_specs=P(axis),
-                out_specs=P(),  # replicated result
-                check_vma=False,  # scan carries start replicated inside the shard
-            )
-        )
-        _fn_cache_put(key, fn)
+    # jitted shard_map executables are cached because the serving path
+    # calls these per aggregate, and a closure rebuilt each call would
+    # defeat jax.jit's trace cache (it keys on function identity + shapes).
+    # NOT keyed on P2: jit retraces per input shape under one entry, and
+    # nothing in the closure bakes the shard width
+    fn = fn_cache(
+        "mesh", ("reduce", ctx.n, mesh, axis, ring, kernel, interpret),
+        lambda: jax.jit(jax.shard_map(
+            step,
+            mesh=mesh,
+            in_specs=P(axis),
+            out_specs=P(),  # replicated result
+            check_vma=False,  # scan carries start replicated inside the shard
+        )),
+    )
     return fn(cs)
 
 
 def sharded_reduce_mul_fixed(ctx: ModCtx, cs, mesh: Mesh, axis: str = "batch",
                              ring: bool = False, kernel: str = "jnp"):
     """Like ModCtx.reduce_mul but mesh-sharded: returns prod(cs) mod n (1, L)."""
-    K = cs.shape[0]
     prod = sharded_reduce_mul(ctx, cs, mesh, axis, ring, kernel)
-    R = 1 << (bn.LIMB_BITS * ctx.L)
-    fix = bn.int_to_limbs(pow(R % ctx.n, K, ctx.n), ctx.L)
-    return ctx.mont_mul(prod, jnp.asarray(fix)[None, :])
+    return ctx.mont_mul(prod, fold_fix(ctx, cs.shape[0])[None, :])
 
 
 def sharded_pow_mod(ctx: ModCtx, bases, exp_digits, mesh: Mesh,
@@ -263,69 +169,42 @@ def sharded_pow_mod(ctx: ModCtx, bases, exp_digits, mesh: Mesh,
     zero collectives; each device exponentiates its shard on the
     configured kernel family.
     """
-    _check_kernel(kernel)
+    check_family(kernel)
     E = int(exp_digits.shape[0])
+    interpret = interpret_default()
+    if kernel == "v2":
+        from dds_tpu.ops import mont_mxu
+
+        def step(local_bases, digits):
+            body = mont_mxu._pow2_body(mont_mxu.MxuCtx.make(ctx), E, interpret)
+            return body(local_bases, digits.astype(jnp.int32))
+    else:
+        def step(local_bases, digits):
+            mul = mont_mul(ctx, "jnp", interpret)
+            mont = mul(
+                local_bases,
+                jnp.broadcast_to(jnp.asarray(ctx.R2), local_bases.shape),
+            )
+            r = _mont_exp_raw(
+                mont, digits, jnp.asarray(ctx.one_mont), jnp.asarray(ctx.N),
+                jnp.uint32(ctx.n0inv),
+            )
+            one_plain = jnp.asarray(bn.ones_batch(1, ctx.L)[0])
+            return mul(r, jnp.broadcast_to(one_plain, r.shape))
+
     # E is in the key only for v2: _pow2_body bakes `E > 1` into the trace;
-    # the jnp/v1 steps derive everything from the digits' runtime shape, so
+    # the jnp step derives everything from the digits' runtime shape, so
     # one entry per modulus serves every exponent width there
-    key = ("pow", ctx.n, mesh, axis, kernel, E if kernel == "v2" else None)
-    fn = _FN_CACHE.get(key)
-    if fn is None:
-        interpret = _interpret_default()
-        if kernel == "v2":
-            from dds_tpu.ops import mont_mxu
-
-            mctx = mont_mxu.MxuCtx.make(ctx)
-            body = mont_mxu._pow2_body(
-                mctx, E, interpret, mont_mxu._use_karatsuba()
-            )
-
-            def step(local_bases, digits):
-                return body(local_bases, digits.astype(jnp.int32))
-        elif kernel == "v1":
-            from dds_tpu.ops import pallas_mont
-
-            R2col = jnp.asarray(ctx.R2)[:, None]
-            one = np.zeros((ctx.L, 1), np.uint32)
-            one[0, 0] = 1
-            one = jnp.asarray(one)
-
-            def step(local_bases, digits):
-                x = local_bases.T                              # (L, B)
-                xm = pallas_mont.mul_lm(
-                    ctx, x, jnp.broadcast_to(R2col, x.shape), interpret=interpret
-                )
-                r = pallas_mont.exp_lm(
-                    ctx, xm, digits.astype(jnp.int32), interpret=interpret
-                )
-                out = pallas_mont.mul_lm(
-                    ctx, r, jnp.broadcast_to(one, r.shape), interpret=interpret
-                )
-                return out.T
-        else:
-            N = jnp.asarray(ctx.N)
-            n0inv = jnp.uint32(ctx.n0inv)
-            R2 = jnp.asarray(ctx.R2)
-            one_mont = jnp.asarray(ctx.one_mont)
-            one_plain = np.zeros((ctx.L,), np.uint32)
-            one_plain[0] = 1
-            one_plain = jnp.asarray(one_plain)
-
-            def step(local_bases, digits):
-                mont = _mont_mul_raw(
-                    local_bases, jnp.broadcast_to(R2, local_bases.shape), N, n0inv
-                )
-                r = _mont_exp_raw(mont, digits, one_mont, N, n0inv)
-                return _mont_mul_raw(r, jnp.broadcast_to(one_plain, r.shape), N, n0inv)
-
-        fn = jax.jit(
-            jax.shard_map(
-                step,
-                mesh=mesh,
-                in_specs=(P(axis), P()),
-                out_specs=P(axis),
-                check_vma=False,  # scan carries start replicated inside the shard
-            )
-        )
-        _fn_cache_put(key, fn)
+    fn = fn_cache(
+        "mesh",
+        ("pow", ctx.n, mesh, axis, kernel, interpret,
+         E if kernel == "v2" else None),
+        lambda: jax.jit(jax.shard_map(
+            step,
+            mesh=mesh,
+            in_specs=(P(axis), P()),
+            out_specs=P(axis),
+            check_vma=False,  # scan carries start replicated inside the shard
+        )),
+    )
     return fn(bases, jnp.asarray(exp_digits))

@@ -18,9 +18,8 @@ ciphertext lanes stay memory-resident and host<->device traffic is
 index-only. On a single device (the test fabric) everything degrades to
 one jit over default-placed buffers: same math, same single dispatch.
 
-R-power accounting for the fused tree (structure-independent, same
-argument as `parallel/mesh._tree_reduce_local`): K real operands plus
-any number of Montgomery-identity pads through any tree shape yield
+R-power accounting for the fused tree is ops/kernel's: K real operands
+plus any number of Montgomery-identity pads through any tree shape yield
 prod * R^-(K-1); one final multiply by R^K mod n fixes the domain.
 
 The write-path ingest queue (`note_write` / `ingest_pending`) lets the
@@ -40,90 +39,60 @@ import numpy as np
 from dds_tpu.obs import kprof
 from dds_tpu.obs.metrics import metrics
 from dds_tpu.ops import bignum as bn
-from dds_tpu.ops.flags import karatsuba_mode
+from dds_tpu.ops.kernel import (
+    FAMILIES, fn_cache, fold_fix, halving_tree, interpret_default, mont_mul,
+    pairwise_tree,
+)
 from dds_tpu.ops.montgomery import ModCtx
 from dds_tpu.resident.pool import ResidentPool
 from dds_tpu.utils.queues import TimedQueue
 
-KERNELS = ("jnp", "v1", "v2")
-
-# jitted fused-fold executables, keyed by (modulus, S, kernel family,
-# interpret, karatsuba mode, mesh, axis): shapes retrace per input under
-# one entry (like parallel/mesh's "reduce" cache), the bounded FIFO caps
-# client-driven modulus churn exactly like the other kernel caches.
-_FN_CACHE: dict = {}
-_FN_CACHE_MAX = 64
-_FN_CACHE_LOCK = threading.Lock()
-
-
-def _interpret_default() -> bool:
-    import jax
-
-    return jax.default_backend() != "tpu"
-
 
 def _fused_fold_fn(ctx: ModCtx, S: int, kernel: str, mesh, axis: str):
-    """ONE compiled callable per (modulus, S, kernel, mesh): gathers each
-    group's rows from its pool buffer, pads to the common power-of-two
-    width with the Montgomery identity, tree-folds every group slab, and
-    tail-combines the S partials — all inside a single dispatch."""
+    """ONE compiled callable per (modulus, S, kernel, interpret, mesh):
+    gathers each group's rows from its pool buffer, pads to the common
+    power-of-two width with the Montgomery identity, tree-folds every
+    group slab, and tail-combines the S partials — all inside a single
+    dispatch. Shapes retrace per input under one cache entry."""
     import jax
     import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
-    interpret = _interpret_default()
-    kmode = karatsuba_mode() if kernel == "v2" else None
+    interpret = interpret_default()
     use_mesh = (
         mesh is not None and mesh.devices.size > 1
         and S % mesh.devices.size == 0
     )
-    key = ("fused", ctx.n, S, kernel, interpret, kmode,
-           mesh if use_mesh else None, axis)
-    fn = _FN_CACHE.get(key)
-    kprof.cache_event("resident_fold", hit=fn is not None)
-    if fn is not None:
-        return fn
-
-    from dds_tpu.ops.foldmany import _mul_bm
-    from jax.sharding import PartitionSpec as P
-
-    mul = _mul_bm(ctx, kernel, interpret)
-    one_mont = jnp.asarray(ctx.one_mont)
     L = ctx.L
 
-    def local_tree(stack):
-        # (G, P2, L) -> (G, L): halving tree over the operand axis of
-        # every group slab at once, no collectives
-        t = stack
-        while t.shape[1] > 1:
-            h = t.shape[1] // 2
-            t = mul(
-                t[:, :h].reshape(-1, L), t[:, h : 2 * h].reshape(-1, L)
-            ).reshape(t.shape[0], h, L)
-        return t[:, 0]
-
-    def tail(partials):
-        # (S, L) -> (1, L): the combine_partials tail tree, on-device
-        t = partials
-        while t.shape[0] > 1:
-            if t.shape[0] % 2:
-                t = jnp.concatenate([t, one_mont[None, :]], axis=0)
-            t = mul(t[0::2], t[1::2])
-        return t
-
-    if use_mesh:
-        step = jax.shard_map(
-            lambda local: tail(
-                jax.lax.all_gather(local_tree(local), axis, tiled=True)
-            ),
-            mesh=mesh,
-            in_specs=P(axis),
-            out_specs=P(),  # replicated combined partial
-            check_vma=False,
-        )
-    else:
-        step = lambda stack: tail(local_tree(stack))  # noqa: E731
-
     def run(bufs, idxs, fix):
+        mul = mont_mul(ctx, kernel, interpret)
+        one_mont = jnp.asarray(ctx.one_mont)
+
+        def mul_slabs(a, b):                       # (G, h, L) operands
+            return mul(a.reshape(-1, L), b.reshape(-1, L)).reshape(a.shape)
+
+        def local_tree(stack):
+            # (G, P2, L) -> (G, L): every group slab's halving tree at
+            # once, no collectives
+            return halving_tree(mul_slabs, stack, axis=1)[:, 0]
+
+        def tail(partials):
+            # (S, L) -> (1, L): the combine_partials tail tree, on-device
+            return pairwise_tree(mul, partials, one_mont)
+
+        if use_mesh:
+            step = jax.shard_map(
+                lambda local: tail(
+                    jax.lax.all_gather(local_tree(local), axis, tiled=True)
+                ),
+                mesh=mesh,
+                in_specs=P(axis),
+                out_specs=P(),  # replicated combined partial
+                check_vma=False,
+            )
+        else:
+            step = lambda stack: tail(local_tree(stack))  # noqa: E731
         P2 = 1
         for idx in idxs:
             P2 = max(P2, 1 << max(0, (idx.shape[0] - 1).bit_length()))
@@ -138,19 +107,18 @@ def _fused_fold_fn(ctx: ModCtx, S: int, kernel: str, mesh, axis: str):
             slabs.append(rows)
         return mul(step(jnp.stack(slabs)), fix)
 
-    fn = jax.jit(run)
-    with _FN_CACHE_LOCK:
-        while len(_FN_CACHE) >= _FN_CACHE_MAX:
-            _FN_CACHE.pop(next(iter(_FN_CACHE)), None)
-        _FN_CACHE[key] = fn
-    return fn
+    return fn_cache(
+        "resident_fold",
+        (ctx.n, S, kernel, interpret, mesh if use_mesh else None, axis),
+        lambda: jax.jit(run),
+    )
 
 
 class ResidentPlane:
     """Per-group resident pools + the fused single-dispatch sharded fold.
 
     `kernel` picks the Montgomery multiply family for the fused fold
-    (same rule as the backend's composite paths: v1/v2 on real TPU, the
+    (same rule as the backend's composite paths: v2 on a TPU, the
     portable jnp scans elsewhere). `mesh`/`axis` enable mesh placement;
     None is the single-device fallback. `reduce_factory(modulus)`
     optionally supplies the per-pool single-fold reduce (backends inject
@@ -159,7 +127,7 @@ class ResidentPlane:
     def __init__(self, kernel: str = "jnp", mesh=None, axis: str = "batch",
                  initial_rows: int = 256, max_rows: int = 1 << 20,
                  reduce_factory=None, max_pending: int = 8192):
-        self.kernel = kernel if kernel in KERNELS else "jnp"
+        self.kernel = kernel if kernel in FAMILIES else "jnp"
         self.mesh = mesh
         self.axis = axis
         self.initial_rows = int(initial_rows)
@@ -286,10 +254,7 @@ class ResidentPlane:
             idxs.append(jnp.asarray(idx))
             total += len(ops)
         fn = _fused_fold_fn(ctx, len(parts), self.kernel, self.mesh, self.axis)
-        R = 1 << (bn.LIMB_BITS * ctx.L)
-        fix = jnp.asarray(
-            bn.int_to_limbs(pow(R % ctx.n, total, ctx.n), ctx.L)
-        )[None, :]
+        fix = fold_fix(ctx, total)[None, :]
         out = kprof.profiled(
             "resident_fold",
             lambda: fn(tuple(bufs), tuple(idxs), fix),

@@ -3,7 +3,7 @@ from hiding.
 
 - every Pallas entry point cross-lowers to Mosaic for TPU from this CPU
   host with `interpret=False` (a kernel that only ever ran in interpret
-  mode, as the removed fused Karatsuba did with its scatter-adds, fails
+  mode, as a removed fused Karatsuba did with its scatter-adds, fails
   here, before the chip);
 - the tpu backend refuses a CPU nobody asked for;
 - the compile cache lands where the operator put it, else in the checkout;
@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from dds_tpu.ops import foldmany
 from dds_tpu.ops import mont_mxu as mx
-from dds_tpu.ops import pallas_mont as pm
 from dds_tpu.ops.montgomery import ModCtx
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -48,31 +47,21 @@ def lowers_to_mosaic(fn, *shapes) -> None:
     assert "tpu_custom_call" in text, "no Mosaic kernel in the lowering"
 
 
-def test_v1_entry_points_lower_for_tpu():
+def test_v2_multiply_lowers_for_tpu():
     lowers_to_mosaic(
-        lambda a, b: pm.mul_lm(CTX, a, b, interpret=False), u32(L, 8), u32(L, 8)
-    )
-    lowers_to_mosaic(pm._pow_fn(CTX, 4, False), u32(8, L), i32(4))
-    lowers_to_mosaic(pm._reduce_fn(CTX, 8, False), u32(8, L), u32(L))
-
-
-@pytest.mark.parametrize("mode", [False, "k1"])
-def test_v2_multiply_lowers_for_tpu_in_every_karatsuba_mode(mode):
-    lowers_to_mosaic(
-        lambda a, b: mx.mul2_lm(MCTX, a, b, False, mode), u32(L, 8), u32(L, 8)
+        lambda a, b: mx.mul2_lm(MCTX, a, b, False), u32(L, 8), u32(L, 8)
     )
 
 
 def test_v2_entry_points_lower_for_tpu():
-    lowers_to_mosaic(mx._pow2_fn(MCTX, 4, False, False), u32(8, L), i32(4))
-    lowers_to_mosaic(mx._reduce2_fn(MCTX, 8, False, False), u32(8, L), u32(L))
+    lowers_to_mosaic(mx._pow2_fn(MCTX, 4, False), u32(8, L), i32(4))
+    lowers_to_mosaic(mx._reduce2_fn(MCTX, 8, False), u32(8, L), u32(L))
 
 
-@pytest.mark.parametrize("kernel", ["v1", "v2"])
+@pytest.mark.parametrize("kernel", ["v2"])
 def test_foldmany_entry_points_lower_for_tpu(kernel, monkeypatch):
     # foldmany picks interpret mode itself from the backend in use
-    monkeypatch.setattr(foldmany, "_interpret_default", lambda: False)
-    monkeypatch.delenv("DDS_KARATSUBA", raising=False)
+    monkeypatch.setattr(foldmany, "interpret_default", lambda: False)
     lowers_to_mosaic(
         foldmany._fold_many_fn(CTX, kernel, 2), u32(8, L), u32(2, L)
     )

@@ -41,25 +41,29 @@ def test_backend_fold_many_dispatches_kernel_family():
 
     n = rng.getrandbits(256) | (1 << 255) | 1
     folds = [[rng.randrange(1, n) for _ in range(4)] for _ in range(2)]
-    be = TpuBackend(pallas=True, kernel="v2", min_device_batch=0)
+    be = TpuBackend(pallas=True, min_device_batch=0)
     assert be.modmul_fold_many(folds, n) == [_want(f, n) for f in folds]
 
 
-def test_fold_many_cache_keys_on_karatsuba_mode_and_interpret(monkeypatch):
-    """Flipping DDS_KARATSUBA mid-process must MISS the compiled-fn cache
-    (a stale hit would silently serve the other variant's kernel)."""
+def test_fold_many_cache_keys_on_interpret(monkeypatch):
+    """A backend flipped mid-process must MISS the compiled-fn cache (a
+    stale hit would serve a trace with the other interpret mode baked in)."""
+    from dds_tpu.ops import kernel
     from dds_tpu.ops.montgomery import ModCtx
 
     n = rng.getrandbits(256) | (1 << 255) | 1
     ctx = ModCtx.make(n)
-    monkeypatch.delenv("DDS_KARATSUBA", raising=False)
-    foldmany._fold_many_fn(ctx, "v2", 2)
-    keys_off = {k for k in foldmany._FN_CACHE if k[0] == ctx.n}
-    monkeypatch.setenv("DDS_KARATSUBA", "1")
-    foldmany._fold_many_fn(ctx, "v2", 2)
-    keys_k1 = {k for k in foldmany._FN_CACHE if k[0] == ctx.n}
-    assert keys_k1 != keys_off  # a NEW entry was compiled, not reused
-    assert any(k[-1] == "k1" for k in keys_k1 - keys_off)
+
+    def keys():
+        return {k for name, k in kernel._FN_CACHE
+                if name == "foldmany" and k[0] == ctx.n}
+
+    interpreted = foldmany._fold_many_fn(ctx, "v2", 2)
+    assert foldmany._fold_many_fn(ctx, "v2", 2) is interpreted
+    keys_interpreted = keys()
+    monkeypatch.setattr(foldmany, "interpret_default", lambda: False)
+    assert foldmany._fold_many_fn(ctx, "v2", 2) is not interpreted
+    assert keys() - keys_interpreted == {(ctx.n, "v2", 2, False)}
 
 
 def test_prod_tb_env_flag_validated_loudly(monkeypatch):

@@ -109,9 +109,7 @@ def test_mul2_matches_python(bits):
         assert g == (a * b * Rinv) % n
 
 
-def test_reduce_mul2_matches_python_and_v1():
-    from dds_tpu.ops import pallas_mont as pm
-
+def test_reduce_mul2_matches_python_and_jnp():
     rng = random.Random(7)
     n = _rand_mod(rng, 512)
     ctx = ModCtx.make(n)
@@ -124,8 +122,8 @@ def test_reduce_mul2_matches_python_and_v1():
         batch = bn.ints_to_batch(cs, ctx.L)
         got2 = bn.batch_to_ints(np.asarray(mx.reduce_mul2(mctx, batch, interpret=True)))[0]
         assert got2 == want, f"v2 fold wrong at K={K}"
-        got1 = bn.batch_to_ints(np.asarray(pm.reduce_mul(ctx, batch, interpret=True)))[0]
-        assert got1 == want, f"v1 fold wrong at K={K}"
+        got1 = bn.batch_to_ints(np.asarray(ctx.reduce_mul(batch)))[0]
+        assert got1 == want, f"jnp fold wrong at K={K}"
 
 
 @pytest.mark.parametrize("bits,ebits", [(256, 17), (256, 64), (512, 130)])
@@ -157,37 +155,3 @@ def test_pow_mod2_zero_exponent():
     bases = [rng.randrange(1, n) for _ in range(3)]
     out = mx.pow_mod2(mctx, bn.ints_to_batch(bases, ctx.L), 0)
     assert bn.batch_to_ints(np.asarray(out)) == [1, 1, 1]
-
-
-@pytest.mark.parametrize("bits", [256, 512])
-def test_prod_lm_k1_matches_python(bits):
-    """Karatsuba product variant: exact full products, any even L."""
-    import random
-
-    rng = random.Random(bits)
-    L = bn.n_limbs_for_bits(bits)
-    xs = [rng.getrandbits(bits) for _ in range(3)]
-    ys = [rng.getrandbits(bits) for _ in range(3)]
-    T = np.asarray(mx.prod_lm_k1(bn.ints_to_batch(xs, L).T,
-                                 bn.ints_to_batch(ys, L).T))
-    for i in range(3):
-        val = sum(int(d) << (16 * k) for k, d in enumerate(T[:, i]))
-        assert val == xs[i] * ys[i]
-
-
-def test_reduce_mul2_karatsuba_flag(monkeypatch):
-    """DDS_KARATSUBA=1 routes mul2 through prod_lm_k1 with identical
-    results."""
-    import random
-
-    monkeypatch.setenv("DDS_KARATSUBA", "1")
-    rng = random.Random(31)
-    n = rng.getrandbits(512) | (1 << 511) | 1
-    ctx = ModCtx.make(n)
-    mctx = mx.MxuCtx.make(ctx)
-    cs = [rng.randrange(n) for _ in range(8)]
-    out = mx.reduce_mul2(mctx, bn.ints_to_batch(cs, ctx.L))
-    want = 1
-    for c in cs:
-        want = want * c % n
-    assert bn.limbs_to_int(np.asarray(out)[0]) == want
