@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import base64
 import json
-from dataclasses import dataclass, fields
+from dataclasses import MISSING, dataclass, fields
 from typing import Any, Optional
 
 DDSSet = list  # a stored record: JSON-safe list of column values
@@ -138,11 +138,19 @@ class ReadTagBatch:
     fingerprint: Optional[bytes] = None
     # shard-map epoch, same fencing contract as Envelope.epoch
     epoch: int = -1
+    # fingerprint of the vector the proxy last verified FROM THE REPLICA IT
+    # SENDS THIS TO (each replica gets its own, under the one nonce). A
+    # replica that remembers that state of its kept vector answers with a
+    # `delta` reply: the positions replaced since. None, or a base it does
+    # not remember: the full reply.
+    base: Optional[bytes] = None
 
 
 @dataclass(frozen=True)
 class TagBatchReply:
-    tags: tuple   # ABDTag per key in the request's order (empty if unchanged)
+    # ABDTag per key in the request's order; empty if unchanged; in a delta
+    # (`base` set) the tag now held at each of `positions`
+    tags: tuple
     digest: str
     signature: bytes
     nonce: int
@@ -153,6 +161,13 @@ class TagBatchReply:
     # it for its next request.
     unchanged: bool = False
     fingerprint: Optional[bytes] = None
+    # delta reply (`base` is not None): "since my vector fingerprinted to
+    # `base` (the one your request named), the positions in `positions`
+    # (ascending, each once) were replaced; they now hold `tags`, and the
+    # vector fingerprints to `fingerprint`" — signature via
+    # abd_batch_delta_signature over all of it.
+    base: Optional[bytes] = None
+    positions: tuple = ()
 
 
 @dataclass(frozen=True)
@@ -687,6 +702,8 @@ def from_dict(d: dict):
     cls = _TYPES[d["__msg__"]]
     kwargs = {}
     for f in fields(cls):
+        if f.name not in d and f.default is not MISSING:
+            continue  # written by a version that lacked the field
         v = d[f.name]
         if f.type == "tuple" and isinstance(v, list):  # JSON has no tuples
             v = tuple(_dec(x) for x in v)

@@ -46,9 +46,101 @@ from dds_tpu.utils.trust import TrustedNodesList
 
 log = logging.getLogger("dds.quorum_client")
 
-# vote marker: "this replica's whole tag vector equals the caller's
-# fingerprinted cached vector" (see read_tags)
-_UNCHANGED = object()
+# key sets (by keys digest) whose senders' verified tag vectors a proxy
+# keeps between rounds; past it the one used longest ago goes
+MAX_TAG_ROUNDS = 4
+# a sender's kept vector may differ from the caller's list at a quarter of
+# the positions (at least this many) before it is dropped for a full reply
+MIN_KEPT_DIFF = 64
+# the vote of a replica whose vector equals the round's reference list:
+# it differs from it nowhere (see `_TagRound`)
+_SAME: dict = {}
+
+
+def _differing(old: list, new: list) -> list[int]:
+    """Positions at which two lists of one length hold unequal tags. The
+    lists mostly share their objects: slices compare at C speed (identity
+    first), and only a slice that differs is walked."""
+    out = []
+    for a in range(0, len(old), 128):
+        b = a + 128
+        if old[a:b] != new[a:b]:
+            out.extend(i for i in range(a, min(b, len(old)))
+                       if old[i] is not new[i] and old[i] != new[i])
+    return out
+
+
+class _KeptVectors:
+    """Per key set, what the proxy has verified from each replica: sender
+    -> (fingerprint, diff), the vector that sender last attested, held
+    sparsely as "`ref` except `diff[position]`". `ref` is the proxy's own
+    copy of the caller's `cached_tags` (which fingerprints to
+    `fingerprint`), re-based when the caller's list moves; `gen` counts the
+    re-bases, so that a round in flight over one knows its diffs no longer
+    fit. A record is never altered, only replaced: a round keeps the ones
+    it sent as bases.
+
+    Records are keyed by SENDER and a delta is only ever applied to the
+    record of the replica that sent it: the fingerprint in a delta is its
+    sender's claim (nobody re-hashes K fields to check it), so a base
+    looked up by value would let one replica's claim stand in for
+    another's vector."""
+
+    __slots__ = ("ref", "fingerprint", "epoch", "gen", "senders")
+
+    def __init__(self, cached_tags: list, fingerprint: bytes, epoch: int):
+        self.ref = list(cached_tags)
+        self.fingerprint = fingerprint
+        self.epoch = epoch
+        self.gen = 0
+        self.senders: dict[str, tuple] = {}
+
+    def rebase(self, cached_tags: list, fingerprint: bytes) -> None:
+        """The caller's list moved: one pass finds where, and each sender's
+        diff takes in those positions (its vector did not move)."""
+        ref = self.ref
+        moved = _differing(ref, cached_tags)
+        room = max(len(ref) // 4, MIN_KEPT_DIFF)
+        for sender, (fp, diff) in list(self.senders.items()):
+            diff = dict(diff)
+            for i in moved:
+                held = diff.pop(i, ref[i])
+                if held != cached_tags[i]:
+                    diff[i] = held
+            if len(diff) > room:
+                # a replica whose votes never make the quorum falls behind
+                # without end: past what a delta is worth, start it anew
+                del self.senders[sender]
+            else:
+                self.senders[sender] = (fp, diff)
+        for i in moved:
+            ref[i] = cached_tags[i]
+        self.fingerprint = fingerprint
+        self.gen += 1
+
+
+class _TagRound:
+    """One `read_tags` round in flight. A vote is the sender's vector held
+    sparsely against the round's reference list `ref`: {position: tag}
+    where it differs (`_SAME` where it does not). `ref` is the caller's
+    `cached_tags` when the round carries a fingerprint, else the first full
+    reply accepted. `kept`, `gen` and `bases` (sender -> the record whose
+    fingerprint this request named to it) tie the round to the key set's
+    `_KeptVectors`; `kept` is None for a round without a fingerprint."""
+
+    __slots__ = ("fut", "votes", "digest", "keys", "fingerprint", "ref",
+                 "kept", "gen", "bases", "verify_ms", "kinds")
+
+    def __init__(self, fut, digest, keys, fingerprint, ref, kept, bases):
+        self.fut = fut
+        self.votes: dict[str, dict] = {}
+        self.digest, self.keys, self.fingerprint = digest, keys, fingerprint
+        self.ref = ref
+        self.kept = kept
+        self.gen = kept.gen if kept is not None else 0
+        self.bases = bases
+        self.verify_ms = 0.0
+        self.kinds = {"unchanged": 0, "delta": 0, "full": 0}
 
 
 @dataclass
@@ -113,9 +205,10 @@ class AbdClient:
         # challenge nonce -> (future, coordinator)
         self._pending: dict[int, tuple[asyncio.Future, str]] = {}
         self._preferred: list[str] = []  # supervisor's freshest-half view
-        # tag-broadcast nonce -> (future, sender->tags votes, digest, keys,
-        # request fingerprint | None)
-        self._pending_tags: dict[int, tuple] = {}
+        # tag-broadcast nonce -> the round in flight
+        self._pending_tags: dict[int, _TagRound] = {}
+        # keys digest -> what each replica's vector was last verified to be
+        self._kept_vectors: dict[str, _KeptVectors] = {}
         # Constellation: when a ShardRouter owns this client it installs a
         # supplier for the ACTIVE map epoch; every Envelope/ReadTagBatch is
         # stamped with it so replicas can fence stale routes. None = -1 =
@@ -654,11 +747,12 @@ class AbdClient:
         """A replica fenced a ReadTagBatch: the whole round fails with
         WrongShardError (the router re-partitions against a fresh map). A
         forged fence earns the sender a suspicion strike instead."""
-        fut, _, _, keys, _ = self._pending_tags[msg.nonce]
+        rnd = self._pending_tags[msg.nonce]
+        fut = rnd.fut
         if fut.done():
             return
         if (
-            msg.key not in keys
+            msg.key not in rnd.keys
             or not sigs.validate_proxy_signature(
                 self.cfg.proxy_mac_secret, msg.key, msg.nonce, msg.signature,
                 ["wrong-shard", msg.epoch],
@@ -671,38 +765,144 @@ class AbdClient:
         ))
 
     def _on_tag_batch_reply(self, sender: str, msg: M.TagBatchReply) -> None:
-        fut, votes, digest, keys, fp = self._pending_tags[msg.nonce]
-        if fut.done() or sender in votes:
+        """Verify one reply and count it: each `_vote_*` returns None for a
+        reply to refuse, else (the sender's vector as a diff against the
+        round's reference list, the fingerprint it is attested under)."""
+        rnd = self._pending_tags[msg.nonce]
+        if rnd.fut.done() or sender in rnd.votes:
             return
+        t0 = time.perf_counter()
         if msg.unchanged:
-            # "my vector equals the fingerprint you sent": only meaningful
-            # when we sent one and it matches; MAC covers (fp, digest, nonce)
-            if (
-                fp is None
-                or msg.fingerprint != fp
-                or msg.digest != digest
-                or not sigs.validate_abd_batch_unchanged_signature(
-                    self.cfg.abd_mac_secret, fp, msg.digest, msg.nonce,
-                    msg.signature,
-                )
-            ):
-                self.replicas.increment_suspicion(sender)
-                return
-            votes[sender] = _UNCHANGED
+            kind, vote = "unchanged", self._vote_unchanged(msg, rnd)
+        elif msg.base is not None:
+            kind, vote = "delta", self._vote_delta(sender, msg, rnd)
         else:
-            if (
-                msg.digest != digest
-                or len(msg.tags) != len(keys)
-                or not sigs.validate_abd_batch_signature(
-                    self.cfg.abd_mac_secret, msg.tags, msg.digest, msg.nonce,
-                    msg.signature,
-                )
-            ):
-                self.replicas.increment_suspicion(sender)
-                return
-            votes[sender] = tuple(msg.tags)
-        if len(votes) >= self.cfg.quorum_size:
-            fut.set_result(list(votes.values()))
+            kind, vote = "full", self._vote_full(msg, rnd)
+        rnd.verify_ms += (time.perf_counter() - t0) * 1e3
+        if vote is None:
+            self.replicas.increment_suspicion(sender)
+            return
+        rnd.votes[sender], attested = vote
+        rnd.kinds[kind] += 1
+        kept = rnd.kept
+        if attested is not None and kept is not None and kept.gen == rnd.gen:
+            # what this sender's vector is now verified to be: made from
+            # the record this round named as its base and nothing else, so
+            # it is a state the sender attested whichever of two concurrent
+            # rounds lands last. Not kept once the reference list was
+            # re-based under the round: its diff no longer fits
+            kept.senders[sender] = (attested, rnd.votes[sender])
+        if len(rnd.votes) >= self.cfg.quorum_size:
+            rnd.fut.set_result(list(rnd.votes.values()))
+
+    def _vote_unchanged(self, msg, rnd: _TagRound):
+        """"My vector equals the fingerprint you sent": only meaningful
+        when we sent one and it matches; MAC covers (fp, digest, nonce)."""
+        fp = rnd.fingerprint
+        if (
+            fp is None
+            or msg.fingerprint != fp
+            or msg.digest != rnd.digest
+            or not sigs.validate_abd_batch_unchanged_signature(
+                self.cfg.abd_mac_secret, fp, msg.digest, msg.nonce,
+                msg.signature,
+            )
+        ):
+            return None
+        return _SAME, fp
+
+    def _vote_full(self, msg, rnd: _TagRound):
+        """All K tags: verified by formatting each for the MAC, as ever,
+        then held against the round's reference list. Re-anchors what is
+        kept of this sender, under the fingerprint of the very bytes the
+        MAC covered."""
+        if msg.digest != rnd.digest or len(msg.tags) != len(rnd.keys):
+            return None
+        blob = sigs.tags_blob(msg.tags)
+        if not sigs.validate_abd_batch_blob_signature(
+            self.cfg.abd_mac_secret, blob, msg.digest, msg.nonce,
+            msg.signature,
+        ):
+            return None
+        tags = list(msg.tags)
+        if rnd.ref is None:
+            rnd.ref = tags
+            return _SAME, None
+        diff = {i: tags[i] for i in _differing(rnd.ref, tags)}
+        return diff, (sigs.blob_fingerprint(blob)
+                      if rnd.kept is not None else None)
+
+    def _vote_delta(self, sender: str, msg, rnd: _TagRound):
+        """The positions this sender replaced since the vector we last
+        verified FROM IT: accepted only against the base this request named
+        to this sender, positions in range and strictly ascending, one
+        MAC over the entries carried; applied to that sender's record and
+        to no other."""
+        base = rnd.bases.get(sender)
+        positions, tags = msg.positions, msg.tags
+        reason = None
+        if base is None or msg.base != base[0]:
+            reason = "unknown_base"
+        elif (
+            len(positions) != len(tags)
+            or not all(type(p) is int for p in positions)
+            or not all(isinstance(t, M.ABDTag) for t in tags)
+            or any(b <= a for a, b in zip(positions, positions[1:]))
+            or (positions and not (0 <= positions[0]
+                                   and positions[-1] < len(rnd.keys)))
+        ):
+            reason = "bad_positions"
+        elif (
+            msg.digest != rnd.digest
+            or not isinstance(msg.fingerprint, bytes)
+            or not sigs.validate_abd_batch_delta_signature(
+                self.cfg.abd_mac_secret, base[0], msg.fingerprint,
+                positions, tags, msg.digest, msg.nonce, msg.signature)
+        ):
+            reason = "bad_mac"
+        if reason is not None:
+            metrics.inc(
+                "dds_tag_round_delta_discarded_total",
+                **self._mlabels(reason=reason),
+                help="delta replies to ReadTagBatch refused, by reason",
+            )
+            return None
+        ref = rnd.ref
+        diff = dict(base[1])
+        for i, tag in zip(positions, tags):
+            if tag is ref[i] or tag == ref[i]:
+                diff.pop(i, None)
+            else:
+                diff[i] = tag
+        metrics.inc(
+            "dds_tag_round_delta_entries_total", len(positions),
+            **self._mlabels(),
+            help="tags carried by accepted delta replies to ReadTagBatch",
+        )
+        return diff, msg.fingerprint
+
+    def _kept_for(self, digest: str, fingerprint: bytes, cached_tags: list,
+                  trusted: list) -> _KeptVectors:
+        """The key set's kept vectors, brought to this round: made anew for
+        a key set not kept (or kept under another shard-map epoch, or
+        another length), re-based when the caller's list moved, and without
+        the senders no longer trusted."""
+        epoch = self._epoch()
+        kept = self._kept_vectors.pop(digest, None)
+        if (
+            kept is None
+            or kept.epoch != epoch
+            or len(kept.ref) != len(cached_tags)
+        ):
+            while len(self._kept_vectors) >= MAX_TAG_ROUNDS:
+                del self._kept_vectors[next(iter(self._kept_vectors))]
+            kept = _KeptVectors(cached_tags, fingerprint, epoch)
+        elif kept.fingerprint != fingerprint:
+            kept.rebase(cached_tags, fingerprint)
+        self._kept_vectors[digest] = kept   # newest last
+        for gone in kept.senders.keys() - set(trusted):
+            del kept.senders[gone]
+        return kept
 
     async def read_tags(
         self,
@@ -742,7 +942,23 @@ class AbdClient:
         always confirm itself; the caller's audit (not this round) is what
         bounds that class either way. `digest` may be passed in when the
         caller already computed the keys digest (it is part of the request
-        MAC either way)."""
+        MAC either way).
+
+        Between the two, what a round costs follows what moved. With a
+        fingerprint the proxy keeps, per key set and per SENDER, the vector
+        it last verified from that replica (`_KeptVectors`) and names its
+        fingerprint to that replica alone as the request's `base`; a
+        replica that remembers the state answers with a `delta`: the
+        positions it replaced since, one MAC over those. A full reply
+        (first round, a reseeded replica, a base trimmed away) is verified
+        as ever and re-anchors its sender. Votes are held as "`cached_tags`
+        except at these positions", and the per-key max runs over the
+        union of those positions only; the result is element for element
+        the max over the same votes taken whole. Deflation-resistance is
+        again unchanged: the honest quorum-intersection replica's chain
+        starts at a fully verified reply and every link carries its MAC,
+        and a delta is applied to its own sender's record only, so a liar
+        can misstate no vote but its own, which it always could."""
         trusted = self.replicas.get_trusted()
         if len(trusted) < self.cfg.quorum_size:
             raise ByzUnknownReplyError(
@@ -763,32 +979,73 @@ class AbdClient:
             digest = sigs.key_from_set(list(keys))
         sig = sigs.proxy_signature(self.cfg.proxy_mac_secret, digest, nonce)
         fut: asyncio.Future = asyncio.get_event_loop().create_future()
-        self._pending_tags[nonce] = (fut, {}, digest, tuple(keys), fingerprint)
+        keys_t = tuple(keys)
         try:
-            with tracer.span("abd.read_tags", k=len(keys)):
+            with tracer.span("abd.read_tags", k=len(keys)) as rm:
                 t0 = time.perf_counter()
-                req = M.ReadTagBatch(tuple(keys), nonce, sig, fingerprint,
-                                     epoch=self._epoch())
+                if fingerprint is not None and keys_t:
+                    kept = self._kept_for(digest, fingerprint, cached_tags,
+                                          trusted)
+                    bases = dict(kept.senders)
+                else:
+                    kept, bases = None, {}
+                rnd = self._pending_tags[nonce] = _TagRound(
+                    fut, digest, keys_t, fingerprint,
+                    cached_tags if kept is not None else None, kept, bases)
+                epoch = self._epoch()
                 for replica in trusted:
-                    self.net.send(self.addr, replica, req)
-                vectors = await asyncio.wait_for(fut, timeout)
+                    # each replica is named the vector last verified from
+                    # IT as the base of a delta; none kept, none named
+                    base = bases.get(replica)
+                    self.net.send(self.addr, replica, M.ReadTagBatch(
+                        keys_t, nonce, sig, fingerprint, epoch,
+                        base[0] if base is not None else None))
+                votes = await asyncio.wait_for(fut, timeout)
+                t1 = time.perf_counter()
                 metrics.observe(
-                    "dds_quorum_rtt_seconds", time.perf_counter() - t0,
+                    "dds_quorum_rtt_seconds", t1 - t0,
                     **self._mlabels(op="read_tags"),
                     help="proxy->coordinator quorum round-trip time",
                 )
-            if not keys:
-                return []
-            if all(v is _UNCHANGED for v in vectors):
-                # return the caller's own list BY IDENTITY: callers use
-                # `result is cached_tags` as the all-fresh signal
-                return cached_tags
-            expanded = [
-                cached_tags if v is _UNCHANGED else v for v in vectors
-            ]
-            return [max(col) for col in zip(*expanded)]
+                rm.update(rnd.kinds)
+                for kind, n in rnd.kinds.items():
+                    if n:
+                        metrics.inc(
+                            "dds_tag_round_votes_total", n,
+                            **self._mlabels(kind=kind),
+                            help="accepted ReadTagBatch votes by reply kind",
+                        )
+                tracer.record("abd.read_tags.verify", rnd.verify_ms,
+                              _ctx=obs_context.child(), _t_end=t1)
+                if not keys:
+                    return []
+                merged = self._merge_votes(rnd.ref, votes, cached_tags)
+                tracer.record(
+                    "abd.read_tags.merge", (time.perf_counter() - t1) * 1e3,
+                    _ctx=obs_context.child())
+                return merged
         finally:
             self._pending_tags.pop(nonce, None)
+
+    @staticmethod
+    def _merge_votes(ref, votes: list, cached_tags: list | None) -> list:
+        """The per-key max over the quorum's votes, each `ref` except at
+        the positions of its dict: computed where some vote differs from
+        `ref`, `ref`'s own tag elsewhere. The caller's own list BY IDENTITY
+        when the max moves nothing (no vote differs, or those that do are
+        older): callers use `result is cached_tags` as the all-fresh
+        signal."""
+        out = None
+        for i in set().union(*votes):
+            at = ref[i]
+            top = max(v.get(i, at) for v in votes)
+            if top != at:
+                if out is None:
+                    out = list(ref)
+                out[i] = top
+        if out is None:
+            return cached_tags if cached_tags is not None else list(ref)
+        return out
 
     def refresh_from(self, supervisor: str) -> None:
         """Ask the supervisor for the freshest active replicas (fire & forget;

@@ -80,6 +80,10 @@ MAX_TAG_VECTORS = 8
 # a key set nobody asked about for that long is cheaper built anew, and
 # the log of stored keys stays bounded
 MAX_TAG_VECTOR_LAG = 1 << 16
+# a kept vector remembers the positions it replaced, for delta replies,
+# until they outnumber a quarter of its keys (a delta that long is no
+# cheaper than the full reply); a small key set keeps this many
+MIN_DELTA_HISTORY = 64
 
 
 class _TagVector:
@@ -88,10 +92,18 @@ class _TagVector:
     digest (a function of the keys alone), key -> position, the tag per
     position and its `sigs.tag_field`, the joined blob the MAC covers and
     its fingerprint. `seen` is how far into the replica's log of stored
-    keys the vector has been brought."""
+    keys the vector has been brought.
+
+    For delta replies it also keeps `moved`, the positions it replaced, in
+    order, and `marks`: the fingerprint of each state it sealed -> how far
+    `moved` had got (counted from the first position ever replaced;
+    `trimmed` of them are gone from the front). A proxy that names a
+    remembered fingerprint as its base is owed the positions after that
+    mark. Equal fingerprints are equal vectors, so the newest mark of a
+    fingerprint serves."""
 
     __slots__ = ("digest", "index", "tags", "fields", "reply_tags", "blob",
-                 "fingerprint", "seen")
+                 "fingerprint", "seen", "moved", "marks", "trimmed")
 
     def __init__(self, keys: tuple, digest: str, repository: dict,
                  blank: tuple, seen: int):
@@ -101,31 +113,55 @@ class _TagVector:
         self.tags = [repository.get(k, blank)[0] for k in keys]
         self.fields = [sigs.tag_field(t) for t in self.tags]
         self.seen = seen
+        self.moved: list[int] = []
+        self.marks: dict[bytes, int] = {}
+        self.trimmed = 0
         self.seal()
 
     def seal(self) -> None:
         """One copy, one join, one hash: the reply's parts of the tags and
-        fields as they now stand."""
+        fields as they now stand, and the mark a later delta starts from."""
         self.reply_tags = tuple(self.tags)
         self.blob = sigs.fields_blob(self.fields)
         self.fingerprint = sigs.blob_fingerprint(self.blob)
+        self.marks[self.fingerprint] = self.trimmed + len(self.moved)
 
     def patch(self, stored: list, repository: dict) -> int:
         """Take in the keys `stored` since `seen` (the log's tail): replace
         the tag and field of those in the set whose tag moved, then seal
         once. Returns how many were replaced."""
         index, tags, fields = self.index, self.tags, self.fields
+        moved = self.moved
         changed = 0
         for key in stored:
             i = index.get(key)
             if i is not None and tags[i] is not (tag := repository[key][0]):
                 tags[i] = tag
                 fields[i] = sigs.tag_field(tag)
+                moved.append(i)
                 changed += 1
         self.seen += len(stored)
         if changed:
             self.seal()
+            keep = max(len(tags) // 4, MIN_DELTA_HISTORY)
+            if len(moved) > keep:
+                # forget the older half: a base from before it is owed
+                # the full reply
+                cut = len(moved) - keep // 2
+                del moved[:cut]
+                self.trimmed += cut
+                self.marks = {fp: at for fp, at in self.marks.items()
+                              if at >= self.trimmed}
         return changed
+
+    def delta_since(self, base) -> tuple | None:
+        """The positions replaced since the state that fingerprinted to
+        `base`, ascending and each once; None when that state is not
+        remembered (never sealed here, or trimmed away)."""
+        at = self.marks.get(base) if isinstance(base, bytes) else None
+        if at is None:
+            return None
+        return tuple(sorted(set(self.moved[at - self.trimmed:])))
 
 
 class BFTABDNode:
@@ -479,6 +515,18 @@ class BFTABDNode:
                         sigs.abd_batch_unchanged_signature(
                             cfg.abd_mac_secret, fp, digest, nonce),
                         nonce, unchanged=True, fingerprint=fp,
+                    )
+                elif (at := vec.delta_since(msg.base)) is not None:
+                    # the proxy holds this replica's vector as of `base`:
+                    # ship and MAC the positions replaced since, from the
+                    # kept tags and fields
+                    tags, fields = vec.tags, vec.fields
+                    reply = M.TagBatchReply(
+                        tuple([tags[i] for i in at]), digest,
+                        sigs.abd_batch_delta_signature(
+                            cfg.abd_mac_secret, msg.base, fp, at,
+                            [fields[i] for i in at], digest, nonce),
+                        nonce, fingerprint=fp, base=msg.base, positions=at,
                     )
                 else:
                     # the MAC covers the kept blob: no tag is formatted

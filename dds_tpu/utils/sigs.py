@@ -120,10 +120,20 @@ def abd_batch_blob_signature(
     return _mac(secret, blob + f"|{digest}|{nonce}".encode())
 
 
+def validate_abd_batch_blob_signature(
+    secret: bytes, blob: bytes, digest: str, nonce: int, given: bytes
+) -> bool:
+    """`validate_abd_batch_signature` for a verifier that wants the blob
+    it formatted for more than the MAC (its fingerprint)."""
+    return hmac.compare_digest(
+        abd_batch_blob_signature(secret, blob, digest, nonce), given)
+
+
 def validate_abd_batch_signature(
     secret: bytes, tags, digest: str, nonce: int, given: bytes
 ) -> bool:
-    return hmac.compare_digest(abd_batch_signature(secret, tags, digest, nonce), given)
+    return validate_abd_batch_blob_signature(
+        secret, tags_blob(tags), digest, nonce, given)
 
 
 def abd_batch_unchanged_signature(
@@ -141,6 +151,35 @@ def validate_abd_batch_unchanged_signature(
 ) -> bool:
     return hmac.compare_digest(
         abd_batch_unchanged_signature(secret, fingerprint, digest, nonce), given
+    )
+
+
+def abd_batch_delta_signature(
+    secret: bytes, base: bytes, fingerprint: bytes, positions, fields,
+    digest: str, nonce: int,
+) -> bytes:
+    """Replica signature over a 'delta' ReadTagBatch reply: "since my
+    vector fingerprinted to `base`, these positions were replaced and hold
+    these tags (`fields`: their `tag_field`s), and the vector now
+    fingerprints to `fingerprint`". Domain-separated from the full and the
+    `unchanged` reply. Injective: the fingerprints go in as hex, a
+    position is an integer, and a `tag_field` length-prefixes its id."""
+    pairs = ";".join(f"{p}:{f}" for p, f in zip(positions, fields))
+    return _mac(secret, (f"delta|{base.hex()}|{fingerprint.hex()}|{pairs}"
+                         f"|{digest}|{nonce}").encode())
+
+
+def validate_abd_batch_delta_signature(
+    secret: bytes, base: bytes, fingerprint: bytes, positions, tags,
+    digest: str, nonce: int, given: bytes,
+) -> bool:
+    """Verify a delta reply from the verifier's own tag objects: one
+    `tag_field` per tag carried, none per key of the set."""
+    return hmac.compare_digest(
+        abd_batch_delta_signature(
+            secret, base, fingerprint, positions,
+            [tag_field(t) for t in tags], digest, nonce),
+        given,
     )
 
 

@@ -27,6 +27,7 @@ import time
 
 import pytest
 
+from dds_tpu.core import messages as M
 from dds_tpu.core.errors import WrongShardError
 from dds_tpu.fabric.deploy import initial_map, parse_role
 from dds_tpu.fabric.gossip import RemoteShardManager
@@ -37,6 +38,7 @@ from dds_tpu.http.miniserver import (
     http_request_full,
 )
 from dds_tpu.shard.shardmap import ShardMap
+from dds_tpu.utils import sigs
 from dds_tpu.utils.config import DDSConfig
 from tests.test_core import run
 
@@ -749,3 +751,91 @@ def test_flagship_multi_os_process_fleet(tmp_path):
             fleet.stop()
 
     asyncio.run(go())
+
+
+# ------------------------------------- the tag round's delta reply on the wire
+
+
+def _votes_by_kind() -> dict:
+    from dds_tpu.obs.metrics import metrics
+
+    return {k: metrics.value("dds_tag_round_votes_total", kind=k) or 0.0
+            for k in ("unchanged", "delta", "full")}
+
+
+def test_tag_round_delta_fields_round_trip_and_older_frames_still_decode():
+    """(d) `ReadTagBatch.base` and `TagBatchReply.base` / `.positions` go
+    through `messages.dumps` / `loads` (positions element-wise, as ints),
+    and a frame written before the fields existed decodes to their
+    defaults."""
+    fp, base = bytes(range(32)), bytes(range(32, 64))
+    req = M.ReadTagBatch(("a", "b"), 7, b"sig", fp, epoch=3, base=base)
+    delta = M.TagBatchReply(
+        (M.ABDTag(9, "replica-1"), M.ABDTag(4, "r;2|x")), "DIGEST", b"mac",
+        7, fingerprint=fp, base=base, positions=(0, 5))
+    for m in (req, delta, M.ReadTagBatch(("a",), 1),
+              M.TagBatchReply((), "D", b"", 1, unchanged=True,
+                              fingerprint=fp)):
+        back = M.loads(M.dumps(m))
+        assert back == m
+        assert type(back) is type(m)
+    back = M.loads(M.dumps(delta))
+    assert isinstance(back.positions, tuple) and back.positions == (0, 5)
+    assert back.base == base and isinstance(back.tags[1], M.ABDTag)
+    for m, new in ((req, ("base",)), (delta, ("base", "positions"))):
+        old = json.loads(M.dumps(m))
+        for name in new:
+            del old[name]
+        was = M.from_dict(old)
+        assert was.base is None and getattr(was, "positions", ()) == ()
+        assert was.nonce == m.nonce and was.fingerprint == m.fingerprint
+    with pytest.raises(KeyError):   # a field without a default is still owed
+        M.from_dict({"__msg__": "TagBatchReply", "tags": []})
+
+
+def test_over_tcp_every_tag_is_a_fresh_object_and_a_delta_round_still_holds():
+    """(d) Over `TcpNet` no tag of a reply is the caller's object: the
+    kept vectors compare by value, a delta round crosses the wire, and the
+    caller still gets its own list back when nothing differs."""
+
+    async def go():
+        from dds_tpu.core.quorum_client import AbdClient, AbdClientConfig
+        from dds_tpu.core.replica import BFTABDNode, ReplicaConfig
+        from dds_tpu.core.transport import TcpNet
+
+        net = TcpNet("127.0.0.1", 0)
+        await net.start()
+        host = net.advertised
+        try:
+            addrs = [f"{host}/replica-{i}" for i in range(4)]
+            nodes = [BFTABDNode(a, addrs, f"{host}/supervisor", net,
+                                ReplicaConfig(quorum_size=3)) for a in addrs]
+            client = AbdClient(
+                f"{host}/proxy", net, addrs,
+                AbdClientConfig(request_timeout=3.0, quorum_size=3))
+            keys = [f"k{i}" for i in range(12)]
+            for i, k in enumerate(keys):
+                for n in nodes:
+                    n._store(k, M.ABDTag(i + 1, "replica-0"), [i])
+            cached = await client.read_tags(keys)
+            fp = sigs.tags_fingerprint(cached)
+            assert (await client.read_tags(
+                keys, fingerprint=fp, cached_tags=cached)) is cached
+            before = _votes_by_kind()
+            newer = M.ABDTag(100, "replica-1")
+            for n in nodes:
+                n._store(keys[5], newer, [100])
+            got = await client.read_tags(keys, fingerprint=fp,
+                                         cached_tags=cached)
+            assert got == cached[:5] + [newer] + cached[6:]
+            assert got[5] is not newer          # it crossed the wire
+            assert _votes_by_kind() == {**before,
+                                        "delta": before["delta"] + 3}
+            # the caller takes it in: the next round is all `unchanged`
+            fp2 = sigs.tags_fingerprint(got)
+            assert (await client.read_tags(
+                keys, fingerprint=fp2, cached_tags=got)) is got
+        finally:
+            await net.stop()
+
+    run(go())

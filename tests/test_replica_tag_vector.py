@@ -62,7 +62,7 @@ class Rig:
         blank = (M.ABDTag(0, self.node.name), None)
         return tuple(self.node.repository.get(k, blank)[0] for k in keys)
 
-    async def ask(self, keys, fingerprint=None, signature=None):
+    async def ask(self, keys, fingerprint=None, signature=None, base=None):
         nonce = sigs.generate_nonce()
         digest = sigs.key_from_set(list(keys))
         if signature is None:
@@ -70,9 +70,38 @@ class Rig:
                 self.node.cfg.proxy_mac_secret, digest, nonce)
         self.inbox.clear()
         await self.node.handle(
-            "proxy", M.ReadTagBatch(tuple(keys), nonce, signature, fingerprint))
+            "proxy", M.ReadTagBatch(tuple(keys), nonce, signature, fingerprint,
+                                    base=base))
         await self.net.quiesce()
         return nonce, digest, list(self.inbox)
+
+    async def ask_delta(self, keys, base, held, fingerprint=b"\x01" * 32):
+        """One round that names `base`, the fingerprint of `held` (the
+        vector this replica answered with then). Returns the reply and
+        whether it was a delta; a delta is held to: its positions are
+        exactly where a fresh lookup differs from `held` or a tag was
+        stored again, ascending and each once; applied to `held` it gives
+        the fresh lookup; its MAC is the delta MAC over all of it."""
+        nonce, digest, got = await self.ask(keys, fingerprint, base=base)
+        (reply,) = got
+        want = self.fresh(keys)
+        assert reply.fingerprint == sigs.tags_fingerprint(want)
+        if reply.base is None:
+            return reply, False
+        assert reply.base == base and not reply.unchanged
+        at = list(reply.positions)
+        assert at == sorted(set(at)) and len(at) == len(reply.tags)
+        applied = list(held)
+        for i, t in zip(at, reply.tags):
+            applied[i] = t
+        assert tuple(applied) == want
+        assert reply.signature == sigs.abd_batch_delta_signature(
+            self.node.cfg.abd_mac_secret, base, reply.fingerprint, at,
+            [sigs.tag_field(t) for t in reply.tags], digest, nonce)
+        assert sigs.validate_abd_batch_delta_signature(
+            self.node.cfg.abd_mac_secret, base, reply.fingerprint, at,
+            reply.tags, digest, nonce, reply.signature)
+        return reply, True
 
     async def ask_and_check(self, keys, fingerprint=None):
         """One authenticated round, held to the reply computed afresh."""
@@ -386,6 +415,142 @@ def test_replayed_nonce_is_refused_before_the_vector_is_touched():
         await rig.net.quiesce()
         assert not any(isinstance(m, M.TagBatchReply) for m in rig.inbox)
         assert vec.seen == seen
+
+    run(go())
+
+
+# ------------------------------------------------------------- delta replies
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_delta_applied_to_its_base_is_the_fresh_lookup(seed, monkeypatch):
+    """A round that names a base the vector remembers is answered with the
+    positions replaced since, whatever was stored meanwhile and however
+    many rounds old the base is; a base it never sealed, one from before a
+    reseed and one trimmed away are owed the full reply, byte for byte the
+    parent's."""
+    monkeypatch.setattr(replica_mod, "MIN_DELTA_HISTORY", 8)
+    rng = random.Random(seed)
+
+    async def go():
+        rig = Rig()
+        keys = tuple(f"key-{i:02d}" for i in range(20))
+        for k in keys:
+            rig.store(k)
+        first = await rig.ask_and_check(keys)
+        bases = [(first.fingerprint, first.tags)]    # states it answered from
+        deltas = fulls = 0
+        for step in range(80):
+            op = rng.choice(["store"] * 5 + ["ask"] * 4 + ["burst", "reseed"])
+            if op == "store":
+                rig.store(rng.choice(keys + ("outside",)))
+            elif op == "burst":
+                for _ in range(10):
+                    rig.store(rng.choice(keys))
+            elif op == "reseed":
+                rig.node._install_repository(dict(rig.node.repository))
+            else:
+                base, held = rng.choice(bases[-4:])
+                reply, was_delta = await rig.ask_delta(keys, base, held)
+                if was_delta:
+                    deltas += 1
+                else:
+                    fulls += 1
+                    assert reply.tags == rig.fresh(keys)
+                bases.append((reply.fingerprint, rig.fresh(keys)))
+        assert deltas > 5 and fulls > 0
+        # a base it never sealed, and no base: the parent's full reply
+        reply, was_delta = await rig.ask_delta(keys, b"\x07" * 32, ())
+        assert not was_delta
+        await rig.ask_and_check(keys)
+        # `unchanged` outranks a delta
+        fp = sigs.tags_fingerprint(rig.fresh(keys))
+        _, _, (reply,) = await rig.ask(keys, fp, base=fp)
+        assert reply.unchanged and reply.base is None
+
+    run(go())
+
+
+def test_a_base_that_is_the_vector_itself_is_an_empty_delta():
+    """The proxy's own list differs from this replica's vector, which has
+    not moved since the proxy last verified it: nothing to ship but the
+    MAC."""
+
+    async def go():
+        rig = Rig()
+        keys = ("a", "b", "c")
+        for k in keys:
+            rig.store(k)
+        first = await rig.ask_and_check(keys)
+        reply, was_delta = await rig.ask_delta(keys, first.fingerprint,
+                                               first.tags)
+        assert was_delta and reply.positions == () and reply.tags == ()
+
+    run(go())
+
+
+def test_a_trimmed_history_forgets_old_bases_and_keeps_new_ones(monkeypatch):
+    """The positions kept are bounded by the key set (a quarter of it, a
+    floor for small sets): past it the older half goes with the marks that
+    pointed into it, and never by a setting."""
+    monkeypatch.setattr(replica_mod, "MIN_DELTA_HISTORY", 8)
+
+    async def go():
+        rig = Rig()
+        keys = tuple(f"key-{i:02d}" for i in range(16))
+        for k in keys:
+            rig.store(k)
+        old = await rig.ask_and_check(keys)
+        vec = rig.node._tag_vectors[keys]
+        recent = None
+        for step in range(30):
+            rig.store(keys[step % 16])
+            reply = await rig.ask_and_check(keys)
+            if step == 27:
+                recent = (reply.fingerprint, reply.tags)
+            assert len(vec.moved) <= 8 and len(vec.marks) <= 9
+        _, was_delta = await rig.ask_delta(keys, old.fingerprint, old.tags)
+        assert not was_delta
+        reply, was_delta = await rig.ask_delta(keys, *recent)
+        assert was_delta and len(reply.positions) == 2
+
+    run(go())
+
+
+def test_a_delta_round_ships_and_formats_what_moved_not_k(monkeypatch):
+    """(c) After m stores a delta round formats m tags (the patch), no key
+    digest, no `tags_blob`, and ships m tags, not K."""
+    calls = {"key_from_set": 0, "tag_field": 0, "tags_blob": 0}
+
+    async def go():
+        rig = Rig()
+        keys = tuple(f"key-{i:03d}" for i in range(256))
+        for k in keys:
+            rig.store(k)
+        first = await rig.ask_and_check(keys)
+        digest = sigs.key_from_set(list(keys))
+        for k in random.Random(5).sample(keys, 3):
+            rig.store(k)
+        nonce = sigs.generate_nonce()
+        sig = sigs.proxy_signature(rig.node.cfg.proxy_mac_secret, digest,
+                                   nonce)
+        for name in calls:
+            real = getattr(sigs, name)
+
+            def wrapped(*a, _real=real, _name=name, **kw):
+                calls[_name] += 1
+                return _real(*a, **kw)
+
+            monkeypatch.setattr(sigs, name, wrapped)
+        rig.inbox.clear()
+        await rig.node.handle("proxy", M.ReadTagBatch(
+            keys, nonce, sig, b"\x01" * 32, base=first.fingerprint))
+        monkeypatch.undo()
+        await rig.net.quiesce()
+        (reply,) = rig.inbox
+        assert reply.base == first.fingerprint
+        assert len(reply.tags) == len(reply.positions) == 3
+        assert calls == {"key_from_set": 0, "tag_field": 3, "tags_blob": 0}
 
     run(go())
 
