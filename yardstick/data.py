@@ -31,13 +31,17 @@ class Dataset:
     """K rows and every version of them written so far."""
 
     def __init__(self, seed: int, k: int, plain_bits: int = 16,
-                 step_bits: int = 32):
+                 step_bits: int = 32, paillier_bits: int = 2048,
+                 rsa_bits: int = 1024):
         rng = random.Random(seed)
         self.rng = rng
         self.k = k
-        self.paillier = pai = reference.Paillier(keys.PAILLIER_P,
-                                                 keys.PAILLIER_Q)
-        self.rsa = rsa = reference.Rsa(keys.RSA_P, keys.RSA_Q, keys.RSA_E)
+        # the keys by the sizes the configuration names: a KeyError that
+        # lists the sizes on file when there is none of that size
+        self.paillier = pai = reference.Paillier(
+            *keys.on_file(keys.PAILLIER, "Paillier", paillier_bits))
+        self.rsa = rsa = reference.Rsa(
+            *keys.on_file(keys.RSA, "RSA", rsa_bits))
         self.moduli = {PSSE: pai.n2, MSE: rsa.n}
         # obfuscator chain: rn <- rn * hop, one modmul per ciphertext
         self._rn = pai.obfuscator(rng.randrange(2, pai.n))

@@ -45,7 +45,6 @@ ROOT = os.path.dirname(HERE)
 OUT_DIR = os.path.join(ROOT, ".yardstick_out")
 SETUP_TIMEOUT = 1100.0   # a cold first aggregate compiles inside the request
 LOAD_ATTEMPTS = 4        # tries of one PutSet during the load
-STALL_SECONDS = 3.0      # an event loop silent this long gets its stacks dumped
 GAP_NS = 20_000.0        # a longer pause of the device lies between programs
 TRACE_SECONDS = 2.0      # the stretch of the window the profiler records
 
@@ -100,6 +99,11 @@ def percentile(values: list[float], q: float) -> float:
 # ------------------------------------------------------------- deployment
 
 
+# what `build_config` sets from the configuration's named keys (and all of
+# `replicas.`): a `settings` entry may not say it a second way
+NAMED_SETTINGS = ("recovery.enabled", "proxy.port", "proxy.crypto_backend")
+
+
 def build_config(conf: dict):
     from dds_tpu.utils.config import DDSConfig
 
@@ -113,7 +117,32 @@ def build_config(conf: dict):
     cfg.recovery.enabled = bool(conf["recovery"])
     cfg.proxy.port = 0
     cfg.proxy.crypto_backend = conf["crypto_backend"]
+    for path, value in conf.get("settings", {}).items():
+        apply_setting(cfg, path, value)
     return cfg
+
+
+def apply_setting(cfg, path: str, value) -> None:
+    """One entry of a configuration's `settings`: a dotted path into
+    `DDSConfig` and the value it gets. Refused: a path the named keys
+    already set, one that does not exist, a value of another type than
+    the default's (a whole group is not a value)."""
+    if path.startswith("replicas.") or path in NAMED_SETTINGS:
+        raise SetupError(f"settings: {path!r} is set by the configuration's "
+                         "named keys (replicas, sentinels, quorum, "
+                         "max_faults, recovery, crypto_backend)")
+    *groups, leaf = path.split(".")
+    node = cfg
+    for g in groups:
+        node = getattr(node, g, None)
+    if leaf not in getattr(node, "__dataclass_fields__", ()):
+        raise SetupError(f"settings: DDSConfig has no {path!r}")
+    default = getattr(node, leaf)
+    if type(value) is not type(default):
+        raise SetupError(f"settings: {path!r} is {type(default).__name__} "
+                         f"({default!r}), not {type(value).__name__} "
+                         f"({value!r})")
+    setattr(node, leaf, value)
 
 
 @dataclass
@@ -189,9 +218,15 @@ class Run:
         self.args, self.cell, self.device = args, cell, device
         self.conf = cell["config_file"]
         self.mix = cell["mix"]
-        self.data = Dataset(args.seed, int(self.conf["rows"]),
-                            int(self.conf["plain_bits"]),
-                            int(self.conf["update_step_bits"]))
+        self.cfg = build_config(self.conf)   # refused before any row is made
+        try:
+            self.data = Dataset(args.seed, int(self.conf["rows"]),
+                                int(self.conf["plain_bits"]),
+                                int(self.conf["update_step_bits"]),
+                                int(self.conf.get("paillier_bits", 2048)),
+                                int(self.conf.get("rsa_bits", 1024)))
+        except KeyError as e:
+            raise SetupError(e.args[0]) from e
         # the deployment shares this process's heap: a full collection
         # walks every container alive, so the harness's rows would lengthen
         # the program's own collector pauses. Park them where it never looks.
@@ -238,6 +273,14 @@ class Run:
 
     def note_check(self, name: str, value: float, limit: float) -> None:
         self.checks.append((name, value, limit))
+
+    def gc_pause_s(self) -> float:
+        """Seconds the collector (jax's hook in it included) has held the
+        process, by the program's own count."""
+        from dds_tpu.obs.metrics import metrics
+
+        return sum(metrics.value("dds_gc_pause_seconds_total",
+                                 generation=str(g)) or 0.0 for g in range(3))
 
     def pool_stats(self) -> dict | None:
         """The device pool's own counts for the additive column, where the
@@ -382,18 +425,15 @@ class Run:
 
     async def _heartbeat(self) -> None:
         """How late the event loop runs (clients, proxy and replicas share
-        it), and a dump of every thread's stack on stderr should it fall
-        silent for STALL_SECONDS: a stall is then a finding, not a riddle."""
+        it). A loop that falls silent is the program's to report: its own
+        watchdog (`obs/runtime.LoopSampler`) logs every thread's stack,
+        read under the interpreter lock. `faulthandler`'s timed dump reads
+        them without it, and killed the run it was to explain."""
         tick = 0.05
-        try:
-            while True:
-                faulthandler.dump_traceback_later(STALL_SECONDS, exit=False)
-                t = time.perf_counter()
-                await asyncio.sleep(tick)
-                self.loop_lag_ms.append(
-                    (time.perf_counter() - t - tick) * 1e3)
-        finally:
-            faulthandler.cancel_dump_traceback_later()
+        while True:
+            t = time.perf_counter()
+            await asyncio.sleep(tick)
+            self.loop_lag_ms.append((time.perf_counter() - t - tick) * 1e3)
 
     async def measure(self, seed: int | None = None) -> None:
         """One window of the mix, then the quiet point after it."""
@@ -408,6 +448,7 @@ class Run:
             tracing = asyncio.ensure_future(self._profile(
                 args.seconds, time.perf_counter() + args.seconds))
         self.pool_before = self.pool_stats()
+        self.gc_before = self.gc_pause_s()
         self.loop_lag_ms: list[float] = []
         beat = asyncio.ensure_future(self._heartbeat())
         self.setup_s = time.perf_counter() - T_START
@@ -453,14 +494,16 @@ class Run:
         deployment (`stop`) whatever happens after this returns."""
         from dds_tpu.run import launch
 
-        cfg = build_config(self.conf)
-        self.dep = await launch(cfg)
-        self.host, self.port = cfg.proxy.host, self.dep.server.cfg.port
+        self.dep = await launch(self.cfg)
+        self.host, self.port = self.cfg.proxy.host, self.dep.server.cfg.port
         be = self.dep.server.backend
         say("deployment", config=self.conf["name"],
             backend=getattr(be, "name", None),
             platform=getattr(be, "platform", None),
-            pallas=getattr(be, "pallas", None))
+            pallas=getattr(be, "pallas", None),
+            paillier_bits=self.data.paillier.n.bit_length(),
+            rsa_bits=self.data.rsa.n.bit_length(), limbs=self.limbs,
+            settings=self.conf.get("settings", {}))
         await self.load()
         first = await self.quiet_point("after_load", SETUP_TIMEOUT)
         self.window.setup["first_agg_s"] = first
@@ -488,6 +531,8 @@ class Run:
     # ------------------------------------------------------------ reporting
 
     def report(self) -> dict:
+        from dds_tpu.obs.metrics import metrics
+
         args, tr = self.args, self.traffic
         every = self.warm.ops + tr.ops
         tr.judge(every)
@@ -548,6 +593,9 @@ class Run:
                                    if op.t_done > self.t_end),
             generator_late_ms_p95=(percentile(tr.late_ms, 0.95)
                                    if tr.late_ms else 0.0),
+            loop_stalls_reported=metrics.value(
+                "dds_event_loop_stalls_total") or 0.0,
+            gc_pause_s_since_open=self.gc_pause_s() - self.gc_before,
             pool_before=self.pool_before, pool_after=self.pool_after)
 
         units = {m["name"]: m["unit"] for m in self.cell["end_to_end"]}
@@ -573,6 +621,9 @@ class Run:
                                             "unit": spec["unit"]}
             if breakdown:
                 out["breakdown"] = breakdown
+        # every number compared beside its limit, last in the line
+        out["checks"] = {name: {"value": value, "limit": limit}
+                         for name, value, limit in self.checks}
         return out
 
     def _reduce_trace(self) -> dict | None:
@@ -655,6 +706,8 @@ def main(argv=None) -> int:
                     help="copy the profiler's file there, to look at it")
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
+    # a run that dies of a signal leaves its python stacks on stderr
+    faulthandler.enable()
     # the compile cache at a fixed path inside the checkout, unless the
     # machine brings its own
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
@@ -674,6 +727,9 @@ def main(argv=None) -> int:
         print(f"yardstick: {e}", file=sys.stderr)
         return 2
     print(json.dumps(result), flush=True)
+    for name, c in result["checks"].items():
+        print(f"yardstick: check {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
     return 0
 
 

@@ -18,7 +18,7 @@ def test_paillier_known_vector():
 
 
 def test_paillier_sum_is_a_product_at_full_width():
-    pai = reference.Paillier(keys.PAILLIER_P, keys.PAILLIER_Q)
+    pai = reference.Paillier(*keys.PAILLIER[2048])
     assert pai.n.bit_length() == 2048
     rng = random.Random(7)
     plains = [rng.randrange(1 << 16) for _ in range(9)]
@@ -48,7 +48,7 @@ def test_rsa_known_vector_and_product():
     rsa = reference.Rsa(61, 53, 17)
     assert rsa.encrypt(65) == 2790
     assert rsa.decrypt(2790) == 65
-    big = reference.Rsa(keys.RSA_P, keys.RSA_Q, keys.RSA_E)
+    big = reference.Rsa(*keys.RSA[1024])
     assert big.n.bit_length() == 1024
     cs = [big.encrypt(m) for m in (3, 5, 7, 11)]
     assert big.decrypt(reference.fold(cs, big.n)) == 3 * 5 * 7 * 11
