@@ -661,12 +661,19 @@ _TYPES = {
 }
 
 
+_CLASSES = frozenset(_TYPES.values())
+# what JSON carries as it is: the bulk of a key tuple or a positions vector
+_PLAIN = frozenset({str, int, float, bool, type(None), list, dict})
+
+
 def _enc(v):
+    if type(v) in _PLAIN:
+        return v
     if isinstance(v, bytes):
         return {"__b64__": base64.b64encode(v).decode()}
     if isinstance(v, ABDTag):
         return {"__tag__": [v.seq, v.id]}
-    if type(v) in _TYPES.values():
+    if type(v) in _CLASSES:
         return to_dict(v)
     return v
 

@@ -52,6 +52,9 @@ MAX_TAG_ROUNDS = 4
 # a sender's kept vector may differ from the caller's list at a quarter of
 # the positions (at least this many) before it is dropped for a full reply
 MIN_KEPT_DIFF = 64
+# rounds whose quorum is met stay open this many deep for the votes that
+# come after it (see `AbdClient._on_late_tag_reply`)
+MAX_LATE_ROUNDS = 8
 # the vote of a replica whose vector equals the round's reference list:
 # it differs from it nowhere (see `_TagRound`)
 _SAME: dict = {}
@@ -126,13 +129,19 @@ class _TagRound:
     `cached_tags` when the round carries a fingerprint, else the first full
     reply accepted. `kept`, `gen` and `bases` (sender -> the record whose
     fingerprint this request named to it) tie the round to the key set's
-    `_KeptVectors`; `kept` is None for a round without a fingerprint."""
+    `_KeptVectors`; `kept` is None for a round without a fingerprint.
+    `asked` is who the round was sent to: once its quorum is met it stays
+    open for the votes of the others (`AbdClient._on_late_tag_reply`)."""
 
     __slots__ = ("fut", "votes", "digest", "keys", "fingerprint", "ref",
-                 "kept", "gen", "bases", "verify_ms", "kinds")
+                 "kept", "gen", "bases", "verify_ms", "kinds", "asked",
+                 "late")
 
-    def __init__(self, fut, digest, keys, fingerprint, ref, kept, bases):
+    def __init__(self, fut, digest, keys, fingerprint, ref, kept, bases,
+                 asked):
         self.fut = fut
+        self.asked = asked
+        self.late: set[str] = set()   # who answered after the quorum
         self.votes: dict[str, dict] = {}
         self.digest, self.keys, self.fingerprint = digest, keys, fingerprint
         self.ref = ref
@@ -207,6 +216,8 @@ class AbdClient:
         self._preferred: list[str] = []  # supervisor's freshest-half view
         # tag-broadcast nonce -> the round in flight
         self._pending_tags: dict[int, _TagRound] = {}
+        # nonce -> a round whose quorum is met, while replies are still owed
+        self._late_tags: dict[int, _TagRound] = {}
         # keys digest -> what each replica's vector was last verified to be
         self._kept_vectors: dict[str, _KeptVectors] = {}
         # Constellation: when a ShardRouter owns this client it installs a
@@ -231,8 +242,17 @@ class AbdClient:
             if not fut.done():
                 fut.set_result(msg)
             return
-        if isinstance(msg, M.TagBatchReply) and msg.nonce in self._pending_tags:
-            self._on_tag_batch_reply(sender, msg)
+        if isinstance(msg, M.TagBatchReply):
+            # correlated by REQUEST nonce. A reply to a round that is over
+            # (its quorum was met by the others: over sockets the last
+            # one's frame is decoded whenever the loop gets to it) ends
+            # HERE: fallen through to the junk-reply path it would resolve
+            # an Envelope op this sender happens to coordinate, and strike
+            # an honest replica for being last
+            if msg.nonce in self._pending_tags:
+                self._on_tag_batch_reply(sender, msg)
+            elif msg.nonce in self._late_tags:
+                self._on_late_tag_reply(sender, msg, self._late_tags[msg.nonce])
             return
         if isinstance(msg, M.WrongShard):
             # shard fence rejection: resolve the matching outstanding
@@ -769,21 +789,37 @@ class AbdClient:
         reply to refuse, else (the sender's vector as a diff against the
         round's reference list, the fingerprint it is attested under)."""
         rnd = self._pending_tags[msg.nonce]
-        if rnd.fut.done() or sender in rnd.votes:
+        if rnd.fut.done():
+            return self._on_late_tag_reply(sender, msg, rnd)
+        if sender in rnd.votes:
             return
         t0 = time.perf_counter()
-        if msg.unchanged:
-            kind, vote = "unchanged", self._vote_unchanged(msg, rnd)
-        elif msg.base is not None:
-            kind, vote = "delta", self._vote_delta(sender, msg, rnd)
-        else:
-            kind, vote = "full", self._vote_full(msg, rnd)
+        kind, vote = self._verify_vote(sender, msg, rnd)
         rnd.verify_ms += (time.perf_counter() - t0) * 1e3
         if vote is None:
             self.replicas.increment_suspicion(sender)
             return
         rnd.votes[sender], attested = vote
+        self._keep_vote(sender, attested, rnd.votes[sender], rnd)
         rnd.kinds[kind] += 1
+        if kind == "delta":
+            metrics.inc(
+                "dds_tag_round_delta_entries_total", len(msg.positions),
+                **self._mlabels(),
+                help="tags carried by accepted delta replies to ReadTagBatch",
+            )
+        if len(rnd.votes) >= self.cfg.quorum_size:
+            rnd.fut.set_result(list(rnd.votes.values()))
+
+    def _verify_vote(self, sender: str, msg: M.TagBatchReply, rnd: _TagRound):
+        if msg.unchanged:
+            return "unchanged", self._vote_unchanged(msg, rnd)
+        if msg.base is not None:
+            return "delta", self._vote_delta(sender, msg, rnd)
+        return "full", self._vote_full(msg, rnd)
+
+    @staticmethod
+    def _keep_vote(sender: str, attested, diff: dict, rnd: _TagRound) -> None:
         kept = rnd.kept
         if attested is not None and kept is not None and kept.gen == rnd.gen:
             # what this sender's vector is now verified to be: made from
@@ -791,9 +827,36 @@ class AbdClient:
             # it is a state the sender attested whichever of two concurrent
             # rounds lands last. Not kept once the reference list was
             # re-based under the round: its diff no longer fits
-            kept.senders[sender] = (attested, rnd.votes[sender])
-        if len(rnd.votes) >= self.cfg.quorum_size:
-            rnd.fut.set_result(list(rnd.votes.values()))
+            kept.senders[sender] = (attested, diff)
+
+    def _on_late_tag_reply(self, sender: str, msg: M.TagBatchReply,
+                           rnd: _TagRound) -> None:
+        """A vote that comes after its round's quorum moves no answer, but
+        it is verified like the others and kept as what the proxy holds of
+        that sender: the next round names it as the sender's base and is
+        owed a delta. Dropped unverified, the replica that answers last
+        (over sockets: any of them) would be named no base round after
+        round, and ship, format and MAC all K tags each time for a vote
+        nobody counts. A late vote that fails verification is not kept,
+        and strikes nobody: it was not waited for."""
+        kept = rnd.kept
+        if (
+            kept is None or kept.gen != rnd.gen or sender not in rnd.asked
+            or sender in rnd.votes or sender in rnd.late
+        ):
+            return
+        rnd.late.add(sender)
+        kind, vote = self._verify_vote(sender, msg, rnd)
+        if vote is not None:
+            diff, attested = vote
+            self._keep_vote(sender, attested, diff, rnd)
+            metrics.inc(
+                "dds_tag_round_late_votes_total", **self._mlabels(kind=kind),
+                help="ReadTagBatch votes verified and kept after their "
+                     "round's quorum was met, by reply kind",
+            )
+        if len(rnd.votes) + len(rnd.late) >= len(rnd.asked):
+            self._late_tags.pop(msg.nonce, None)
 
     def _vote_unchanged(self, msg, rnd: _TagRound):
         """"My vector equals the fingerprint you sent": only meaningful
@@ -874,11 +937,6 @@ class AbdClient:
                 diff.pop(i, None)
             else:
                 diff[i] = tag
-        metrics.inc(
-            "dds_tag_round_delta_entries_total", len(positions),
-            **self._mlabels(),
-            help="tags carried by accepted delta replies to ReadTagBatch",
-        )
         return diff, msg.fingerprint
 
     def _kept_for(self, digest: str, fingerprint: bytes, cached_tags: list,
@@ -951,7 +1009,10 @@ class AbdClient:
         replica that remembers the state answers with a `delta`: the
         positions it replaced since, one MAC over those. A full reply
         (first round, a reseeded replica, a base trimmed away) is verified
-        as ever and re-anchors its sender. Votes are held as "`cached_tags`
+        as ever and re-anchors its sender. A vote that comes after the
+        quorum is verified and kept all the same (`_on_late_tag_reply`),
+        so whoever answers last is named its base next time too. Votes are
+        held as "`cached_tags`
         except at these positions", and the per-key max runs over the
         union of those positions only; the result is element for element
         the max over the same votes taken whole. Deflation-resistance is
@@ -991,7 +1052,8 @@ class AbdClient:
                     kept, bases = None, {}
                 rnd = self._pending_tags[nonce] = _TagRound(
                     fut, digest, keys_t, fingerprint,
-                    cached_tags if kept is not None else None, kept, bases)
+                    cached_tags if kept is not None else None, kept, bases,
+                    frozenset(trusted))
                 epoch = self._epoch()
                 for replica in trusted:
                     # each replica is named the vector last verified from
@@ -1025,7 +1087,16 @@ class AbdClient:
                     _ctx=obs_context.child())
                 return merged
         finally:
-            self._pending_tags.pop(nonce, None)
+            rnd = self._pending_tags.pop(nonce, None)
+            if (
+                rnd is not None and rnd.kept is not None and fut.done()
+                and not fut.cancelled() and fut.exception() is None
+                and len(rnd.votes) + len(rnd.late) < len(rnd.asked)
+            ):
+                # quorum met, replies still owed: open for them a while
+                while len(self._late_tags) >= MAX_LATE_ROUNDS:
+                    del self._late_tags[next(iter(self._late_tags))]
+                self._late_tags[nonce] = rnd
 
     @staticmethod
     def _merge_votes(ref, votes: list, cached_tags: list | None) -> list:

@@ -21,12 +21,19 @@ the HMAC layer inside the messages.
 from __future__ import annotations
 
 import asyncio
+import hashlib
+import hmac
+import itertools
+import json
 import logging
+import time
 from typing import Awaitable, Callable, Optional
 
 from dds_tpu.core import messages as M
 from dds_tpu.obs import context as obs_context
+from dds_tpu.obs.metrics import metrics
 from dds_tpu.utils.tasks import supervised_task
+from dds_tpu.utils.trace import tracer
 
 log = logging.getLogger("dds.transport")
 
@@ -109,6 +116,14 @@ class TcpNet(Transport):
 
     One listening socket per host serves all endpoints registered on it;
     outbound connections are cached per destination host.
+
+    What the wire costs is recorded per frame, never per key: a
+    `net.serialize` span on the sending side (dict-encode, both JSON
+    encodes, MAC/signature) and a `net.deserialize` span on the receiving
+    side (JSON decode, MAC/signature check, `from_dict`), each with meta
+    `bytes`, `msg` (the message class) and `dest`; counters
+    `dds_net_frames_total` and `dds_net_frame_bytes_total` by `direction`
+    and `msg`, and `dds_net_frames_dropped_total` by `reason`.
     """
 
     def __init__(
@@ -157,24 +172,16 @@ class TcpNet(Transport):
         # below peers' recorded max until the clock catches up. Pair with
         # intranet TLS (which closes on-path capture entirely) where those
         # windows matter.
-        import itertools
-        import time as _time
-
-        self._send_ctr = itertools.count(_time.time_ns())
+        self._send_ctr = itertools.count(time.time_ns())
         self._seen_ctr: dict[str, int] = {}
         self._lock = asyncio.Lock()
 
     @staticmethod
     def _frame_body(src: str, dest: str, payload: dict, ctr=None) -> bytes:
-        import json
-
         return json.dumps([src, dest, ctr, payload], sort_keys=True).encode()
 
     def _frame_mac(self, body: bytes) -> str:
-        import hashlib
-        import hmac as hmac_mod
-
-        return hmac_mod.new(self._frame_secret, body, hashlib.sha256).hexdigest()
+        return hmac.new(self._frame_secret, body, hashlib.sha256).hexdigest()
 
     # endpoint addresses look like "host:port/name"
     @staticmethod
@@ -232,102 +239,122 @@ class TcpNet(Transport):
     # the receiver buffer it
     MAX_FRAME = 32 * 1024 * 1024
 
+    @staticmethod
+    def _note_frame(span: str, direction: str, t0: float, parent, size: int,
+                    msg: str, dest: str) -> None:
+        """One frame crossed the codec: its span (ending now, a child of
+        the sender's span where the frame carries one) and counts."""
+        tracer.record(
+            span, (time.perf_counter() - t0) * 1e3,
+            _ctx=obs_context.child(parent) if parent is not None else None,
+            bytes=size, msg=msg, dest=dest.rsplit("/", 1)[-1])
+        metrics.inc("dds_net_frames_total", direction=direction, msg=msg,
+                    help="TcpNet frames by direction and message class")
+        metrics.inc("dds_net_frame_bytes_total", size, direction=direction,
+                    msg=msg,
+                    help="TcpNet frame bytes (length prefix not counted) by "
+                         "direction and message class")
+
+    @staticmethod
+    def _drop(reason: str, why: str, *args) -> None:
+        log.warning("dropping frame: " + why, *args)
+        metrics.inc("dds_net_frames_dropped_total", reason=reason,
+                    help="TcpNet frames refused, by reason")
+
     async def _serve(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
         try:
             while True:
                 hdr = await reader.readexactly(4)
                 size = int.from_bytes(hdr, "big")
                 if size > self.MAX_FRAME:
-                    log.warning(
-                        "dropping connection from %s: %d-byte frame declared",
-                        writer.get_extra_info("peername"), size,
-                    )
+                    # the connection goes with it: nothing after a length
+                    # that was not read can be told apart from its body
+                    self._drop("oversize", "%d bytes declared by %s", size,
+                               writer.get_extra_info("peername"))
                     break
                 frame = await reader.readexactly(size)
-                import json
-
                 # Per-frame decode must not tear down the shared connection:
                 # a malformed frame (or one from a peer speaking a newer
                 # codec during a rolling upgrade) is logged and skipped —
                 # killing the loop here would silently drop every queued
                 # frame behind it from the same peer.
-                try:
-                    obj = json.loads(frame)
-                    src, dest, payload = obj["src"], obj["dest"], obj["msg"]
-                    if not isinstance(src, str) or not isinstance(dest, str):
-                        raise ValueError("non-string src/dest")
-                except Exception as e:
-                    log.warning(
-                        "dropping undecodable frame from %s: %s",
-                        writer.get_extra_info("peername"), e,
-                    )
+                got = self._open_frame(frame)
+                if got is None:
                     continue
-                body = None
-                if self._frame_secret is not None or self._peer_keys is not None:
-                    body = self._frame_body(src, dest, payload, obj.get("ctr"))
-                if self._frame_secret is not None:
-                    import hmac as hmac_mod
-
-                    if not hmac_mod.compare_digest(
-                        obj.get("mac", ""), self._frame_mac(body)
-                    ):
-                        log.warning("dropping frame with bad MAC (src claims %s)", src)
-                        continue
-                if self._peer_keys is not None:
-                    src_host = src.split("/", 1)[0]
-                    pub = self._peer_keys.get(src_host)
-                    try:
-                        if pub is None:
-                            raise ValueError("unregistered src host")
-                        # the signed dest must name THIS process (by its
-                        # ADVERTISED address): endpoint names repeat across
-                        # hosts (proxy-0, nodehost), so a frame captured on
-                        # the wire to host A must not verify and dispatch
-                        # on host B
-                        if "/" in dest and dest.split("/", 1)[0] != self.advertised:
-                            raise ValueError("frame destined for another host")
-                        pub.verify(bytes.fromhex(obj.get("sig", "")), body)
-                        ctr = int(obj["ctr"])
-                        if ctr <= self._seen_ctr.get(src_host, -1):
-                            raise ValueError("replayed frame counter")
-                        self._seen_ctr[src_host] = ctr
-                    except Exception:
-                        log.warning(
-                            "dropping frame with bad/missing node signature, "
-                            "wrong dest host, or replayed counter "
-                            "(src claims %s)", src,
-                        )
-                        continue
-                name = dest.split("/", 1)[1] if "/" in dest else dest
-                handler = self._handlers.get(name)
-                if handler is not None:
-                    try:
-                        msg = M.from_dict(payload)
-                    except Exception as e:
-                        log.warning(
-                            "dropping frame with undecodable payload from "
-                            "%s: %s", src, e,
-                        )
-                        continue
-                    # restore the sender's trace context (frame `tc`, see
-                    # _send) so spans recorded by the handler join the
-                    # originating request's trace tree across the TCP hop.
-                    # Observability metadata only — outside the MAC, and a
-                    # malformed field degrades to an unlinked span, never
-                    # a dropped message.
-                    tc = obs_context.from_wire(obj.get("tc"))
-                    if tc is not None:
-                        supervised_task(
-                            self._handle_traced(handler, tc, src, msg),
-                            name=f"tcp.handle:{src}",
-                        )
-                    else:
-                        supervised_task(handler(src, msg),
-                                        name=f"tcp.handle:{src}")
+                handler, tc, src, msg = got
+                if tc is not None:
+                    supervised_task(
+                        self._handle_traced(handler, tc, src, msg),
+                        name=f"tcp.handle:{src}",
+                    )
+                else:
+                    supervised_task(handler(src, msg),
+                                    name=f"tcp.handle:{src}")
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
         finally:
             writer.close()
+
+    def _open_frame(self, frame: bytes):
+        """Decode and authenticate one inbound frame: (handler, the
+        sender's trace context, src, message), or None for a frame that is
+        dropped (counted by reason) or addressed to no endpoint here."""
+        t0 = time.perf_counter()
+        try:
+            obj = json.loads(frame)
+            src, dest, payload = obj["src"], obj["dest"], obj["msg"]
+            if not isinstance(src, str) or not isinstance(dest, str):
+                raise ValueError("non-string src/dest")
+        except Exception as e:
+            return self._drop("undecodable", "undecodable (%s)", e)
+        body = None
+        if self._frame_secret is not None or self._peer_keys is not None:
+            body = self._frame_body(src, dest, payload, obj.get("ctr"))
+        if self._frame_secret is not None:
+            mac = obj.get("mac")
+            if not (isinstance(mac, str) and mac.isascii()
+                    and hmac.compare_digest(mac, self._frame_mac(body))):
+                return self._drop("bad_mac", "bad MAC (src claims %s)", src)
+        if self._peer_keys is not None:
+            src_host = src.split("/", 1)[0]
+            pub = self._peer_keys.get(src_host)
+            try:
+                if pub is None:
+                    raise ValueError("unregistered src host")
+                # the signed dest must name THIS process (by its
+                # ADVERTISED address): endpoint names repeat across
+                # hosts (proxy-0, nodehost), so a frame captured on
+                # the wire to host A must not verify and dispatch
+                # on host B
+                if "/" in dest and dest.split("/", 1)[0] != self.advertised:
+                    raise ValueError("frame destined for another host")
+                pub.verify(bytes.fromhex(obj.get("sig", "")), body)
+                ctr = int(obj["ctr"])
+                if ctr <= self._seen_ctr.get(src_host, -1):
+                    raise ValueError("replayed frame counter")
+                self._seen_ctr[src_host] = ctr
+            except Exception:
+                return self._drop(
+                    "bad_signature", "bad/missing node signature, wrong "
+                    "dest host, or replayed counter (src claims %s)", src)
+        name = dest.split("/", 1)[1] if "/" in dest else dest
+        handler = self._handlers.get(name)
+        if handler is None:
+            return None
+        try:
+            msg = M.from_dict(payload)
+        except Exception as e:
+            return self._drop("bad_payload",
+                              "undecodable payload from %s (%s)", src, e)
+        # restore the sender's trace context (frame `tc`, see _send) so
+        # spans recorded by the handler join the originating request's
+        # trace tree across the TCP hop. Observability metadata only —
+        # outside the MAC, and a malformed field degrades to an unlinked
+        # span, never a dropped message.
+        tc = obs_context.from_wire(obj.get("tc"))
+        self._note_frame("net.deserialize", "received", t0, tc, len(frame),
+                         type(msg).__name__, dest)
+        return handler, tc, src, msg
 
     @staticmethod
     async def _handle_traced(handler, tc, src: str, msg) -> None:
@@ -342,9 +369,6 @@ class TcpNet(Transport):
                         name=f"tcp.send:{dest}")
 
     async def _send(self, src: str, dest: str, msg: object) -> None:
-        import json
-        import time
-
         host, port, _ = self.split(dest)
         conn_key = f"{host}:{port}"
         try:
@@ -358,9 +382,9 @@ class TcpNet(Transport):
             obj = {"src": src, "dest": dest, "msg": payload}
             # trace-context propagation (ensure_future copied the caller's
             # contextvars into this task, so current() is the sender's span)
-            tc = obs_context.to_wire()
-            if tc is not None:
-                obj["tc"] = tc
+            cur = obs_context.current()
+            if cur is not None:
+                obj["tc"] = obs_context.to_wire(cur)
             if self._frame_secret is not None or self._node_key is not None:
                 ctr = next(self._send_ctr) if self._node_key is not None else None
                 if ctr is not None:
@@ -371,33 +395,20 @@ class TcpNet(Transport):
                 if self._node_key is not None:
                     obj["sig"] = self._node_key.sign(body).hex()
             frame = json.dumps(obj).encode()
-            if tc is not None:
-                # Chronoscope's serialize stage: dict-encode + json + frame
-                # MAC/signature, attributed to the SENDER's span (tc is only
-                # non-None inside one)
-                from dds_tpu.utils.trace import tracer
-
-                cur = obs_context.current()
-                tracer.record(
-                    "net.serialize",
-                    (time.perf_counter() - t_ser) * 1e3,
-                    _ctx=obs_context.child(cur) if cur is not None else None,
-                    bytes=len(frame), dest=dest.rsplit("/", 1)[-1],
-                )
             if len(frame) > self.MAX_FRAME:
                 # symmetric with the receive bound: sending it anyway would
                 # get the shared cached connection killed at the receiver,
                 # silently losing queued frames behind it
-                log.error(
-                    "refusing to send %d-byte frame %s -> %s (MAX_FRAME %d)",
-                    len(frame), src, dest, self.MAX_FRAME,
-                )
-                return
+                return self._drop(
+                    "too_large_to_send", "%d bytes %s -> %s not sent "
+                    "(MAX_FRAME %d)", len(frame), src, dest, self.MAX_FRAME)
+            # Chronoscope's serialize stage: dict-encode + json + frame
+            # MAC/signature
+            self._note_frame("net.serialize", "sent", t_ser, cur, len(frame),
+                             type(msg).__name__, dest)
             t_drain = time.perf_counter()
             w.write(len(frame).to_bytes(4, "big") + frame)
             await w.drain()
-            from dds_tpu.obs.metrics import metrics
-
             metrics.observe(
                 "dds_net_drain_seconds", time.perf_counter() - t_drain,
                 help="TCP send-buffer drain wait (backpressure signal)",

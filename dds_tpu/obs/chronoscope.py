@@ -66,7 +66,7 @@ STAGES = (
     "admission",                # backpressure decision at the front door
     "assemble",                 # aggregate operand assembly (memo-miss work)
     "coalesce-wait",            # sat in the proxy fold coalescer window
-    "serialize",                # message -> wire frame (+ MAC/sig)
+    "serialize",                # message <-> wire frame (+ MAC/sig)
     "quorum-rtt",               # ABD round: on the wire + remote queueing
     "hmac-verify",              # proxy-side reply signature validation
     "replica-apply",            # replica handler work (storage + sign)
@@ -99,7 +99,7 @@ def _stage_of(name: str) -> str:
         return "admission"
     if name == "proxy.coalesce_wait":
         return "coalesce-wait"
-    if name == "net.serialize":
+    if name in ("net.serialize", "net.deserialize"):
         return "serialize"
     if name == "abd.verify":
         return "hmac-verify"

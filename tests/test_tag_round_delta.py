@@ -459,8 +459,9 @@ def test_a_delta_to_a_round_that_named_no_base_is_refused():
 def test_a_delta_round_formats_and_compares_what_it_carries_not_k(
         m, monkeypatch):
     """(c) K = 600 keys, m of them written between two rounds. The proxy
-    calls `tag_field` once per entry of the deltas it accepted, `tags_blob`
-    never, and orders tags only at the positions some vote moved."""
+    calls `tag_field` once per entry of the deltas it verified (the
+    quorum's and the one that came after it), `tags_blob` never, and orders
+    tags only at the positions some vote of the quorum moved."""
     calls = {"tag_field": 0, "tags_blob": 0, "lt": 0}
     real_field, real_blob = sigs.tag_field, sigs.tags_blob
 
@@ -510,7 +511,9 @@ def test_a_delta_round_formats_and_compares_what_it_carries_not_k(
         carried = (metrics.value("dds_tag_round_delta_entries_total")
                    - entries)
         assert carried == 3 * m               # q deltas of m entries each
-        assert calls == {"tag_field": 3 * m, "tags_blob": 0, "lt": 3 * m}
+        # the fourth replica's delta, after the quorum, is verified and
+        # kept too (PR 34): m more fields formatted, no order taken
+        assert calls == {"tag_field": 4 * m, "tags_blob": 0, "lt": 3 * m}
         spans = tracer.summary()
         assert spans["abd.read_tags.verify"]["count"] == 1
         assert spans["abd.read_tags.merge"]["count"] == 1
