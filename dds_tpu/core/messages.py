@@ -125,7 +125,12 @@ class ReadTagBatch:
     replicas answer (and burn an anti-replay nonce) only for holders of
     the proxy secret. This is the aggregate-cache validation op the
     reference lacks — it re-reads every stored set through full ABD
-    quorums per aggregate instead (`dds/http/DDSRestServer.scala:397-446`)."""
+    quorums per aggregate instead (`dds/http/DDSRestServer.scala:397-446`).
+
+    The key set is NAMED by `digest` (what the MAC covers) and `count`;
+    `keys` is empty unless the request teaches the set: a replica that
+    holds the digest's keys answers from them, one that does not says
+    `KeySetUnknown` and is sent the keys, once, under a nonce of their own."""
 
     keys: tuple
     nonce: int
@@ -144,6 +149,25 @@ class ReadTagBatch:
     # `delta` reply: the positions replaced since. None, or a base it does
     # not remember: the full reply.
     base: Optional[bytes] = None
+    # `sigs.key_from_set` of the key set, in request order, and how many
+    # keys it has. With `keys` empty and `count` > 0 the request is named:
+    # answerable only by a replica that holds the digest's keys. ("" is a
+    # frame of the schema before these fields: a replica does not answer it.)
+    digest: str = ""
+    count: int = 0
+
+
+@dataclass(frozen=True)
+class KeySetUnknown:
+    """Replica -> proxy: "I hold no key set under `digest`" in answer to
+    an authenticated named `ReadTagBatch` (request nonce `nonce`, which it
+    spends). Signed with the intranet MAC over (digest, nonce) under a
+    domain tag of its own (`sigs.abd_keyset_unknown_signature`). No vote:
+    the proxy answers that sender alone with the keys."""
+
+    digest: str
+    nonce: int
+    signature: bytes
 
 
 @dataclass(frozen=True)
@@ -645,7 +669,7 @@ _TYPES = {
     for cls in (
         IRead, IWrite, IReadReply, IWriteReply, Envelope,
         ReadTag, TagReply, Write, WriteAck, Read, ReadReply,
-        ReadTagBatch, TagBatchReply,
+        ReadTagBatch, TagBatchReply, KeySetUnknown,
         Suspect, Awake, State, Sleep, Complying, Kill,
         Redeploy, Redeployed, RequestReplicas, ActiveReplicas, Compromise,
         Crash,

@@ -52,6 +52,11 @@ MAX_TAG_ROUNDS = 4
 # a sender's kept vector may differ from the caller's list at a quarter of
 # the positions (at least this many) before it is dropped for a full reply
 MIN_KEPT_DIFF = 64
+# key sets (by keys digest) of which a proxy remembers which replicas have
+# answered for them, and so are sent the digest without the keys; as many
+# as a replica keeps (`replica.MAX_TAG_VECTORS`); past it the one used
+# longest ago goes, and its next round carries the keys
+MAX_NAMED_SETS = 8
 # rounds whose quorum is met stay open this many deep for the votes that
 # come after it (see `AbdClient._on_late_tag_reply`)
 MAX_LATE_ROUNDS = 8
@@ -131,16 +136,22 @@ class _TagRound:
     fingerprint this request named to it) tie the round to the key set's
     `_KeptVectors`; `kept` is None for a round without a fingerprint.
     `asked` is who the round was sent to: once its quorum is met it stays
-    open for the votes of the others (`AbdClient._on_late_tag_reply`)."""
+    open for the votes of the others (`AbdClient._on_late_tag_reply`).
+    `nonces` are the request nonces the round answers to: its own, and one
+    more for each replica that said `KeySetUnknown` and was sent the keys;
+    `carried` is who has been sent the keys in this round (each once)."""
 
     __slots__ = ("fut", "votes", "digest", "keys", "fingerprint", "ref",
                  "kept", "gen", "bases", "verify_ms", "kinds", "asked",
-                 "late")
+                 "late", "nonces", "carried", "epoch")
 
     def __init__(self, fut, digest, keys, fingerprint, ref, kept, bases,
-                 asked):
+                 asked, nonce, epoch):
         self.fut = fut
         self.asked = asked
+        self.nonces = [nonce]
+        self.epoch = epoch
+        self.carried: set[str] = set()
         self.late: set[str] = set()   # who answered after the quorum
         self.votes: dict[str, dict] = {}
         self.digest, self.keys, self.fingerprint = digest, keys, fingerprint
@@ -220,6 +231,10 @@ class AbdClient:
         self._late_tags: dict[int, _TagRound] = {}
         # keys digest -> what each replica's vector was last verified to be
         self._kept_vectors: dict[str, _KeptVectors] = {}
+        # keys digest -> the replicas that have answered for it with a
+        # verified vote: they hold the digest's keys and are sent the
+        # digest alone, until one says `KeySetUnknown`
+        self._keyset_holders: dict[str, set[str]] = {}
         # Constellation: when a ShardRouter owns this client it installs a
         # supplier for the ACTIVE map epoch; every Envelope/ReadTagBatch is
         # stamped with it so replicas can fence stale routes. None = -1 =
@@ -253,6 +268,15 @@ class AbdClient:
                 self._on_tag_batch_reply(sender, msg)
             elif msg.nonce in self._late_tags:
                 self._on_late_tag_reply(sender, msg, self._late_tags[msg.nonce])
+            return
+        if isinstance(msg, M.KeySetUnknown):
+            # correlated by REQUEST nonce, and ends HERE like a late vote:
+            # one that comes after its round is remembered and strikes
+            # nobody; one that matches no round kept is dropped
+            rnd = (self._pending_tags.get(msg.nonce)
+                   or self._late_tags.get(msg.nonce))
+            if rnd is not None:
+                self._on_keyset_unknown(sender, msg, rnd)
             return
         if isinstance(msg, M.WrongShard):
             # shard fence rejection: resolve the matching outstanding
@@ -801,6 +825,7 @@ class AbdClient:
             return
         rnd.votes[sender], attested = vote
         self._keep_vote(sender, attested, rnd.votes[sender], rnd)
+        self._holds_keyset(sender, rnd)
         rnd.kinds[kind] += 1
         if kind == "delta":
             metrics.inc(
@@ -850,13 +875,89 @@ class AbdClient:
         if vote is not None:
             diff, attested = vote
             self._keep_vote(sender, attested, diff, rnd)
+            self._holds_keyset(sender, rnd)
             metrics.inc(
                 "dds_tag_round_late_votes_total", **self._mlabels(kind=kind),
                 help="ReadTagBatch votes verified and kept after their "
                      "round's quorum was met, by reply kind",
             )
         if len(rnd.votes) + len(rnd.late) >= len(rnd.asked):
-            self._late_tags.pop(msg.nonce, None)
+            for nonce in rnd.nonces:
+                self._late_tags.pop(nonce, None)
+
+    def _holds_keyset(self, sender: str, rnd: _TagRound) -> None:
+        """A verified vote: its sender holds the keys of the round's
+        digest, and is owed no more than the digest from now on."""
+        holders = self._keyset_holders.get(rnd.digest)
+        if holders is not None:
+            holders.add(sender)
+
+    def _on_keyset_unknown(self, sender: str, msg: M.KeySetUnknown,
+                           rnd: _TagRound) -> None:
+        """A replica holds no keys under the digest a request named (it was
+        reseeded, or evicted the set). Authenticated, it is remembered: the
+        next request to that sender carries the keys. While the round is
+        still waiting, that next request is sent now, to that sender alone
+        and under a nonce it has not seen: at most once per replica and
+        round. A replica that was sent the keys in this round already
+        (first, or in answer to an earlier `unknown`) gets nothing more: it
+        gives no vote this round, and is not struck. A forged or wrongly
+        MAC'd `unknown` moves nothing."""
+        if (
+            sender not in rnd.asked
+            or msg.digest != rnd.digest
+            or not isinstance(msg.signature, bytes)
+            or not sigs.validate_abd_keyset_unknown_signature(
+                self.cfg.abd_mac_secret, msg.digest, msg.nonce,
+                msg.signature)
+        ):
+            return
+        holders = self._keyset_holders.get(rnd.digest)
+        if holders is not None:
+            holders.discard(sender)
+        if rnd.fut.done() or sender in rnd.votes or sender in rnd.carried:
+            return
+        nonce = sigs.generate_nonce()
+        rnd.nonces.append(nonce)
+        self._pending_tags[nonce] = rnd
+        self._request_tags(rnd, sender, nonce, sigs.proxy_signature(
+            self.cfg.proxy_mac_secret, rnd.digest, nonce), carry=True)
+
+    def _request_tags(self, rnd: _TagRound, replica: str, nonce: int,
+                      sig: bytes, carry: bool) -> None:
+        """One `ReadTagBatch` to one replica under `nonce` and its MAC
+        `sig`: the digest and the count always, the keys when `carry`.
+        Each replica is named the vector last verified from IT as the
+        base of a delta; none kept, none named."""
+        if carry:
+            rnd.carried.add(replica)
+            if type(rnd.keys) is not tuple:
+                rnd.keys = tuple(rnd.keys)
+        base = rnd.bases.get(replica)
+        metrics.inc(
+            "dds_tag_round_requests_total",
+            **self._mlabels(keys="carried" if carry else "named"),
+            help="ReadTagBatch requests sent, by whether they carried the "
+                 "keys or named them by digest alone",
+        )
+        self.net.send(self.addr, replica, M.ReadTagBatch(
+            rnd.keys if carry else (), nonce, sig, rnd.fingerprint,
+            rnd.epoch, base[0] if base is not None else None,
+            rnd.digest, len(rnd.keys)))
+
+    def _holders_for(self, digest: str, trusted: list) -> set:
+        """Who of `trusted` has answered for this digest, newest digest
+        last; a digest not remembered starts with nobody, so its round
+        carries the keys to everyone."""
+        holders = self._keyset_holders.pop(digest, None)
+        if holders is None:
+            while len(self._keyset_holders) >= MAX_NAMED_SETS:
+                del self._keyset_holders[next(iter(self._keyset_holders))]
+            holders = set()
+        else:
+            holders.intersection_update(trusted)
+        self._keyset_holders[digest] = holders
+        return holders
 
     def _vote_unchanged(self, msg, rnd: _TagRound):
         """"My vector equals the fingerprint you sent": only meaningful
@@ -1019,7 +1120,26 @@ class AbdClient:
         again unchanged: the honest quorum-intersection replica's chain
         starts at a fully verified reply and every link carries its MAC,
         and a delta is applied to its own sender's record only, so a liar
-        can misstate no vote but its own, which it always could."""
+        can misstate no vote but its own, which it always could.
+
+        The request names the key set by `digest` (what its MAC covers)
+        and carries the K keys only to a replica that has not yet answered
+        for that digest with a verified vote (`_keyset_holders`: first
+        round, newly trusted, forgotten past `MAX_NAMED_SETS`). A replica
+        that holds no keys under the digest says `KeySetUnknown` under its
+        MAC and is sent the keys, alone, inside the same round and under a
+        nonce of their own, at most once per replica and round
+        (`_on_keyset_unknown`). Which form a request takes follows what
+        this proxy has observed of that replica and digest, nothing else.
+        Deflation-resistance is unchanged once more: a replica adopts
+        carried keys only if they hash to the digest the proxy MAC'd, so a
+        vote is over the caller's key set or it is no vote; an `unknown`
+        is none, and no replica's claim stands in for another's. The one
+        lever a Byzantine replica gains is `unknown` every round: it costs
+        the proxy one carried request to that replica per round (what
+        every replica cost in every round before), never a vote of the
+        honest quorum, and strikes nobody, since an honest replica that
+        was reseeded or evicted the set says it too, once."""
         trusted = self.replicas.get_trusted()
         if len(trusted) < self.cfg.quorum_size:
             raise ByzUnknownReplyError(
@@ -1038,30 +1158,29 @@ class AbdClient:
         nonce = sigs.generate_nonce()
         if digest is None:
             digest = sigs.key_from_set(list(keys))
-        sig = sigs.proxy_signature(self.cfg.proxy_mac_secret, digest, nonce)
         fut: asyncio.Future = asyncio.get_event_loop().create_future()
-        keys_t = tuple(keys)
         try:
             with tracer.span("abd.read_tags", k=len(keys)) as rm:
                 t0 = time.perf_counter()
-                if fingerprint is not None and keys_t:
+                if fingerprint is not None and keys:
                     kept = self._kept_for(digest, fingerprint, cached_tags,
                                           trusted)
                     bases = dict(kept.senders)
                 else:
                     kept, bases = None, {}
                 rnd = self._pending_tags[nonce] = _TagRound(
-                    fut, digest, keys_t, fingerprint,
+                    fut, digest, keys, fingerprint,
                     cached_tags if kept is not None else None, kept, bases,
-                    frozenset(trusted))
-                epoch = self._epoch()
+                    frozenset(trusted), nonce, self._epoch())
+                # a replica that has answered for this digest holds its
+                # keys and is sent the digest alone; the others (first
+                # round, newly trusted, said `unknown`) are sent the keys
+                holders = self._holders_for(digest, trusted)
+                sig = sigs.proxy_signature(self.cfg.proxy_mac_secret, digest,
+                                           nonce)
                 for replica in trusted:
-                    # each replica is named the vector last verified from
-                    # IT as the base of a delta; none kept, none named
-                    base = bases.get(replica)
-                    self.net.send(self.addr, replica, M.ReadTagBatch(
-                        keys_t, nonce, sig, fingerprint, epoch,
-                        base[0] if base is not None else None))
+                    self._request_tags(rnd, replica, nonce, sig,
+                                       carry=replica not in holders)
                 votes = await asyncio.wait_for(fut, timeout)
                 t1 = time.perf_counter()
                 metrics.observe(
@@ -1069,7 +1188,7 @@ class AbdClient:
                     **self._mlabels(op="read_tags"),
                     help="proxy->coordinator quorum round-trip time",
                 )
-                rm.update(rnd.kinds)
+                rm.update(rnd.kinds, carried=len(rnd.carried))
                 for kind, n in rnd.kinds.items():
                     if n:
                         metrics.inc(
@@ -1088,15 +1207,19 @@ class AbdClient:
                 return merged
         finally:
             rnd = self._pending_tags.pop(nonce, None)
-            if (
-                rnd is not None and rnd.kept is not None and fut.done()
-                and not fut.cancelled() and fut.exception() is None
-                and len(rnd.votes) + len(rnd.late) < len(rnd.asked)
-            ):
-                # quorum met, replies still owed: open for them a while
-                while len(self._late_tags) >= MAX_LATE_ROUNDS:
-                    del self._late_tags[next(iter(self._late_tags))]
-                self._late_tags[nonce] = rnd
+            if rnd is not None:
+                for extra in rnd.nonces[1:]:
+                    self._pending_tags.pop(extra, None)
+                if (
+                    rnd.kept is not None and fut.done()
+                    and not fut.cancelled() and fut.exception() is None
+                    and len(rnd.votes) + len(rnd.late) < len(rnd.asked)
+                ):
+                    # quorum met, replies still owed: open for them a while
+                    while len(self._late_tags) >= MAX_LATE_ROUNDS:
+                        del self._late_tags[next(iter(self._late_tags))]
+                    for owed in rnd.nonces:
+                        self._late_tags[owed] = rnd
 
     @staticmethod
     def _merge_votes(ref, votes: list, cached_tags: list | None) -> list:

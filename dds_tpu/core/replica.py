@@ -89,10 +89,11 @@ MIN_DELTA_HISTORY = 64
 class _TagVector:
     """What the ReadTagBatch reply for one key set is made of, kept across
     repository changes and patched by the keys stored since: the keys'
-    digest (a function of the keys alone), key -> position, the tag per
-    position and its `sigs.tag_field`, the joined blob the MAC covers and
-    its fingerprint. `seen` is how far into the replica's log of stored
-    keys the vector has been brought.
+    digest and the keys it stands for (a named request carries the digest
+    alone), key -> position, the tag per position and its
+    `sigs.tag_field`, the joined blob the MAC covers and its fingerprint.
+    `seen` is how far into the replica's log of stored keys the vector has
+    been brought.
 
     For delta replies it also keeps `moved`, the positions it replaced, in
     order, and `marks`: the fingerprint of each state it sealed -> how far
@@ -102,12 +103,13 @@ class _TagVector:
     mark. Equal fingerprints are equal vectors, so the newest mark of a
     fingerprint serves."""
 
-    __slots__ = ("digest", "index", "tags", "fields", "reply_tags", "blob",
-                 "fingerprint", "seen", "moved", "marks", "trimmed")
+    __slots__ = ("digest", "keys", "index", "tags", "fields", "reply_tags",
+                 "blob", "fingerprint", "seen", "moved", "marks", "trimmed")
 
     def __init__(self, keys: tuple, digest: str, repository: dict,
                  blank: tuple, seen: int):
         self.digest = digest
+        self.keys = keys
         self.index = {k: i for i, k in enumerate(keys)}
         # read without materializing default entries in the repository
         self.tags = [repository.get(k, blank)[0] for k in keys]
@@ -196,8 +198,13 @@ class BFTABDNode:
         # bumped on every observable repository change (stored Write, Sleep
         # reseed, Kill wipe, snapshot restore)
         self.repo_version = 0
-        # keys-tuple -> _TagVector: what each key set's ReadTagBatch reply
-        # is made of, kept across stored writes. `_store` logs the key it
+        # keys digest -> _TagVector: what each key set's ReadTagBatch reply
+        # is made of, and the keys the digest stands for, kept across stored
+        # writes. What a digest names is a function of the keys alone and
+        # could outlive a reseed; it is kept IN the vector and goes with it
+        # all the same (one table, one bound, and a reseed, wipe or restore
+        # costs each key set one `KeySetUnknown` and one carried request,
+        # next to a transfer of the whole state). `_store` logs the key it
         # changed (`_stored_since`, only while a vector is kept) and a round
         # patches its vector by the keys logged since it last looked:
         # O(keys written since) per round, nothing when none was. The
@@ -205,7 +212,7 @@ class BFTABDNode:
         # `repo_version` on every change that names its key; a change that
         # names none (reseed, wipe, prune, a bare `repo_version` bump) leaves
         # it behind, and the vectors are dropped before they are next read
-        self._tag_vectors: dict[tuple, _TagVector] = {}
+        self._tag_vectors: dict[str, _TagVector] = {}
         self._stored_since: list[str] = []
         self._vector_version = 0
         # Aegis: incremental (key -> tag, value-digest) hash index — the
@@ -272,7 +279,8 @@ class BFTABDNode:
 
     def _drop_tag_vectors(self) -> None:
         """The repository changed in a way that names no key: nothing kept
-        describes it. The next round of each key set builds anew."""
+        describes it. The next round of each key set builds anew, from
+        keys the proxy is asked for again (`KeySetUnknown`)."""
         self._tag_vectors.clear()
         self._stored_since.clear()
         self._vector_version = self.repo_version
@@ -337,11 +345,20 @@ class BFTABDNode:
                      epoch=epoch, sent_epoch=sent_epoch, msg=what)
         self._send(dest, M.WrongShard(key, epoch, nonce, sig))
 
+    @staticmethod
+    def _count_keyset(outcome: str) -> None:
+        metrics.inc(
+            "dds_replica_keyset_total", outcome=outcome,
+            help="ReadTagBatch requests by what the replica held of the "
+                 "named key set (refused: carried keys of another digest)",
+        )
+
     def _tag_vector(self, keys: tuple, vec: _TagVector | None,
                     digest: str) -> tuple[_TagVector, str, int]:
         """(vector, outcome, tags replaced) for an AUTHENTICATED
-        ReadTagBatch: `vec`, the vector the probe found kept for this key
-        set (None when it found none), brought up to the repository.
+        ReadTagBatch: `vec`, the vector the probe found kept under this
+        digest (None when it found none, and `keys` are then the request's
+        own, hashed to the digest), brought up to the repository.
         `reused` when no key of the set was stored since it last looked,
         `patched` when those that were had their tag and field replaced
         (and one join and one hash redone), `rebuilt` when there was none
@@ -354,7 +371,7 @@ class BFTABDNode:
         if vec is None:
             while len(self._tag_vectors) >= MAX_TAG_VECTORS:
                 del self._tag_vectors[next(iter(self._tag_vectors))]
-            vec = self._tag_vectors[keys] = _TagVector(
+            vec = self._tag_vectors[digest] = _TagVector(
                 keys, digest, self.repository,
                 (M.ABDTag(0, self.name), None), len(log),
             )
@@ -364,11 +381,11 @@ class BFTABDNode:
         changed = vec.patch(log[vec.seen:], self.repository)
         # the log is needed only as far back as the vector furthest behind;
         # one that fell too far behind goes instead of holding it
-        behind = [ks for ks, v in self._tag_vectors.items()
+        behind = [d for d, v in self._tag_vectors.items()
                   if v.seen < len(log)]
         if not behind or len(log) > MAX_TAG_VECTOR_LAG:
-            for ks in behind:
-                del self._tag_vectors[ks]
+            for d in behind:
+                del self._tag_vectors[d]
             log.clear()
             for v in self._tag_vectors.values():
                 v.seen = 0
@@ -473,15 +490,16 @@ class BFTABDNode:
                 # coordinator: authenticate the request BEFORE burning an
                 # anti-replay nonce, or unauthenticated traffic could both
                 # enumerate tags (write-activity oracle) and grow the nonce
-                # set without bound. The kept vectors are PROBED read-only
-                # here (the digest of a key set already asked about is kept
-                # with its vector: it is a function of the keys alone) and
-                # only built, patched or evicted after the MAC verifies —
-                # pre-auth traffic must not be able to evict the hot vector
-                # or grow the table
-                kept = self._tag_vectors.get(keys)
-                digest = (kept.digest if kept is not None
-                          else sigs.key_from_set(list(keys)))
+                # set without bound. The request names its key set by the
+                # digest its MAC covers; the kept vectors are PROBED
+                # read-only by it (one string's hash, no key is hashed or
+                # compared) and only built, patched or evicted after the
+                # MAC verifies — pre-auth traffic must not be able to evict
+                # the hot vector, grow the table or teach it a key set
+                digest = msg.digest
+                if not digest or not isinstance(digest, str):
+                    return   # the schema before `digest`: no name, no answer
+                kept = self._tag_vectors.get(digest)
                 if not sigs.validate_proxy_signature(
                     cfg.proxy_mac_secret, digest, nonce, psig
                 ):
@@ -491,6 +509,30 @@ class BFTABDNode:
                     self._debug("invalid nonce - repeated (tag batch)")
                     self._suspect(sender)
                     return
+                if kept is not None:
+                    # whatever keys the request carried, the digest's own
+                    # are the ones kept: hashed once, when they were learned
+                    keys, held = kept.keys, "known"
+                elif not keys and msg.count > 0:
+                    # named, and nothing held under the name (never taught,
+                    # evicted, or dropped with a reseed): say so under this
+                    # replica's MAC and spend the nonce; the proxy answers
+                    # with the keys under a nonce of their own
+                    self.incoming[nonce] = True
+                    self._count_keyset("unknown")
+                    self._send(sender, M.KeySetUnknown(
+                        digest, nonce, sigs.abd_keyset_unknown_signature(
+                            cfg.abd_mac_secret, digest, nonce)))
+                    return
+                else:
+                    keys, held = tuple(keys), "learned"
+                    if sigs.key_from_set(list(keys)) != digest:
+                        # keys that are not the named set's: refused like a
+                        # bad signature, nothing burnt, built or evicted
+                        self._debug("carried keys do not hash to the digest")
+                        self._count_keyset("refused")
+                        return
+                self._count_keyset(held)
                 if self.shard is not None:
                     bad = next(
                         (k for k in keys if self._shard_fenced(k)), None
@@ -964,7 +1006,8 @@ class BFTABDNode:
                 # inflated tags under an empty signature, replayed x2: the
                 # proxy drops these on MAC failure; even if the tags landed
                 # they could only force spurious cache re-fetches
-                fake = tuple(M.ABDTag(1 << 30, self.name) for _ in keys)
+                fake = tuple(M.ABDTag(1 << 30, self.name)
+                             for _ in range(msg.count or len(keys)))
                 for _ in range(2):
                     self._send(sender, M.TagBatchReply(fake, "forged", b"", nonce))
 

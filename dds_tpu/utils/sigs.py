@@ -183,6 +183,20 @@ def validate_abd_batch_delta_signature(
     )
 
 
+def abd_keyset_unknown_signature(secret: bytes, digest: str, nonce: int) -> bytes:
+    """Replica signature over a `KeySetUnknown` answer to a named
+    ReadTagBatch: "I hold no key set under `digest`". Domain-separated
+    from every reply that is a vote."""
+    return _mac(secret, f"keyset-unknown|{digest}|{nonce}".encode())
+
+
+def validate_abd_keyset_unknown_signature(
+    secret: bytes, digest: str, nonce: int, given: bytes
+) -> bool:
+    return hmac.compare_digest(
+        abd_keyset_unknown_signature(secret, digest, nonce), given)
+
+
 def value_digest(value) -> str:
     """sha256 hex of a stored set's canonical form — the per-entry content
     commitment behind verified state transfer and Merkle anti-entropy. A

@@ -36,6 +36,16 @@ class _Shard:
         return key not in self.disowned
 
 
+def vector_of(node, keys):
+    """The vector a replica keeps for a key set: found by the keys' digest."""
+    return node._tag_vectors[sigs.key_from_set(list(keys))]
+
+
+def kept_sets(node) -> list:
+    """The key sets a replica keeps a vector for, oldest first."""
+    return [v.keys for v in node._tag_vectors.values()]
+
+
 class Rig:
     """One replica and a proxy's address that collects what it answers."""
 
@@ -71,7 +81,8 @@ class Rig:
         self.inbox.clear()
         await self.node.handle(
             "proxy", M.ReadTagBatch(tuple(keys), nonce, signature, fingerprint,
-                                    base=base))
+                                    base=base, digest=digest,
+                                    count=len(keys)))
         await self.net.quiesce()
         return nonce, digest, list(self.inbox)
 
@@ -258,7 +269,8 @@ def test_a_round_after_m_stores_formats_m_tags_and_hashes_no_keys(
             before = _counts()
             spans_before = len(tracer.events("replica.tag_vector"))
             await rig.node.handle(
-                "proxy", M.ReadTagBatch(keys, nonce, sig, None))
+                "proxy", M.ReadTagBatch(keys, nonce, sig, None,
+                                        digest=digest, count=len(keys)))
             monkeypatch.undo()
             spans = tracer.events("replica.tag_vector")[spans_before:]
             return _delta(before), spans
@@ -348,15 +360,15 @@ def test_key_sets_and_the_store_log_stay_bounded(monkeypatch):
         for ks in sets:
             await rig.ask_and_check(ks)
         assert len(node._tag_vectors) == replica_mod.MAX_TAG_VECTORS
-        assert sets[0] not in node._tag_vectors
-        assert sets[-1] in node._tag_vectors
+        assert sets[0] not in kept_sets(node)
+        assert sets[-1] in kept_sets(node)
         hot = sets[-1]
         for round_ in range(6):               # only `hot` is asked about
             for _ in range(3):
                 rig.store(hot[round_ % 3])
             await rig.ask_and_check(hot)
             assert len(node._stored_since) <= 10 + 3
-        assert list(node._tag_vectors) == [hot]
+        assert kept_sets(node) == [hot]
         assert node._stored_since == []
         for ks in sets:                       # the dropped ones build anew
             await rig.ask_and_check(ks)
@@ -380,7 +392,7 @@ def test_unauthenticated_rounds_on_a_kept_key_set_leave_it_alone():
             rig.store(k)
         await rig.ask_and_check(keys)
         tag = rig.store("a")
-        vec = node._tag_vectors[keys]
+        vec = vector_of(node, keys)
         state = (vec.seen, vec.tags[:], vec.fields[:], vec.blob,
                  vec.fingerprint, node._stored_since[:], dict(node.incoming))
         before = _counts()
@@ -389,7 +401,7 @@ def test_unauthenticated_rounds_on_a_kept_key_set_leave_it_alone():
             assert got == []
             _, _, got = await rig.ask((f"bogus-{i}",) * 3, signature=b"bogus")
             assert got == []
-        assert list(node._tag_vectors) == [keys]
+        assert kept_sets(node) == [keys]
         assert (vec.seen, vec.tags, vec.fields, vec.blob, vec.fingerprint,
                 node._stored_since, node.incoming) == state
         assert _delta(before) == {}
@@ -407,11 +419,12 @@ def test_replayed_nonce_is_refused_before_the_vector_is_touched():
         rig.store("a")
         nonce, digest, _ = await rig.ask(keys)
         rig.store("a")
-        vec = rig.node._tag_vectors[keys]
+        vec = vector_of(rig.node, keys)
         seen = vec.seen
         rig.inbox.clear()
         sig = sigs.proxy_signature(rig.node.cfg.proxy_mac_secret, digest, nonce)
-        await rig.node.handle("proxy", M.ReadTagBatch(keys, nonce, sig, None))
+        await rig.node.handle("proxy", M.ReadTagBatch(
+            keys, nonce, sig, None, digest=digest, count=len(keys)))
         await rig.net.quiesce()
         assert not any(isinstance(m, M.TagBatchReply) for m in rig.inbox)
         assert vec.seen == seen
@@ -501,7 +514,7 @@ def test_a_trimmed_history_forgets_old_bases_and_keeps_new_ones(monkeypatch):
         for k in keys:
             rig.store(k)
         old = await rig.ask_and_check(keys)
-        vec = rig.node._tag_vectors[keys]
+        vec = vector_of(rig.node, keys)
         recent = None
         for step in range(30):
             rig.store(keys[step % 16])
@@ -544,7 +557,8 @@ def test_a_delta_round_ships_and_formats_what_moved_not_k(monkeypatch):
             monkeypatch.setattr(sigs, name, wrapped)
         rig.inbox.clear()
         await rig.node.handle("proxy", M.ReadTagBatch(
-            keys, nonce, sig, b"\x01" * 32, base=first.fingerprint))
+            keys, nonce, sig, b"\x01" * 32, base=first.fingerprint,
+            digest=digest, count=len(keys)))
         monkeypatch.undo()
         await rig.net.quiesce()
         (reply,) = rig.inbox
@@ -579,7 +593,7 @@ def test_acknowledged_writes_are_in_the_next_quorum_round(n_keys):
         await c.net.quiesce()
         for name in c.active:
             node = c.replicas[name]
-            vec = node._tag_vectors[tuple(keys)]
+            vec = vector_of(node, tuple(keys))
             assert vec.fingerprint == sigs.tags_fingerprint(
                 tuple(node.repository[k][0] for k in keys))
 

@@ -15,6 +15,7 @@ import json
 from dds_tpu.core import messages as M
 from dds_tpu.core.errors import ByzantineError
 from dds_tpu.core.quorum_client import AbdClient, AbdClientConfig
+from dds_tpu.utils import sigs
 
 from tests.test_core import Cluster, run
 from tests.test_rest import PROVIDER, call, rest_stack
@@ -299,7 +300,7 @@ def test_unauthenticated_tag_batch_cannot_evict_memo_cache():
         target = c.replicas["replica-0"]
         before = dict(target._tag_vectors)
         assert before  # the legit vector is resident
-        vec = before[("k",)]
+        vec = before[sigs.key_from_set(["k"])]
         state = (vec.seen, vec.tags[:], vec.fingerprint,
                  target._stored_since[:])
         assert state[3] == ["k"]

@@ -112,9 +112,14 @@ class Bench:
         await self.c.net.quiesce()
         want = [max(col) for col in zip(*(self.held(a) for a in voters))]
         assert list(got) == want
+        # the reference over the same voters and no others: a replica that
+        # was reseeded answers the first proxy to name the set to it one
+        # exchange later (`KeySetUnknown`, then the keys), so which replies
+        # come first is no longer the same for two proxies in turn
+        self.down = set(self.c.active) - set(voters)
         ref = await self.reference.read_tags(self.keys, digest=self.digest)
         await self.c.net.quiesce()
-        assert self.reference.voters == voters
+        assert sorted(self.reference.voters) == sorted(voters)
         assert list(got) == ref
         assert (got is cached) == (want == cached)
         self.down = set()

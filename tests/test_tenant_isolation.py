@@ -328,6 +328,9 @@ def test_shred_chaos_drill_other_tenants_linearizable_zero_verdicts(tmp_path):
     from dds_tpu.obs.watchtower import watchtower
     from dds_tpu.run import launch
 
+    # the auditor is the process's: what an earlier test's deployment left
+    # in it (the same keys under other tags) is not this run's
+    watchtower.reset()
     flight_dir = str(tmp_path / "drill")
     kr = TenantKeyring(paillier_bits=512, rsa_bits=512, grace=300.0)
     plains = {"alice": [3, 14, 15], "bob": [92, 65], "victim": [35, 89, 79]}
