@@ -250,13 +250,29 @@ class BFTABDNode:
     def _send(self, dest: str, msg) -> None:
         self.net.send(self.addr, dest, msg)
 
-    def _suspect(self, endpoint: str) -> None:
-        tracer.event("replica.suspect", by=self.name, suspect=endpoint)
+    def _suspect(self, endpoint: str, **why) -> None:
+        tracer.event("replica.suspect", by=self.name, suspect=endpoint, **why)
         metrics.inc(
             "dds_suspect_votes_total", suspect=endpoint.rsplit("/", 1)[-1],
             help="Suspect votes raised toward the supervisor",
         )
         self._send(self.supervisor, M.Suspect(endpoint, sigs.generate_nonce()))
+
+    def _reject(self, sender: str, msg, reason: str, text: str,
+                suspect: bool = True) -> None:
+        """This replica, healthy, refuses `msg`: one count a message, by
+        `reason` (bad_mac | unknown_nonce | repeated_nonce | wrong_phase).
+        A refusal that is evidence against an authenticated peer also
+        votes `sender` suspect, and the vote's event names the message
+        class and the reason; one that could come from anybody (a bad
+        proxy MAC) is counted and nothing more."""
+        self._debug(text)
+        metrics.inc(
+            "dds_replica_rejected_total", reason=reason,
+            help="messages a healthy replica refused, by reason",
+        )
+        if suspect:
+            self._suspect(sender, msg=type(msg).__name__, reason=reason)
 
     def _debug(self, text: str) -> None:
         if self.cfg.debug:
@@ -437,7 +453,9 @@ class BFTABDNode:
         match msg:
             case M.Envelope(call, nonce, signature):
                 if nonce in self.outgoing:
-                    self._debug("invalid nonce from proxy - repeated")
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce from proxy - repeated",
+                                 suspect=False)
                     return
                 req = _Outgoing(sender, call, nonce)
                 match call:
@@ -445,7 +463,9 @@ class BFTABDNode:
                         if not sigs.validate_proxy_signature(
                             cfg.proxy_mac_secret, key, nonce, signature
                         ):
-                            self._debug("invalid proxy signature")
+                            self._reject(sender, msg, "bad_mac",
+                                         "invalid proxy signature",
+                                         suspect=False)
                         elif self._shard_fenced(key):
                             # fence AFTER authentication (an unauthenticated
                             # probe must not learn the keyspace layout) and
@@ -461,7 +481,9 @@ class BFTABDNode:
                         if not sigs.validate_proxy_signature(
                             cfg.proxy_mac_secret, key, nonce, signature, value
                         ):
-                            self._debug("invalid proxy signature")
+                            self._reject(sender, msg, "bad_mac",
+                                         "invalid proxy signature",
+                                         suspect=False)
                         elif self._shard_fenced(key):
                             req.expired = True
                             self._reply_wrong_shard(
@@ -477,8 +499,8 @@ class BFTABDNode:
 
             case M.ReadTag(key, nonce):
                 if nonce in self.incoming:
-                    self._debug("invalid nonce - repeated")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated")
                     return
                 self.incoming[nonce] = False
                 tag, contents = self._state(key)
@@ -503,11 +525,13 @@ class BFTABDNode:
                 if not sigs.validate_proxy_signature(
                     cfg.proxy_mac_secret, digest, nonce, psig
                 ):
-                    self._debug("invalid proxy signature (tag batch)")
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid proxy signature (tag batch)",
+                                 suspect=False)
                     return
                 if nonce in self.incoming:
-                    self._debug("invalid nonce - repeated (tag batch)")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated (tag batch)")
                     return
                 if kept is not None:
                     # whatever keys the request carried, the digest's own
@@ -601,13 +625,13 @@ class BFTABDNode:
                 if not sigs.validate_abd_signature(
                     cfg.abd_mac_secret, value, tag, nonce, signature
                 ):
-                    self._debug("invalid ABD signature")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid ABD signature")
                     return
                 req = self.outgoing.get(nonce)
                 if req is None:
-                    self._debug("invalid nonce - unknown")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
                     return
                 if req.expired:
                     self._debug("invalid nonce - expired (late quorum reply)")
@@ -616,8 +640,8 @@ class BFTABDNode:
                     # a reply type must match its request's phase: a forged
                     # TagReply against a read/tag-read nonce would otherwise
                     # pollute that quorum accumulator
-                    self._debug("TagReply for a non-write request")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "wrong_phase",
+                                 "TagReply for a non-write request")
                     return
                 req.read_quorum[sender] = (tag, value, signature)
                 if len(req.read_quorum) >= cfg.quorum_size:
@@ -635,12 +659,12 @@ class BFTABDNode:
                 if not sigs.validate_abd_signature(
                     cfg.abd_mac_secret, value, tag, nonce, signature
                 ):
-                    self._debug("invalid ABD signature")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid ABD signature")
                     return
                 if nonce not in self.incoming:
-                    self._debug("invalid nonce - unknown")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
                     return
                 if self.incoming[nonce]:
                     self._debug("invalid nonce - expired at Write (late quorum reply)")
@@ -669,15 +693,15 @@ class BFTABDNode:
             case M.WriteAck(key, nonce):
                 req = self.outgoing.get(nonce)
                 if req is None:
-                    self._debug("invalid nonce - unknown")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
                     return
                 if req.expired:
                     self._debug("invalid nonce - expired at WriteAck (late reply)")
                     return
                 if not isinstance(req.call, (M.IRead, M.IWrite)):
-                    self._debug("WriteAck for a request with no write phase")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "wrong_phase",
+                                 "WriteAck for a request with no write phase")
                     return
                 req.write_quorum.add(sender)
                 if self._quorum_met(req.write_quorum):
@@ -724,8 +748,8 @@ class BFTABDNode:
 
             case M.Read(key, nonce):
                 if nonce in self.incoming:
-                    self._debug("invalid nonce - repeated")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated")
                     return
                 self.incoming[nonce] = False
                 tag, contents = self._state(key)
@@ -736,20 +760,20 @@ class BFTABDNode:
                 if not sigs.validate_abd_signature(
                     cfg.abd_mac_secret, value, tag, nonce, signature
                 ):
-                    self._debug("invalid ABD signature")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid ABD signature")
                     return
                 req = self.outgoing.get(nonce)
                 if req is None:
-                    self._debug("invalid nonce - unknown")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
                     return
                 if req.expired:
                     self._debug("invalid nonce - expired at ReadReply (late reply)")
                     return
                 if not isinstance(req.call, M.IRead):
-                    self._debug("ReadReply for a non-read request")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "wrong_phase",
+                                 "ReadReply for a non-read request")
                     return
                 req.read_quorum[sender] = (tag, value, signature)
                 if self._quorum_met(req.read_quorum):
@@ -795,11 +819,13 @@ class BFTABDNode:
                     cfg.abd_mac_secret, "lease-request",
                     {"region": region, "ttl": ttl}, nonce, signature,
                 ):
-                    self._debug("invalid lease-request signature")
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid lease-request signature",
+                                 suspect=False)
                     return
                 if nonce in self.incoming:
-                    self._debug("invalid nonce - repeated (lease request)")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated (lease request)")
                     return
                 self.incoming[nonce] = True
                 ok = self.lease_table is not None
@@ -823,11 +849,13 @@ class BFTABDNode:
                     cfg.abd_mac_secret, "lease-revoke",
                     {"region": region}, nonce, signature,
                 ):
-                    self._debug("invalid lease-revoke signature")
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid lease-revoke signature",
+                                 suspect=False)
                     return
                 if nonce in self.incoming:
-                    self._debug("invalid nonce - repeated (lease revoke)")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated (lease revoke)")
                     return
                 self.incoming[nonce] = True
                 if self.lease_table is not None:
@@ -840,11 +868,13 @@ class BFTABDNode:
                     cfg.proxy_mac_secret, key, nonce, signature,
                     ["local-read", region],
                 ):
-                    self._debug("invalid proxy signature (local read)")
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid proxy signature (local read)",
+                                 suspect=False)
                     return
                 if nonce in self.incoming:
-                    self._debug("invalid nonce - repeated (local read)")
-                    self._suspect(sender)
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated (local read)")
                     return
                 self.incoming[nonce] = True
                 served = (

@@ -71,6 +71,8 @@ class SupervisorConfig:
 
 
 class BFTSupervisor:
+    MAX_VOTE_NONCES = 4096   # `Suspect` nonces remembered, oldest out
+
     def __init__(
         self,
         addr: str,
@@ -86,7 +88,12 @@ class BFTSupervisor:
         self.cfg = config or SupervisorConfig()
         self.active: list[tuple[str, int]] = [(a, time.monotonic_ns()) for a in active]
         self.sentinent: list[str] = list(sentinent)
-        self.nonces: set[int] = set()
+        # the vote nonces seen lately, oldest first (a dict for its order):
+        # a duplicated or replayed `Suspect` is refused while its nonce is
+        # among the last MAX_VOTE_NONCES. Not every nonce ever seen: a
+        # replica that lies and has no spare to give way to raises votes
+        # for as long as it is there, four a write
+        self.nonces: dict[int, None] = {}
         self.quorum: dict[str, set[str]] = {}
         self.redeploy = redeploy
         self._rng = rng or random.Random()
@@ -170,6 +177,13 @@ class BFTSupervisor:
     # ------------------------------------------------------------- messages
 
     async def handle(self, sender: str, msg) -> None:
+        # one span a message, as `replica.handle`: what the supervisor's
+        # mail (a `Suspect` per message an honest replica refused) costs
+        # the loop it shares
+        with tracer.span("supervisor.handle", msg=type(msg).__name__):
+            await self._handle(sender, msg)
+
+    async def _handle(self, sender: str, msg) -> None:
         match msg:
             case M.RequestReplicas():
                 # freshest half of the active list, minimum one
@@ -182,7 +196,9 @@ class BFTSupervisor:
             case M.Suspect(replica, nonce):
                 if nonce in self.nonces:
                     return
-                self.nonces.add(nonce)
+                self.nonces[nonce] = None
+                if len(self.nonces) > self.MAX_VOTE_NONCES:
+                    del self.nonces[next(iter(self.nonces))]
                 voters = self.quorum.setdefault(replica, set())
                 voters.add(sender)
                 if len(voters) >= self.cfg.quorum_size:

@@ -35,7 +35,7 @@ from dds_tpu.core.supervisor import BFTSupervisor, SupervisorConfig
 from dds_tpu.core.transport import InMemoryNet, TcpNet
 from dds_tpu.http.server import DDSRestServer, ProxyConfig
 from dds_tpu.obs.slo import SloEngine
-from dds_tpu.malicious.trudy import Trudy
+from dds_tpu.malicious.trudy import AttackType, Trudy, parse_attack
 from dds_tpu.models.facade import HomoProvider
 from dds_tpu.utils.config import DDSConfig
 
@@ -58,6 +58,9 @@ class Deployment:
     # ShardGroup list lives on constellation.groups; `replicas` above is
     # the merged view (snapshots / anti-entropy / health reuse it as-is)
     constellation: object = None
+    # the victims of the attack `launch` fired itself (`attacks.at_launch`);
+    # None when it fired none, and `run_workload` then fires its own
+    launch_victims: list | None = None
 
     async def stop(self) -> None:
         if self.constellation is not None:
@@ -98,6 +101,64 @@ def _log_backend(server: DDSRestServer) -> None:
 
 async def launch(cfg: DDSConfig | None = None) -> Deployment:
     cfg = cfg or DDSConfig()
+    if cfg.attacks.at_launch and not cfg.attacks.enabled:
+        raise ValueError(
+            "attacks.at_launch is set but attacks.enabled is not: the "
+            "replicas would ignore the attack this launch is asked to fire"
+        )
+    dep = await _launch(cfg)
+    if cfg.attacks.at_launch:
+        try:
+            await _attack_at_launch(dep)
+        except BaseException:
+            await dep.stop()
+            raise
+    return dep
+
+
+async def _attack_at_launch(dep: Deployment) -> None:
+    """`attacks.at_launch`: what the upstream's `Main.scala:187-193` does,
+    once, with the deployment serving and nobody else there to fire:
+    `attacks.type` at up to `byz_max_faults` replicas drawn by
+    random.Random(attacks.chaos_seed), so the same seed over the same
+    endpoints names the same victims. Returns once the local victims have
+    taken the attack, so the first request already meets it."""
+    cfg = dep.cfg
+    if dep.trudy is None:
+        raise ValueError(
+            "attacks.at_launch is set but this process hosts no replica "
+            "group to attack (fabric role)"
+        )
+    attack = parse_attack(cfg.attacks.type)
+    dep.trudy._rng = random.Random(cfg.attacks.chaos_seed)
+    victims = dep.trudy.trigger(attack)
+    dep.launch_victims = victims
+    log.warning(
+        "attack at launch: %s at %s (attacks.chaos_seed %d)",
+        attack.value, [v.rsplit("/", 1)[-1] for v in victims],
+        cfg.attacks.chaos_seed,
+    )
+    # `Crash` and `Compromise` travel as messages: a victim in this
+    # process has taken one when it is off the transport, or byzantine
+    if attack is AttackType.CRASH:
+        def taken(node):
+            return not dep.net.has_endpoint(node.addr)
+    elif attack is AttackType.BYZANTINE:
+        def taken(node):
+            return node.behavior == "byzantine"
+    else:
+        return
+    local = [dep.replicas[v] for v in victims if v in dep.replicas]
+    for _ in range(200):
+        if all(taken(node) for node in local):
+            return
+        await asyncio.sleep(0.005)
+    raise RuntimeError(
+        f"attack at launch: {attack.value} never reached {victims}"
+    )
+
+
+async def _launch(cfg: DDSConfig) -> Deployment:
     stoppables = []
 
     # Atlas [retry]: the per-region deadline/backoff overrides for THIS
@@ -940,9 +1001,12 @@ async def run_workload(dep: Deployment, provider: HomoProvider | None = None,
     if dep.trudy is not None:
         dep.trudy._rng = rng  # make --seed reproduce attack victim selection
     dt = cfg.client.data_table
-    if cfg.attacks.enabled and dep.trudy is not None:
+    if (cfg.attacks.enabled and dep.trudy is not None
+            and dep.launch_victims is None):
         # fire mid-run like the reference (Main.scala:187-193): the workload
-        # below must complete correct quorums against a damaged cluster
+        # below must complete correct quorums against a damaged cluster.
+        # Not a second time where `launch` fired already (at_launch): two
+        # draws could name more than f victims
         asyncio.get_event_loop().call_later(
             0.1, lambda: dep.trudy.trigger(cfg.attacks.type)
         )

@@ -724,6 +724,13 @@ class AttackConfig:
     # fault schedules; the seed reproduces the exact fault trace
     chaos_enabled: bool = False
     chaos_seed: int = 0
+    # `run.launch` fires `type` once itself, after the deployment serves
+    # and before it returns (the upstream's `Main.scala:187-193`), at up
+    # to `replicas.byz-max-faults` victims drawn by
+    # random.Random(chaos_seed): a deployment that runs compromised from
+    # its first request, with nobody else there to fire. Needs `enabled`
+    # (launch refuses it without); hand-fired harnesses leave it off.
+    at_launch: bool = False
 
 
 @dataclass

@@ -788,8 +788,8 @@ def test_the_carried_share_reduces_recorded_counters_with_counter_share():
     assert spec["layer"] == _layer("quorum.tag_full_vote_share")["layer"]
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == "quorum.tag_keys_carried_share"
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "quorum.tag_keys_carried_share")
     assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
     assert {k: entry[k] for k in ("unit", "better", "moves", "layer")} == {
         k: spec[k] for k in ("unit", "better", "moves", "layer")}

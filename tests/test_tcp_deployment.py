@@ -62,9 +62,10 @@ def _deployment_cfg(kind: str):
     return cfg
 
 
-async def _script(kind: str) -> tuple[dict, dict]:
+async def _script(kind: str, cfg=None) -> tuple[dict, dict]:
     """One seeded sequence of every REST operation kind through a
-    deployment; returns (what each step answered, the model it leaves)."""
+    deployment (`cfg`, or the plain one of that transport); returns (what
+    each step answered, the model it leaves)."""
     from dds_tpu.core.transport import InMemoryNet, TcpNet
     from dds_tpu.http.miniserver import http_request
     from dds_tpu.run import launch
@@ -73,7 +74,7 @@ async def _script(kind: str) -> tuple[dict, dict]:
     rows = [[str(i), "x", str(rng.randrange(2, SUM_MOD)),
              str(rng.randrange(2, MULT_MOD)), "y", "z", "w", None]
             for i in range(ROWS)]
-    cfg = _deployment_cfg(kind)
+    cfg = cfg or _deployment_cfg(kind)
     dep = await launch(cfg)
     host, port = cfg.proxy.host, dep.server.cfg.port
     assert type(dep.net) is (TcpNet if kind == "tcp" else InMemoryNet)
