@@ -262,6 +262,7 @@ class TcpNet(Transport):
                     help="TcpNet frames refused, by reason")
 
     async def _serve(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+        asyncio.current_task().set_name("tcp.serve")
         try:
             while True:
                 hdr = await reader.readexactly(4)
@@ -281,15 +282,15 @@ class TcpNet(Transport):
                 got = self._open_frame(frame)
                 if got is None:
                     continue
-                handler, tc, src, msg = got
+                handler, tc, src, name, msg = got
                 if tc is not None:
                     supervised_task(
                         self._handle_traced(handler, tc, src, msg),
-                        name=f"tcp.handle:{src}",
+                        name=f"tcp.handle:{name}",
                     )
                 else:
                     supervised_task(handler(src, msg),
-                                    name=f"tcp.handle:{src}")
+                                    name=f"tcp.handle:{name}")
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
         finally:
@@ -297,8 +298,9 @@ class TcpNet(Transport):
 
     def _open_frame(self, frame: bytes):
         """Decode and authenticate one inbound frame: (handler, the
-        sender's trace context, src, message), or None for a frame that is
-        dropped (counted by reason) or addressed to no endpoint here."""
+        sender's trace context, src, the endpoint's name here, message), or
+        None for a frame that is dropped (counted by reason) or addressed
+        to no endpoint here."""
         t0 = time.perf_counter()
         try:
             obj = json.loads(frame)
@@ -354,7 +356,7 @@ class TcpNet(Transport):
         tc = obs_context.from_wire(obj.get("tc"))
         self._note_frame("net.deserialize", "received", t0, tc, len(frame),
                          type(msg).__name__, dest)
-        return handler, tc, src, msg
+        return handler, tc, src, name, msg
 
     @staticmethod
     async def _handle_traced(handler, tc, src: str, msg) -> None:
@@ -406,13 +408,8 @@ class TcpNet(Transport):
             # MAC/signature
             self._note_frame("net.serialize", "sent", t_ser, cur, len(frame),
                              type(msg).__name__, dest)
-            t_drain = time.perf_counter()
             w.write(len(frame).to_bytes(4, "big") + frame)
             await w.drain()
-            metrics.observe(
-                "dds_net_drain_seconds", time.perf_counter() - t_drain,
-                help="TCP send-buffer drain wait (backpressure signal)",
-            )
         except OSError:
             log.warning("send failed %s -> %s", src, dest)
             self._conns.pop(conn_key, None)

@@ -88,6 +88,8 @@ class HttpServer:
                 pass
 
     async def _serve(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+        # the loop's ledger (obs/runtime) books this task's steps by its name
+        asyncio.current_task().set_name("http.conn")
         try:
             while True:
                 line = await reader.readline()

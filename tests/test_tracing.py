@@ -339,12 +339,414 @@ def test_a_stalled_loop_is_reported_once_with_its_stack(tmp_path, caplog):
     warned = [r.getMessage() for r in caplog.records
               if r.name == "dds.runtime"]
     assert len(warned) == 1 and "the_culprit" in warned[0]
+    assert "held_by=foreign task=Task-" in warned[0]    # the ledger's word
     (incident,) = tmp_path.glob("incident-*-loop_stall.jsonl")
     head = json.loads(incident.read_text().splitlines()[0])
     assert head["incident"] == "loop_stall"
     assert "the_culprit" in head["info"]["stack"]
     assert head["info"]["silent_s"] >= 1.0
     assert "counters" not in head
+
+
+# ------------------------------------------------------ the loop's ledger
+
+def _ledger_now() -> dict:
+    return {t: [metrics.value(n, tenant=t) or 0.0
+                for n in runtime.LEDGER_SERIES]
+            for t in runtime.TENANTS}
+
+
+def _ledgered(body):
+    """Run `body` under a sampler of its own: what it returned, what each
+    tenant gained (seconds, callbacks, ready-wait) from `start()` to the
+    end of `stop()`, and how long that was."""
+    async def go():
+        before = _ledger_now()
+        sampler = runtime.LoopSampler()
+        t0 = time.perf_counter()
+        sampler.start()
+        try:
+            got = await body()
+        finally:
+            await sampler.stop()
+        wall = time.perf_counter() - t0
+        after = _ledger_now()
+        return got, {t: [b - a for a, b in zip(before[t], after[t])]
+                     for t in runtime.TENANTS}, wall
+
+    return asyncio.run(go())
+
+
+def _spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.fixture
+def every_pass(monkeypatch):
+    """Book every pass of the loop: whose a callback is, told apart from
+    how many of them the ledger looks at."""
+    monkeypatch.setattr(runtime, "LEDGER_EVERY", 1)
+
+
+def test_the_tenants_seconds_sum_to_the_wall_time():
+    async def body():
+        async def worker():
+            for _ in range(25):
+                _spin(0.004)
+                await asyncio.sleep(0.006)
+
+        await asyncio.gather(worker(), worker(), asyncio.sleep(0.5))
+
+    _got, gained, wall = _ledgered(body)
+    total = sum(v[0] for v in gained.values())
+    assert wall >= 0.5 and total == pytest.approx(wall, rel=0.02)
+    assert gained["idle"][1] == 0              # idle runs no handle
+    assert gained["foreign"][0] >= 0.19        # 2 x 25 x 4 ms of spinning
+    assert all(v[2] >= 0 for v in gained.values())
+
+
+def test_a_loop_that_only_sleeps_is_idle():
+    async def body():
+        await asyncio.sleep(0.5)
+
+    _got, gained, wall = _ledgered(body)
+    assert gained["idle"][0] >= 0.90 * wall
+    assert gained["background"][1] > 0         # the sampler's own ticks
+
+
+@pytest.mark.parametrize("name, tenant", [
+    ("http.conn", "request"),
+    ("tcp.send:127.0.0.1:4000/replica-1", "transport"),
+    ("tcp.serve", "transport"),
+    ("inmem.deliver:replica-2", "replica"),
+    ("tcp.handle:s0-replica-3", "replica"),
+    ("inmem.deliver:proxy-0", "proxy_inbox"),
+    ("tcp.handle:supervisor", "supervisor"),
+    ("inmem.deliver:nodehost", "background"),
+    ("antientropy.loop", "foreign"),           # by its code: the test's
+    (None, "foreign"),
+])
+def test_a_task_that_spins_lands_under_its_tenant(every_pass, name, tenant):
+    async def body():
+        async def spinner():
+            _spin(0.05)
+
+        task = asyncio.ensure_future(spinner())
+        if name:
+            task.set_name(name)
+        await task
+
+    _got, gained, _wall = _ledgered(body)
+    assert gained[tenant][0] >= 0.05 and gained[tenant][1] >= 1
+    others = sum(v[0] for t, v in gained.items()
+                 if t not in (tenant, "idle"))
+    assert others < gained[tenant][0]
+
+
+def test_while_the_loop_has_headroom_every_pass_is_booked_and_exact():
+    async def body():
+        async def worker():
+            for _ in range(20):
+                _spin(0.003)
+                await asyncio.sleep(0.005)
+
+        task = asyncio.ensure_future(worker())
+        task.set_name("inmem.deliver:replica-1")
+        await task
+
+    _got, gained, wall = _ledgered(body)
+    assert runtime.LEDGER_EVERY > 1
+    seconds, callbacks, _wait = gained["replica"]
+    # 20 steps of 3 ms between waits: nothing is sampled, nothing scaled
+    assert 0.060 <= seconds <= 0.075
+    assert callbacks == 21           # the first step and twenty wake-ups
+    assert sum(v[0] for v in gained.values()) == pytest.approx(wall, rel=0.02)
+
+
+def test_the_passes_not_booked_are_split_as_the_booked_ones_and_scaled():
+    async def body():
+        async def hopper():
+            for _ in range(1500):
+                _spin(0.0002)
+                await asyncio.sleep(0)
+
+        task = asyncio.ensure_future(hopper())
+        task.set_name("inmem.deliver:replica-0")
+        await task
+
+    _got, gained, wall = _ledgered(body)
+    assert runtime.LEDGER_EVERY > 1
+    # 0.3 s of spinning in 1,500 passes and never a wait, so the loop is
+    # under load and one pass in `LEDGER_EVERY` is booked: the tenant gets (nearly) all of it, the sum stays exact, and
+    # the callbacks and the ready-wait are scaled up to all the passes
+    assert gained["replica"][0] >= 0.27
+    assert sum(v[0] for v in gained.values()) == pytest.approx(wall, rel=0.02)
+    assert 1200 <= gained["replica"][1] <= 1800
+    assert gained["idle"][1] == 0
+
+
+@pytest.mark.parametrize("period", [2, 4])
+def test_a_loop_whose_passes_repeat_is_not_booked_at_one_phase(
+        monkeypatch, period):
+    """One step of 0.3 ms under `replica`, then `period` - 1 passes of a
+    plain callback of 0.1 ms each, 1,500 times over: with a fixed stride of
+    4 the booked passes fell on one phase and `replica` read 95 % where
+    booking every pass reads 72 (period 2) and 47 (period 4)."""
+    async def body():
+        loop = asyncio.get_running_loop()
+
+        async def chain():
+            for _ in range(1500):
+                _spin(0.0003)
+                fut, left = loop.create_future(), [period - 1]
+
+                def hop():
+                    _spin(0.0001)
+                    left[0] -= 1
+                    if left[0] <= 0:
+                        fut.set_result(None)
+                    else:
+                        loop.call_soon(hop)
+
+                loop.call_soon(hop)
+                await fut
+
+        task = asyncio.ensure_future(chain())
+        task.set_name("inmem.deliver:replica-0")
+        await task
+
+    def share():
+        _got, gained, _wall = _ledgered(body)
+        return gained["replica"][0] / sum(v[0] for v in gained.values())
+
+    thinned = share()
+    monkeypatch.setattr(runtime, "LEDGER_EVERY", 1)
+    # an estimate from an eighth of the passes: a few points of noise,
+    # where one phase of the period reads 23 to 48 points off
+    assert thinned == pytest.approx(share(), abs=0.12)
+
+
+def test_an_unnamed_task_of_the_package_is_background_and_of_http_a_request():
+    from dds_tpu.http.miniserver import http_request
+    from dds_tpu.utils.tasks import drain
+
+    async def body():
+        # both fail fast (nothing listens on port 1): what matters is
+        # whose coroutine the unnamed task runs
+        t1 = asyncio.ensure_future(http_request("127.0.0.1", 1, "GET", "/"))
+        t2 = asyncio.ensure_future(drain(0.01))
+        await asyncio.gather(t1, t2, return_exceptions=True)
+        return runtime._ledger._task_tenant(t1), runtime._ledger._task_tenant(t2)
+
+    (of_http, of_utils), _gained, _wall = _ledgered(body)
+    assert runtime.TENANTS[of_http] == "request"
+    assert runtime.TENANTS[of_utils] == "background"
+
+
+@pytest.mark.parametrize("transport", ["memory", "tcp"])
+def test_a_deployment_books_its_work_under_the_tables_tenants(
+        monkeypatch, every_pass, transport):
+    import re
+
+    from dds_tpu.http.miniserver import http_request
+    from dds_tpu.run import launch
+
+    monkeypatch.setenv("DDS_TPU_MIN_BATCH", "0")
+    nsqr = ((1 << 61) - 1) ** 2
+
+    async def go():
+        cfg = _small_cfg()
+        if transport == "tcp":
+            cfg.transport.kind, cfg.transport.port = "tcp", 0
+            cfg.security.transport_frame_secret = "ledger-test"
+        before = _ledger_now()
+        frames = metrics.value("dds_net_frames_total", direction="received",
+                               msg="Envelope") or 0
+        dep = await launch(cfg)
+        host, port = cfg.proxy.host, dep.server.cfg.port
+        try:
+            for i in range(6):
+                st, _b = await http_request(
+                    host, port, "POST", "/PutSet",
+                    json.dumps({"contents": [str(i), "x", str(9 + i)]}
+                               ).encode())
+                assert st == 200
+            st, _b = await http_request(
+                host, port, "GET", f"/SumAll?position=2&nsqr={nsqr}")
+            assert st == 200
+        finally:
+            await dep.stop()
+        after = _ledger_now()
+        got = (metrics.value("dds_net_frames_total", direction="received",
+                             msg="Envelope") or 0) - frames
+        return {t: [b - a for a, b in zip(before[t], after[t])]
+                for t in runtime.TENANTS}, got
+
+    gained, frames = asyncio.run(go())
+    busy = {"request", "replica", "proxy_inbox", "socket", "foreign", "loop",
+            "background"}
+    if transport == "tcp":
+        busy.add("transport")
+        assert frames > 0
+        # a frame is one `tcp.send` task and at least one step of
+        # `tcp.serve`: the transport's callbacks outnumber the frames
+        assert gained["transport"][1] > 2 * frames
+    else:
+        assert gained["transport"] == [0, 0, 0]
+    for t in busy:
+        assert gained[t][0] > 0 and gained[t][1] > 0, t
+    # every label value ever emitted is one of the table's
+    for series in runtime.LEDGER_SERIES:
+        values = set(re.findall(
+            series + r'\{tenant="([^"]*)"\}', metrics.render()))
+        assert values and values <= set(runtime.TENANTS)
+    # the per-frame drain histogram went with the ledger's coming
+    assert "drain_seconds" not in metrics.render()
+
+
+def test_stop_restores_the_loop_twice_over_and_another_threads_loop_is_not_counted():
+    from asyncio import events
+
+    run = events.Handle._run
+    other = {}
+
+    def elsewhere():
+        async def hops():
+            for _ in range(3000):
+                await asyncio.sleep(0)
+            _spin(0.03)
+
+        other["done"] = asyncio.run(hops()) is None
+
+    async def body():
+        selector = asyncio.get_running_loop()._selector
+        assert "select" in vars(selector) and events.Handle._run is not run
+        thread = threading.Thread(target=elsewhere)
+        thread.start()
+        while thread.is_alive():
+            await asyncio.sleep(0.02)
+        thread.join(timeout=5)
+        return selector
+
+    for _ in range(2):
+        selector, gained, wall = _ledgered(body)
+        assert events.Handle._run is run
+        assert "select" not in vars(selector)
+        assert runtime._ledger is None
+        assert other.pop("done") is True
+        # the other loop's 3,000 hops and 30 ms are nobody's here: this
+        # loop only polled the thread
+        assert sum(v[1] for v in gained.values()) < 1500
+        assert sum(v[0] for v in gained.values()) == pytest.approx(
+            wall, rel=0.05, abs=0.02)
+
+
+def test_a_second_sampler_on_the_loop_leaves_the_first_ones_ledger_alone(
+        every_pass):
+    from asyncio import events
+
+    run = events.Handle._run
+
+    async def body():
+        first = runtime._ledger
+        second = runtime.LoopSampler()
+        second.start()
+        try:
+            assert runtime._ledger is first and second._ledger is None
+        finally:
+            await second.stop()
+        assert runtime._ledger is first and events.Handle._run is not run
+        _spin(0.02)
+
+    _got, gained, _wall = _ledgered(body)
+    assert events.Handle._run is run and gained["foreign"][0] >= 0.02
+
+
+def _blocked_by_a_sleeper(times: int, under_load: bool):
+    """`runtime.loop_blocked` spans of `times` tasks named
+    `tcp.send:the-sleeper` that each hold the loop for 60 ms; `under_load`
+    a hopper keeps the loop from ever waiting meanwhile."""
+    async def body():
+        async def sleeper():
+            time.sleep(0.06)
+
+        async def hopper():
+            while under_load:
+                await asyncio.sleep(0)
+
+        load = asyncio.ensure_future(hopper())
+        spans = []
+        for _ in range(times):
+            await asyncio.sleep(0.05)    # let the sampler tick
+            n = len(tracer.events("runtime.loop_blocked"))
+            t0 = time.perf_counter()
+            task = asyncio.ensure_future(sleeper())
+            task.set_name("tcp.send:the-sleeper")
+            await task
+            await asyncio.sleep(0.05)
+            spans += [s for s in tracer.events("runtime.loop_blocked")[n:]
+                      if s.t_end - s.dur_ms / 1e3 <= t0 + 0.06]
+        load.cancel()
+        return spans
+
+    return _ledgered(body)[0]
+
+
+def test_a_loop_blocked_span_names_its_holder():
+    (s,) = _blocked_by_a_sleeper(1, under_load=False)
+    assert s.meta["held_by"] == "transport"
+    assert s.meta["task"] == "tcp.send:the-sleeper"
+    assert 59.0 <= s.meta["held_ms"] <= s.dur_ms + runtime.TICK * 1e3 + 1.0
+
+
+def test_under_load_a_late_pass_not_booked_names_nobody_never_another():
+    spans = _blocked_by_a_sleeper(8, under_load=True)
+    assert len(spans) == 8 and runtime.LEDGER_EVERY > 1
+    named = [s for s in spans if "held_by" in s.meta]
+    assert len(named) < 8    # the sleeper's pass was not always a booked one
+    for s in named:
+        assert s.meta["task"] == "tcp.send:the-sleeper"
+        assert s.meta["held_ms"] >= 59.0
+
+
+def test_with_the_tracer_off_no_ledger_is_installed(monkeypatch):
+    from asyncio import events
+
+    run = events.Handle._run
+    monkeypatch.setattr(tracer, "enabled", False)
+
+    async def body():
+        assert events.Handle._run is run and runtime._ledger is None
+        assert "select" not in vars(asyncio.get_running_loop()._selector)
+        _spin(0.02)
+        await asyncio.sleep(0.05)
+
+    _got, gained, _wall = _ledgered(body)
+    assert all(v == [0, 0, 0] for v in gained.values())
+
+
+def test_a_loop_without_the_private_names_gets_no_ledger(monkeypatch):
+    from asyncio import events
+
+    run = events.Handle._run
+
+    async def body():
+        ledger = runtime._Ledger(asyncio.get_running_loop())
+        monkeypatch.delattr(events.Handle, "_run")
+        try:
+            assert ledger.install() is False
+        finally:
+            monkeypatch.undo()
+
+        class Selectorless:
+            pass
+
+        assert runtime._Ledger(Selectorless()).install() is False
+        assert events.Handle._run is run
+
+    asyncio.run(body())
 
 
 # ------------------------------------------------ what a span costs a trace

@@ -17,7 +17,15 @@ file and prints, for the spans that ended inside the window:
 - spans per completed `SumAll` (spans of the traces rooted in
   `http.GET.SumAll`, over those roots);
 - the limb count and the product (`schoolbook`, `karatsuba1`, `cios`) the
-  `kernel.fold` spans name, with their count.
+  `kernel.fold` spans name, with their count;
+- the window's ledger of the event loop (`obs/runtime`: counters
+  `dds_event_loop_*_total{tenant}`, read by `run` as the window opens and
+  at its end): seconds, callbacks and mean ready-wait by tenant, and
+  seconds and callbacks a frame (TCP: `net.deserialize` spans) or a
+  message handled (memory: `replica.handle` + `supervisor.handle` spans).
+  A tenant's share is given of the window and, as the benchmark's
+  `loop.*_share` metrics give it, of the loop's busy seconds. Left out for
+  a program that keeps no ledger.
 
 The benchmark's own per-layer metrics read the same spans through
 `yardstick/reducers`; this is the builder's look at what they leave out.
@@ -34,15 +42,45 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONTAINERS = ("proxy.fetch_stored", "proxy.fold")
 BLOCKED = "runtime.loop_blocked"
+# the tenants that carry a message from a sender's `send` to a handler's
+# first line, and the handlers themselves
+CARRIERS = ("transport", "socket")
+HANDLERS = ("replica", "proxy_inbox", "supervisor")
+
+
+def read_ledger() -> dict | None:
+    """The loop's ledger now: tenant -> [seconds, callbacks, ready-wait],
+    with the instant it was read; None where the program keeps none."""
+    from dds_tpu.obs import runtime
+    from dds_tpu.obs.metrics import metrics
+
+    series = getattr(runtime, "LEDGER_SERIES", ())
+    got = {t: [metrics.value(n, tenant=t) or 0.0 for n in series]
+           for t in getattr(runtime, "TENANTS", ())}
+    if not any(v[0] for v in got.values()):
+        return None
+    return {"t": time.perf_counter(), "by_tenant": got}
 
 
 def run(out: str, argv: list[str]) -> int:
     sys.path.insert(0, ROOT)
+    import asyncio
+
     from yardstick import run as yr
+    from yardstick import traffic as yt
 
     kept: list = []
     marks: dict = {}
     measure = yr.Run.measure
+    drive = yt.Traffic.run
+
+    async def driven(self, seconds):
+        # the ledger as the window opens and at its end (`drive` returns
+        # later, when what was in flight has ended)
+        marks["ledger_before"] = read_ledger()
+        asyncio.get_running_loop().call_later(
+            seconds, lambda: marks.update(ledger_after=read_ledger()))
+        return await drive(self, seconds)
 
     def keep(rec):
         # a program older than `t_end` is placed by when it told us
@@ -59,6 +97,7 @@ def run(out: str, argv: list[str]) -> int:
             marks.update(t0=self.t0, t_end=self.t_end)
 
     yr.Run.measure = measured
+    yt.Traffic.run = driven
     code = yr.main(argv)
     os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
     with _open(out, "wt") as f:
@@ -154,6 +193,30 @@ def rest(path: str) -> dict:
             what = f"limbs={m.get('limbs')} product={m.get('product')}"
             folds[what] = folds.get(what, 0) + 1
     out["folds"] = folds
+    before, after = head.get("ledger_before"), head.get("ledger_after")
+    if before and after:
+        gained = {t: [b - a for a, b in zip(before["by_tenant"][t], v)]
+                  for t, v in after["by_tenant"].items()}
+        frames = len(by_name.get("net.deserialize", ()))
+        handled = (len(by_name.get("replica.handle", ()))
+                   + len(by_name.get("supervisor.handle", ())))
+
+        def per(what, tenants, n, unit):
+            return [what, unit] + [
+                sum(gained[t][i] for t in tenants if t in gained) / n
+                for i in (0, 1)]
+
+        out["ledger"] = {
+            "stretch_s": after["t"] - before["t"], "by_tenant": gained,
+            "frames": frames, "handled": handled,
+            # [what, a unit of what, seconds a unit, callbacks a unit]
+            "per_unit": [per(*row) for row in (
+                ("transport + socket", CARRIERS, frames, "frame"),
+                ("replica + proxy_inbox + supervisor", HANDLERS, handled,
+                 "message handled"),
+                ("every running tenant",
+                 [t for t in gained if t != "idle"], frames or handled,
+                 "frame" if frames else "message handled")) if row[2]]}
     roots = [s for s in spans if s["name"] == "http.GET.SumAll"]
     ids = {s["trace_id"] for s in roots}
     out["sumalls"] = len(roots)
@@ -180,6 +243,24 @@ def main(argv: list[str]) -> int:
                   f"{100 * c['left_share']:.2f} %")
         for what, n in res["folds"].items():
             print(f"kernel.fold: {what} x {n}")
+        led = res.get("ledger")
+        if led:
+            total = sum(v[0] for v in led["by_tenant"].values())
+            print(f"who holds the loop (ledger over {led['stretch_s']:.3f} s"
+                  f", tenants sum to {total:.3f}):")
+            busy = (total - led["by_tenant"]["idle"][0]) or 1.0
+            for t, (sec, n, wait) in led["by_tenant"].items():
+                print(f"  {t:12s} {sec:8.3f} s {100 * sec / total:6.2f} % "
+                      + (f"{100 * sec / busy:6.2f} % of busy "
+                         if t != "idle" else " " * 17)
+                      + f"{int(n):9d} callbacks"
+                      + (f" {1e6 * sec / n:8.1f} us each, ready-wait "
+                         f"{1e3 * wait / n:7.3f} ms each" if n else ""))
+            for what, unit, sec, n in led["per_unit"]:
+                print(f"  {what}: {1e3 * sec:.4f} ms and {n:.2f} callbacks "
+                      f"a {unit}")
+            print(f"  ({led['frames']} frames, {led['handled']} messages "
+                  "handled by a replica or the supervisor)")
         for name, (mean, n) in res["mean_ms"].items():
             print(f"  {name:36s} {mean:10.3f} ms x {n}")
         return 0
