@@ -65,6 +65,24 @@ class IWriteReply:
 
 
 @dataclass(frozen=True)
+class IReadBatch:
+    """Many keys read as ONE ABD round through one coordinator (an
+    aggregate's re-reads: the stale keys and the audit's sample). Per key
+    it is `IRead`: the maximum-tag value of a verified quorum, stored at a
+    quorum before the answer. The proxy MAC covers the key list's digest
+    (`sigs.key_from_set`) and the nonce."""
+
+    keys: tuple
+
+
+@dataclass(frozen=True)
+class IReadBatchReply:
+    # one IReadReply per requested key, in the request's order; the
+    # envelope's proxy MAC covers the keys' digest, every value and tag
+    replies: tuple
+
+
+@dataclass(frozen=True)
 class Envelope:
     call: Any          # one of the I* messages above
     nonce: int
@@ -200,6 +218,48 @@ class ReadReply:
     key: str
     value: Optional[DDSSet]
     signature: bytes
+    nonce: int
+
+
+@dataclass(frozen=True)
+class BatchEntry:
+    """One key's part of a batched read or write-back: `ReadReply`'s and
+    `Write`'s fields less the nonce, which the batch states once.
+    `signature` is `sigs.abd_signature(value, tag, nonce)` by the replica
+    that reported the entry, so a write-back carries it on unchanged."""
+
+    tag: ABDTag
+    key: str
+    value: Optional[DDSSet]
+    signature: bytes
+
+
+@dataclass(frozen=True)
+class ReadBatch:
+    """Coordinator -> replicas: `Read` with a key list where a key was."""
+
+    keys: tuple
+    nonce: int
+
+
+@dataclass(frozen=True)
+class ReadBatchReply:
+    # one BatchEntry per key of the ReadBatch, in its order
+    entries: tuple
+    nonce: int
+
+
+@dataclass(frozen=True)
+class WriteBatch:
+    """Coordinator -> replicas: the write-back of the keys on which a
+    ReadBatch's quorum disagreed, each entry as it was reported."""
+
+    entries: tuple
+    nonce: int
+
+
+@dataclass(frozen=True)
+class WriteBatchAck:
     nonce: int
 
 
@@ -668,7 +728,9 @@ _TYPES = {
     cls.__name__: cls
     for cls in (
         IRead, IWrite, IReadReply, IWriteReply, Envelope,
+        IReadBatch, IReadBatchReply,
         ReadTag, TagReply, Write, WriteAck, Read, ReadReply,
+        BatchEntry, ReadBatch, ReadBatchReply, WriteBatch, WriteBatchAck,
         ReadTagBatch, TagBatchReply, KeySetUnknown,
         Suspect, Awake, State, Sleep, Complying, Kill,
         Redeploy, Redeployed, RequestReplicas, ActiveReplicas, Compromise,

@@ -375,16 +375,18 @@ class AbdClient:
     def _epoch(self) -> int:
         return self.shard_epoch() if self.shard_epoch is not None else -1
 
-    def _check_wrong_shard(self, reply, coord: str, key: str, challenge: int):
-        """Validate a WrongShard fence reply for an Envelope op. A valid
-        fence raises WrongShardError (no suspicion — the replica behaved
-        correctly); a forged one is a protocol violation like any other."""
+    def _check_wrong_shard(self, reply, coord: str, keys: tuple,
+                           challenge: int):
+        """Validate a WrongShard fence reply for an Envelope op over
+        `keys` (one, or a batch's). A valid fence raises WrongShardError
+        (no suspicion — the replica behaved correctly); a forged one is a
+        protocol violation like any other."""
         if not isinstance(reply, M.WrongShard):
             return
         cfg = self.cfg
         if (
             reply.nonce != challenge
-            or reply.key != key
+            or reply.key not in keys
             or not sigs.validate_proxy_signature(
                 cfg.proxy_mac_secret, reply.key, reply.nonce, reply.signature,
                 ["wrong-shard", reply.epoch],
@@ -393,7 +395,7 @@ class AbdClient:
             self._coord_failed(coord)
             raise ByzInvalidSignatureError(coord)
         self._breaker(coord).record_success()
-        raise WrongShardError(key, replica_epoch=reply.epoch,
+        raise WrongShardError(reply.key, replica_epoch=reply.epoch,
                               sent_epoch=self._epoch())
 
     @staticmethod
@@ -530,7 +532,7 @@ class AbdClient:
                 M.IRead(key), nonce, sig, exclude, deadline, op="fetch"
             )
             span_meta["coordinator"] = coord
-            self._check_wrong_shard(reply, coord, key, challenge)
+            self._check_wrong_shard(reply, coord, (key,), challenge)
 
             match reply:
                 case M.Envelope(M.IReadReply(k, value, tag), rnonce, rsig):
@@ -561,6 +563,69 @@ class AbdClient:
                     self._coord_failed(coord)
                     raise ByzUnknownReplyError(coord)
 
+    async def fetch_sets_attributed(self, keys, exclude=(),
+                                    deadline: Optional[Deadline] = None):
+        """Quorum read of many keys as ONE round through one coordinator
+        (`IReadBatch`); returns [(set|None, tag, coordinator)] in request
+        order. Per key it is `fetch_set_attributed`'s read: the quorum's
+        maximum-tag value, at a quorum before it is answered. Breakers,
+        the preferred half, `exclude` and `deadline` steer the one
+        coordinator as they steer a single read's. An aggregate's re-reads
+        come this way; a point operation and a lease read do not."""
+        keys = tuple(keys)
+        if not keys:
+            return []
+        cfg = self.cfg
+        digest = sigs.key_from_set(list(keys))
+        nonce = sigs.generate_nonce()
+        sig = sigs.proxy_signature(cfg.proxy_mac_secret, digest, nonce)
+        metrics.inc(
+            "dds_read_batch_rounds_total", **self._mlabels(),
+            help="batched quorum reads (IReadBatch) sent by the proxy",
+        )
+        # validated INSIDE the span, as `abd.fetch` is: `reads` carries
+        # each key's audit facts [key, seq, tag_id] for the Watchtower
+        with tracer.span("abd.fetch_batch", k=len(keys)) as span_meta:
+            reply, coord, challenge = await self._ask(
+                M.IReadBatch(keys), nonce, sig, exclude, deadline,
+                op="fetch_batch",
+            )
+            span_meta["coordinator"] = coord
+            self._check_wrong_shard(reply, coord, keys, challenge)
+
+            match reply:
+                case M.Envelope(M.IReadBatchReply(replies), rnonce, rsig):
+                    if rnonce != challenge:
+                        self._coord_failed(coord)
+                        raise ByzFailedNonceChallengeError(coord)
+                    shaped = isinstance(replies, tuple) and all(
+                        isinstance(r, M.IReadReply)
+                        and isinstance(r.tag, (M.ABDTag, type(None)))
+                        for r in replies)
+                    t_v = time.perf_counter()
+                    verified = shaped and sigs.validate_proxy_signature(
+                        cfg.proxy_mac_secret, digest, rnonce, rsig,
+                        [[r.set, sigs.tag_payload(r.tag)] for r in replies],
+                    )
+                    self._note_verify("read_batch", t_v)
+                    if not verified:
+                        self._coord_failed(coord)
+                        raise ByzInvalidSignatureError(coord)
+                    if tuple(r.key for r in replies) != keys:
+                        self._coord_failed(coord)
+                        raise ByzInvalidKeyError(coord)
+                    self._breaker(coord).record_success()
+                    span_meta["ok"] = True
+                    span_meta["op"] = "read"
+                    span_meta["reads"] = [
+                        [r.key, r.tag.seq, r.tag.id] for r in replies
+                        if r.tag is not None
+                    ]
+                    return [(r.set, r.tag, coord) for r in replies]
+                case _:
+                    self._coord_failed(coord)
+                    raise ByzUnknownReplyError(coord)
+
     async def write_set(self, key: str, value,
                         deadline: Optional[Deadline] = None) -> str:
         """Quorum write (value=None removes); returns the key on success."""
@@ -577,7 +642,7 @@ class AbdClient:
                 M.IWrite(key, value), nonce, sig, (), deadline, op="write"
             )
             span_meta["coordinator"] = coord
-            self._check_wrong_shard(reply, coord, key, challenge)
+            self._check_wrong_shard(reply, coord, (key,), challenge)
 
             match reply:
                 case M.Envelope(M.IWriteReply(k, tag), rnonce, rsig):

@@ -12,6 +12,12 @@ Protocol summary (healthy):
 - proxy `Envelope(IRead)` -> broadcast `Read`; on quorum of `ReadReply`
   take max (tag, value, signature), broadcast write-back `Write` with the
   *original* signature; on quorum of `WriteAck` answer `IReadReply`.
+- proxy `Envelope(IReadBatch)` -> the same read over a key list, as one
+  round: broadcast `ReadBatch`; on quorum of `ReadBatchReply` take each
+  key's max entry; the keys on which the quorum disagreed are written back
+  in one `WriteBatch` (original signatures), and on quorum of
+  `WriteBatchAck` (or at once, when every key was settled) answer
+  `IReadBatchReply`. An aggregate's re-reads come this way.
 - every inbound protocol message is HMAC-verified and nonce-replay-checked;
   violations raise `Suspect` votes to the supervisor
   (`BFTABDNode.scala:137,158,165,212,219,250,298,319,326`).
@@ -336,11 +342,53 @@ class BFTABDNode:
         names = {s.rsplit("/", 1)[-1] for s in responders}
         return holders <= names
 
+    def _entries_verify(self, entries, nonce: int) -> bool:
+        """Every entry of a batch is a `BatchEntry` under a valid ABD
+        signature for `nonce`: `ReadReply`'s and `Write`'s check, per
+        entry. One bad entry refuses the message."""
+        secret = self.cfg.abd_mac_secret
+        return isinstance(entries, tuple) and all(
+            isinstance(e, M.BatchEntry) and isinstance(e.key, str)
+            and isinstance(e.tag, M.ABDTag)
+            and sigs.validate_abd_signature(
+                secret, e.value, e.tag, nonce, e.signature)
+            for e in entries
+        )
+
+    def _answer_read_batch(self, req: _Outgoing) -> None:
+        """The batched read is complete (every key settled, or the
+        write-back at a quorum): one `IReadBatchReply` under the challenge
+        nonce, its proxy MAC over the keys' digest, every value and tag."""
+        cfg = self.cfg
+        req.expired = True
+        challenge = req.client_nonce + cfg.nonce_increment
+        best = req.set_to_read
+        sig = sigs.proxy_signature(
+            cfg.proxy_mac_secret, sigs.key_from_set(list(req.call.keys)),
+            challenge, [[e.value, sigs.tag_payload(e.tag)] for e in best],
+        )
+        self._send(req.client, M.Envelope(
+            M.IReadBatchReply(tuple(
+                M.IReadReply(e.key, e.value, tag=e.tag) for e in best)),
+            challenge, sig,
+        ))
+
     def _shard_fenced(self, key: str) -> bool:
         """True when this group must NOT serve `key` under its current
         shard map (Constellation epoch fencing). Unsharded nodes never
         fence."""
         return self.shard is not None and not self.shard.owns(key)
+
+    def _note_storage_fence(self, what: str, key: str) -> None:
+        """A write (`what`: the message class) reached storage for a key
+        this group does not own: counted and traced, never stored."""
+        metrics.inc(
+            "dds_shard_fenced_total", shard=str(self.shard.group_id),
+            msg=what,
+            help="requests fenced for keys outside the group's shard map",
+        )
+        tracer.event("shard.fence", replica=self.name, key=key,
+                     epoch=self.shard.epoch, msg=what)
 
     def _reply_wrong_shard(self, dest: str, key: str, nonce: int,
                            sent_epoch: int, what: str) -> None:
@@ -477,6 +525,28 @@ class BFTABDNode:
                             )
                         else:
                             self._broadcast(M.Read(key, nonce))
+                    case M.IReadBatch(keys):
+                        if not (
+                            isinstance(keys, tuple)
+                            and all(isinstance(k, str) for k in keys)
+                            and sigs.validate_proxy_signature(
+                                cfg.proxy_mac_secret,
+                                sigs.key_from_set(list(keys)), nonce,
+                                signature)
+                        ):
+                            self._reject(sender, msg, "bad_mac",
+                                         "invalid proxy signature (read batch)",
+                                         suspect=False)
+                        elif (bad := next(
+                            (k for k in keys if self._shard_fenced(k)), None
+                        )) is not None:
+                            req.expired = True
+                            self._reply_wrong_shard(
+                                sender, bad, nonce + cfg.nonce_increment,
+                                msg.epoch, "IReadBatch",
+                            )
+                        else:
+                            self._broadcast(M.ReadBatch(keys, nonce))
                     case M.IWrite(key, value):
                         if not sigs.validate_proxy_signature(
                             cfg.proxy_mac_secret, key, nonce, signature, value
@@ -676,14 +746,7 @@ class BFTABDNode:
                     # stored nor acked — the op can't reach quorum, the
                     # client retries, and the retry fences at the
                     # coordinator. Zero stale-epoch writes ever land.
-                    metrics.inc(
-                        "dds_shard_fenced_total",
-                        shard=str(self.shard.group_id), msg="Write",
-                        help="requests fenced for keys outside the group's "
-                             "shard map",
-                    )
-                    tracer.event("shard.fence", replica=self.name, key=key,
-                                 epoch=self.shard.epoch, msg="Write")
+                    self._note_storage_fence("Write", key)
                     return
                 cur_tag, _ = self._state(key)
                 if cur_tag < tag:
@@ -813,6 +876,123 @@ class BFTABDNode:
                         return
                     # ABD write-back phase, re-using the original signature
                     self._broadcast(M.Write(max_tag, key, max_val, max_sig, nonce))
+
+            case M.ReadBatch(keys, nonce):
+                if not (isinstance(keys, tuple)
+                        and all(isinstance(k, str) for k in keys)):
+                    self._debug("ReadBatch whose keys are no tuple of strings")
+                    return
+                if nonce in self.incoming:
+                    self._reject(sender, msg, "repeated_nonce",
+                                 "invalid nonce - repeated")
+                    return
+                self.incoming[nonce] = False
+                entries = []
+                for key in keys:
+                    tag, contents = self._state(key)
+                    entries.append(M.BatchEntry(
+                        tag, key, contents, sigs.abd_signature(
+                            cfg.abd_mac_secret, contents, tag, nonce)))
+                self._send(sender, M.ReadBatchReply(tuple(entries), nonce))
+
+            case M.ReadBatchReply(entries, nonce):
+                if not self._entries_verify(entries, nonce):
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid ABD signature")
+                    return
+                req = self.outgoing.get(nonce)
+                if req is None:
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
+                    return
+                if req.expired:
+                    self._debug("invalid nonce - expired at ReadBatchReply (late reply)")
+                    return
+                if not isinstance(req.call, M.IReadBatch) or (
+                    tuple(e.key for e in entries) != req.call.keys
+                ):
+                    # another request's phase, or not the keys asked for
+                    self._reject(sender, msg, "wrong_phase",
+                                 "ReadBatchReply for a request it does not answer")
+                    return
+                if req.set_to_read is not None:
+                    self._debug("ReadBatchReply after the read quorum (late reply)")
+                    return
+                req.read_quorum[sender] = entries
+                if self._quorum_met(req.read_quorum):
+                    votes = list(req.read_quorum.values())
+                    req.read_quorum = {}
+                    # per key: the quorum's maximum entry; a key on which
+                    # every member reported it is settled (the read
+                    # optimisation above), the others are written back
+                    # together, each under its original signature
+                    best, back = [], []
+                    for column in zip(*votes):
+                        top = max(column, key=lambda e: e.tag)
+                        best.append(top)
+                        if any(e.tag != top.tag for e in column):
+                            back.append(top)
+                    req.set_to_read = best
+                    for outcome, n in (("settled", len(best) - len(back)),
+                                       ("written_back", len(back))):
+                        if n:
+                            metrics.inc(
+                                "dds_read_batch_keys_total", n,
+                                outcome=outcome,
+                                help="keys of batched reads by whether the "
+                                     "quorum agreed on them or they were "
+                                     "written back first",
+                            )
+                    if back:
+                        self._broadcast(M.WriteBatch(tuple(back), nonce))
+                    else:
+                        self._answer_read_batch(req)
+
+            case M.WriteBatch(entries, nonce):
+                if not self._entries_verify(entries, nonce):
+                    self._reject(sender, msg, "bad_mac",
+                                 "invalid ABD signature")
+                    return
+                if nonce not in self.incoming:
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
+                    return
+                if self.incoming[nonce]:
+                    self._debug("invalid nonce - expired at WriteBatch (late quorum reply)")
+                    return
+                self.incoming[nonce] = True
+                bad = next((e.key for e in entries
+                            if self._shard_fenced(e.key)), None)
+                if bad is not None:
+                    # storage-layer fence, as for Write: nothing stored,
+                    # nothing acked
+                    self._note_storage_fence("WriteBatch", bad)
+                    return
+                for e in entries:
+                    if self._state(e.key)[0] < e.tag:
+                        self._store(e.key, e.tag, e.value)
+                self._send(sender, M.WriteBatchAck(nonce))
+
+            case M.WriteBatchAck(nonce):
+                req = self.outgoing.get(nonce)
+                if req is None:
+                    self._reject(sender, msg, "unknown_nonce",
+                                 "invalid nonce - unknown")
+                    return
+                if req.expired:
+                    self._debug("invalid nonce - expired at WriteBatchAck (late reply)")
+                    return
+                if not isinstance(req.call, M.IReadBatch) or (
+                    req.set_to_read is None
+                ):
+                    self._reject(sender, msg, "wrong_phase",
+                                 "WriteBatchAck for a request with no "
+                                 "batched write phase")
+                    return
+                req.write_quorum.add(sender)
+                if self._quorum_met(req.write_quorum):
+                    req.write_quorum = set()
+                    self._answer_read_batch(req)
 
             case M.LeaseRequest(region, ttl, nonce, signature):
                 if not sigs.validate_manifest_signature(
@@ -974,6 +1154,20 @@ class BFTABDNode:
                 if cur_tag < tag:
                     self._store(key, tag, value)
 
+            case M.WriteBatch(entries, nonce):
+                if not self._entries_verify(entries, nonce):
+                    self._debug("invalid ABD signature (sentinent)")
+                    return
+                if nonce in self.incoming:
+                    self._debug("invalid nonce - repeated (sentinent)")
+                    return
+                self.incoming[nonce] = True
+                for e in entries:
+                    if not self._shard_fenced(e.key) and (
+                        self._state(e.key)[0] < e.tag
+                    ):
+                        self._store(e.key, e.tag, e.value)
+
             case M.Awake():
                 self._debug("waking up")
                 data = {
@@ -1060,6 +1254,30 @@ class BFTABDNode:
                     sender,
                     M.ReadReply(tag, key, [",test,", 31, True], b"10010100110010", nonce),
                 )
+
+            case M.ReadBatch(keys, nonce):
+                # as for Read, per key: a random tag, garbage, a bad signature
+                rid = sender.rsplit("/", 1)[-1]
+                self._send(sender, M.ReadBatchReply(tuple(
+                    M.BatchEntry(M.ABDTag(random.getrandbits(31), rid), key,
+                                 [",test,", 31, True], b"10010100110010")
+                    for key in keys), nonce))
+
+            case M.ReadBatchReply(entries, nonce):
+                # as for ReadReply: forged writes under a random tag
+                tag = M.ABDTag(random.getrandbits(31), sender.rsplit("/", 1)[-1])
+                sig = sigs.abd_signature(cfg.abd_mac_secret, None, tag, nonce + 1)
+                forged = M.WriteBatch(tuple(
+                    M.BatchEntry(tag, e.key, None, sig) for e in entries
+                    if isinstance(e, M.BatchEntry)), nonce + 1)
+                for replica in self.all_replicas:
+                    self._send(replica, forged)
+
+            case M.WriteBatch(_, nonce):
+                self._send(sender, M.WriteBatchAck(nonce))
+
+            case M.WriteBatchAck(_):
+                pass  # omission
 
             case M.Kill():
                 self._wipe()

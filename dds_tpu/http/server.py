@@ -99,6 +99,14 @@ _REQ_TENANT: contextvars.ContextVar = contextvars.ContextVar(
 _RETRYABLE = (ByzantineError, WrongShardError, asyncio.TimeoutError,
               NoTrustedNodesError, OSError)
 
+# keys one batched re-read carries (`_reread`). A row is about 2 KB on the
+# wire at Paillier-2048 and 4.5 KB at 4096, and the reply, the write-back
+# and the answer each carry every row of the batch: 256 rows are a frame of
+# 0.6 to 1.2 MB, a thirtieth of `TcpNet.MAX_FRAME`, and a handler of a few
+# ms on the one loop. The flush after a forged audit (K = 16,384 rows) is
+# 64 such batches, gathered.
+REREAD_BATCH = 256
+
 # Observability/control routes stay admission-exempt: operators must be
 # able to see WHY the system is shedding while it sheds, so /health,
 # /metrics, /slo, /shards (and the debug-gated /_trace, and the Meridian
@@ -981,14 +989,43 @@ class DDSRestServer:
             self._dirty.clear()
 
     async def _reread(self, keys: list[str], audit: int) -> list:
-        """Full ABD re-reads of `keys`, gathered (`audit` of them are the
-        audit's sample, the rest stale); exceptions come back in place."""
+        """Full ABD re-reads of `keys` (`audit` of them are the audit's
+        sample, the rest stale) as ONE batched quorum round through one
+        coordinator (`AbdClient.fetch_sets_attributed`), in batches of
+        `REREAD_BATCH` gathered when there are more, each under a
+        coordinator drawn on its own. Under a read lease each key is one
+        hop to the holder already, so those stay single reads, gathered.
+        Exceptions come back in place."""
         with tracer.span("assembly.reread", stale=len(keys) - audit,
                          audit=audit):
-            return await asyncio.gather(
-                *(self._fetch_tagged(k) for k in keys),
+            if self.abd.cfg.lease_enabled:
+                return await asyncio.gather(
+                    *(self._fetch_tagged(k) for k in keys),
+                    return_exceptions=True,
+                )
+            chunks = [keys[i:i + REREAD_BATCH]
+                      for i in range(0, len(keys), REREAD_BATCH)]
+            if len(chunks) == 1:
+                try:
+                    return await self._fetch_batch(chunks[0])
+                except Exception as e:  # noqa: BLE001 - the caller raises it
+                    return [e] * len(keys)
+            parts = await asyncio.gather(
+                *(self._fetch_batch(c) for c in chunks),
                 return_exceptions=True,
             )
+            return [r for chunk, part in zip(chunks, parts)
+                    for r in ([part] * len(chunk)
+                              if isinstance(part, BaseException) else part)]
+
+    async def _fetch_batch(self, keys: list[str]) -> list:
+        dl = self._request_deadline()
+        results = await self._retry(
+            lambda: self.abd.fetch_sets_attributed(keys, deadline=dl), dl
+        )
+        for key, (value, tag, _coord) in zip(keys, results):
+            self._cache_put(key, tag, value)
+        return results
 
     async def _fetch_tagged(self, key: str, exclude=()):
         dl = self._request_deadline()
@@ -1425,6 +1462,14 @@ class DDSRestServer:
         bounded by the per-round audit (see aggregate_cache_audit). Keys
         that fail validation (or were never cached) take the full ABD read,
         refilling the cache.
+
+        The stale keys and the audit's sample are re-read together, as one
+        batched ABD read (`_reread`). Each audited key is still read
+        through a full quorum under a coordinator drawn uniformly from the
+        same set as a single read's, so a key's chance of being audited
+        by an honest coordinator is what it was; what changed is that one
+        aggregate's keys share the draw. The corroborating re-read
+        (`_audit_verdict`) stays a single read through ANOTHER coordinator.
 
         The reference re-reads every set through full quorums per aggregate
         (`DDSRestServer.scala:397-446`); this replaces K 2-round-trip reads

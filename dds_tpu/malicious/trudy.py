@@ -85,6 +85,22 @@ class StaleTagForger(BFTABDNode):
                     M.IReadReply(key, self.forged_value, tag=tag),
                     challenge, sig,
                 ))
+            case M.Envelope(M.IReadBatch(keys), nonce, _sig) if self.forging:
+                # the same forgery over an aggregate's batched re-read
+                tag = M.ABDTag(*self.forged_tag)
+                challenge = nonce + self.cfg.nonce_increment
+                sig = sigs.proxy_signature(
+                    self.cfg.proxy_mac_secret,
+                    sigs.key_from_set(list(keys)), challenge,
+                    [[self.forged_value, sigs.tag_payload(tag)]
+                     for _ in keys],
+                )
+                self._send(sender, M.Envelope(
+                    M.IReadBatchReply(tuple(
+                        M.IReadReply(k, self.forged_value, tag=tag)
+                        for k in keys)),
+                    challenge, sig,
+                ))
             case _:
                 await super()._healthy(sender, msg)
 

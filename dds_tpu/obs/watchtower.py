@@ -79,8 +79,8 @@ log = logging.getLogger("dds.watchtower")
 __all__ = ["Verdict", "Watchtower", "watchtower"]
 
 # phase classification of replica.handle spans by message type
-_READ_PHASE_MSGS = {"Read", "ReadTag"}
-_WRITE_PHASE_MSGS = {"Write"}
+_READ_PHASE_MSGS = {"Read", "ReadTag", "ReadBatch"}
+_WRITE_PHASE_MSGS = {"Write", "WriteBatch"}
 _BREAKER_EVENTS = {"breaker.open", "breaker.half_open", "breaker.closed"}
 
 
@@ -343,6 +343,12 @@ class Watchtower:
                     self._check_lease_intersection(r)
                 elif self.check_quorum:
                     self._check_quorum_intersection(r, children)
+            elif r.name == "abd.fetch_batch" and r.meta.get("ok"):
+                # a batched read is one op a key, all under the one span:
+                # the same tag history per key, one quorum geometry
+                ops.extend(self._distill_batch(r))
+                if self.check_quorum:
+                    self._check_quorum_intersection(r, children)
         for r in records:
             if r.kind == "event" and r.name == "audit.repair":
                 self._check_repair(r)
@@ -401,6 +407,28 @@ class Watchtower:
             lease=bool(rec.meta.get("lease")),
             replica=str(rec.meta.get("replica", "")),
         )
+
+    @staticmethod
+    def _distill_batch(rec) -> list[_Op]:
+        """The reads of an `abd.fetch_batch` span, from its `reads` list
+        of [key, seq, tag_id]: each over the span's own stretch."""
+        end = rec.ts
+        start = end - rec.dur_ms / 1e3
+        coordinator = str(rec.meta.get("coordinator", ""))
+        ops = []
+        for fact in rec.meta.get("reads") or ():
+            try:
+                key, seq, tag_id = fact
+                tag = (int(seq), str(tag_id))
+            except (TypeError, ValueError):
+                continue
+            if isinstance(key, str):
+                ops.append(_Op(
+                    op="read", key=key, tag=tag, start=start, end=end,
+                    trace_id=rec.trace_id, coordinator=coordinator,
+                    lease=False, replica="",
+                ))
+        return ops
 
     def _check_lease_intersection(self, op_span) -> None:
         """Audit a lease-tagged read against the lease ground truth: the

@@ -137,7 +137,13 @@ class ShardRouter:
 
     async def _point(self, op: str, key: str, call):
         gid, client = self._route(key)
-        self._charge(gid)
+        return await self._via(gid, client, op, call)
+
+    async def _via(self, gid: str, client: AbdClient, op: str, call,
+                   n: int = 1):
+        """`call(client)` as `n` ops charged to group `gid`: timed, and a
+        fence from the group refreshes the map before it propagates."""
+        self._charge(gid, n)
         t0 = time.perf_counter()
         try:
             return await call(client)
@@ -178,6 +184,35 @@ class ShardRouter:
         )
 
     # ------------------------------------------------------------- batches
+
+    async def fetch_sets_attributed(self, keys, exclude=(),
+                                    deadline: Optional[Deadline] = None):
+        """Batched quorum read (`AbdClient.fetch_sets_attributed`): the
+        keys partitioned by owning group as `read_tags` partitions them,
+        one batch a group, gathered; results in request order."""
+        keys = list(keys)
+        smap = self.shard_manager.current()
+        index: dict[str, list[int]] = {}
+        for i, k in enumerate(keys):
+            index.setdefault(smap.owner(k), []).append(i)
+
+        async def one(gid: str, idxs: list[int]):
+            client = self.clients.get(gid)
+            if client is None:
+                raise WrongShardError(keys[idxs[0]], sent_epoch=smap.epoch)
+            sub = [keys[i] for i in idxs]
+            return await self._via(
+                gid, client, "fetch_batch",
+                lambda c: c.fetch_sets_attributed(sub, exclude,
+                                                  deadline=deadline),
+                n=len(sub))
+
+        results = await asyncio.gather(*(one(g, ix) for g, ix in index.items()))
+        out = [None] * len(keys)
+        for part, idxs in zip(results, index.values()):
+            for i, r in zip(idxs, part):
+                out[i] = r
+        return out
 
     async def read_tags(
         self,

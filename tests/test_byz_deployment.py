@@ -453,3 +453,159 @@ def test_a_refused_message_is_counted_once_by_its_reason(reason):
         assert "K" not in node.repository or node.repository["K"][1] is None
 
     run(go())
+
+
+# ------------- (e) the batched read (IReadBatch): the liar keeps lying, and
+# every refusal of a batch message is counted and voted on like its kin's
+
+
+def test_a_liar_in_a_batch_forges_every_entry_and_moves_none():
+    """As a participant the liar answers `ReadBatch` as it answers `Read`:
+    a random tag and garbage per key under a bad signature. One message
+    refused (`bad_mac`), one vote, and every key's answer is the honest
+    quorum's."""
+
+    async def go():
+        c = Cluster()
+        c.through("replica-2")
+        tags = {}
+        for k in ("A", "B", "C"):
+            _, tags[k] = await c.abd.write_set_tagged(k, [k])
+        await c.net.quiesce()
+        before, voted = rejected(), votes(c.liar)
+        out = await c.abd.fetch_sets_attributed(["A", "B", "C"])
+        await c.net.quiesce()
+        assert out == [([k], tags[k], "replica-2") for k in ("A", "B", "C")]
+        assert since(before, rejected()) == {"bad_mac": 1}
+        assert votes(c.liar) - voted == 1
+        asked = [m for m in c.sent_to_liar if isinstance(m, M.ReadBatch)]
+        assert len(asked) == 1 and asked[0].keys == ("A", "B", "C")
+        assert c.abd.replicas.suspicions()[c.liar] == 0
+
+    run(go())
+
+
+@pytest.mark.parametrize("lagging", NAMES[:3])
+def test_a_batch_written_back_with_the_liars_ack_is_read_back(lagging):
+    """The liar acks a `WriteBatch` it never stores. The write-back can
+    close on the liar and two honest replicas, one of them the
+    coordinator, whose own copy the write-back has then repaired: the
+    value is at two honest replicas, and every later quorum of three
+    holds one of them."""
+
+    async def go():
+        c = Cluster()
+        first = next(n for n in c.honest if n != lagging)
+        c.through(first)
+        _, tag = await c.abd.write_set_tagged("K", ["new"])
+        await c.abd.write_set("L", ["same"])
+        await c.net.quiesce()
+        # one honest replica trails on K: the batch's quorum disagrees
+        c.nodes[lagging].repository["K"] = (M.ABDTag(0, lagging), None)
+        out = await c.abd.fetch_sets_attributed(["K", "L"])
+        await c.net.quiesce()
+        assert [r[:2] for r in out][0] == (["new"], tag)
+        acks = [m for m in c.sent_to_liar if isinstance(m, M.WriteBatch)]
+        assert len(acks) == 1 and [e.key for e in acks[0].entries] == ["K"]
+        assert c.stored(c.liar, "K")[1] is None          # acked, not stored
+        assert all(c.stored(n, "K") == (tag, ["new"]) for n in c.honest)
+        for coordinator in c.honest:
+            c.through(coordinator)
+            assert await c.abd.fetch_set_tagged("K") == (["new"], tag)
+
+    run(go())
+
+
+def test_a_liar_coordinating_a_batch_is_struck_and_the_batch_goes_elsewhere():
+    """As a coordinator the liar answers `IReadBatch` with its bare reply:
+    a protocol violation, a strike, and the retry reads through an honest
+    one."""
+    from dds_tpu.core.errors import ByzUnknownReplyError
+
+    async def go():
+        c = Cluster()
+        c.through("replica-1")
+        _, tag = await c.abd.write_set_tagged("K", ["row"])
+        c.through(c.liar)
+        with pytest.raises(ByzUnknownReplyError):
+            await c.abd.fetch_sets_attributed(["K"])
+        assert c.abd.replicas.suspicions()[c.liar] == 1
+        c.through("replica-1")
+        assert await c.abd.fetch_sets_attributed(["K"]) == [
+            (["row"], tag, "replica-1")]
+
+    run(go())
+
+
+def _batch_refusals():
+    """(reason, case) -> (the message a healthy replica must refuse, what
+    it was sent first); built per test, so that the nonce is fresh."""
+    cfg = ReplicaConfig(quorum_size=3)
+    secret, psecret = cfg.abd_mac_secret, cfg.proxy_mac_secret
+    nonce = sigs.generate_nonce()
+    tag = M.ABDTag(3, "replica-1")
+    good = M.BatchEntry(tag, "K", [1], sigs.abd_signature(secret, [1], tag, nonce))
+    forged = M.BatchEntry(tag, "K", [1], b"")
+    iread = M.Envelope(M.IRead("K"), nonce,
+                       sigs.proxy_signature(psecret, "K", nonce))
+    ibatch = M.Envelope(M.IReadBatch(("K",)), nonce, sigs.proxy_signature(
+        psecret, sigs.key_from_set(["K"]), nonce))
+    asked = [("replica-1", M.ReadBatch(("K",), nonce))]
+    return {
+        ("bad_mac", "ReadBatchReply"): (
+            M.ReadBatchReply((good, forged), nonce), [("proxy-0", ibatch)]),
+        ("bad_mac", "WriteBatch"): (M.WriteBatch((forged,), nonce), asked),
+        ("unknown_nonce", "ReadBatchReply"): (
+            M.ReadBatchReply((good,), nonce), []),
+        ("unknown_nonce", "WriteBatch"): (M.WriteBatch((good,), nonce), []),
+        ("unknown_nonce", "WriteBatchAck"): (M.WriteBatchAck(nonce), []),
+        ("repeated_nonce", "ReadBatch"): (M.ReadBatch(("K",), nonce), asked),
+        ("wrong_phase", "ReadBatchReply_to_a_single_read"): (
+            M.ReadBatchReply((good,), nonce), [("proxy-0", iread)]),
+        ("wrong_phase", "WriteBatchAck_to_a_single_read"): (
+            M.WriteBatchAck(nonce), [("proxy-0", iread)]),
+        ("wrong_phase", "ReadReply_to_a_batch"): (
+            M.ReadReply(tag, "K", [1], good.signature, nonce),
+            [("proxy-0", ibatch)]),
+        ("wrong_phase", "WriteAck_to_a_batch"): (
+            M.WriteAck("K", nonce), [("proxy-0", ibatch)]),
+        ("wrong_phase", "ReadBatchReply_for_other_keys"): (
+            M.ReadBatchReply((M.BatchEntry(
+                tag, "other", [1], good.signature),), nonce),
+            [("proxy-0", ibatch)]),
+        ("wrong_phase", "WriteBatchAck_before_the_read_quorum"): (
+            M.WriteBatchAck(nonce), [("proxy-0", ibatch)]),
+    }
+
+
+@pytest.mark.parametrize("reason,case", sorted(_batch_refusals()))
+def test_a_refused_batch_message_is_counted_and_voted_on_like_its_kin(
+        reason, case):
+    """`ReadBatchReply` is refused where `ReadReply` is, `WriteBatch` where
+    `Write` is, `WriteBatchAck` where `WriteAck` is (one count by reason,
+    one vote naming the class), a single read's replies do not answer a
+    batch's nonce nor a batch's a single read's, and nothing is stored."""
+
+    async def go():
+        net = InMemoryNet()
+        node = BFTABDNode("replica-0", NAMES, "supervisor", net,
+                          ReplicaConfig(quorum_size=3))
+        msg, first = _batch_refusals()[reason, case]
+        for sender, m in first:
+            await node.handle(sender, m)
+        before, voted = rejected(), votes("replica-1")
+        events = []
+        tracer.subscribe(events.append)
+        try:
+            await node.handle("replica-1", msg)
+        finally:
+            tracer.unsubscribe(events.append)
+        assert since(before, rejected()) == {reason: 1}
+        assert votes("replica-1") - voted == 1
+        vote = next(e for e in events if e.name == "replica.suspect")
+        assert vote.meta["reason"] == reason
+        assert vote.meta["msg"] == type(msg).__name__
+        assert "K" not in node.repository or node.repository["K"][1] is None
+        assert "other" not in node.repository
+
+    run(go())

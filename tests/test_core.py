@@ -16,6 +16,7 @@ from dds_tpu.core.quorum_client import AbdClient, AbdClientConfig
 from dds_tpu.core.replica import BFTABDNode, ReplicaConfig
 from dds_tpu.core.supervisor import BFTSupervisor, SupervisorConfig
 from dds_tpu.core.transport import InMemoryNet
+from dds_tpu.obs.metrics import metrics
 from dds_tpu.utils import sigs
 
 
@@ -954,5 +955,369 @@ def test_concurrent_suspects_single_recovery():
         # non-active endpoints are not recoverable
         await c.supervisor.recover("proxy-0")
         assert len(c.supervisor.active) == 7
+
+    run(go())
+
+
+# ------------------------------------------- the batched read (IReadBatch)
+#
+# An aggregate's re-reads go to one coordinator as one ABD round over a key
+# list. Per key it is the single read: same value, same tag, stored at a
+# quorum before the answer.
+
+
+def small(quorum=3, n=4):
+    c = Cluster(n_active=n, n_sentinent=0, quorum=quorum)
+    c.client.cfg.quorum_size = quorum
+    return c
+
+
+def watch(c, dest, seen):
+    """Note every message delivered to `dest` (class name, message)."""
+
+    async def f(msg):
+        seen.append(msg)
+        return msg
+
+    c.net.link_filters[dest] = f
+
+
+BATCH_CASES = {
+    "stored": ["a", "b", "c"],
+    "missing": ["nope-1", "nope-2"],
+    "removed": ["gone", "a"],
+    "mixed": ["a", "nope-1", "gone", "c", "b"],
+    "one_key": ["b"],
+    "repeated_key": ["a", "b", "a"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(BATCH_CASES))
+def test_a_batched_read_returns_what_single_reads_return(case):
+    async def go():
+        c = small()
+        for k in ("a", "b", "c", "gone"):
+            await c.client.write_set(k, [k, 1])
+        await c.client.write_set("b", ["b", 2])
+        await c.client.write_set("gone", None)
+        keys = BATCH_CASES[case]
+        singles = [await c.client.fetch_set_attributed(k) for k in keys]
+        batch = await c.client.fetch_sets_attributed(keys)
+        assert [r[:2] for r in batch] == [r[:2] for r in singles]
+        # one coordinator served the whole batch
+        assert len({r[2] for r in batch}) == 1
+        assert await c.client.fetch_sets_attributed([]) == []
+
+    run(go())
+
+
+@pytest.mark.parametrize("lagging", ["replica-0", "replica-1", "replica-2"])
+def test_a_batch_writes_back_only_the_keys_its_quorum_disagreed_on(lagging):
+    """replica-3 is cut off, so the quorum is the other three and holds the
+    laggard: the one key it trails on is written back, in one `WriteBatch`
+    of one entry, and is stored at a quorum BEFORE the proxy is answered;
+    the keys the quorum agreed on are answered without a write phase."""
+
+    async def go():
+        c = small()
+        keys = ["a", "b", "c"]
+        for k in keys:
+            await c.client.write_set(k, [k, 1])
+        await c.net.quiesce()
+        c.client._preferred = ["replica-0"]
+        node = c.replicas[lagging]
+        newest = node.repository["b"]
+        node.repository["b"] = (M.ABDTag(0, lagging), None)
+
+        async def cut(msg):
+            return None
+
+        c.net.link_filters["replica-3"] = cut
+        sent: dict[str, list] = {}
+        for r in ("replica-0", "replica-1", "replica-2"):
+            watch(c, r, sent.setdefault(r, []))
+        holders_at_answer = []
+
+        async def at_answer(msg):
+            if isinstance(msg, M.Envelope):
+                holders_at_answer.append(sum(
+                    c.replicas[r].repository["b"] == newest
+                    for r in ("replica-0", "replica-1", "replica-2")))
+            return msg
+
+        c.net.link_filters["proxy-0"] = at_answer
+        back = metrics.value("dds_read_batch_keys_total",
+                             outcome="written_back") or 0.0
+        settled = metrics.value("dds_read_batch_keys_total",
+                                outcome="settled") or 0.0
+        out = await c.client.fetch_sets_attributed(keys)
+        await c.net.quiesce()
+        assert [r[0] for r in out] == [[k, 1] for k in keys]
+        assert holders_at_answer == [3]
+        writes = [m for m in sent[lagging] if isinstance(m, M.WriteBatch)]
+        assert len(writes) == 1
+        assert [e.key for e in writes[0].entries] == ["b"]
+        assert node.repository["b"] == newest
+        assert metrics.value("dds_read_batch_keys_total",
+                             outcome="written_back") - back == 1
+        assert metrics.value("dds_read_batch_keys_total",
+                             outcome="settled") - settled == 2
+        # no single-key protocol message was used for any of it
+        assert not any(isinstance(m, (M.Read, M.Write, M.ReadReply))
+                       for msgs in sent.values() for m in msgs)
+
+    run(go())
+
+
+def test_a_batch_whose_quorum_agrees_has_no_write_phase():
+    async def go():
+        c = small()
+        for k in ("a", "b"):
+            await c.client.write_set(k, [k])
+        await c.net.quiesce()
+        seen: list = []
+        for r in c.active:
+            watch(c, r, seen)
+        await c.client.fetch_sets_attributed(["a", "b"])
+        await c.net.quiesce()
+        kinds = {type(m).__name__ for m in seen}
+        assert kinds == {"Envelope", "ReadBatch", "ReadBatchReply"}
+        # the tenth message is the proxy's: 1 + 4 + 4 at the replicas
+        assert len(seen) == 9
+
+    run(go())
+
+
+def test_a_point_read_sends_what_it_sent_before_batches():
+    """`GetSet` and `WriteElement` keep `IRead` / `IWrite`: no batch class
+    on a point operation's path, 9 and 17 messages at the replicas."""
+
+    async def go():
+        c = small()
+        seen: list = []
+        for r in c.active:
+            watch(c, r, seen)
+        await c.client.write_set("k", [1])
+        await c.net.quiesce()
+        assert {type(m).__name__ for m in seen} == {
+            "Envelope", "ReadTag", "TagReply", "Write", "WriteAck"}
+        assert len(seen) == 17
+        del seen[:]
+        await c.client.fetch_set("k")
+        await c.net.quiesce()
+        assert {type(m).__name__ for m in seen} == {
+            "Envelope", "Read", "ReadReply"}
+        assert len(seen) == 9
+
+    run(go())
+
+
+@pytest.mark.parametrize("late", ["ReadBatchReply", "WriteBatchAck"])
+def test_a_batch_reply_after_its_quorum_is_ignored(late):
+    """The fourth replica's reply comes when the round is over (or its
+    read phase is): nothing is refused, nobody is voted on, and the
+    answer is the quorum's."""
+
+    async def go():
+        c = small()
+        await c.client.write_set("a", [1])
+        await c.net.quiesce()
+        c.client._preferred = ["replica-0"]
+        if late == "WriteBatchAck":
+            c.replicas["replica-1"].repository["a"] = (
+                M.ABDTag(0, "replica-1"), None)
+        held: list = []
+        gate = asyncio.Event()
+
+        async def hold(msg):
+            if type(msg).__name__ == late:
+                held.append(msg)
+                await gate.wait()
+            return msg
+
+        c.net.link_filters[("replica-3", "replica-0")] = hold
+        before = {r: metrics.value("dds_replica_rejected_total", reason=r)
+                  or 0.0 for r in ("bad_mac", "unknown_nonce",
+                                   "repeated_nonce", "wrong_phase")}
+        out = await c.client.fetch_sets_attributed(["a"])
+        assert out[0][0] == [1] and len(held) == 1
+        gate.set()
+        await c.net.quiesce()
+        after = {r: metrics.value("dds_replica_rejected_total", reason=r)
+                 or 0.0 for r in before}
+        assert after == before
+        assert c.replicas["replica-1"].repository["a"][1] == [1]
+
+    run(go())
+
+
+_T = M.ABDTag(7, "replica-1;x|y")
+_ENTRY = M.BatchEntry(_T, "kéy/1", [1, "a", None, {"__msg__": "Kill"}],
+                      bytes(range(40)))
+BATCH_MESSAGES = {
+    "IReadBatch": M.Envelope(M.IReadBatch(("a", "b")), 2**63 + 5, b"\x01"),
+    "IReadBatchReply": M.Envelope(M.IReadBatchReply((
+        M.IReadReply("a", [1, None], tag=_T), M.IReadReply("b", None, tag=_T),
+    )), 9, b"\x02"),
+    "BatchEntry": _ENTRY,
+    "ReadBatch": M.ReadBatch(("a", "b"), 11),
+    "ReadBatchReply": M.ReadBatchReply((_ENTRY, _ENTRY), 11),
+    "WriteBatch": M.WriteBatch((_ENTRY,), 11),
+    "WriteBatchAck": M.WriteBatchAck(11),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BATCH_MESSAGES))
+def test_batch_messages_cross_the_codec(name):
+    msg = BATCH_MESSAGES[name]
+    back = M.loads(M.dumps(msg))
+    assert back == msg and type(back) is type(msg)
+    if name in ("ReadBatchReply", "WriteBatch"):
+        assert type(back.entries) is tuple
+        e = back.entries[0]
+        assert type(e) is M.BatchEntry and type(e.tag) is M.ABDTag
+        assert type(e.signature) is bytes
+        # a stored row stays opaque: no column is decoded as a message
+        assert e.value[3] == {"__msg__": "Kill"}
+    if name == "IReadBatchReply":
+        assert all(type(r) is M.IReadReply for r in back.call.replies)
+
+
+@pytest.mark.parametrize("paillier_bits", [2048, 4096])
+def test_a_capped_batch_fits_a_frame_many_times_over(paillier_bits):
+    """`REREAD_BATCH` rows as the cells store them (one Paillier
+    ciphertext mod n^2 and an RSA-1024 one as decimal strings, five short
+    columns), in the widest message of the round, against `MAX_FRAME`."""
+    from dds_tpu.core.transport import TcpNet
+    from dds_tpu.http.server import REREAD_BATCH
+
+    digits = len(str(2 ** (2 * paillier_bits)))
+    row = [12345, "x" * 44, "9" * digits, "9" * 309, "y" * 44, "z" * 44,
+           "w" * 44, None]
+    key = sigs.key_from_set(row)
+    entry = M.BatchEntry(M.ABDTag(3, "replica-2"), key, row, b"\x00" * 32)
+    frame = M.dumps(M.ReadBatchReply((entry,) * REREAD_BATCH, 2**62))
+    assert 100 <= REREAD_BATCH <= 1000
+    assert len(frame) * 8 < TcpNet.MAX_FRAME
+    answer = M.dumps(M.Envelope(M.IReadBatchReply(
+        (M.IReadReply(key, row, tag=entry.tag),) * REREAD_BATCH), 1, b"s"))
+    assert len(answer) * 8 < TcpNet.MAX_FRAME
+
+
+class _Answering:
+    """A coordinator under the test's control: answers `IReadBatch` with
+    what `forge` makes of the honest answer."""
+
+    def __init__(self, c, addr, forge):
+        self.c, self.addr, self.forge = c, addr, forge
+        c.net.register(addr, self.handle)
+
+    async def handle(self, sender, msg):
+        cfg = self.c.rcfg
+        keys = msg.call.keys
+        tag = M.ABDTag(4, "replica-1")
+        replies = [M.IReadReply(k, [k], tag=tag) for k in keys]
+        challenge = msg.nonce + cfg.nonce_increment
+        digest = sigs.key_from_set(list(keys))
+
+        def sign(replies, challenge=challenge, digest=digest):
+            return sigs.proxy_signature(
+                cfg.proxy_mac_secret, digest, challenge,
+                [[r.set, sigs.tag_payload(r.tag)] for r in replies])
+
+        self.c.net.send(self.addr, sender, self.forge(
+            replies, challenge, sign))
+
+
+FORGERIES = {
+    "honest": (None, lambda rs, ch, sign: M.Envelope(
+        M.IReadBatchReply(tuple(rs)), ch, sign(rs))),
+    "wrong_challenge": ("ByzFailedNonceChallengeError",
+                        lambda rs, ch, sign: M.Envelope(
+        M.IReadBatchReply(tuple(rs)), ch + 1, sign(rs, challenge=ch + 1))),
+    "bad_mac": ("ByzInvalidSignatureError", lambda rs, ch, sign: M.Envelope(
+        M.IReadBatchReply(tuple(rs)), ch, b"forged")),
+    "value_swapped_after_signing": (
+        "ByzInvalidSignatureError", lambda rs, ch, sign: M.Envelope(
+            M.IReadBatchReply((M.IReadReply(rs[0].key, ["planted"],
+                                            tag=rs[0].tag), *rs[1:])),
+            ch, sign(rs))),
+    "tag_swapped_after_signing": (
+        "ByzInvalidSignatureError", lambda rs, ch, sign: M.Envelope(
+            M.IReadBatchReply((M.IReadReply(rs[0].key, rs[0].set,
+                                            tag=M.ABDTag(99, "x")), *rs[1:])),
+            ch, sign(rs))),
+    "keys_in_another_order": (
+        "ByzInvalidKeyError", lambda rs, ch, sign: M.Envelope(
+            M.IReadBatchReply(tuple(rs[::-1])), ch, sign(rs[::-1]))),
+    "a_key_left_out": (
+        "ByzInvalidKeyError", lambda rs, ch, sign: M.Envelope(
+            M.IReadBatchReply(tuple(rs[1:])), ch, sign(rs[1:]))),
+    "signed_for_other_keys": (
+        "ByzInvalidSignatureError", lambda rs, ch, sign: M.Envelope(
+            M.IReadBatchReply(tuple(rs)), ch,
+            sign(rs, digest=sigs.key_from_set(["other"])))),
+    "a_single_reads_reply": (
+        "ByzUnknownReplyError", lambda rs, ch, sign: M.Envelope(
+            rs[0], ch, sigs.proxy_signature(
+                b"rest2abd", rs[0].key, ch,
+                [rs[0].set, sigs.tag_payload(rs[0].tag)]))),
+    "bare_reply": ("ByzUnknownReplyError",
+                   lambda rs, ch, sign: M.IReadReply("x", None)),
+    "entries_of_no_shape": (
+        "ByzInvalidSignatureError", lambda rs, ch, sign: M.Envelope(
+            M.IReadBatchReply(("a", "b")), ch, b"sig")),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(FORGERIES))
+def test_the_proxy_verifies_a_batched_answer_as_it_verifies_a_single_one(kind):
+    """Challenge nonce, proxy MAC over every value and tag, keys echoed in
+    order: each failure is the typed Byzantine error a single read raises,
+    and a strike on the coordinator."""
+    from dds_tpu.core import errors
+
+    async def go():
+        c = small()
+        error, forge = FORGERIES[kind]
+        _Answering(c, "replica-0", forge)
+        c.client._preferred = ["replica-0"]
+        keys = ["a", "b", "c"]
+        if error is None:
+            out = await c.client.fetch_sets_attributed(keys)
+            assert out == [([k], M.ABDTag(4, "replica-1"), "replica-0")
+                           for k in keys]
+            assert c.client.replicas._strikes.get("replica-0", 0) == 0
+        else:
+            with pytest.raises(getattr(errors, error)):
+                await c.client.fetch_sets_attributed(keys)
+            assert c.client.replicas._strikes["replica-0"] == 1
+
+    run(go())
+
+
+def test_a_batched_read_steers_its_coordinator_like_a_single_read():
+    """`exclude`, the breakers and the deadline reach `_ask` unchanged."""
+    from dds_tpu.utils.retry import Deadline, DeadlineExceededError
+
+    async def go():
+        c = small()
+        await c.client.write_set("a", [1])
+        for _ in range(8):
+            out = await c.client.fetch_sets_attributed(
+                ["a"], exclude=("replica-0", "replica-1", "replica-2"))
+            assert out[0][2] == "replica-3"
+        for _ in range(3):
+            c.client._breaker("replica-3").record_failure()
+        out = await c.client.fetch_sets_attributed(
+            ["a"], exclude=("replica-0", "replica-1"))
+        assert out[0][2] == "replica-2"
+        rounds = metrics.value("dds_read_batch_rounds_total")
+        with pytest.raises(DeadlineExceededError):
+            await c.client.fetch_sets_attributed(["a"], deadline=Deadline(0.0))
+        assert metrics.value("dds_read_batch_rounds_total") == rounds + 1
+        stats = metrics.histogram_stats("dds_quorum_rtt_seconds",
+                                        op="fetch_batch")
+        assert stats["count"] >= 9
 
     run(go())

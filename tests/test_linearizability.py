@@ -197,6 +197,90 @@ def test_byzantine_faults_mid_workload():
 
 
 # ---------------------------------------------------------------------------
+# the batched read (IReadBatch): per key still an atomic register
+# ---------------------------------------------------------------------------
+
+KEYS = ["LINREG-0", "LINREG-1", "LINREG-2"]
+
+
+async def _key_writer(cluster, recs, wid, n_writes, rng):
+    for i in range(n_writes):
+        k = rng.randrange(len(KEYS))
+        value = [f"w{wid}-{i}"]
+        t0 = time.monotonic()
+        await retry(lambda: cluster.client.write_set(KEYS[k], value), 0.01, 5)
+        recs[k].record("write", f"w{wid}-{i}", t0, time.monotonic())
+        await asyncio.sleep(rng.uniform(0, 0.002))
+
+
+async def _batch_reader(cluster, recs, n_reads, rng, single_every=0):
+    """Reads all keys as one batch; every `single_every`-th read is a
+    single read of one key instead, so both entry points share a history."""
+    for n in range(n_reads):
+        t0 = time.monotonic()
+        if single_every and n % single_every == 0:
+            k = rng.randrange(len(KEYS))
+            got = await retry(
+                lambda: cluster.client.fetch_set(KEYS[k]), 0.01, 5)
+            recs[k].record("read", got[0] if got else None, t0,
+                           time.monotonic())
+        else:
+            out = await retry(
+                lambda: cluster.client.fetch_sets_attributed(KEYS), 0.01, 5)
+            t1 = time.monotonic()
+            for rec, (got, _tag, _coord) in zip(recs, out):
+                rec.record("read", got[0] if got else None, t0, t1)
+        await asyncio.sleep(rng.uniform(0, 0.002))
+
+
+@pytest.mark.parametrize("seed,fault,single_every", [
+    (41, None, 0), (42, None, 3), (43, "crash", 0), (44, "byzantine", 0),
+    (45, "byzantine", 2),
+])
+def test_batched_reads_keep_every_key_an_atomic_register(
+        seed, fault, single_every):
+    """Concurrent writers over three keys and readers that read all three
+    as one batch (write-backs of the keys in flight included): each key's
+    history passes the register checks, with crashes or compromised
+    replicas within f = 2 mid-workload too, and a final batch agrees with
+    a quorum of replicas on every key."""
+
+    async def go():
+        rng = random.Random(seed)
+        c = Cluster()
+        recs = [Recorder() for _ in KEYS]
+        jobs = [
+            _key_writer(c, recs, 0, 8, rng),
+            _key_writer(c, recs, 1, 8, rng),
+            _key_writer(c, recs, 2, 8, rng),
+            _batch_reader(c, recs, 12, rng, single_every),
+            _batch_reader(c, recs, 12, rng, single_every),
+        ]
+        if fault:
+            trudy = Trudy(c.net, c.active, max_faults=2,
+                          rng=random.Random(seed))
+
+            async def attacker():
+                await asyncio.sleep(0.005)
+                trudy.trigger(fault)
+
+            jobs.append(attacker())
+        await asyncio.gather(*jobs)
+        for rec in recs:
+            check_atomic_register(rec.ops)
+        final = await retry(
+            lambda: c.client.fetch_sets_attributed(KEYS), 0.01, 5)
+        await c.net.quiesce()
+        for k, (value, tag, _coord) in zip(KEYS, final):
+            holders = [r for r in c.replicas.values()
+                       if r.behavior == "healthy"
+                       and r.repository.get(k, (None, None)) == (tag, value)]
+            assert len(holders) >= 3, (k, len(holders))
+
+    run(go())
+
+
+# ---------------------------------------------------------------------------
 # chaos suite: the SAME atomic-register checker under seeded fault schedules
 # ---------------------------------------------------------------------------
 

@@ -242,6 +242,62 @@ def _remap_all_to(smap, gid, epoch=None):
     ).sign(SECRET)
 
 
+@pytest.mark.parametrize("S", [1, 2, 3])
+def test_router_batched_read_partitions_by_owner_and_keeps_request_order(S):
+    """`fetch_sets_attributed` on the router: one `IReadBatch` a group that
+    owns a key of the batch, results in the caller's order, each key's
+    coordinator one of its OWN group's replicas."""
+
+    async def go():
+        const, net = constellation(S=S)
+        r = const.router
+        keys = [f"BATCH-{i}" for i in range(10)]
+        for k in keys:
+            await r.write_set(k, [k])
+        groups = r.partition_keys(keys)
+        assert len(groups) == S
+        from dds_tpu.obs.metrics import metrics
+
+        def rounds():
+            return sum(metrics.value("dds_read_batch_rounds_total",
+                                     shard=g) or 0.0 for g in r.clients)
+
+        before = rounds()
+        out = await r.fetch_sets_attributed(keys)
+        assert rounds() - before == S
+        for k, (value, tag, coord) in zip(keys, out):
+            assert value == [k]
+            assert (value, tag) == await r.fetch_set_tagged(k)
+            assert coord in const.group(r.owner(k)).replicas
+        assert await r.fetch_sets_attributed([]) == []
+        await const.stop()
+
+    run(go())
+
+
+def test_epoch_fence_rejects_a_batch_that_holds_a_foreign_key():
+    """The coordinator fences `IReadBatch` per key as it fences `IRead`:
+    one key the group no longer owns and the batch is answered
+    `WrongShard` (signed, no suspicion), and nothing of it is served."""
+
+    async def go():
+        const, net = constellation(S=2, n_sentinent=0)
+        r = const.router
+        smap = const.manager.current()
+        mine = [k for k in (f"F{i}" for i in range(64))
+                if smap.owner(k) == "s1"][:3]
+        for k in mine:
+            await r.write_set(k, ["v0"])
+        const.group("s1").state.install(_remap_all_to(smap, "s0"))
+        with pytest.raises(WrongShardError) as err:
+            await r.fetch_sets_attributed(mine)
+        assert err.value.key in mine
+        assert not any(const.group("s1").client.replicas.suspicions().values())
+        await const.stop()
+
+    run(go())
+
+
 def test_epoch_fence_rejects_stale_route_then_retry_lands():
     async def go():
         const, net = constellation(S=2, n_sentinent=0)

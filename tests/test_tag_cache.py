@@ -12,9 +12,12 @@ can at worst force spurious re-fetches.
 import asyncio
 import json
 
+import pytest
+
 from dds_tpu.core import messages as M
 from dds_tpu.core.errors import ByzantineError
 from dds_tpu.core.quorum_client import AbdClient, AbdClientConfig
+from dds_tpu.obs.metrics import metrics
 from dds_tpu.utils import sigs
 
 from tests.test_core import Cluster, run
@@ -420,15 +423,24 @@ def test_defer_to_exclusion_picks_a_different_coordinator():
 # --------------------------------------------------------------- proxy level
 
 def _count_fetches(server):
-    """Wrap the proxy's quorum read so tests can count full ABD fetches."""
-    counter = {"n": 0}
+    """Wrap the proxy's quorum reads so tests can count the keys read
+    through full ABD quorums (`n`; single reads and the keys of batched
+    ones alike) and the batched rounds they took (`rounds`)."""
+    counter = {"n": 0, "rounds": 0}
     orig = server.abd.fetch_set_attributed
+    orig_batch = server.abd.fetch_sets_attributed
 
     async def counted(key, exclude=(), deadline=None):
         counter["n"] += 1
         return await orig(key, exclude, deadline=deadline)
 
+    async def counted_batch(keys, exclude=(), deadline=None):
+        counter["n"] += len(keys)
+        counter["rounds"] += 1
+        return await orig_batch(keys, exclude, deadline=deadline)
+
     server.abd.fetch_set_attributed = counted
+    server.abd.fetch_sets_attributed = counted_batch
     return counter
 
 
@@ -565,6 +577,230 @@ def test_audit_benign_concurrent_write_refreshes_without_flush():
             # no flush: all keys still cached, bumped key at its new tag
             assert set(server._cache) == set(keys)
             assert server._cache[keys[0]][0] > stale_tags[keys[0]]
+
+    asyncio.run(go())
+
+
+async def _stored_rows(server, vals):
+    keys = []
+    for v in vals:
+        row = PROVIDER.encrypt_row([v], 1, ["PSSE"])
+        _, key = await call(server, "POST", "/PutSet", {"contents": row})
+        keys.append(key.decode())
+    return keys
+
+
+async def _sum(server):
+    pk = PROVIDER.keys.psse.public
+    _, data = await call(server, "GET",
+                         f"/SumAll?position=0&nsqr={pk.nsquare}")
+    return PROVIDER.keys.psse.decrypt(int(json.loads(data)["result"]))
+
+
+@pytest.mark.parametrize("stale,audit", [(0, 2), (1, 2), (3, 2), (2, 0),
+                                         (0, 0)])
+def test_an_aggregates_rereads_are_one_batched_round(stale, audit):
+    """The stale keys and the audit's sample go to ONE coordinator as one
+    `IReadBatch`: `stale + audit` keys through full quorums, one round,
+    no single read; with nothing to re-read, no round at all."""
+
+    async def go():
+        async with rest_stack() as (server, replicas, _):
+            server.cfg.aggregate_cache_audit = audit
+            vals = [11, 22, 33, 44, 55, 66]
+            keys = await _stored_rows(server, vals)
+            assert await _sum(server) == sum(vals)
+            other = AbdClient(
+                "proxy-ext", server.abd.net, list(replicas),
+                AbdClientConfig(request_timeout=2.0),
+            )
+            for i in range(stale):
+                vals[i] += 100
+                await other.write_set(
+                    keys[i], PROVIDER.encrypt_row([vals[i]], 1, ["PSSE"]))
+            counter = _count_fetches(server)
+            singles = []
+            orig = server.abd.fetch_set_attributed
+
+            async def single(key, exclude=(), deadline=None):
+                singles.append(key)
+                return await orig(key, exclude, deadline=deadline)
+
+            server.abd.fetch_set_attributed = single
+            rounds = metrics.value("dds_read_batch_rounds_total") or 0.0
+            assert await _sum(server) == sum(vals)
+            want = stale + audit
+            assert counter == {"n": want, "rounds": 1 if want else 0}
+            assert singles == []
+            assert (metrics.value("dds_read_batch_rounds_total") or 0.0
+                    ) - rounds == (1 if want else 0)
+
+    asyncio.run(go())
+
+
+@pytest.mark.parametrize("cap,n_keys", [(1, 3), (2, 5), (4, 4), (4, 9)])
+def test_the_flush_after_a_forged_audit_rereads_in_capped_batches(
+        cap, n_keys, monkeypatch):
+    """A planted value at the true tag still flushes the cache, and the
+    flush re-reads every other key in batches of at most `REREAD_BATCH`,
+    gathered: ceil(remaining / cap) rounds more, the answer from quorum
+    reads only."""
+    import dds_tpu.http.server as srv
+
+    async def go():
+        async with rest_stack() as (server, _, _):
+            monkeypatch.setattr(srv, "REREAD_BATCH", cap)
+            vals = list(range(10, 10 + n_keys))
+            keys = await _stored_rows(server, vals)
+            server.cfg.aggregate_cache_audit = 1
+            # every entry planted, so whichever key the audit draws fails
+            for k in keys:
+                tag, _ = server._cache[k]
+                server._cache[k] = (
+                    tag, PROVIDER.encrypt_row([999], 1, ["PSSE"]))
+            server._table = None
+            counter = _count_fetches(server)
+            sizes = []
+            inner = server.abd.fetch_sets_attributed
+
+            async def sized(ks, exclude=(), deadline=None):
+                sizes.append(len(ks))
+                return await inner(ks, exclude, deadline=deadline)
+
+            server.abd.fetch_sets_attributed = sized
+            assert await _sum(server) == sum(vals)
+            rest = n_keys - 1
+            assert counter["n"] == n_keys
+            assert sorted(sizes) == sorted(
+                [1] + [cap] * (rest // cap) + ([rest % cap] if rest % cap
+                                               else []))
+            assert max(sizes) <= cap
+            # flushed: the cache holds the reads made after the flush and
+            # nothing from before it (the audited key's refill went too)
+            assert len(server._cache) == rest
+
+    asyncio.run(go())
+
+
+def test_the_audits_corroboration_is_a_single_read_through_another_coordinator():
+    """A newer (value, tag) from the audited batch is corroborated by ONE
+    single read that excludes the coordinator the batch went through."""
+
+    async def go():
+        async with rest_stack() as (server, replicas, _):
+            vals = [11, 22, 33]
+            keys = await _stored_rows(server, vals)
+            server.cfg.aggregate_cache_audit = len(keys)
+            stale_tags = {k: server._cache[k][0] for k in keys}
+
+            async def frozen_read_tags(ks, **_kw):
+                return [stale_tags[k] for k in ks]
+
+            server.abd.read_tags = frozen_read_tags
+            other = AbdClient(
+                "proxy-ext4", server.abd.net, list(replicas),
+                AbdClientConfig(request_timeout=2.0),
+            )
+            await other.write_set(
+                keys[0], PROVIDER.encrypt_row([100], 1, ["PSSE"]))
+            batch_coords, singles = [], []
+            inner = server.abd.fetch_sets_attributed
+            orig = server.abd.fetch_set_attributed
+
+            async def batch(ks, exclude=(), deadline=None):
+                out = await inner(ks, exclude, deadline=deadline)
+                batch_coords.append(out[0][2])
+                return out
+
+            async def single(key, exclude=(), deadline=None):
+                out = await orig(key, exclude, deadline=deadline)
+                singles.append((key, tuple(exclude), out[2]))
+                return out
+
+            server.abd.fetch_sets_attributed = batch
+            server.abd.fetch_set_attributed = single
+            assert await _sum(server) == 100 + 22 + 33
+            assert len(batch_coords) == 1
+            assert [(k, ex) for k, ex, _ in singles] == [
+                (keys[0], (batch_coords[0],))]
+            assert singles[0][2] != batch_coords[0]
+            assert set(server._cache) == set(keys)      # no flush
+
+    asyncio.run(go())
+
+
+def test_under_a_read_lease_rereads_stay_single_reads():
+    """A lease read is one hop to the holder already: `_reread` batches
+    nothing where `lease_enabled` is set."""
+
+    async def go():
+        async with rest_stack() as (server, _, _):
+            vals = [1, 2, 3]
+            await _stored_rows(server, vals)
+            server.abd.cfg.lease_enabled = True
+            counter = _count_fetches(server)
+            assert await _sum(server) == sum(vals)
+            assert counter == {"n": 2, "rounds": 0}
+
+    asyncio.run(go())
+
+
+@pytest.mark.parametrize("name,steady,writer", [
+    ("quorum.reread_rounds_per_agg", 1.0, 1.0),
+    ("quorum.reread_written_back_share", 0.0, 25.0),
+])
+def test_the_yardsticks_reread_metrics_read_the_batch_counters(
+        name, steady, writer):
+    """`yardstick/layers/<name>.json` is data for a reducer the yardstick
+    has, listed for all six cells: one round an aggregate; no key written
+    back while the store stands still, and the share of keys the
+    coordinator wrote back (one of four here) once replicas disagree."""
+    import importlib
+    import os
+    from types import SimpleNamespace
+
+    from yardstick.run import Window
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "layers", f"{name}.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
+    assert {k: entry[k] for k in ("unit", "better", "moves", "layer")} == {
+        k: spec[k] for k in ("unit", "better", "moves", "layer")}
+    assert (spec["moves"], spec["layer"]) == ("agg_p50_ms", "quorum round")
+    reduce = importlib.import_module(
+        f"yardstick.reducers.{spec['reducer']}").reduce
+
+    async def go():
+        async with rest_stack(n=4, quorum=3) as (server, replicas, _):
+            server.abd.cfg.quorum_size = 3
+            vals = [1, 2, 3, 4]
+            keys = await _stored_rows(server, vals)
+            await server.abd.net.quiesce()
+            assert await _sum(server) == sum(vals)
+
+            async def window(n_aggs):
+                w = Window({}, "test", {})
+                w.open({name: spec})
+                try:
+                    for _ in range(n_aggs):
+                        assert await _sum(server) == sum(vals)
+                        w.ops.append(SimpleNamespace(kind="aggregate",
+                                                     status=200))
+                    return reduce(w, **spec["args"])
+                finally:
+                    w.close()
+
+            assert await window(3) == pytest.approx(steady)
+            # two of four replicas trail on one key: every quorum of three
+            # disagrees on it, and the audit reads all four keys
+            server.cfg.aggregate_cache_audit = len(keys)
+            for r in list(replicas.values())[:2]:
+                r.repository[keys[0]] = (M.ABDTag(0, r.name), None)
+            assert await window(1) == pytest.approx(writer)
 
     asyncio.run(go())
 

@@ -118,6 +118,81 @@ def test_read_fast_path_skips_write_phase_legally():
     assert wt.verdicts() == []
 
 
+def commit_batch(t, reads, read_replicas=(), write_replicas=(),
+                 coordinator="replica-0"):
+    """One committed batched read: root -> abd.fetch_batch (ok, its
+    `reads` facts per key) -> replica.handle children per phase."""
+    with t.span("http.SumAll"):
+        with t.span("abd.fetch_batch", k=len(reads), coordinator=coordinator,
+                    ok=True, op="read",
+                    reads=[[k, seq, tid] for k, seq, tid in reads]):
+            for r in read_replicas:
+                with t.span("replica.handle", replica=r, msg="ReadBatch"):
+                    pass
+            for r in write_replicas:
+                with t.span("replica.handle", replica=r, msg="WriteBatch"):
+                    pass
+
+
+@pytest.mark.parametrize("case,reads,writes,problems", [
+    ("settled", R7[:5], (), []),
+    ("written_back", R7[:5], R7[2:7], []),
+    ("no_read_quorum", R7[:3], (), ["read_phase=3<5"]),
+    ("forged_answer", (), (), ["read_phase=0<5"]),
+    ("write_back_short", R7[:5], R7[:2], ["write_phase=2<5"]),
+    ("phases_apart", R7[:5], R7[3:5] + ["replica-7", "replica-8",
+                                        "replica-9"], ["intersection=2<3"]),
+])
+def test_a_batched_reads_quorums_are_audited_once_for_all_its_keys(
+        case, reads, writes, problems):
+    wt, t = make_wt()
+    commit_batch(t, [("k1", 1, "replica-0"), ("k2", 4, "replica-3")],
+                 read_replicas=reads, write_replicas=writes)
+    vs = wt.verdicts()
+    assert [v.invariant for v in vs] == (
+        ["quorum_intersection"] if problems else [])
+    for v in vs:
+        assert v.detail["op"] == "abd.fetch_batch"
+        assert all(any(p in q for q in v.detail["problems"])
+                   for p in problems)
+    assert wt.stats()["ops_audited"] == 2   # one op a key
+
+
+@pytest.mark.parametrize("stale_key", ["k1", "k2", None])
+def test_every_key_of_a_batch_is_held_to_its_own_tag_history(stale_key):
+    """A batched read that returns a key at a tag below one committed
+    before it began is `tag_monotonicity` for THAT key, as a single
+    read's would be; the other keys of the batch are clean."""
+    wt, t = make_wt(check_quorum=False)
+    commit_op(t, "write", "k1", 5, "replica-1")
+    commit_op(t, "write", "k2", 7, "replica-2")
+    time.sleep(0.002)
+    seqs = {"k1": 5, "k2": 7}
+    if stale_key:
+        seqs[stale_key] = 2
+    commit_batch(t, [("k1", seqs["k1"], "replica-1"),
+                     ("k2", seqs["k2"], "replica-2")],
+                 coordinator="replica-6")
+    vs = wt.verdicts()
+    if stale_key is None:
+        assert vs == []
+    else:
+        assert [v.invariant for v in vs] == ["tag_monotonicity"]
+        assert vs[0].detail["key"] == stale_key
+        assert vs[0].detail["tag"][0] == 2
+        assert vs[0].detail["coordinator"] == "replica-6"
+    assert wt.stats()["ops_audited"] == 4
+
+
+def test_a_batch_without_its_facts_audits_nothing_and_flags_nothing():
+    wt, t = make_wt(check_quorum=False)
+    with t.span("abd.fetch_batch", k=2, coordinator="replica-0"):
+        pass                      # a failed attempt: no `ok`, never a commit
+    with t.span("abd.fetch_batch", k=2, ok=True, reads=[["k", "x"], 7, None]):
+        pass                      # facts of no shape are skipped, not raised on
+    assert wt.verdicts() == [] and wt.stats()["ops_audited"] == 0
+
+
 # ------------------------------------------------------- unit: tag ordering
 
 
@@ -337,6 +412,58 @@ def test_forged_tag_under_chaos_yields_exact_verdicts(tmp_path):
     idx = [json.loads(l) for l in open(tmp_path / "index.jsonl")]
     assert any(e["kind"] == "audit_tag_monotonicity"
                and e["trace_id"] == mono.trace_id for e in idx)
+
+
+async def _forged_batch_schedule(seed, attack: bool):
+    """The schedule above with the read an aggregate's batched re-read:
+    two keys written twice each, then one `IReadBatch` steered through
+    replica-6, Trudy's `StaleTagForger`."""
+    from dds_tpu.malicious.trudy import StaleTagForger
+
+    net, client, replicas = _chaos_cluster(seed, special_cls=StaleTagForger)
+    replicas["replica-6"].forging = attack
+    others = tuple(a for a in R7 if a != "replica-6")
+    try:
+        for k in ("KA", "KB"):
+            await client.write_set(k, ["v1"])
+            await client.write_set(k, ["v2"])
+        await asyncio.sleep(0.01)
+        out = await client.fetch_sets_attributed(["KA", "KB"], exclude=others)
+        assert {r[2] for r in out} == {"replica-6"}
+        if attack:
+            assert [r[0] for r in out] == [["stale"], ["stale"]]
+        else:
+            assert [r[0] for r in out] == [["v2"], ["v2"]]
+        await net.quiesce()
+    finally:
+        await net.stop()
+
+
+@pytest.mark.parametrize("attack", [True, False])
+def test_a_forged_batched_read_yields_a_verdict_a_key(attack):
+    """The auditor reads a batch's per-key facts: a forging coordinator's
+    stale tags are `tag_monotonicity` once for EACH key of the batch and
+    `quorum_intersection` once for the round no quorum served, all under
+    the batch's trace; the same schedule served honestly audits clean."""
+    wt = Watchtower(quorum_size=5, n_replicas=7)
+    wt.attach(tracer)
+    try:
+        run(_forged_batch_schedule(seed=23, attack=attack))
+    finally:
+        wt.detach()
+    vs = wt.verdicts()
+    if not attack:
+        assert vs == []
+        assert wt.stats()["ops_audited"] >= 6      # four writes, two reads
+        return
+    mono = [v for v in vs if v.invariant == "tag_monotonicity"]
+    assert sorted(v.detail["key"] for v in mono) == ["KA", "KB"]
+    assert all(v.detail["tag"] == [1, "forged"]
+               and v.detail["coordinator"] == "replica-6" for v in mono)
+    quorum = [v for v in vs if v.invariant == "quorum_intersection"]
+    assert len(quorum) == 1 and quorum[0].detail["op"] == "abd.fetch_batch"
+    assert {v.trace_id for v in vs} == {mono[0].trace_id}
+    assert len(vs) == 3
 
 
 def test_identical_schedule_without_attack_is_clean():

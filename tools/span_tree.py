@@ -15,7 +15,9 @@ file and prints, for the spans that ended inside the window:
   (a coroutine that is ready while another holds the loop waits there),
   and what is left as a share of the container's mean;
 - spans per completed `SumAll` (spans of the traces rooted in
-  `http.GET.SumAll`, over those roots);
+  `http.GET.SumAll`, over those roots), and the messages the replicas
+  handled (over sockets: the frames received) under such a trace, by
+  class, per `SumAll`;
 - the limb count and the product (`schoolbook`, `karatsuba1`, `cios`) the
   `kernel.fold` spans name, with their count;
 - the window's ledger of the event loop (`obs/runtime`: counters
@@ -223,6 +225,18 @@ def rest(path: str) -> dict:
     out["spans_per_sumall"] = (
         sum(1 for s in spans if s["trace_id"] in ids) / len(roots)
         if roots else None)
+    # messages the replicas handled under a SumAll's trace, by class: what
+    # one aggregate costs the loop in handlers (its tag round, its
+    # re-reads); over sockets, the frames received under it likewise
+    for key, span in (("handled_per_sumall", "replica.handle"),
+                      ("frames_per_sumall", "net.deserialize")):
+        by_msg: dict[str, int] = {}
+        for s in spans:
+            if s["name"] == span and s["trace_id"] in ids:
+                msg = str(s.get("meta", {}).get("msg"))
+                by_msg[msg] = by_msg.get(msg, 0) + 1
+        out[key] = ({m: n / len(roots) for m, n in sorted(by_msg.items())}
+                    if roots else None)
     return out
 
 
@@ -243,6 +257,13 @@ def main(argv: list[str]) -> int:
                   f"{100 * c['left_share']:.2f} %")
         for what, n in res["folds"].items():
             print(f"kernel.fold: {what} x {n}")
+        for what, key in (("messages handled", "handled_per_sumall"),
+                          ("frames received", "frames_per_sumall")):
+            per = res.get(key)
+            if per:
+                print(f"{what} a SumAll: {sum(per.values()):.2f} ("
+                      + ", ".join(f"{m} {n:.2f}" for m, n in per.items())
+                      + ")")
         led = res.get("ledger")
         if led:
             total = sum(v[0] for v in led["by_tenant"].values())
