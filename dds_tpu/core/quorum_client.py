@@ -8,9 +8,13 @@ nonce + increment, (b) the proxy HMAC over the reply, (c) the echoed key.
 Every protocol violation increments local suspicion on the coordinator
 (3 strikes excludes it permanently — `utils/TrustedNodesList.scala:23-29`)
 and raises a typed Byzantine exception; mere timeouts instead trip a
-per-coordinator circuit breaker (utils/retry.CircuitBreaker) that steers
+per-replica circuit breaker (utils/retry.CircuitBreaker) that steers
 the next picks elsewhere and self-heals via half-open probes, so replicas
 cut off by a (healed) partition regain coordination without a restart.
+The probe is the proxy's own (`AbdClient._probe_loop`): while a breaker is
+not closed one background task asks its target for the tags of no keys
+every `breaker_reset` seconds, and no user's request is routed to the
+target, nor is it asked in a tag round, while enough others allow.
 Callers may pass a `Deadline` so each attempt's timeout shrinks to the
 remaining request budget instead of a fixed 5 s per layer.
 
@@ -40,6 +44,7 @@ from dds_tpu.core.transport import Transport
 from dds_tpu.obs import context as obs_context
 from dds_tpu.obs.metrics import metrics
 from dds_tpu.utils.retry import CircuitBreaker, Deadline, DeadlineExceededError
+from dds_tpu.utils.tasks import supervised_task
 from dds_tpu.utils.trace import tracer
 from dds_tpu.utils import sigs
 from dds_tpu.utils.trust import TrustedNodesList
@@ -63,6 +68,8 @@ MAX_LATE_ROUNDS = 8
 # the vote of a replica whose vector equals the round's reference list:
 # it differs from it nowhere (see `_TagRound`)
 _SAME: dict = {}
+# the key set a probe asks the tags of (see `AbdClient._probe`)
+_NO_KEYS = sigs.key_from_set([])
 
 
 def _differing(old: list, new: list) -> list[int]:
@@ -139,11 +146,13 @@ class _TagRound:
     open for the votes of the others (`AbdClient._on_late_tag_reply`).
     `nonces` are the request nonces the round answers to: its own, and one
     more for each replica that said `KeySetUnknown` and was sent the keys;
-    `carried` is who has been sent the keys in this round (each once)."""
+    `carried` is who has been sent the keys in this round (each once);
+    `heard` is who of `asked` sent the round anything at all, whatever it
+    was worth: the others were silent (`AbdClient._close_late`)."""
 
     __slots__ = ("fut", "votes", "digest", "keys", "fingerprint", "ref",
                  "kept", "gen", "bases", "verify_ms", "kinds", "asked",
-                 "late", "nonces", "carried", "epoch")
+                 "late", "nonces", "carried", "epoch", "heard")
 
     def __init__(self, fut, digest, keys, fingerprint, ref, kept, bases,
                  asked, nonce, epoch):
@@ -153,6 +162,7 @@ class _TagRound:
         self.epoch = epoch
         self.carried: set[str] = set()
         self.late: set[str] = set()   # who answered after the quorum
+        self.heard: set[str] = set()
         self.votes: dict[str, dict] = {}
         self.digest, self.keys, self.fingerprint = digest, keys, fingerprint
         self.ref = ref
@@ -182,6 +192,10 @@ class AbdClientConfig:
     # a healed partition serve again without a proxy restart.
     breaker_threshold: int = 3
     breaker_reset: float = 2.0
+    # what the proxy's own probe of a target behind an open breaker waits
+    # for its one answer: a probe is nobody's request, so it has no reason
+    # to wait a user's `request_timeout`
+    breaker_probe_timeout: float = 1.0
     # Constellation shard label for this client's metric series (empty =
     # unsharded, series keep their historical label sets)
     shard: str = ""
@@ -220,8 +234,12 @@ class AbdClient:
         self.net = net
         self.cfg = config or AbdClientConfig()
         self.replicas = TrustedNodesList(replicas)
-        # coordinator addr -> CircuitBreaker (created on first failure path)
+        # replica addr -> CircuitBreaker (created on first failure path)
         self.breakers: dict[str, CircuitBreaker] = {}
+        # replica addr -> the task that probes it while its breaker is
+        # not closed (`_probe_loop`); probe nonce -> its one-replica round
+        self._probe_tasks: dict[str, asyncio.Task] = {}
+        self._probes: dict[int, _TagRound] = {}
         # challenge nonce -> (future, coordinator)
         self._pending: dict[int, tuple[asyncio.Future, str]] = {}
         self._preferred: list[str] = []  # supervisor's freshest-half view
@@ -268,6 +286,8 @@ class AbdClient:
                 self._on_tag_batch_reply(sender, msg)
             elif msg.nonce in self._late_tags:
                 self._on_late_tag_reply(sender, msg, self._late_tags[msg.nonce])
+            elif msg.nonce in self._probes:
+                self._on_probe_reply(sender, msg, self._probes[msg.nonce])
             return
         if isinstance(msg, M.KeySetUnknown):
             # correlated by REQUEST nonce, and ends HERE like a late vote:
@@ -330,6 +350,109 @@ class AbdClient:
             )
         return b
 
+    def _unsettled(self) -> tuple:
+        """The replicas whose breaker is open or half-open: whether one
+        is back is its probe's to find out (`_probe_loop`)."""
+        return tuple(n for n, b in self.breakers.items() if not b.settled)
+
+    def _breaker_failed(self, node: str) -> None:
+        """One breaker failure of `node`; a breaker this leaves open gets
+        its probe, unless it has one."""
+        b = self._breaker(node)
+        b.record_failure()
+        if b.settled:
+            return
+        task = self._probe_tasks.get(node)
+        if task is None or task.done():
+            task = self._probe_tasks[node] = supervised_task(
+                self._probe_loop(node, b),
+                name=f"abd.probe:{node.rsplit('/', 1)[-1]}")
+            task.add_done_callback(lambda t: self._probe_ended(node, t))
+
+    def _probe_ended(self, node: str, task: asyncio.Task) -> None:
+        if self._probe_tasks.get(node) is task:
+            del self._probe_tasks[node]
+
+    def _breaker_answered(self, node: str) -> None:
+        """A verified answer from `node`: whatever was held against it is
+        over. A replica nothing was ever held against has no breaker."""
+        b = self.breakers.get(node)
+        if b is not None:
+            b.record_success()
+
+    async def _probe_loop(self, target: str, b: CircuitBreaker) -> None:
+        """The proxy's own look whether `target` is back, for as long as
+        its breaker is not closed: every `breaker_reset` seconds (when the
+        breaker turns half-open) one probe; a verified answer closes the
+        breaker and ends the task, silence or a refused answer re-opens
+        it with a fresh timer. A replica struck out is nobody's to probe:
+        strikes do not heal. Whoever else resolves the breaker meanwhile
+        (the degraded try of a request that found every coordinator
+        refused, a vote that came late) is taken at its word."""
+        while not b.settled and target in self.replicas.get_trusted():
+            eta = b.half_open_eta()
+            if eta > 0:
+                await asyncio.sleep(eta)
+            elif await self._probe(target):
+                b.record_success()
+            elif b.state == b.HALF_OPEN:
+                b.record_failure()
+
+    async def _probe(self, target: str) -> bool:
+        """One authenticated request that costs an honest replica O(1):
+        the tags of no keys (`ReadTagBatch` over the empty set, to `target`
+        alone and under a nonce of its own), its answer MAC-verified as
+        any vote is. True for a verified answer within
+        `breaker_probe_timeout`."""
+        cfg = self.cfg
+        nonce = sigs.generate_nonce()
+        fut: asyncio.Future = asyncio.get_event_loop().create_future()
+        rnd = self._probes[nonce] = _TagRound(
+            fut, _NO_KEYS, (), None, None, None, {}, frozenset((target,)),
+            nonce, self._epoch())
+        outcome = "silent"
+        with tracer.span("abd.probe",
+                         target=target.rsplit("/", 1)[-1]) as meta:
+            try:
+                self.net.send(self.addr, target, M.ReadTagBatch(
+                    (), nonce,
+                    sigs.proxy_signature(cfg.proxy_mac_secret, _NO_KEYS,
+                                         nonce),
+                    None, rnd.epoch, None, _NO_KEYS, 0))
+                verified = await asyncio.wait_for(
+                    fut, cfg.breaker_probe_timeout)
+                outcome = "answered" if verified else "refused"
+            except asyncio.TimeoutError:
+                pass
+            finally:
+                del self._probes[nonce]
+            meta["ok"] = outcome == "answered"
+        metrics.inc(
+            "dds_breaker_probes_total", **self._mlabels(outcome=outcome),
+            help="the proxy's own probes of replicas behind an open "
+                 "breaker, by what came back",
+        )
+        return outcome == "answered"
+
+    def _on_probe_reply(self, sender: str, msg: M.TagBatchReply,
+                        rnd: _TagRound) -> None:
+        """The answer to a probe: a full reply over no keys under the
+        replica's MAC, or it is refused, and strikes its sender as a vote
+        that was waited for does."""
+        if sender not in rnd.asked or rnd.fut.done():
+            return
+        verified = self._vote_full(msg, rnd) is not None
+        if not verified:
+            self.replicas.increment_suspicion(sender)
+        rnd.fut.set_result(verified)
+
+    async def stop(self) -> None:
+        """End the probes: nothing of this client is left pending."""
+        tasks = list(self._probe_tasks.values())
+        for t in tasks:
+            t.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+
     def breaker_states(self) -> dict[str, str]:
         """Current breaker state per coordinator (for the /health route)."""
         return {n: b.state for n, b in sorted(self.breakers.items())}
@@ -363,7 +486,7 @@ class AbdClient:
             help="protocol violations observed per coordinator",
         )
         tracer.event("abd.coordinator_violation", node=coord)
-        self._breaker(coord).record_failure()
+        self._breaker_failed(coord)
 
     def _mlabels(self, **labels) -> dict:
         """Metric labels, plus the shard label when this client serves one
@@ -429,9 +552,19 @@ class AbdClient:
         blocked = tuple(n for n, b in self.breakers.items() if not b.allow())
         self._maybe_fast_fail(blocked, deadline, op)
         timeout = self._attempt_timeout(deadline)
-        coordinator = self.replicas.defer_to(
-            tuple(exclude) + blocked, prefer=self._preferred
-        )
+        unsettled = self._unsettled()
+        pick = self.replicas.defer_to
+        if all(n in unsettled for n in self.replicas.get_trusted()):
+            # nobody is settled: the degraded try, half-open first
+            coordinator = pick(tuple(exclude) + blocked,
+                               prefer=self._preferred)
+        else:
+            # a half-open breaker is its probe's to resolve, not a user's
+            # request's: open or half-open, the last choice of all
+            coordinator = pick(tuple(exclude) + unsettled,
+                               prefer=self._preferred)
+            if coordinator in unsettled:
+                coordinator = pick(unsettled, prefer=self._preferred)
         challenge = nonce + self.cfg.nonce_increment
         fut: asyncio.Future = asyncio.get_event_loop().create_future()
         self._pending[challenge] = (fut, coordinator)
@@ -450,12 +583,17 @@ class AbdClient:
                     ),
                     help="quorum rounds that timed out per coordinator",
                 )
+                metrics.inc(
+                    "dds_request_timeouts_total", **self._mlabels(op=op),
+                    help="requests that waited out a coordinator, by "
+                         "operation",
+                )
                 # transient unreachability: breaker only — the permanent
                 # suspicion counter is reserved for protocol violations, so
                 # a healed partition's replicas regain coordination without
                 # a restart (deviation from the reference, which struck on
                 # every timeout and could never un-strike)
-                self._breaker(coordinator).record_failure()
+                self._breaker_failed(coordinator)
                 raise
             metrics.observe(
                 "dds_quorum_rtt_seconds", time.perf_counter() - t0,
@@ -882,6 +1020,7 @@ class AbdClient:
             return self._on_late_tag_reply(sender, msg, rnd)
         if sender in rnd.votes:
             return
+        rnd.heard.add(sender)
         t0 = time.perf_counter()
         kind, vote = self._verify_vote(sender, msg, rnd)
         rnd.verify_ms += (time.perf_counter() - t0) * 1e3
@@ -891,6 +1030,7 @@ class AbdClient:
         rnd.votes[sender], attested = vote
         self._keep_vote(sender, attested, rnd.votes[sender], rnd)
         self._holds_keyset(sender, rnd)
+        self._breaker_answered(sender)
         rnd.kinds[kind] += 1
         if kind == "delta":
             metrics.inc(
@@ -930,6 +1070,8 @@ class AbdClient:
         nobody counts. A late vote that fails verification is not kept,
         and strikes nobody: it was not waited for."""
         kept = rnd.kept
+        if sender in rnd.asked:
+            rnd.heard.add(sender)   # whatever it is worth: not silent
         if (
             kept is None or kept.gen != rnd.gen or sender not in rnd.asked
             or sender in rnd.votes or sender in rnd.late
@@ -941,14 +1083,28 @@ class AbdClient:
             diff, attested = vote
             self._keep_vote(sender, attested, diff, rnd)
             self._holds_keyset(sender, rnd)
+            self._breaker_answered(sender)
             metrics.inc(
                 "dds_tag_round_late_votes_total", **self._mlabels(kind=kind),
                 help="ReadTagBatch votes verified and kept after their "
                      "round's quorum was met, by reply kind",
             )
         if len(rnd.votes) + len(rnd.late) >= len(rnd.asked):
-            for nonce in rnd.nonces:
-                self._late_tags.pop(nonce, None)
+            self._close_late(rnd)
+
+    def _close_late(self, rnd: _TagRound) -> None:
+        """A round's late window is over: everybody has answered, or
+        `MAX_LATE_ROUNDS` newer rounds have met their quorum since. Who of
+        the asked sent it nothing of any kind in all that time was silent,
+        which is one breaker failure, as a coordinator's timeout is; it
+        strikes nobody. A replica the proxy meets only as a participant
+        is found dead this way, then skipped and probed like any other."""
+        for nonce in rnd.nonces:
+            self._late_tags.pop(nonce, None)
+        silent = rnd.asked - rnd.heard
+        if silent:
+            for node in silent.intersection(self.replicas.get_trusted()):
+                self._breaker_failed(node)
 
     def _holds_keyset(self, sender: str, rnd: _TagRound) -> None:
         """A verified vote: its sender holds the keys of the round's
@@ -968,6 +1124,8 @@ class AbdClient:
         (first, or in answer to an earlier `unknown`) gets nothing more: it
         gives no vote this round, and is not struck. A forged or wrongly
         MAC'd `unknown` moves nothing."""
+        if sender in rnd.asked:
+            rnd.heard.add(sender)
         if (
             sender not in rnd.asked
             or msg.digest != rnd.digest
@@ -1204,7 +1362,18 @@ class AbdClient:
         the proxy one carried request to that replica per round (what
         every replica cost in every round before), never a vote of the
         honest quorum, and strikes nobody, since an honest replica that
-        was reseeded or evicted the set says it too, once."""
+        was reseeded or evicted the set says it too, once.
+
+        Who is asked follows the breakers: a trusted replica whose breaker
+        is open or half-open is left out of the round (`asked`) while the
+        others still number `quorum_size`, and everyone is asked when they
+        do not. The quorum a round needs does not move, so a skipped
+        replica lowers nothing: it could only have been a vote beyond the
+        first `quorum_size`, or none. A breaker opens on a coordinator's
+        timeouts (`_ask`) or on `breaker_threshold` rounds in a row that
+        met their quorum, kept their late window open to the end and
+        heard nothing at all from the replica (`_close_late`); a verified
+        vote, late or not, is a breaker success."""
         trusted = self.replicas.get_trusted()
         if len(trusted) < self.cfg.quorum_size:
             raise ByzUnknownReplyError(
@@ -1233,17 +1402,31 @@ class AbdClient:
                     bases = dict(kept.senders)
                 else:
                     kept, bases = None, {}
+                # a replica behind a breaker that is not closed is its
+                # probe's to ask, while the others can make a quorum: it
+                # is sent nothing, carried nothing, owed nothing late
+                unsettled = self._unsettled()
+                asked = [r for r in trusted if r not in unsettled]
+                if len(asked) < self.cfg.quorum_size:
+                    asked = trusted
+                elif len(asked) < len(trusted):
+                    metrics.inc(
+                        "dds_tag_round_skipped_total",
+                        len(trusted) - len(asked), **self._mlabels(),
+                        help="ReadTagBatch requests not sent to a replica "
+                             "behind an open breaker",
+                    )
                 rnd = self._pending_tags[nonce] = _TagRound(
                     fut, digest, keys, fingerprint,
                     cached_tags if kept is not None else None, kept, bases,
-                    frozenset(trusted), nonce, self._epoch())
+                    frozenset(asked), nonce, self._epoch())
                 # a replica that has answered for this digest holds its
                 # keys and is sent the digest alone; the others (first
                 # round, newly trusted, said `unknown`) are sent the keys
                 holders = self._holders_for(digest, trusted)
                 sig = sigs.proxy_signature(self.cfg.proxy_mac_secret, digest,
                                            nonce)
-                for replica in trusted:
+                for replica in asked:
                     self._request_tags(rnd, replica, nonce, sig,
                                        carry=replica not in holders)
                 votes = await asyncio.wait_for(fut, timeout)
@@ -1282,7 +1465,8 @@ class AbdClient:
                 ):
                     # quorum met, replies still owed: open for them a while
                     while len(self._late_tags) >= MAX_LATE_ROUNDS:
-                        del self._late_tags[next(iter(self._late_tags))]
+                        self._close_late(
+                            self._late_tags[next(iter(self._late_tags))])
                     for owed in rnd.nonces:
                         self._late_tags[owed] = rnd
 

@@ -643,6 +643,7 @@ class DDSRestServer:
             self._keys_saver = None
         if self._keys_dirty:
             self._write_keys_snapshot()  # flush pending mutations on shutdown
+        await self.abd.stop()   # its breakers' probes
         await self._http.stop()
 
     # ------------------------------------------------- stored_keys recovery

@@ -493,6 +493,7 @@ async def _launch(cfg: DDSConfig) -> Deployment:
             quorum_size=cfg.replicas.byz_quorum_size,
             breaker_threshold=cfg.proxy.breaker_threshold,
             breaker_reset=cfg.proxy.breaker_reset,
+            breaker_probe_timeout=cfg.proxy.breaker_probe_timeout,
             fast_fail_all_open=cfg.admission.fast_fail,
         ),
     )
@@ -665,6 +666,7 @@ def shard_configs(cfg: DDSConfig):
         quorum_size=sh.quorum_size,
         breaker_threshold=cfg.proxy.breaker_threshold,
         breaker_reset=cfg.proxy.breaker_reset,
+        breaker_probe_timeout=cfg.proxy.breaker_probe_timeout,
         fast_fail_all_open=cfg.admission.fast_fail,
         # Atlas read-local lease client knobs ([geo]); region + per-group
         # lease_ttl/replica_regions are stamped by the constellation

@@ -318,6 +318,10 @@ class ShardRouter:
         positive = [e for e in etas if e > 0]
         return min(positive) if positive else None
 
+    async def stop(self) -> None:
+        for c in self.clients.values():
+            await c.stop()
+
     def refresh_from(self, supervisor: str | None = None) -> None:
         """Refresh every group from ITS OWN supervisor (pinned on each
         client's config at build time); the argument — the single

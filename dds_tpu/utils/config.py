@@ -122,10 +122,13 @@ class ProxySettings:
     retry_max_delay: float = 2.0
     retry_after_hint: float = 1.0
     handler_timeout: float = 0.0       # miniserver backstop, 0 = off
-    # per-coordinator circuit breaker (transient-failure steering that
-    # self-heals after breaker_reset seconds via a half-open probe)
+    # per-replica circuit breaker (transient-failure steering that
+    # self-heals via a half-open probe): the probe is the proxy's own,
+    # sent every breaker_reset seconds while the breaker is not closed,
+    # and waits breaker_probe_timeout for its one answer
     breaker_threshold: int = 3
     breaker_reset: float = 2.0
+    breaker_probe_timeout: float = 1.0
     key_sync_enabled: bool = False
     key_sync_warm_up: float = 1.0
     key_sync_interval: float = 5.0

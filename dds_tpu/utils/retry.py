@@ -15,7 +15,9 @@ needs under adversarial schedules:
   signal the REST layer maps to 503 + Retry-After instead of hanging.
 - `CircuitBreaker`: per-target closed/open/half-open state. Transient
   unreachability (timeouts) belongs here — it self-heals via the
-  half-open probe once the target returns — while cryptographic protocol
+  half-open probe once the target returns (the probe is its owner's:
+  `core/quorum_client.AbdClient` sends it, no user's request does while
+  another target allows) — while cryptographic protocol
   violations stay on the PERMANENT 3-strike suspicion counter
   (`utils/trust.TrustedNodesList`). Splitting the two is what lets a
   fully-partitioned cluster serve again after heal without a restart.
@@ -172,8 +174,13 @@ class CircuitBreaker:
 
     Guards a single target (one coordinator). Transient-failure state only:
     it self-heals, unlike the permanent `TrustedNodesList` strikes reserved
-    for cryptographic protocol violations. Half-open deliberately admits
-    concurrent probes (no single-probe token): the first recorded outcome
+    for cryptographic protocol violations. Half-open is the state in which
+    somebody has to look: the breaker's owner does (`AbdClient` keeps one
+    background probe per breaker that is not closed and records its
+    outcome here), and routes its users around the target meanwhile
+    (`settled`). Half-open still admits concurrent probes (no single-probe
+    token): where every target is refused a user's request is the
+    degraded try beside the owner's probe, the first recorded outcome
     resolves the state, and a duplicate probe against a healed target is
     harmless while a probe token leaked to a never-chosen candidate would
     wedge the breaker."""
@@ -228,6 +235,13 @@ class CircuitBreaker:
         """May the caller route a request at this target right now?"""
         self._maybe_half_open()
         return self._state != self.OPEN
+
+    @property
+    def settled(self) -> bool:
+        """Closed: nothing is held against the target. Open or half-open,
+        whether it is back is the owner's probe's to find out, not a
+        user's request's while another target is settled."""
+        return self.state == self.CLOSED
 
     def half_open_eta(self) -> float:
         """Seconds until this breaker's next half-open probe (0 when it is

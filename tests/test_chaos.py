@@ -310,6 +310,93 @@ def test_timeouts_trip_breaker_not_permanent_suspicion():
     run(go())
 
 
+@pytest.mark.parametrize("when", ["open", "half_open"])
+def test_a_cut_off_coordinator_is_no_users_to_try_while_another_stands(when):
+    """replica-0 is cut off from the proxy and its breaker opens on three
+    timeouts. From then on, open or half-open, every request goes through
+    replica-1; what the proxy sends replica-0 is its own probe (the tags of
+    no keys), which closes the breaker after the heal with no user's
+    request spent on finding out."""
+
+    async def go():
+        from tests.test_core import Cluster
+
+        net = ChaosNet(InMemoryNet(), seed=9)
+        c = Cluster(net=net)
+        cfg = c.client.cfg
+        cfg.request_timeout, cfg.breaker_reset = 0.1, 0.15
+        cfg.breaker_probe_timeout = 0.05
+        c.client._preferred = ["replica-0", "replica-1"]
+        sent = []
+
+        async def note(msg):
+            sent.append(msg)
+            return msg
+
+        net.inner.link_filters[("proxy-0", "replica-0")] = note
+        p = net.partition(["proxy-0"], ["replica-0"])
+        await c.client.write_set("K", ["row"])
+        b = c.client._breaker("replica-0")
+        for _ in range(40):
+            if not b.settled:
+                break
+            try:
+                await c.client.fetch_set("K")
+            except asyncio.TimeoutError:
+                pass
+        assert b.state == CircuitBreaker.OPEN
+        assert c.client.replicas._strikes["replica-0"] == 0
+        if when == "half_open":
+            await asyncio.sleep(0.16)
+            assert b.state == CircuitBreaker.HALF_OPEN
+        for _ in range(20):
+            assert await c.client.fetch_set("K") == ["row"]
+            assert (await c.client.fetch_sets_attributed(["K"]))[0][2] == (
+                "replica-1")
+        assert not b.settled
+        p.heal()
+        del sent[:]
+        for _ in range(40):
+            if b.settled:
+                break
+            await asyncio.sleep(0.02)
+        assert b.state == CircuitBreaker.CLOSED
+        # found by the probe: nothing of a user's went there meanwhile
+        assert sent and all(isinstance(m, M.ReadTagBatch) and m.count == 0
+                            for m in sent)
+        assert c.client._probe_tasks == {}
+        picked = {(await c.client.fetch_set_attributed("K"))[2]
+                  for _ in range(40)}
+        assert picked == {"replica-0", "replica-1"}      # coordinates again
+        await c.client.stop()
+
+    run(go())
+
+
+def test_an_audit_that_excludes_the_rest_still_avoids_the_open_breaker():
+    """`exclude` (the audit's "another coordinator, please") and the open
+    breakers can together name everyone: a blocked coordinator is then the
+    last choice, after the one the caller wanted to avoid."""
+
+    async def go():
+        from tests.test_core import Cluster
+
+        c = Cluster(n_active=4, n_sentinent=0, quorum=3)
+        c.client.cfg.breaker_reset = 30.0
+        await c.client.write_set("K", ["row"])
+        for _ in range(3):
+            c.client._breaker_failed("replica-3")
+        assert not c.client._breaker("replica-3").settled
+        others = ("replica-0", "replica-1", "replica-2")
+        for _ in range(12):
+            _, _, coord = await c.client.fetch_set_attributed(
+                "K", exclude=others)
+            assert coord in others
+        await c.client.stop()
+
+    run(go())
+
+
 # ------------------------------------- REST graceful degradation end-to-end
 
 

@@ -221,3 +221,68 @@ def test_breaker_success_resets_consecutive_failure_count():
         b.record_failure()
         b.record_success()  # CONSECUTIVE failures trip, interleaved don't
     assert b.state == CircuitBreaker.CLOSED
+
+
+# ------------------------------- half-open has an owner (core/quorum_client)
+
+
+@pytest.mark.parametrize("state", ["closed", "open", "half_open"])
+def test_breaker_is_settled_only_when_closed(state):
+    """`allow()` is what the degraded try asks (half-open admits it);
+    `settled` is what routing asks while another target stands: open or
+    half-open, the target is its owner's probe's to resolve."""
+    clock = FakeClock()
+    b = CircuitBreaker(failure_threshold=1, reset_timeout=1.0, clock=clock)
+    if state != "closed":
+        b.record_failure()
+    if state == "half_open":
+        clock.advance(1.0)
+    assert b.state == state
+    assert b.settled is (state == "closed")
+    assert b.allow() is (state != "open")
+    assert (b.half_open_eta() > 0) is (state == "open")
+
+
+@pytest.mark.parametrize("answers", [[True], [False, True],
+                                     [False, False, False, True]])
+def test_an_owners_probe_walks_the_legal_transitions_only(answers):
+    """The owner's loop as `AbdClient._probe_loop` runs it, on a fake
+    clock: wait out `half_open_eta`, probe, record. Every transition is one
+    `obs/watchtower.breaker_legality` allows, a failed probe restarts the
+    timer, the first verified answer closes."""
+    from dds_tpu.utils.trace import tracer
+
+    clock = FakeClock()
+    b = CircuitBreaker(failure_threshold=3, reset_timeout=2.0, clock=clock,
+                       name="replica-9")
+    moves = []
+
+    def on(rec):
+        if rec.name.startswith("breaker.") and rec.meta["target"] == b.name:
+            moves.append(rec.name.split(".", 1)[1])
+
+    tracer.subscribe(on)
+    try:
+        for _ in range(3):
+            b.record_failure()
+        waited = []
+        for ok in answers:
+            assert not b.settled
+            eta = b.half_open_eta()
+            waited.append(eta)
+            clock.advance(eta)
+            assert b.half_open_eta() == 0 and b.state == b.HALF_OPEN
+            clock.advance(1.0)               # the probe's own wait
+            b.record_success() if ok else b.record_failure()
+        assert b.settled
+    finally:
+        tracer.unsubscribe(on)
+    assert waited == [2.0] * len(answers)    # a fresh timer each time
+    want = ["open", "half_open"] * len(answers) + ["closed"]
+    assert moves == want
+    legal = {"closed": {"open"}, "open": {"half_open", "closed"},
+             "half_open": {"open", "closed"}}
+    state = "closed"
+    for to in moves:
+        assert to in legal[state]
+        state = to

@@ -69,7 +69,11 @@ class Bench:
 
     def __init__(self, n=5, quorum=3, k=40):
         self.c = Cluster(n_active=n, n_sentinent=0, quorum=quorum)
-        cfg = AbdClientConfig(request_timeout=1.0, quorum_size=quorum)
+        # replies are lost here at random, round by round, to choose who
+        # votes: not the silence of a dead replica, which a breaker would
+        # answer by asking fewer (tests/test_tag_round_keyset.py holds that)
+        cfg = AbdClientConfig(request_timeout=1.0, quorum_size=quorum,
+                              breaker_threshold=10**6)
         self.client = Voters("proxy-t", self.c.net, self.c.active, cfg)
         self.reference = Voters("proxy-ref", self.c.net, self.c.active, cfg)
         self.keys = [f"key-{i:03d}" for i in range(k)]

@@ -843,3 +843,146 @@ def test_the_request_bytes_metric_finds_named_and_carried_frames_alike():
             w.close()
 
     rigged(body, kind="tcp")
+
+
+# ------------------- who is asked follows the breakers (ISSUE 42, points 2, 3)
+
+
+def skipped() -> float:
+    return metrics.value("dds_tag_round_skipped_total") or 0.0
+
+
+async def _rounds(rig, keys, cached, n):
+    """n rounds as the server makes them (digest and fingerprint), each
+    settled; returns the requests each replica was sent, round by round."""
+    digest, fp = sigs.key_from_set(keys), sigs.tags_fingerprint(cached)
+    out = []
+    for _ in range(n):
+        del rig.asked[:]
+        got = await rig.client.read_tags(keys, digest=digest, fingerprint=fp,
+                                         cached_tags=cached)
+        await rig.settle()
+        assert got is cached
+        out.append({a: m for a, m in rig.asked})
+    return out
+
+
+@pytest.mark.parametrize("silent", ["replica-0", "replica-3"])
+def test_a_silent_replica_is_found_by_its_silence_then_skipped(silent):
+    """A replica that answers no round: asked (and carried the K keys,
+    being no holder) until `breaker_threshold` rounds have met their
+    quorum, kept their late window open `MAX_LATE_ROUNDS` deep and closed
+    it without a word; then its breaker is open and it is sent nothing.
+    Three verified votes make every round, before and after; nobody is
+    struck."""
+    async def body(rig):
+        keys = [f"{i:0128x}" for i in range(64)]
+        seeded(keys, rig)
+        cached = rig.fresh_max(keys, rig.addrs)
+        rig.net.unregister(silent)
+        client = rig.client
+        before, sent = skipped(), requests_sent()
+        rounds = await _rounds(
+            rig, keys, cached,
+            qc.MAX_LATE_ROUNDS + client.cfg.breaker_threshold - 1)
+        assert silent not in client.breakers or client.breakers[silent].settled
+        assert skipped() == before
+        rounds += await _rounds(rig, keys, cached, 1)
+        # the eleventh closed the third silent window: open from now on
+        assert client.breakers[silent].state == "open"
+        assert sorted(client._probe_tasks) == [silent]
+        assert len(client._late_tags) <= qc.MAX_LATE_ROUNDS
+        first = since(sent, requests_sent())
+        assert first["carried"] == 3 + len(rounds)      # never a holder
+        sent = requests_sent()
+        after = await _rounds(rig, keys, cached, 5)
+        assert all(set(r) == set(rig.addrs) - {silent} for r in after)
+        assert since(sent, requests_sent()) == {"named": 15.0}
+        assert skipped() - before == 5
+        assert rig.strikes() == {}
+        await client.stop()
+
+    rigged(body)
+
+
+def test_a_replica_that_answers_last_is_never_taken_for_silent():
+    """Three votes make the quorum and the fourth comes after it, every
+    round: a verified late vote is a breaker success, so nothing opens."""
+    async def body(rig):
+        keys = [f"{i:0128x}" for i in range(64)]
+        seeded(keys, rig)
+        cached = rig.fresh_max(keys, rig.addrs)
+        before = skipped()
+        rounds = await _rounds(rig, keys, cached, 3 * qc.MAX_LATE_ROUNDS)
+        assert all(len(r) == 4 for r in rounds)
+        assert all(b.settled for b in rig.client.breakers.values())
+        assert rig.client._probe_tasks == {} and skipped() == before
+        assert rig.client._late_tags == {}
+
+    rigged(body)
+
+
+@pytest.mark.parametrize("open_", [1, 2])
+def test_a_skipped_replica_lowers_no_quorum(open_):
+    """With one breaker open the other three are asked and all three must
+    vote; with two open fewer than a quorum would be left, so everyone is
+    asked, as before."""
+    async def body(rig):
+        keys = [f"{i:0128x}" for i in range(32)]
+        seeded(keys, rig)
+        cached = rig.fresh_max(keys, rig.addrs)
+        await _rounds(rig, keys, cached, 1)
+        client = rig.client
+        client.cfg.breaker_reset = 30.0
+        down = rig.addrs[-open_:]
+        for a in down:
+            for _ in range(client.cfg.breaker_threshold):
+                client._breaker_failed(a)
+        before = skipped()
+        (asked,) = await _rounds(rig, keys, cached, 1)
+        if open_ == 1:
+            assert set(asked) == set(rig.addrs) - set(down)
+            assert skipped() - before == 1
+            # the three are needed, all of them: one more silent and the
+            # round fails as it must
+            rig.net.unregister(rig.addrs[0])
+            client.cfg.request_timeout = 0.2
+            with pytest.raises(asyncio.TimeoutError):
+                await client.read_tags(keys, fingerprint=sigs.tags_fingerprint(
+                    cached), cached_tags=cached)
+        else:
+            assert set(asked) == set(rig.addrs)
+            assert skipped() == before
+        await client.stop()
+
+    rigged(body)
+
+
+def test_a_replica_back_behind_a_closed_breaker_is_taught_the_keys_once():
+    async def body(rig):
+        keys = [f"{i:0128x}" for i in range(32)]
+        seeded(keys, rig)
+        cached = rig.fresh_max(keys, rig.addrs)
+        client = rig.client
+        client.cfg.breaker_reset, client.cfg.breaker_probe_timeout = 0.1, 0.1
+        gone = rig.addrs[2]
+        handler = rig.net._handlers[gone]
+        rig.net.unregister(gone)
+        for _ in range(client.cfg.breaker_threshold):
+            client._breaker_failed(gone)
+        (asked,) = await _rounds(rig, keys, cached, 1)
+        assert gone not in asked
+        rig.net.register(gone, handler)                 # it is back
+        for _ in range(100):
+            if client.breakers[gone].settled:
+                break
+            await asyncio.sleep(0.02)
+        assert client.breakers[gone].state == "closed"
+        assert client._probe_tasks == {}
+        sent = requests_sent()
+        rounds = await _rounds(rig, keys, cached, 3)
+        assert all(len(r) == 4 for r in rounds)
+        assert [len(r[gone].keys) for r in rounds] == [32, 0, 0]
+        assert since(sent, requests_sent()) == {"named": 11.0, "carried": 1.0}
+
+    rigged(body)
