@@ -107,7 +107,7 @@ def paillier_fold_one(bits: int, K: int, repeats: int = 3,
     )
     detail = dict(
         K=K, limbs=L, product=product,
-        lane_tile=mont_mxu._tb_for(L // 2 if product == "karatsuba1" else L),
+        lane_tile=mont_mxu.lane_tile(L),
         fold_ms=round(fold_s * 1e3, 4),
     )
     if ladder:

@@ -54,10 +54,10 @@ def roofline(L: int, vpu_rate: float):
       bookkeeping — the measured chain rate already includes one mask per
       multiply, so the bound charges L^2 / chain_rate);
     - REDC: two int8 band matmuls over L8=2L base-2^8 digits:
-      L8^2 + 2*L8^2 = 3*(2L)^2 = 12 L^2 int8 MACs (x2 for the
-      signed/mask split) on the MXU;
-    - carry normalization: ~5 full-width Kogge-Stone passes, bandwidth-
-      bound — not charged (the floor is compute-optimistic).
+      L8^2 + 2*L8^2 = 3*(2L)^2 = 12 L^2 int8 MACs on the MXU (balanced
+      digits since PR 43: one matmul a band, no signed/mask split);
+    - carry normalization: 4 full-width Kogge-Stone passes on the VPU, in
+      the multiply's kernel — not charged (the floor is compute-optimistic).
     """
     rng = np.random.default_rng(3)
     Mi = jnp.asarray(rng.integers(-128, 127, size=(4 * L, 2 * L), dtype=np.int8))
@@ -69,7 +69,7 @@ def roofline(L: int, vpu_rate: float):
 
     mxu_rate = (4 * L * 2 * L * 4096) / timeit(mm, Mi, Vi)  # int8 MAC/s
 
-    floor_s = (L * L) / vpu_rate + (2 * 12 * L * L) / mxu_rate
+    floor_s = (L * L) / vpu_rate + (12 * L * L) / mxu_rate
     return mxu_rate, floor_s
 
 

@@ -3,7 +3,7 @@
 Two Montgomery multiply families exist: "jnp" (ops/montgomery, the portable
 scan kernels: the CPU path and the tests' reference) and "v2" (ops/mont_mxu,
 Pallas product, schoolbook or one Karatsuba level by limb count, + MXU
-band-matmul REDC: what a TPU serves). Callers
+band-matmul REDC with its digit work in Pallas kernels: what a TPU serves). Callers
 (models/backend, ops/foldmany, parallel/mesh, resident/plane, ops/predicate)
 ask here for the multiply of a family, the two fold-tree shapes, the fold's
 domain fix-up, the interpret probe and the bounded cache of jitted callables;

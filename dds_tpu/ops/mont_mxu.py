@@ -16,14 +16,23 @@ and exploits that the *modulus is shared across the batch*:
   N = n), so both products become matmuls against precomputed Toeplitz
   band matrices of the modulus digits in base 2^8 — int8 MXU work that is
   ~free next to the VPU product;
-- carry normalization between stages is Kogge-Stone carry-lookahead in
-  plain XLA: O(log L) full-width vector passes instead of an O(L)
-  sequential carry scan.
+- the digit work around the two matmuls (carry normalization by
+  Kogge-Stone carry-lookahead, O(log L) full-width vector passes instead
+  of an O(L) sequential carry scan; the 16 <-> 8-bit digit splits and
+  merges; the conditional subtract) and the matmuls themselves run in the
+  kernel that made the product, on the lane tile it left in VMEM: a
+  multiply is ONE device program and nothing but its operands and its
+  result crosses HBM (until PR 43 the reduction was about 120 XLA
+  operations a multiply, 4.75 of a 10.8 ms fold at L = 512; PERF.md
+  section 6).
 
-int8 matmuls need inputs in [-128, 127]; digit vectors/matrices live in
-[0, 255], so both are split as x = x' + 128*mask (x' signed, mask the 0/1
-support): M @ d = M'@d' + 128*(mask_M@d') + (128*M'@1 + 2^14*mask_M@1),
-i.e. two int8 matmuls plus a precomputed per-row constant.
+int8 matmuls need inputs in [-128, 127]. The modulus' constants are held
+in balanced base-2^8 digits, which are that as they stand; the varying
+operand's bytes live in [0, 255] and go in less 128, M @ d = M @ (d - 128)
++ 128 * (M @ 1): one int8 matmul a band plus a per-row constant of the
+modulus, which also carries a lift that keeps every output digit
+non-negative (`_band`, `_lift`). The matrices' rows and columns are
+ordered so that the kernel never interleaves digits.
 
 The product is chosen from the limb count L, a static shape at trace time
 (`product_for`): plain schoolbook below `KARATSUBA_MIN_L`, one level of
@@ -55,13 +64,22 @@ from dds_tpu.ops.montgomery import ModCtx
 
 LIMB_BITS = bn.LIMB_BITS          # 16
 MASK16 = np.uint32(0xFFFF)
-MASK8 = np.int32(0xFF)
+MASK8 = np.uint32(0xFF)
 
 # lane tile for the product kernel: swept on a real v5e chip at L=256 —
 # 128 lanes beat 256/512/1024 by ~3-10% (smaller tiles keep the (2L, TB)
 # accumulator and operand blocks comfortably in VMEM)
 PROD_TB = 128
 GROUP = 8                         # a-limbs per aligned accumulator update
+# limb rows every kernel of a multiply runs at are a multiple of this: the
+# Karatsuba split needs halves of GROUP rows, and the (2L, TB) int8 operand
+# of a band product whole (32, 128) tiles
+ROWS = 2 * GROUP
+
+
+def _rows(L: int) -> int:
+    """The limb rows a multiply of L limbs runs at: L rounded up to ROWS."""
+    return -(-L // ROWS) * ROWS
 
 
 def _tb_for(L: int) -> int:
@@ -109,7 +127,7 @@ def product_for(L: int) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Pallas schoolbook product: (L, TB) x (L, TB) canonical -> (2L, TB) redundant
+# Schoolbook accumulation: (rows, TB) x (rows, TB) canonical -> redundant
 # ---------------------------------------------------------------------------
 
 
@@ -142,69 +160,8 @@ def _accumulate_prod(a_read, b, acc_ref, rows: int, TB: int) -> None:
     jax.lax.fori_loop(0, rows // GROUP, body, 0)
 
 
-def _make_prod_kernel(L: int, TB: int):
-    """T = a*b as redundant base-2^16 digits, limbs-major (see
-    _accumulate_prod for the scheme + digit bounds)."""
-    Lacc = 2 * L + GROUP  # top pad so every (L+GROUP)-row update fits
-
-    def kernel(a_ref, b_ref, out_ref, acc_ref):
-        acc_ref[:, :] = jnp.zeros((Lacc, TB), jnp.uint32)
-        _accumulate_prod(
-            lambda i: a_ref[pl.ds(i, 1), :], b_ref[:, :], acc_ref, L, TB
-        )
-        out_ref[:, :] = acc_ref[0 : 2 * L, :]
-
-    return kernel
-
-
-@functools.lru_cache(maxsize=None)
-def _prod_call(L: int, B: int, TB: int, interpret: bool):
-    kernel = _make_prod_kernel(L, TB)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // TB,),
-        in_specs=[
-            pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((2 * L, TB), lambda i: (0, i), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((2 * L, B), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((2 * L + GROUP, TB), jnp.uint32)],
-        interpret=interpret,
-    )
-
-
-def _pad_lanes(x, TB: int):
-    B = x.shape[1]
-    Bp = max(TB, ((B + TB - 1) // TB) * TB)
-    if Bp != B:
-        x = jnp.pad(x, ((0, 0), (0, Bp - B)))
-    return x, B
-
-
-def prod_lm(a, b, TB: int | None = None, interpret: bool | None = None):
-    """Full product of canonical limbs-major operands: (L,B)x(L,B)->(2L,B).
-
-    Handles any L: operands are zero-padded on the limb axis to a multiple
-    of GROUP for the kernel (zero top limbs don't change the value) and the
-    output is sliced back to 2L rows (the padded product's top rows are
-    provably zero). TB=None picks the measured per-L lane tile (_tb_for)."""
-    if interpret is None:
-        interpret = interpret_default()
-    L = a.shape[0]
-    if TB is None:
-        TB = _tb_for(L)
-    Lp = ((L + GROUP - 1) // GROUP) * GROUP
-    if Lp != L:
-        a = jnp.pad(a, ((0, Lp - L), (0, 0)))
-        b = jnp.pad(b, ((0, Lp - L), (0, 0)))
-    a, B = _pad_lanes(a, TB)
-    b, _ = _pad_lanes(b, TB)
-    return _prod_call(Lp, a.shape[1], TB, interpret)(a, b)[: 2 * L, :B]
-
-
 # ---------------------------------------------------------------------------
-# XLA carry normalization (Kogge-Stone) in base 2^16 or 2^8
+# Carry normalization (Kogge-Stone) in base 2^16, inside the kernel
 # ---------------------------------------------------------------------------
 
 
@@ -213,63 +170,69 @@ def _shift_up(x, k: int):
     return jnp.pad(x, ((k, 0), (0, 0)))[: x.shape[0]]
 
 
-def carry_norm(x, bits: int = 16, in_kernel: bool = False):
-    """Redundant digits (u32, < 2^31) -> (canonical digits, carry_out).
+def _at_row0(v, rows: int):
+    """(1, TB) `v` as a (rows, TB) addend that is `v` in row 0, else 0."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, v.shape[1]), 0)
+    return jnp.where(row == 0, v, jnp.uint32(0))
 
-    x: (rows, B) base-2^bits digits, row 0 least significant. Returns
-    canonical digits (< 2^bits) and the (1, B) u32 value carried out past
-    the top row. Three local extract passes bound the pending carries to
-    one bit; a Kogge-Stone generate/propagate prefix scan resolves the
-    remaining ripple in log2(rows) passes.
 
-    `in_kernel`: inside a Pallas kernel generate/propagate are held as 0/1
-    u32 digits instead of booleans (Mosaic cannot shift an i1 vector along
-    sublanes: `tpu.bitcast_vreg i1 -> i32`, PR 21); the same values, and
-    the XLA form's trace is untouched.
-    """
-    mask = jnp.uint32((1 << bits) - 1)
+def carry_norm(x, passes: int = 1):
+    """Redundant base-2^16 digits (u32) -> (canonical digits, carry_out).
+
+    x: (rows, B), row 0 least significant. Returns canonical digits
+    (< 2^16) and the (1, B) u32 value carried out past the top row. One
+    local extract pass takes any u32 digit below 2^17 (0xFFFF + 0xFFFF);
+    `passes=0` is for digits already there (a sum of two canonical digits
+    and a 1). What is left is a single-bit ripple, which a Kogge-Stone
+    generate/propagate prefix scan resolves in log2(rows) steps.
+
+    Generate/propagate are held as 0/1 u32 digits, not booleans: Mosaic
+    cannot shift an i1 vector along sublanes (`tpu.bitcast_vreg i1 -> i32`,
+    PR 21). Plain jnp, so the same function runs in a kernel, in interpret
+    mode and under the tests."""
     x = x.astype(jnp.uint32)
     rows = x.shape[0]
     carry_out = jnp.zeros((1, x.shape[1]), jnp.uint32)
-    for _ in range(3):
-        c = x >> bits
-        x = (x & mask) + _shift_up(c, 1)
+    for _ in range(passes):
+        c = x >> LIMB_BITS
+        x = (x & MASK16) + _shift_up(c, 1)
         carry_out = carry_out + c[-1:]
-    # x <= mask + 1 now; resolve the single-bit ripple with carry-lookahead
-    c = x >> bits
-    s = x & mask
+    c = x >> LIMB_BITS                            # 0/1: x < 2^17
     carry_out = carry_out + c[-1:]
-    a = _shift_up(c, 1)                       # pending +1s
-    s = s + a                                 # <= mask + 1
-    if in_kernel:
-        g = s >> bits
-        p = jnp.where(s == mask, jnp.uint32(1), jnp.uint32(0))
-    else:
-        g = s > mask
-        p = s == mask
+    s = (x & MASK16) + _shift_up(c, 1)            # <= 2^16
+    g = s >> LIMB_BITS
+    p = jnp.where(s == MASK16, jnp.uint32(1), jnp.uint32(0))
     k = 1
     while k < rows:
         g = g | (p & _shift_up(g, k))
-        p = p & _shift_up(p, k)
         k *= 2
-    cin = _shift_up(g.astype(jnp.uint32), 1)
-    carry_out = carry_out + g[-1:].astype(jnp.uint32)
-    return (s + cin) & mask, carry_out
+        if k < rows:                              # the last step reads no p
+            p = p & _shift_up(p, k // 2)
+    carry_out = carry_out + g[-1:]
+    return (s + _shift_up(g, 1)) & MASK16, carry_out
 
 
 # ---------------------------------------------------------------------------
-# One Karatsuba level, in one kernel: three half-width products + recombination
+# The two products: (L, TB) x (L, TB) canonical -> (2L, TB) redundant, in VMEM
 # ---------------------------------------------------------------------------
 
 
-def _make_karatsuba1_kernel(h: int, TB: int):
+def _product_schoolbook(a_ref, b_ref, t_ref, L: int, TB: int) -> None:
+    """T = a*b as redundant base-2^16 digits in t_ref's rows 0..2L (see
+    _accumulate_prod for the scheme + digit bounds); t_ref has GROUP rows
+    more, so that every (L+GROUP)-row update fits."""
+    t_ref[:, :] = jnp.zeros((2 * L + GROUP, TB), jnp.uint32)
+    _accumulate_prod(lambda i: a_ref[pl.ds(i, 1), :], b_ref[:, :], t_ref, L, TB)
+
+
+def _product_karatsuba1(a_ref, b_ref, t_ref, acc_ref, sa_ref, L: int, TB: int) -> None:
     """T = a*b for (2h, TB) canonical operands by one Karatsuba level, all
     in VMEM: with X = 2^(16h), a = a0 + a1*X, b = b0 + b1*X,
 
         T = z0 + [z1 - z0 - z2]*X + z2*X^2,  z1 = (a0+a1)(b0+b1)
 
-    z0 = a0*b0 and z2 = a1*b1 are accumulated straight into the output's
-    rows 0..2h and 2h..4h (z0 + z2*X^2 has no overlap). The half sums are
+    z0 = a0*b0 and z2 = a1*b1 are accumulated straight into T's rows 0..2h
+    and 2h..4h (z0 + z2*X^2 has no overlap). The half sums are
     carry-normalized to h canonical digits sa, sb plus a 0/1 overflow bit
     ca, cb each, so z1' = sa*sb keeps _accumulate_prod's 16-bit contract and
     z1 = z1' + (ca*sb + cb*sa)*X + ca*cb*X^2 over 2h + 1 digits.
@@ -283,215 +246,258 @@ def _make_karatsuba1_kernel(h: int, TB: int):
     (digits 0, D - 2^12, then D - 1 - 2^12 up to row 2h, D - 1 above) makes
     that mid + D^rows exactly: the carry pass leaves mid's canonical digits
     (zero above row 2h) and a carry-out of 1, which is dropped. Every
-    t[k] < 2^29; T's digits stay < h * 2^17 + 2^16, inside _redc's bound."""
+    t[k] < 2^29; T's digits stay < h * 2^17 + 2^16, inside carry_norm's
+    bound. Scratch: acc_ref (2h + GROUP, TB), sa_ref (h, TB)."""
+    h = L // 2
     assert h <= 1024 and h % GROUP == 0
     fill = np.uint32((1 << 28) + 0xFFFF - (1 << 12))
+    sa, ca = carry_norm(a_ref[0:h, :] + a_ref[h : 2 * h, :], passes=0)
+    sb, cb = carry_norm(b_ref[0:h, :] + b_ref[h : 2 * h, :], passes=0)
+    sa_ref[:, :] = sa   # the a-side of a product is read row by row
 
-    def karatsuba1_kernel(a_ref, b_ref, out_ref, acc_ref, sa_ref):
-        sa, ca = carry_norm(a_ref[0:h, :] + a_ref[h : 2 * h, :], in_kernel=True)
-        sb, cb = carry_norm(b_ref[0:h, :] + b_ref[h : 2 * h, :], in_kernel=True)
-        sa_ref[:, :] = sa   # the a-side of a product is read row by row
+    def prod(a_read, b):
+        acc_ref[:, :] = jnp.zeros((2 * h + GROUP, TB), jnp.uint32)
+        _accumulate_prod(a_read, b, acc_ref, h, TB)
+        return acc_ref[0 : 2 * h, :]
 
-        def prod(a_read, b):
-            acc_ref[:, :] = jnp.zeros((2 * h + GROUP, TB), jnp.uint32)
-            _accumulate_prod(a_read, b, acc_ref, h, TB)
-            return acc_ref[0 : 2 * h, :]
-
-        out_ref[0 : 2 * h, :] = prod(
-            lambda i: a_ref[pl.ds(i, 1), :], b_ref[0:h, :]
-        )
-        out_ref[2 * h : 4 * h, :] = prod(
-            lambda i: a_ref[pl.ds(h + i, 1), :], b_ref[h : 2 * h, :]
-        )
-        z1 = prod(lambda i: sa_ref[pl.ds(i, 1), :], sb)
-        row = jax.lax.broadcasted_iota(jnp.int32, (2 * h, TB), 0)
-        c = jnp.where(row == 0, jnp.uint32(1 << 28),
-                      jnp.where(row == 1, fill + np.uint32(1), fill))
-        t = c - out_ref[0 : 2 * h, :] - out_ref[2 * h : 4 * h, :] + z1
-        t = t + jnp.pad(sb * ca + sa * cb, ((h, 0), (0, 0)))
-        row = jax.lax.broadcasted_iota(jnp.int32, (GROUP, TB), 0)
-        top = jnp.where(row == 0, ca * cb + (fill - np.uint32(1 << 28)), MASK16)
-        mid, _ = carry_norm(jnp.concatenate([t, top], axis=0), in_kernel=True)
-        out_ref[h : 3 * h + GROUP, :] = out_ref[h : 3 * h + GROUP, :] + mid
-
-    return karatsuba1_kernel
-
-
-@functools.lru_cache(maxsize=None)
-def _karatsuba1_call(h: int, B: int, TB: int, interpret: bool):
-    def spec(rows):
-        return pl.BlockSpec((rows, TB), lambda i: (0, i), memory_space=pltpu.VMEM)
-
-    return pl.pallas_call(
-        _make_karatsuba1_kernel(h, TB),
-        grid=(B // TB,),
-        in_specs=[spec(2 * h), spec(2 * h)],
-        out_specs=spec(4 * h),
-        out_shape=jax.ShapeDtypeStruct((4 * h, B), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((2 * h + GROUP, TB), jnp.uint32),
-                        pltpu.VMEM((h, TB), jnp.uint32)],
-        interpret=interpret,
+    t_ref[0 : 2 * h, :] = prod(lambda i: a_ref[pl.ds(i, 1), :], b_ref[0:h, :])
+    t_ref[2 * h : 4 * h, :] = prod(
+        lambda i: a_ref[pl.ds(h + i, 1), :], b_ref[h : 2 * h, :]
     )
+    z1 = prod(lambda i: sa_ref[pl.ds(i, 1), :], sb)
+    row = jax.lax.broadcasted_iota(jnp.int32, (2 * h, TB), 0)
+    c = jnp.where(row == 0, jnp.uint32(1 << 28),
+                  jnp.where(row == 1, fill + np.uint32(1), fill))
+    t = c - t_ref[0 : 2 * h, :] - t_ref[2 * h : 4 * h, :] + z1
+    t = t + jnp.pad(sb * ca + sa * cb, ((h, 0), (0, 0)))
+    row = jax.lax.broadcasted_iota(jnp.int32, (GROUP, TB), 0)
+    top = jnp.where(row == 0, ca * cb + (fill - np.uint32(1 << 28)), MASK16)
+    mid, _ = carry_norm(jnp.concatenate([t, top], axis=0))
+    t_ref[h : 3 * h + GROUP, :] = t_ref[h : 3 * h + GROUP, :] + mid
 
 
-def prod_lm_k1(a, b, TB: int | None = None, interpret: bool | None = None):
-    """prod_lm by one Karatsuba level: 3 half-width schoolbook products
-    instead of 1 full-width one (25 % fewer u32 VPU multiplies) and their
-    recombination, in one kernel dispatch (_make_karatsuba1_kernel). Same
-    (2L, B) redundant accumulator as prod_lm; only the digit decomposition
-    differs, which _redc's carry normalization absorbs. L must be a
-    multiple of 2*GROUP (`product_for` sends every other shape to prod_lm).
-    TB=None picks the lane tile measured for half-width rows (_tb_for)."""
-    if interpret is None:
-        interpret = interpret_default()
-    L = a.shape[0]
-    if L % (2 * GROUP):
-        raise ValueError(f"prod_lm_k1 needs L a multiple of {2 * GROUP}, got {L}")
-    h = L // 2
-    if TB is None:
-        TB = _tb_for(h)
-    a, B = _pad_lanes(a, TB)
-    b, _ = _pad_lanes(b, TB)
-    return _karatsuba1_call(h, a.shape[1], TB, interpret)(a, b)[:, :B]
+def _product_scratch(product: str, L: int, TB: int) -> list:
+    """Scratch of `_product_<product>`: t_ref first."""
+    if product == "karatsuba1":
+        h = L // 2
+        return [pltpu.VMEM((2 * L, TB), jnp.uint32),
+                pltpu.VMEM((2 * h + GROUP, TB), jnp.uint32),
+                pltpu.VMEM((h, TB), jnp.uint32)]
+    return [pltpu.VMEM((2 * L + GROUP, TB), jnp.uint32)]
 
 
 # ---------------------------------------------------------------------------
-# Montgomery reduction constants: Toeplitz band matrices in base 2^8
+# Montgomery reduction: two int8 band products on the MXU and the digit work
+# around them, in the kernel that made the product
 # ---------------------------------------------------------------------------
+
+
+def _bytes8(d16):
+    """Canonical 16-bit digits (L, TB) -> the int8 operand of a band
+    product: (2L, TB), low bytes over high bytes, each less 128 (the band
+    matrices' columns are in this order: _band)."""
+    d8 = jnp.concatenate([d16 & MASK8, d16 >> 8], axis=0).astype(jnp.int32)
+    return (d8 - 128).astype(jnp.int8)
+
+
+def _band_dot16(mat_ref, const_ref, d8):
+    """A band product as redundant base-2^16 digits, and what falls off
+    its top. mat_ref @ d8 is (rows8, TB) i32, the even base-2^8 output
+    digits over the odd ones (_band orders the rows so); const_ref's
+    (rows8, 1) constants give d8's 128 back and lift every digit into
+    [0, 2^27) (_band, _lift). So even + (odd mod 2^8) * 2^8 + (odd below
+    >> 8) is a u32 digit; the top odd digit's high part is returned beside
+    them as (1, TB)."""
+    v = jax.lax.dot(mat_ref[:, :], d8, preferred_element_type=jnp.int32)
+    v = (v + const_ref[:, :]).astype(jnp.uint32)
+    rows = v.shape[0] // 2
+    even, odd = v[0:rows], v[rows:]
+    return even + ((odd & MASK8) << 8) + _shift_up(odd >> 8, 1), odd[-1:] >> 8
+
+
+def _redc(t_ref, m_mat, m_const, q_mat, q_const, comp_ref, out_ref,
+          L: int, TB: int) -> None:
+    """Montgomery reduction of the redundant product T in t_ref's rows
+    0..2L (digits < 2^31) -> out_ref (L, TB) canonical, = value(T) * R^-1
+    mod n, for value(T) < n*R. m = T*N' mod R and q = m*n are int8 matmuls
+    against the band matrices; T + q is 0 mod R, its upper half is t < 2n,
+    and t - n is taken where t >= n."""
+    tlo, carry = carry_norm(t_ref[0:L, :])
+    m16, _ = _band_dot16(m_mat, m_const, _bytes8(tlo))       # mod R: top drops
+    m, _ = carry_norm(m16)
+    q16, q_top = _band_dot16(q_mat, q_const, _bytes8(m))     # (2L, TB) < 2^28
+    # the low half of T + q is u*R exactly and its digits are < 2^29, so
+    # the digits under the top two add less than 2^14 to x = s1*D + s2 of
+    # u*D^2 = x + that: u = ceil(x / 2^32), and no carry pass is needed
+    s1 = tlo[L - 1 : L] + q16[L - 1 : L]
+    s2 = tlo[L - 2 : L - 1] + q16[L - 2 : L - 1]
+    y = (s1 & MASK16) + (s2 >> LIMB_BITS)
+    rest = (y & MASK16) | (s2 & MASK16)
+    u = ((s1 >> LIMB_BITS) + (y >> LIMB_BITS)
+         + jnp.where(rest != 0, jnp.uint32(1), jnp.uint32(0)))
+    t, c_top = carry_norm(t_ref[L : 2 * L, :] + q16[L:] + _at_row0(carry + u, L))
+    # t + c_top*R < 2n once the lift's carries (_lift) are taken off
+    _, _, lift_top = _lift(L)
+    c_top = c_top + q_top - jnp.uint32(lift_top)
+    # conditional subtract via complement add: t + (R - n) carries iff t >= n
+    one = jnp.ones((1, TB), jnp.uint32)
+    diff, borrow = carry_norm(t + comp_ref[:, :] + _at_row0(one, L), passes=0)
+    take_diff = jnp.broadcast_to(borrow + c_top, (L, TB)) >= 1
+    out_ref[:, :] = jnp.where(take_diff, diff, t)
 
 
 def _digits8(v: int, count: int) -> np.ndarray:
     return np.array([(v >> (8 * i)) & 0xFF for i in range(count)], np.int32)
 
 
-def _toeplitz8(digits: np.ndarray, out_rows: int, in_cols: int):
-    """M[k, i] = digits[k - i] (0 <= k - i < len), as the int8 pair
-    (signed_part, support_mask) with M = signed + 128 * mask."""
-    d = np.zeros((out_rows, in_cols), np.int32)
-    msk = np.zeros((out_rows, in_cols), np.int8)
-    n = len(digits)
-    for i in range(in_cols):
-        lo, hi = i, min(i + n, out_rows)
-        d[lo:hi, i] = digits[: hi - lo]
-        msk[lo:hi, i] = 1
-    signed = (d - 128 * msk.astype(np.int32)).astype(np.int8)
-    return signed, msk
+def _balanced8(v: int, count: int):
+    """`count` base-2^8 digits in [-128, 127] and the carry out of the top:
+    sum(d[k] * 2^(8k)) + carry * 2^(8 count) == v. An int8 matmul takes
+    them as they stand."""
+    out, carry = np.zeros(count, np.int32), 0
+    for k, d in enumerate(_digits8(v, count)):
+        d = int(d) + carry
+        carry = int(d >= 128)
+        out[k] = d - 256 * carry
+    return out, carry
+
+
+@functools.lru_cache(maxsize=None)
+def _lift(L: int):
+    """Per-digit lifts that make a band product's signed digits
+    non-negative without changing what the reduction reads. Each is
+    `each` (a power of two over |digit| <= 2L * 128 * 255) plus a byte. Over
+    the 2L bytes of a half their value is a whole number of R: `mod R`
+    drops it from the first band. The second band's low half carries `lo`
+    R's into the high half, whose lift is worth top*R - lo: the two
+    together are top*R^2, which _redc takes off the carry out of t.
+    Returns (low-half lift, high-half lift, top)."""
+    each = 1 << (2 * L * 128 * 255).bit_length()
+    if each > 1 << 27:
+        raise ValueError(f"no room in a u32 digit for a modulus of {L} limbs")
+    R = 1 << (LIMB_BITS * L)
+    base = each * ((R - 1) // 255)              # `each` in each of 2L bytes
+    lo = -(-base // R)
+    top = -(-(base + lo) // R)
+    return (each + _digits8(lo * R - base, 2 * L),
+            each + _digits8(top * R - lo - base, 2 * L), top)
+
+
+def _band(digits: np.ndarray, out_rows: int, cols: int, lift: np.ndarray):
+    """The Toeplitz band M[k, i] = digits[k - i] of balanced digits as the
+    kernel takes it, (out_rows, cols) int8, and its (out_rows, 1) row
+    constants. The operand's bytes live in [0, 255] and go in as d' = d -
+    128, so M @ d = M @ d' + 128 * (M @ 1): ONE int8 matmul, with the row
+    sums and `lift` in the constant. Columns follow _bytes8's operand order
+    (low bytes, then high bytes); rows put the even output digits over the
+    odd ones, which is what _band_dot16 pairs: the kernel never interleaves
+    digits."""
+    m = np.zeros((out_rows, cols), np.int32)
+    for i in range(cols):
+        hi = min(i + len(digits), out_rows)
+        m[i:hi, i] = digits[: hi - i]
+    const = 128 * m.sum(axis=1) + lift
+    rows = np.r_[0:out_rows:2, 1:out_rows:2]
+    order = np.r_[0:cols:2, 1:cols:2]
+    return m[rows][:, order].astype(np.int8), const[rows, None].astype(np.int32)
 
 
 @dataclass(frozen=True, eq=False)
 class MxuCtx:
-    """Per-modulus constants for the v2 multiply."""
+    """Per-modulus constants for the v2 multiply. The kernel runs at
+    L = ctx.L rounded up to ROWS limbs with R' = 2^(16 L): mul2_lm shifts
+    one operand up by the difference, so the answer is still a*b*R^-1 for
+    ctx's own R = 2^(16 ctx.L)."""
 
     ctx: ModCtx
-    L8: int
-    m_signed: np.ndarray = field(repr=False)   # (L8, L8) int8: N' band, mod R
-    m_mask: np.ndarray = field(repr=False)
-    q_signed: np.ndarray = field(repr=False)   # (2*L8, L8) int8: N band
-    q_mask: np.ndarray = field(repr=False)
+    L: int
+    m_mat: np.ndarray = field(repr=False)    # (2L, 2L) int8: N' band, mod R'
+    m_const: np.ndarray = field(repr=False)  # (2L, 1) int32
+    q_mat: np.ndarray = field(repr=False)    # (4L, 2L) int8: n band
+    q_const: np.ndarray = field(repr=False)  # (4L, 1) int32
+    comp: np.ndarray = field(repr=False)     # (L, 1) uint32: R' - 1 - n
 
     @staticmethod
     @functools.lru_cache(maxsize=64)
     def make(ctx: ModCtx) -> "MxuCtx":
-        L8 = 2 * ctx.L
-        R = 1 << (LIMB_BITS * ctx.L)
-        nprime = (-pow(ctx.n, -1, R)) % R
-        m_signed, m_mask = _toeplitz8(_digits8(nprime, L8), L8, L8)
-        q_signed, q_mask = _toeplitz8(_digits8(ctx.n, L8), 2 * L8, L8)
-        return MxuCtx(ctx=ctx, L8=L8, m_signed=m_signed, m_mask=m_mask,
-                      q_signed=q_signed, q_mask=q_mask)
+        L = _rows(ctx.L)
+        R = 1 << (LIMB_BITS * L)
+        lift_lo, lift_hi, _ = _lift(L)
+        m_digits, _ = _balanced8((-pow(ctx.n, -1, R)) % R, 2 * L)   # mod R'
+        m_mat, m_const = _band(m_digits, 2 * L, 2 * L, lift_lo)
+        # n's carry out of 2L balanced digits is a digit more: row 4L - 1
+        n_digits, carry = _balanced8(ctx.n, 2 * L)
+        q_mat, q_const = _band(np.append(n_digits, carry), 4 * L, 2 * L,
+                               np.concatenate([lift_lo, lift_hi]))
+        comp = bn.int_to_limbs(R - 1 - ctx.n, L).astype(np.uint32)[:, None]
+        return MxuCtx(ctx=ctx, L=L, m_mat=m_mat, m_const=m_const,
+                      q_mat=q_mat, q_const=q_const, comp=comp)
 
-
-def _band_dot(signed, mask, d8):
-    """M @ d for digit vectors d8 in [0, 255], via two int8 matmuls.
-
-    M = signed + 128*mask, d = d' + 128*support (support = all-ones over
-    the L8 input rows). The constant pieces fold into per-row sums that
-    depend only on the matrices, but computing them against the actual
-    all-ones support costs nothing extra because XLA folds them — so for
-    clarity: M@d = signed@d' + 128*(mask@d') + 128*(signed@ones) +
-    2^14*(mask@ones), with the last two terms precomputed at trace time.
-    """
-    dprime = (d8 - 128).astype(jnp.int8)
-    s = jax.lax.dot(signed.astype(jnp.int8), dprime,
-                    preferred_element_type=jnp.int32)
-    m = jax.lax.dot(mask.astype(jnp.int8), dprime,
-                    preferred_element_type=jnp.int32)
-    ones = jnp.ones((signed.shape[1], 1), jnp.int8)
-    srow = jax.lax.dot(signed.astype(jnp.int8), ones,
-                       preferred_element_type=jnp.int32)
-    mrow = jax.lax.dot(mask.astype(jnp.int8), ones,
-                       preferred_element_type=jnp.int32)
-    return s + 128 * m + 128 * srow + (1 << 14) * mrow
-
-
-def _split8(x16):
-    """(L, B) canonical 16-bit digits -> (2L, B) base-2^8 digits (i32)."""
-    L, B = x16.shape
-    x16 = x16.astype(jnp.int32)
-    lo = x16 & MASK8
-    hi = x16 >> 8
-    return jnp.stack([lo, hi], axis=1).reshape(2 * L, B)
-
-
-def _merge8(q8):
-    """(rows8, B) base-2^8 digits (< 2^11 after pre-pass) -> base-2^16."""
-    rows8, B = q8.shape
-    pair = q8.reshape(rows8 // 2, 2, B)
-    return (pair[:, 0, :] + (pair[:, 1, :] << 8)).astype(jnp.uint32)
-
-
-def _prenorm8(q, passes: int = 2):
-    """Two local base-2^8 extract passes: digits < 2^25 -> < 2^11
-    (pass 1: < 2^8 + 2^17, pass 2: < 2^8 + 2^10), so the 8->16 merge
-    stays < 2^11*2^8 + 2^11 < 2^20, far from u32 overflow. Carries out of
-    the top row cannot occur: all digits are nonnegative and the value
-    fits the row span, so the top digit is always below the base."""
-    q = q.astype(jnp.uint32)
-    for _ in range(passes):
-        q = (q & 0xFF) + _shift_up(q >> 8, 1)
-    return q
+    def operands(self) -> tuple:
+        """The constants in the order _mul_call takes them."""
+        return tuple(jnp.asarray(c) for c in (
+            self.m_mat, self.m_const, self.q_mat, self.q_const, self.comp))
 
 
 # ---------------------------------------------------------------------------
-# the v2 multiply and fold
+# the v2 multiply: one device program
 # ---------------------------------------------------------------------------
 
+def lane_tile(L: int) -> int:
+    """The lane tile a multiply of L limbs runs at: the one measured for
+    its product's row count (_tb_for)."""
+    rows = _rows(L)
+    return _tb_for(rows // 2 if product_for(L) == "karatsuba1" else rows)
 
-def _redc(mctx: MxuCtx, T):
-    """Montgomery reduction of the redundant product T (2L, B) -> (L, B)
-    canonical, = value(T) * R^-1 mod n, for value(T) < n*R."""
-    ctx = mctx.ctx
-    L = ctx.L
 
-    Tlo, cL = carry_norm(T[:L])
-    Thi = T[L:].at[0:1].add(cL)
+@functools.lru_cache(maxsize=None)
+def _mul_call(product: str, L: int, B: int, TB: int, interpret: bool):
+    """a, b (L, B) canonical and MxuCtx.operands() -> a*b*R^-1 mod n (L, B)
+    canonical: the named product, then _redc on it while it is in VMEM. L a
+    multiple of ROWS, B of TB (mul2_lm pads)."""
+    product_fn = (_product_karatsuba1 if product == "karatsuba1"
+                  else _product_schoolbook)
 
-    d8 = _split8(Tlo)
-    m_red = _band_dot(mctx.m_signed, mctx.m_mask, d8)      # (L8, B) >= 0
-    m8, _ = carry_norm(m_red, bits=8)                      # mod R: drop carry
+    def mont_mul(a_ref, b_ref, m_mat, m_const, q_mat, q_const, comp_ref,
+                 out_ref, t_ref, *scratch):
+        product_fn(a_ref, b_ref, t_ref, *scratch, L, TB)
+        _redc(t_ref, m_mat, m_const, q_mat, q_const, comp_ref, out_ref, L, TB)
 
-    q_red = _band_dot(mctx.q_signed, mctx.q_mask, m8.astype(jnp.int32))
-    q16 = _merge8(_prenorm8(q_red))                        # (2L, B) < 2^19
+    tile = pl.BlockSpec((L, TB), lambda i: (0, i), memory_space=pltpu.VMEM)
 
-    s_lo = Tlo + q16[:L]                                   # (T + q) mod R...
-    zeros, u = carry_norm(s_lo)                            # ...== 0: digits
-    del zeros                                              # provably zero
-    t_red = (Thi + q16[L:]).at[0:1].add(u)                 # (T + q) / R
-    t, c_top = carry_norm(t_red)                           # t + c_top*R < 2n
+    def whole(rows, cols):
+        return pl.BlockSpec((rows, cols), lambda i: (0, 0),
+                            memory_space=pltpu.VMEM)
 
-    # conditional subtract via complement add: t - N + R
-    comp = jnp.asarray((MASK16 - ctx.N).astype(np.uint32))[:, None]
-    w = t + comp
-    w = w.at[0:1].add(1)
-    diff, borrow = carry_norm(w)
-    take_diff = (borrow + c_top) >= 1                      # t >= N
-    return jnp.where(take_diff, diff, t)
+    return pl.pallas_call(
+        mont_mul,
+        grid=(B // TB,),
+        in_specs=[tile, tile, whole(2 * L, 2 * L), whole(2 * L, 1),
+                  whole(4 * L, 2 * L), whole(4 * L, 1), whole(L, 1)],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((L, B), jnp.uint32),
+        scratch_shapes=_product_scratch(product, L, TB),
+        interpret=interpret,
+        name=f"mont_mul_{product}",
+    )
 
 
 def mul2_lm(mctx: MxuCtx, a, b, interpret: bool | None = None):
-    """Montgomery product a*b*R^-1 mod n, limbs-major (L, B) canonical."""
-    prod = prod_lm_k1 if product_for(a.shape[0]) == "karatsuba1" else prod_lm
-    return _redc(mctx, prod(a, b, interpret=interpret))
+    """Montgomery product a*b*R^-1 mod n, limbs-major (L, B) canonical.
+    Lanes are zero-padded to the tile and limbs to mctx.L once, here: `a`
+    at the bottom (a * D^pad), `b` at the top, so that the reduction by
+    R' = R * D^pad gives a*b*R^-1."""
+    if interpret is None:
+        interpret = interpret_default()
+    L, B = a.shape
+    TB = lane_tile(L)
+    rpad, lpad = mctx.L - L, -B % TB
+    if rpad or lpad:
+        a = jnp.pad(a, ((rpad, 0), (0, lpad)))
+        b = jnp.pad(b, ((0, rpad), (0, lpad)))
+    out = _mul_call(product_for(L), mctx.L, B + lpad, TB, interpret)(
+        a, b, *mctx.operands())
+    return out[:L, :B] if rpad or lpad else out
 
 
 # ---------------------------------------------------------------------------
@@ -506,7 +512,9 @@ def _pow2_body(mctx: MxuCtx, E: int, interpret: bool):
     ctx = mctx.ctx
 
     def run(bases, digits):
-        x = bases.T                                           # (L, B)
+        B = bases.shape[0]
+        # lanes padded to the tile once, not by each of the multiplies
+        x = jnp.pad(bases.T, ((0, 0), (0, -B % lane_tile(ctx.L))))  # (L, B')
         shape = x.shape
         r2 = jnp.broadcast_to(jnp.asarray(ctx.R2)[:, None], shape)
         xm = mul2_lm(mctx, x, r2, interpret)                  # to mont
@@ -534,7 +542,7 @@ def _pow2_body(mctx: MxuCtx, E: int, interpret: bool):
         out = mul2_lm(
             mctx, acc, jnp.broadcast_to(one, shape), interpret
         )                                                     # from mont
-        return out.T
+        return out[:, :B].T
 
     return run
 
@@ -567,10 +575,20 @@ def _reduce2_fn(mctx: MxuCtx, P2: int, interpret: bool):
     # the served fold: the yardstick reads its device time by the name
     # `jit_run`, so the jitted function stays `run`
     def run(cs, fix):
-        x = halving_tree(
-            lambda a, b: mul2_lm(mctx, a, b, interpret), cs.T, axis=1
-        )
-        x = mul2_lm(mctx, x[:, :1], fix[:, None], interpret)
+        def mul(a, b):
+            return mul2_lm(mctx, a, b, interpret)
+
+        # down to one lane tile the tree halves; below it the operands
+        # stay a tile wide (lane j times lane j + w, the lanes above w
+        # carrying products nobody reads) and are sliced once, at the end
+        TB = lane_tile(mctx.ctx.L)
+        x = jnp.pad(cs.T, ((0, 0), (0, max(0, TB - P2))))
+        x = halving_tree(mul, x, axis=1, width=TB)
+        w = min(P2, TB)
+        while w > 1:
+            w //= 2
+            x = mul(x, jnp.roll(x, -w, axis=1))
+        x = mul(x, jnp.broadcast_to(fix[:, None], x.shape))
         return x[:, :1].T
 
     return jax.jit(run)

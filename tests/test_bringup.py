@@ -53,6 +53,64 @@ def test_v2_multiply_lowers_for_tpu():
     )
 
 
+def _paillier_width_mctx(L):
+    """A modulus of L limbs (any odd number of that width: lowering reads
+    its shape, not its arithmetic)."""
+    n = random.Random(L).getrandbits(16 * L) | (1 << (16 * L - 1)) | 1
+    return mx.MxuCtx.make(ModCtx.make(n))
+
+
+# what a multiply may leave outside Mosaic: nothing, but the lane pads and the
+# slice back where the batch is no whole tile
+MAX_XLA_OPS_A_MULTIPLY = 3
+
+
+def _xla_ops(text):
+    """The operations of a lowering's main function that are neither a
+    Mosaic kernel nor a constant (or a constant's broadcast to a column)."""
+    import re
+
+    main = text[text.index("func.func public @main"):].split("func.func private")[0]
+    ops = re.findall(r"= (?:(?:stablehlo|chlo)\.)?([a-z_]+)", main)
+    return ops.count("custom_call"), [
+        op for op in ops if op not in ("custom_call", "constant", "broadcast_in_dim")
+    ]
+
+
+@pytest.mark.parametrize("L,lanes", [(256, 256), (512, 256), (256, 130), (512, 1)])
+def test_a_multiply_is_one_kernel(L, lanes):
+    """Product and reduction lower to ONE Mosaic kernel at Paillier-2048 and
+    -4096 widths, int8 band products inside, and the multiply's lowering
+    holds nothing else (two pads and a slice for a ragged batch): the 120
+    XLA operations a multiply was until PR 43 cannot grow back unseen."""
+    mctx = _paillier_width_mctx(L)
+    x = u32(L, lanes)
+    text = (
+        jax.jit(lambda a, b: mx.mul2_lm(mctx, a, b, False))
+        .trace(x, x).lower(lowering_platforms=("tpu",)).as_text()
+    )
+    kernels, xla = _xla_ops(text)
+    assert kernels == 1 and "tpu_custom_call" in text
+    assert len(xla) <= MAX_XLA_OPS_A_MULTIPLY, xla
+    if lanes % 128 == 0:
+        assert xla == []
+
+
+def test_the_served_fold_is_its_fifteen_multiplies_and_little_else():
+    """The fold of 16,384 Paillier-2048 rows: 15 kernels, the transpose in,
+    two slices a wide level, a lane roll a narrow one and the slice out; it
+    was 1,576 operations."""
+    mctx = _paillier_width_mctx(256)
+    text = (
+        mx._reduce2_fn(mctx, 16384, False)
+        .trace(u32(16384, 256), u32(256))
+        .lower(lowering_platforms=("tpu",)).as_text()
+    )
+    kernels, xla = _xla_ops(text)
+    assert kernels == 15
+    assert len(xla) <= 30 and set(xla) <= {"call", "slice", "transpose"}, xla
+
+
 def test_v2_entry_points_lower_for_tpu():
     lowers_to_mosaic(mx._pow2_fn(MCTX, 4, False), u32(8, L), i32(4))
     lowers_to_mosaic(mx._reduce2_fn(MCTX, 8, False), u32(8, L), u32(L))
@@ -90,25 +148,28 @@ def one_v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("L", [384, 512])
-def test_the_karatsuba_kernel_compiles_for_a_v5e_at_real_widths(one_v5e_chip, L):
-    """Mosaic takes the kernel at Paillier-3072 and -4096 widths and the
-    lane tile it runs at (VMEM, alignment, no boolean vector shifted):
-    what interpret mode and a lowering cannot show, without a chip."""
+@pytest.mark.parametrize("L", [256, 384, 512])
+def test_the_multiply_compiles_for_a_v5e_at_real_widths(one_v5e_chip, L):
+    """Mosaic takes the multiply's kernel (the schoolbook product at
+    Paillier-2048 width, the Karatsuba one at -3072 and -4096, the
+    reduction with its int8 matmuls behind either) at the lane tile it runs
+    at (VMEM, alignment, int8 tiles, no boolean vector shifted): what
+    interpret mode and a lowering cannot show, without a chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
+    mctx = _paillier_width_mctx(L)
     x = jax.ShapeDtypeStruct((L, 256), jnp.uint32, sharding=one_v5e_chip)
     # a compile for a described chip cannot be read back from the cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
         compiled = jax.jit(
-            lambda a, b: mx.prod_lm_k1(a, b, interpret=False)
+            lambda a, b: mx.mul2_lm(mctx, a, b, False)
         ).lower(x, x).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("kernel", ["v2"])

@@ -1,16 +1,18 @@
 """Known-answer tests for the hybrid VPU+MXU Montgomery multiply (v2).
 
-Exactness is the whole game: every stage (carry normalization, schoolbook
-product, band-matmul reduction, full multiply, fold) is compared against
-python int arithmetic. Runs in Pallas interpret mode on the CPU mesh
+Exactness is the whole game: every stage (carry normalization, both
+products, the reduction behind them, full multiply, fold) is compared
+against python int arithmetic. Runs in Pallas interpret mode on the CPU mesh
 (tests/conftest.py); the same code paths compile for TPU.
 """
 
 import random
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental import pallas as pl
 
 from dds_tpu.ops import bignum as bn
 from dds_tpu.ops import mont_mxu as mx
@@ -32,45 +34,70 @@ def _from_lm(x):
     return bn.batch_to_ints(np.asarray(x).T)
 
 
-def test_carry_norm_preserves_value_16():
-    rng = np.random.default_rng(0)
-    rows, B = 24, 3
-    x = rng.integers(0, 1 << 31, size=(rows, B), dtype=np.uint32)
-    digits, carry = mx.carry_norm(jnp.asarray(x))
+def _value(T):
+    """The integer each lane of a redundant (rows, B) accumulator carries."""
+    T = np.asarray(T)
+    return [
+        sum(int(T[k, b]) << (16 * k) for k in range(T.shape[0]))
+        for b in range(T.shape[1])
+    ]
+
+
+@pytest.mark.parametrize("passes,top,rows,B", [
+    (1, 1 << 32, 24, 3),      # any u32 digit: one extract pass
+    (1, 1 << 27, 520, 2),     # a product's digits, the rows of L = 512's mid
+    (0, 1 << 17, 32, 5),      # a sum of two canonical digits and a carry
+    (0, 1 << 16, 1, 4),       # one row: nothing to scan
+])
+def test_carry_norm_preserves_value(passes, top, rows, B):
+    rng = np.random.default_rng(rows)
+    x = rng.integers(0, top, size=(rows, B), dtype=np.uint64).astype(np.uint32)
+    x[:, 0] = top - 1                                  # every digit at the bound
+    if rows > 2:
+        x[1:, 1] = 0xFFFF                              # one carry ripples to the top
+        x[0, 1] = 0x10000 if top > 0x10000 else 0xFFFF
+    digits, carry = mx.carry_norm(jnp.asarray(x), passes=passes)
     digits, carry = np.asarray(digits), np.asarray(carry)
-    for b in range(B):
-        want = sum(int(x[k, b]) << (16 * k) for k in range(rows))
-        got = sum(int(digits[k, b]) << (16 * k) for k in range(rows))
-        got += int(carry[0, b]) << (16 * rows)
-        assert got == want
-        assert digits[:, b].max() <= 0xFFFF
+    assert int(digits.max()) <= 0xFFFF
+    got = [v + (int(c) << (16 * rows)) for v, c in zip(_value(digits), carry[0])]
+    assert got == _value(x)
 
 
-def test_carry_norm_preserves_value_8():
-    rng = np.random.default_rng(1)
-    rows, B = 32, 2
-    x = rng.integers(0, 1 << 25, size=(rows, B), dtype=np.uint32)
-    digits, carry = mx.carry_norm(jnp.asarray(x), bits=8)
-    digits, carry = np.asarray(digits), np.asarray(carry)
-    for b in range(B):
-        want = sum(int(x[k, b]) << (8 * k) for k in range(rows))
-        got = sum(int(digits[k, b]) << (8 * k) for k in range(rows))
-        got += int(carry[0, b]) << (8 * rows)
-        assert got == want
-        assert digits[:, b].max() <= 0xFF
+def _product(pairs, L, product):
+    """The redundant (2L, B) product T the named in-kernel product leaves
+    in VMEM for _redc, from one interpreted program over all B lanes."""
+    B = len(pairs)
+    product_fn = getattr(mx, f"_product_{product}")
+
+    def body(a_ref, b_ref, out_ref, t_ref, *scratch):
+        product_fn(a_ref, b_ref, t_ref, *scratch, L, B)
+        out_ref[:, :] = t_ref[0 : 2 * L, :]
+
+    return np.asarray(pl.pallas_call(
+        body,
+        out_shape=jax.ShapeDtypeStruct((2 * L, B), jnp.uint32),
+        scratch_shapes=mx._product_scratch(product, L, B),
+        interpret=True,
+    )(_to_lm([a for a, _ in pairs], L), _to_lm([b for _, b in pairs], L)))
 
 
-def test_prod_lm_matches_python():
+def _check_product(T, pairs, L, product):
+    """value(T) = a*b, every digit inside the bound _redc's carry passes
+    are promised: rows lo-halves and rows hi-halves a digit, and the
+    Karatsuba middle term's 2^16."""
+    rows = L // 2 if product == "karatsuba1" else L
+    assert T.shape == (2 * L, len(pairs))
+    assert _value(T) == [a * b for a, b in pairs]
+    assert int(T.max()) < rows * (1 << 17) + (1 << 16)
+
+
+def test_schoolbook_product_matches_python():
     rng = random.Random(2)
     L = 32  # 512-bit operands
-    vals_a = [rng.getrandbits(16 * L) for _ in range(4)]
-    vals_b = [rng.getrandbits(16 * L) for _ in range(4)]
-    T = mx.prod_lm(_to_lm(vals_a, L), _to_lm(vals_b, L), interpret=True)
-    digits, carry = mx.carry_norm(T)
-    assert int(np.asarray(carry).max()) == 0
-    got = _from_lm(digits)
-    for g, a, b in zip(got, vals_a, vals_b):
-        assert g == a * b
+    full = (1 << (16 * L)) - 1
+    pairs = [(rng.getrandbits(16 * L), rng.getrandbits(16 * L)) for _ in range(4)]
+    pairs += [(full, full), (0, full), (1, 1)]
+    _check_product(_product(pairs, L, "schoolbook"), pairs, L, "schoolbook")
 
 
 def test_mul2_odd_limb_count():
@@ -158,17 +185,98 @@ def test_pow_mod2_zero_exponent():
 
 
 # ---------------------------------------------------------------------------
-# the product chosen from L: one Karatsuba level from KARATSUBA_MIN_L up
+# the reduction (_redc) behind both products, in the one kernel
 # ---------------------------------------------------------------------------
 
 
-def _value(T):
-    """The integer each lane of a redundant (rows, B) accumulator carries."""
-    T = np.asarray(T)
-    return [
-        sum(int(T[k, b]) << (16 * k) for k in range(T.shape[0]))
-        for b in range(T.shape[1])
-    ]
+def _tail_modulus(rng, L):
+    """An odd modulus in [3/4, 1) of R = 2^(16 L): (T + m*n) / R then lands
+    below n, between n and R, and above R (the top carry), so every branch
+    of the tail's select is met by random operands."""
+    bits = 16 * L
+    return rng.getrandbits(bits) | (3 << (bits - 2)) | 1
+
+
+def _tail_branch(a, b, mctx):
+    """Which way the tail's select goes for a*b, by python ints, at the
+    rows the kernels run (R' = 2^(16 mctx.L), a shifted up by the pad)."""
+    n, L = mctx.ctx.n, mctx.L
+    R = 1 << (16 * L)
+    T = (a << (16 * (L - mctx.ctx.L))) * b
+    t = (T + (T * -pow(n, -1, R)) % R * n) // R
+    return "top_carry" if t >= R else "t_ge_n" if t >= n else "t_lt_n"
+
+
+@pytest.mark.parametrize("lanes", [1, 3, 128, 130])
+@pytest.mark.parametrize("L,product", [
+    (16, "schoolbook"), (16, "karatsuba1"), (33, "schoolbook"),
+    (64, "schoolbook"), (64, "karatsuba1"), (128, "schoolbook"),
+    (128, "karatsuba1"),
+])
+def test_reduction_kernels_match_python(L, product, lanes, monkeypatch):
+    """mul2_lm = product, then _redc (carry passes, the two band products,
+    the select) against python ints: L a tile's rows, odd (33: padded to 48)
+    and wide; one lane, a few, a whole tile and one over; 0, 1, n - 1 and
+    R mod n against each other; and, from a tile of lanes up, every branch
+    of the tail."""
+    monkeypatch.setattr(mx, "KARATSUBA_MIN_L", 0 if product == "karatsuba1" else 1 << 20)
+    assert mx.product_for(L) == product
+    rng = random.Random(1000 * L + lanes)
+    n = _tail_modulus(rng, L)
+    ctx = ModCtx.make(n)
+    assert ctx.L == L
+    mctx = mx.MxuCtx.make(ctx)
+    assert mctx.L == -(-L // 16) * 16
+    R = 1 << (16 * L)
+    Rinv = pow(R, -1, n)
+    special = [0, 1, n - 1, R % n]
+    pairs = [(rng.randrange(n), rng.randrange(n))]
+    pairs += [(x, y) for x in special for y in special]
+    pairs += [(rng.randrange(n), rng.randrange(n)) for _ in range(lanes)]
+    pairs = pairs[:lanes]
+    A, B = [a for a, _ in pairs], [b for _, b in pairs]
+    out = mx.mul2_lm(mctx, _to_lm(A, L), _to_lm(B, L), interpret=True)
+    assert out.shape == (L, lanes)
+    assert _from_lm(out) == [a * b * Rinv % n for a, b in pairs]
+    if lanes >= 128:
+        want = {"t_lt_n", "t_ge_n"} | ({"top_carry"} if mctx.L == L else set())
+        assert {_tail_branch(a, b, mctx) for a, b in pairs} == want
+
+
+@pytest.mark.parametrize("L", [16, 64])
+def test_redc_takes_a_product_at_the_redundant_digit_bound(L):
+    """_redc reads a redundant T out of VMEM. Here its digits go to 2^30,
+    past anything a product leaves there (_check_product's bound), and its
+    value to n*R - 1."""
+    rng = random.Random(L)
+    n = _tail_modulus(rng, L)
+    mctx = mx.MxuCtx.make(ModCtx.make(n))
+    R = 1 << (16 * L)
+    vals = [rng.randrange(n * R) for _ in range(5)] + [n * R - 1, R - 1, 0]
+    B = len(vals)
+    T = np.zeros((2 * L, B), np.uint32)
+    for lane, v in enumerate(vals):
+        d = [int(x) for x in bn.int_to_limbs(v, 2 * L)]
+        for k in range(2 * L - 1):      # digit k takes x*2^16 off digit k + 1
+            x = min(d[k + 1], (1 << 14) - 1)
+            d[k] += x << 16
+            d[k + 1] -= x
+        T[:, lane] = d
+    assert _value(T) == vals and int(T.max()) >= 1 << 29
+
+    def body(t_ref, m_mat, m_const, q_mat, q_const, comp_ref, out_ref):
+        mx._redc(t_ref, m_mat, m_const, q_mat, q_const, comp_ref, out_ref, L, B)
+
+    out = pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct((L, B), jnp.uint32), interpret=True
+    )(jnp.asarray(T), *mctx.operands())
+    Rinv = pow(R, -1, n)
+    assert _from_lm(out) == [v * Rinv % n for v in vals]
+
+
+# ---------------------------------------------------------------------------
+# the product chosen from L: one Karatsuba level from KARATSUBA_MIN_L up
+# ---------------------------------------------------------------------------
 
 
 def _recombination_operands(rng, L):
@@ -195,14 +303,8 @@ def _recombination_operands(rng, L):
 
 @pytest.mark.parametrize("L", [16, 48, 384, 512])
 def test_karatsuba_product_matches_python(L):
-    rng = random.Random(L)
-    pairs = _recombination_operands(rng, L)
-    A, B = [a for a, _ in pairs], [b for _, b in pairs]
-    T = mx.prod_lm_k1(_to_lm(A, L), _to_lm(B, L), interpret=True)
-    assert T.shape == (2 * L, len(pairs))
-    assert _value(T) == [a * b for a, b in pairs]
-    # the redundant digits _redc's carry passes are promised (< 2^31)
-    assert int(np.asarray(T).max()) < (L // 2) * (1 << 17) + (1 << 16)
+    pairs = _recombination_operands(random.Random(L), L)
+    _check_product(_product(pairs, L, "karatsuba1"), pairs, L, "karatsuba1")
 
 
 def _threshold_neighbours():
@@ -237,8 +339,6 @@ def test_a_limb_count_the_split_cannot_take_runs_schoolbook(L, monkeypatch):
     monkeypatch.setattr(mx, "KARATSUBA_MIN_L", 0)
     assert mx.product_for(L) == "schoolbook"
     assert mx.product_for(L - L % 16 + 16) == "karatsuba1"
-    with pytest.raises(ValueError, match="multiple of"):
-        mx.prod_lm_k1(jnp.zeros((L, 1), jnp.uint32), jnp.zeros((L, 1), jnp.uint32))
     rng = random.Random(L)
     n = _rand_mod(rng, 16 * L)
     ctx = ModCtx.make(n)
@@ -265,21 +365,21 @@ def _pallas_kernels(L):
 
 
 def test_the_product_is_chosen_by_the_limb_count_alone(monkeypatch):
-    """One Pallas dispatch a multiply either way: the schoolbook kernel
-    below the threshold, the Karatsuba one from it up; no environment
-    variable and no argument has a say."""
+    """One Pallas dispatch a multiply either way, product and reduction: the
+    schoolbook product below the threshold, the Karatsuba one from it up;
+    no environment variable and no argument has a say."""
     import inspect
 
     from dds_tpu.ops import flags
 
-    assert _pallas_kernels(256) == ["kernel"]              # _make_prod_kernel's
-    assert _pallas_kernels(384) == ["karatsuba1_kernel"]
-    assert _pallas_kernels(512) == ["karatsuba1_kernel"]
+    assert _pallas_kernels(256) == ["mont_mul_schoolbook"]
+    assert _pallas_kernels(384) == ["mont_mul_karatsuba1"]
+    assert _pallas_kernels(512) == ["mont_mul_karatsuba1"]
     assert mx.product_for(256) == "schoolbook" and mx.product_for(64) == "schoolbook"
     assert mx.product_for(384) == "karatsuba1" and mx.product_for(512) == "karatsuba1"
     for name in ("DDS_KARATSUBA", "DDS_KERNEL", "DDS_PRODUCT", "DDS_PALLAS"):
         monkeypatch.setenv(name, "schoolbook")
-    assert _pallas_kernels(512) == ["karatsuba1_kernel"]
+    assert _pallas_kernels(512) == ["mont_mul_karatsuba1"]
     assert list(inspect.signature(mx.mul2_lm).parameters) == ["mctx", "a", "b", "interpret"]
     assert list(inspect.signature(mx.product_for).parameters) == ["L"]
     for fn in (mx._reduce2_fn, mx._pow2_fn):

@@ -4,12 +4,16 @@ the rows of the five cells that fold there) and at Paillier-4096 (L = 512,
 P2 = 16384: `p4096-bft4-sumall-steady`), the sha256 of its TPU lowering as jax
 prints it, and of the same text with the source locations taken out of every
 Mosaic kernel's serialized body, with the product the multiply chose from L.
+A fold's lowering is one Mosaic kernel a multiply (`kernels=15` of 16,384
+rows: product and Montgomery reduction in one program since PR 43), a
+transpose in, a lane roll at each level narrower than a tile and a slice out.
 
 The first names the checkout: each kernel's body carries the absolute path,
 line and call stack of the code that traced it, so two checkouts, or two
 line numberings of one file, never agree on it. The second is equal exactly
 when the two trees trace the same XLA ops and the same Mosaic kernels in
-the same order: run it in both and compare (PR 31 did; PERF.md section 6).
+the same order: run it in both and compare (PR 31 and PR 38 did; PERF.md
+section 6 keeps the hashes of the program each PR left).
 
     JAX_PLATFORMS=cpu python tools/fold_lowering.py [out_dir]
 
