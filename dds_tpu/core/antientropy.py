@@ -85,14 +85,16 @@ class MerkleIndex:
         blob = f"{key}|{tag.seq}|{tag.id}|{vd}".encode()
         return int.from_bytes(hashlib.sha256(blob).digest(), "big")
 
-    def update(self, key: str, tag, value) -> None:
+    def update(self, key: str, tag, value, vd: str | None = None) -> None:
+        """`vd`: the value's digest, where the caller has it already."""
         old = self._entries.get(key)
         b = self.bucket_of(key)
         if old is not None:
             self._acc[b] ^= old[2]
             del self._entries[key]
         if self._tracked(tag, value):
-            vd = sigs.value_digest(value)
+            if vd is None:
+                vd = sigs.value_digest(value)
             contrib = self._contribution(key, tag, vd)
             self._acc[b] ^= contrib
             self._entries[key] = (tag, vd, contrib)
@@ -146,6 +148,7 @@ class AntiEntropy:
         self.sync_timeout = 2.0
         self._rng = random.Random()
         self._task: Optional[asyncio.Task] = None
+        self._kick = asyncio.Event()   # a round is wanted now (`kick`)
         self._pending: dict[int, asyncio.Future] = {}
         # Atlas cross-region pairing: endpoint -> region labels, a bias
         # toward cross-region pulls (the links where divergence actually
@@ -227,9 +230,25 @@ class AntiEntropy:
             except asyncio.CancelledError:
                 pass
 
+    def kick(self) -> None:
+        """The node knows it lacks something (a reseed refused entries):
+        the loop's next round starts now, not when its timer is due."""
+        self._kick.set()
+
     async def _loop(self) -> None:
         while True:
-            await asyncio.sleep(self.interval + self._rng.uniform(0, self.jitter))
+            try:
+                await asyncio.wait_for(
+                    self._kick.wait(),
+                    self.interval + self._rng.uniform(0, self.jitter))
+            except asyncio.TimeoutError:
+                pass
+            self._kick.clear()
+            if self.node.reseeding:
+                # emptied by a `Kill` and not yet whole again: every key
+                # would read as stale and be pulled, value by value, into a
+                # repository the reseed is about to replace
+                continue
             peers = [p for p in self.node.all_replicas if p != self.node.addr]
             if not peers:
                 continue

@@ -275,13 +275,24 @@ class Suspect:
 
 @dataclass(frozen=True)
 class Awake:
-    pass
+    """Supervisor -> spare: wake up and hand over your state. With
+    `chunk_keys` > 0 the answer is streamed: `StateChunk`s of kind
+    "state" under `session`, `chunk_keys` keys each and a slice of the
+    nonces in every one, then a `State` that carries no data and says how
+    many chunks there were. Without, the whole state as one `State` (the
+    reference's form, and what `verified_transfer` off keeps)."""
+
+    session: int = 0
+    chunk_keys: int = 0
 
 
 @dataclass(frozen=True)
 class State:
     data: dict         # key -> {"tag": [seq, id], "value": set|None}
     nonces: list[int]
+    # the streamed answer's last frame: `total` chunks went under `session`
+    session: int = 0
+    total: int = 0
 
 
 @dataclass(frozen=True)
@@ -386,10 +397,14 @@ class StateChunk:
     seq: int
     entries: dict
     # which ingest path owns the session: "recovery" (SleepBegin reseed,
-    # replaces the repository) or "migrate" (ShardMigrateBegin, merges
-    # verified entries store-if-newer). Typed so a chunk that races its
-    # header can never complete the WRONG kind of session.
+    # replaces the repository), "migrate" (ShardMigrateBegin, merges
+    # verified entries store-if-newer) or "state" (a woken spare's answer
+    # to a chunked `Awake`, on its way to the supervisor). Typed so a chunk
+    # that races its header can never complete the WRONG kind of session.
     kind: str = "recovery"
+    # this chunk's slice of the seeder's nonce table (kinds "state" and
+    # "recovery"): the table travels with the state, in bounded frames
+    nonces: tuple = ()
 
 
 @dataclass(frozen=True)

@@ -330,6 +330,15 @@ class AbdClient:
                 # list (coordinator load-balancing, DDSRestServer.scala:139-147)
                 # — merge, don't reset: broadcasts (read_tags) need the whole
                 # quorum membership, which a partial view must not shrink
+                known = set(self.replicas.get_all())
+                joined = [r for r in msg.replicas if r not in known]
+                if joined:
+                    metrics.inc(
+                        "dds_membership_changes_total", len(joined),
+                        **self._mlabels(kind="joined"),
+                        help="endpoints an ActiveReplicas named that the "
+                             "proxy did not know (a promoted spare)",
+                    )
                 self.replicas.merge(msg.replicas)
                 self._preferred = list(msg.replicas)
             return

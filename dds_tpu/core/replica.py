@@ -29,6 +29,7 @@ than seq-with-arbitrary-tie-break; the ABD HMAC signs the true `tag.seq`
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import random
 import time
@@ -172,6 +173,27 @@ class _TagVector:
         return tuple(sorted(set(self.moved[at - self.trimmed:])))
 
 
+class _Reseed:
+    """One verified reseed in flight at the replica being put to sleep:
+    the header, the chunks that came before the manifests were settled,
+    and what the chunks taken in so far have built: the repository to be,
+    its Merkle index beside it, the entries refused, the nonces."""
+
+    __slots__ = ("begin", "sender", "verified", "early", "seen",
+                 "repository", "merkle", "rejected", "nonces")
+
+    def __init__(self):
+        self.begin: M.SleepBegin | None = None
+        self.sender: str | None = None
+        self.verified: dict | None = None   # settled manifests, once known
+        self.early: dict[int, M.StateChunk] = {}
+        self.seen: set[int] = set()
+        self.repository: dict[str, tuple] = {}
+        self.merkle = MerkleIndex()
+        self.rejected: list[str] = []
+        self.nonces: list[int] = []
+
+
 class BFTABDNode:
     """One replica endpoint. `addr` must appear in `replicas`."""
 
@@ -226,9 +248,12 @@ class BFTABDNode:
         self.merkle = MerkleIndex()
         # per-replica sync agent; run.launch (or a test) starts its loop
         self.antientropy = AntiEntropy(self)
-        # verified-reseed sessions in flight: session -> {begin, chunks}
+        # verified-reseed sessions in flight: session -> _Reseed
         # (SleepBegin and StateChunks may arrive in any order)
-        self._recovery_sessions: dict[int, dict] = {}
+        self._recovery_sessions: dict[int, _Reseed] = {}
+        # keys stored since a `Kill` emptied this replica, until the
+        # reseed that follows is whole (it keeps them); None otherwise
+        self._since_kill: set[str] | None = None
         # Constellation: the group's shared fencing state (shard.ShardState
         # duck-type: group_id / epoch / owns(key)). None = unsharded, no
         # fencing. Shard-migration sessions buffer separately from
@@ -297,6 +322,8 @@ class BFTABDNode:
         self._vector_version += 1
         if self._tag_vectors:
             self._stored_since.append(key)
+        if self._since_kill is not None:
+            self._since_kill.add(key)
         self.merkle.update(key, tag, value)
 
     def _drop_tag_vectors(self) -> None:
@@ -307,13 +334,23 @@ class BFTABDNode:
         self._stored_since.clear()
         self._vector_version = self.repo_version
 
-    def _install_repository(self, repository: dict) -> None:
+    def _install_repository(self, repository: dict,
+                            merkle: MerkleIndex | None = None) -> None:
         """Replace the whole repository (reseed / snapshot restore): bump
-        the version, drop the kept tag vectors, rebuild the Merkle index."""
+        the version, drop the kept tag vectors, and take `merkle` as its
+        index where the caller built one beside it, else rebuild it."""
         self.repository = repository
         self.repo_version += 1
         self._drop_tag_vectors()
-        self.merkle.rebuild(repository)
+        if merkle is None:
+            self.merkle.rebuild(repository)
+        else:
+            self.merkle = merkle
+
+    @property
+    def reseeding(self) -> bool:
+        """Between a `Kill` and the last chunk of the reseed it opens."""
+        return self._since_kill is not None
 
     def _wipe(self) -> None:
         self.repository = {}
@@ -323,6 +360,7 @@ class BFTABDNode:
         self._drop_tag_vectors()
         self.merkle.rebuild({})
         self._recovery_sessions.clear()
+        self._since_kill = set()
 
     def _quorum_met(self, responders) -> bool:
         """Quorum gate for the rounds this coordinator closes. Plain
@@ -1093,12 +1131,13 @@ class BFTABDNode:
                 })
                 for n in nonces:
                     self.incoming[int(n)] = True
+                self._since_kill = None
                 self._debug("going to sleep")
                 self._send(sender, M.Complying())
                 self.behavior = "sentinent"
 
             case M.SleepBegin():
-                self._recovery_ingest(sender, msg)
+                await self._recovery_begin(sender, msg)
 
             case M.ShardMigrateBegin():
                 self._migrate_ingest(sender, msg)
@@ -1106,15 +1145,11 @@ class BFTABDNode:
             case M.StateChunk():
                 if msg.kind == "migrate":
                     self._migrate_ingest(sender, msg)
-                else:
-                    self._recovery_ingest(sender, msg)
+                elif msg.kind == "recovery":
+                    self._recovery_chunk(msg)
 
             case M.StateDigestRequest(nonce):
-                manifest = self.merkle.manifest()
-                sig = sigs.manifest_signature(
-                    cfg.abd_mac_secret, self.addr, manifest, nonce
-                )
-                self._send(sender, M.StateDigest(manifest, nonce, sig))
+                self._answer_manifest(sender, nonce)
 
             case (M.MerkleRootRequest() | M.MerkleBucketRequest()
                   | M.MerkleKeysRequest() | M.RepairRequest() | M.MerkleRoot()
@@ -1168,23 +1203,18 @@ class BFTABDNode:
                     ):
                         self._store(e.key, e.tag, e.value)
 
-            case M.Awake():
+            case M.Awake(session, chunk_keys):
                 self._debug("waking up")
-                data = {
-                    k: {"tag": [t.seq, t.id], "value": v}
-                    for k, (t, v) in self.repository.items()
-                }
-                self._send(sender, M.State(data, list(self.incoming.keys())))
+                with tracer.span("recovery.state_build", replica=self.name,
+                                 keys=len(self.repository),
+                                 nonces=len(self.incoming)):
+                    self._answer_awake(sender, session, chunk_keys)
                 self.behavior = "healthy"
 
             case M.StateDigestRequest(nonce):
                 # the supervisor's spare-freshness probe and the verified-
                 # transfer quorum both reach spares too
-                manifest = self.merkle.manifest()
-                sig = sigs.manifest_signature(
-                    cfg.abd_mac_secret, self.addr, manifest, nonce
-                )
-                self._send(sender, M.StateDigest(manifest, nonce, sig))
+                self._answer_manifest(sender, nonce)
 
             case (M.MerkleRootRequest() | M.MerkleBucketRequest()
                   | M.MerkleKeysRequest() | M.RepairRequest() | M.MerkleRoot()
@@ -1200,6 +1230,11 @@ class BFTABDNode:
 
             case M.StateChunk() if msg.kind == "migrate":
                 self._migrate_ingest(sender, msg)
+
+            case M.StateChunk() if (msg.kind == "recovery"
+                                    and msg.session in self._recovery_sessions):
+                # a reseed this replica began: it sleeps from the header on
+                self._recovery_chunk(msg)
 
             case M.Kill():
                 self._wipe()
@@ -1287,52 +1322,160 @@ class BFTABDNode:
 
     MAX_RECOVERY_SESSIONS = 4
 
-    def _recovery_ingest(self, sender: str, msg) -> None:
-        """Buffer one frame of a verified reseed (SleepBegin header or a
-        StateChunk); transports reorder, so completion is by count, not
-        order. Sessions are bounded: a flood of bogus session ids evicts
+    def _answer_manifest(self, sender: str, nonce: int) -> None:
+        """One `StateDigestRequest` answered: the signed manifest of every
+        tracked entry (O(K): the listing, its canonical form, one MAC)."""
+        with tracer.span("recovery.manifest", replica=self.name,
+                         keys=len(self.merkle)):
+            manifest = self.merkle.manifest()
+            sig = sigs.manifest_signature(
+                self.cfg.abd_mac_secret, self.addr, manifest, nonce
+            )
+            self._send(sender, M.StateDigest(manifest, nonce, sig))
+
+    def _answer_awake(self, sender: str, session: int,
+                      chunk_keys: int) -> None:
+        """The state a woken spare hands the supervisor. Chunked
+        (`chunk_keys` > 0): `StateChunk`s of kind "state", each with its
+        slice of the nonce table, then a `State` without data that says how
+        many there were. Else the reference's one `State`.
+
+        The nonces are every one this replica has seen, not the newest N:
+        a nonce carries no time and nothing bounds how late a captured
+        message may be replayed, so any cut would be a guess at the
+        replayer's patience. They travel in bounded frames instead."""
+        entries = [
+            (k, {"tag": [t.seq, t.id], "value": v})
+            for k, (t, v) in self.repository.items()
+        ]
+        nonces = list(self.incoming)
+        if chunk_keys <= 0:
+            self._send(sender, M.State(dict(entries), nonces))
+            return
+        total = max(1, -(-len(entries) // chunk_keys))
+        per = -(-len(nonces) // total)
+        for seq in range(total):
+            self._send(sender, M.StateChunk(
+                session, seq,
+                dict(entries[seq * chunk_keys:(seq + 1) * chunk_keys]),
+                "state", tuple(nonces[seq * per:(seq + 1) * per])))
+        self._send(sender, M.State({}, [], session, total))
+
+    def _reseed(self, session: int) -> "_Reseed":
+        """The verified reseed under `session`, begun on its first frame.
+        Sessions are bounded: a flood of bogus session ids evicts
         oldest-first instead of growing without bound."""
-        sess = self._recovery_sessions.get(msg.session)
+        sess = self._recovery_sessions.get(session)
         if sess is None:
             while len(self._recovery_sessions) >= self.MAX_RECOVERY_SESSIONS:
                 self._recovery_sessions.pop(next(iter(self._recovery_sessions)))
-            sess = self._recovery_sessions[msg.session] = {
-                "begin": None, "sender": None, "chunks": {},
-            }
-        if isinstance(msg, M.SleepBegin):
-            sess["begin"] = msg
-            sess["sender"] = sender
-        else:
-            sess["chunks"][int(msg.seq)] = msg.entries
-        self._try_complete_recovery(msg.session)
+            sess = self._recovery_sessions[session] = _Reseed()
+        return sess
 
-    def _try_complete_recovery(self, session: int) -> None:
-        sess = self._recovery_sessions.get(session)
-        begin = sess["begin"]
-        if begin is None:
+    def _reseeding(self, session: int, sess: "_Reseed") -> bool:
+        """Whether `sess` is still this replica's to go on with after it
+        gave up the loop: not wiped away (`Kill`) and not evicted."""
+        return self._recovery_sessions.get(session) is sess
+
+    async def _recovery_begin(self, sender: str, msg: M.SleepBegin) -> None:
+        """The header of a verified reseed: settle what the relayed
+        manifests attest, one signer a pass of the loop (each is O(K): its
+        canonical form, its MAC, its K votes), then take in the chunks that
+        came before that was known, one a pass. Chunks that come after are
+        taken in as they arrive (`_recovery_chunk`).
+
+        The replica sleeps from the header on, not from the last chunk:
+        between the two its repository is what was written since the
+        `Kill` and nothing else, and a replica that votes in quorums with
+        that is one vote short for everybody. Asleep it stores the writes
+        that reach it, acknowledges none and answers no read, and what it
+        stored joins the seeded state when that is whole."""
+        sess = self._reseed(msg.session)
+        if sess.begin is not None:
             return
-        chunks = sess["chunks"]
-        if sum(1 for s in chunks if 0 <= s < begin.total) < begin.total:
+        sess.begin, sess.sender = msg, sender
+        self.behavior = "sentinent"
+        votes: dict[tuple, set] = {}
+        for item in msg.digests:
+            with tracer.span("recovery.install", replica=self.name,
+                             stage="manifest"):
+                _tally_manifest(votes, item, self.cfg.abd_mac_secret)
+            await asyncio.sleep(0)
+            if not self._reseeding(msg.session, sess):
+                return
+        sess.verified = _settle_manifest(votes, msg.support)
+        while sess.early:
+            seq = next(iter(sess.early))
+            self._install_chunk(sess, seq, sess.early.pop(seq))
+            await asyncio.sleep(0)
+            if not self._reseeding(msg.session, sess):
+                return
+        self._try_complete_recovery(msg.session, sess)
+
+    def _recovery_chunk(self, msg: M.StateChunk) -> None:
+        """One `StateChunk` of a verified reseed; transports reorder, so
+        completion is by count, not order."""
+        sess = self._reseed(msg.session)
+        if sess.verified is None:
+            sess.early[int(msg.seq)] = msg
             return
-        verified = self._verified_manifest(begin.digests, begin.support)
-        repository: dict[str, tuple] = {}
-        rejected: list[str] = []
-        for seq in range(begin.total):
-            for key, e in chunks[seq].items():
+        self._install_chunk(sess, int(msg.seq), msg)
+        self._try_complete_recovery(msg.session, sess)
+
+    def _install_chunk(self, sess: "_Reseed", seq: int,
+                       msg: M.StateChunk) -> None:
+        """Verify, digest and index one chunk against the settled
+        manifests: an entry is kept only as the (tag, value digest) that
+        `support` signers attest. The spare's state is data, not truth."""
+        if seq in sess.seen or not 0 <= seq < sess.begin.total:
+            return
+        sess.seen.add(seq)
+        verified, accepted = sess.verified, 0
+        with tracer.span("recovery.install", replica=self.name,
+                         stage="chunk", keys=len(msg.entries)):
+            for key, e in msg.entries.items():
                 try:
                     tag = M.ABDTag(int(e["tag"][0]), str(e["tag"][1]))
                     value = e["value"]
                 except (KeyError, TypeError, ValueError, IndexError):
-                    rejected.append(key)
+                    sess.rejected.append(key)
                     continue
-                want = verified.get(key)
-                if want == (tag.seq, tag.id, sigs.value_digest(value)):
-                    repository[key] = (tag, value)
+                vd = sigs.value_digest(value)
+                if verified.get(key) == (tag.seq, tag.id, vd):
+                    sess.repository[key] = (tag, value)
+                    sess.merkle.update(key, tag, value, vd)
+                    accepted += 1
                 else:
-                    rejected.append(key)
+                    sess.rejected.append(key)
+            sess.nonces.extend(msg.nonces)
+        for outcome, n in (("accepted", accepted),
+                           ("rejected", len(msg.entries) - accepted)):
+            if n:
+                metrics.inc(
+                    "dds_recovery_seeded_entries_total", n, outcome=outcome,
+                    help="entries of verified reseeds, by what the digest "
+                         "quorum said of them",
+                )
+
+    def _try_complete_recovery(self, session: int, sess: "_Reseed") -> None:
+        begin = sess.begin
+        if (begin is None or sess.verified is None
+                or len(sess.seen) < begin.total):
+            return
         self._recovery_sessions.pop(session, None)
-        self._install_repository(repository)
-        for n in begin.nonces:
+        repository, rejected = sess.repository, sess.rejected
+        # what was written HERE since the `Kill`, each write under its
+        # coordinator's MAC (acknowledged before the header came, stored
+        # asleep after it), is newer than any seed and stays
+        for key in self._since_kill or ():
+            tag, value = self.repository.get(key, (None, None))
+            seeded = repository.get(key)
+            if tag is not None and (seeded is None or seeded[0] < tag):
+                repository[key] = (tag, value)
+                sess.merkle.update(key, tag, value)
+        self._since_kill = None
+        self._install_repository(repository, sess.merkle)
+        for n in (*begin.nonces, *sess.nonces):
             self.incoming[int(n)] = True
         if rejected:
             log.warning(
@@ -1351,11 +1494,12 @@ class BFTABDNode:
                 "recovery_digest_mismatch", replica=self.name,
                 rejected=sorted(rejected)[:32], accepted=len(repository),
             )
+            self.antientropy.kick()
         self._debug(
             f"reseeded with {len(repository)} verified entries "
             f"({len(rejected)} rejected); going to sleep"
         )
-        self._send(sess["sender"], M.Complying())
+        self._send(sess.sender, M.Complying())
         self.behavior = "sentinent"
 
     def _verified_manifest(self, digests: list, support: int) -> dict:
@@ -1463,25 +1607,39 @@ def verified_manifest(digests: list, support: int, secret: bytes) -> dict:
     and keep only entries attested identically by >= `support` (= f+1)
     distinct signers — at least one of which is then honest, so no
     single Byzantine spare or relay can smuggle a forged entry. Shared
-    by verified recovery reseeds, shard-migration ingest, and the
-    rebalancer's source-side planning (shard/rebalance)."""
+    by verified recovery reseeds (which take it a signer at a time),
+    shard-migration ingest, and the rebalancer's source-side planning
+    (shard/rebalance)."""
     votes: dict[tuple, set] = {}
     for item in digests:
+        _tally_manifest(votes, item, secret)
+    return _settle_manifest(votes, support)
+
+
+def _tally_manifest(votes: dict, item, secret: bytes) -> None:
+    """One relayed manifest: its HMAC verified, its entries added to
+    `votes` under their signer. A bad MAC or a malformed item adds
+    nothing."""
+    try:
+        signer, manifest, nonce, sighex = item
+        if not sigs.validate_manifest_signature(
+            secret, str(signer), manifest,
+            int(nonce), bytes.fromhex(sighex),
+        ):
+            return
+    except (TypeError, ValueError):
+        return
+    for key, ent in manifest.items():
         try:
-            signer, manifest, nonce, sighex = item
-            if not sigs.validate_manifest_signature(
-                secret, str(signer), manifest,
-                int(nonce), bytes.fromhex(sighex),
-            ):
-                continue
-        except (TypeError, ValueError):
+            attested = (str(key), int(ent[0]), str(ent[1]), str(ent[2]))
+        except (TypeError, ValueError, IndexError):
             continue
-        for key, ent in manifest.items():
-            try:
-                attested = (str(key), int(ent[0]), str(ent[1]), str(ent[2]))
-            except (TypeError, ValueError, IndexError):
-                continue
-            votes.setdefault(attested, set()).add(str(signer))
+        votes.setdefault(attested, set()).add(str(signer))
+
+
+def _settle_manifest(votes: dict, support: int) -> dict:
+    """Per key the newest (seq, id, value digest) that `support` distinct
+    signers attested identically."""
     verified: dict[str, tuple] = {}
     for (key, seq, tid, vd), signers in votes.items():
         if len(signers) < support:

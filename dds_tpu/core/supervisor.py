@@ -70,6 +70,23 @@ class SupervisorConfig:
     debug: bool = False
 
 
+@dataclass
+class _Wake:
+    """A chunked `Awake` answer in flight: the spare asked, its chunks by
+    `seq`, and how many its closing `State` says there are."""
+
+    fut: asyncio.Future
+    spare: str
+    chunks: dict
+    total: int | None = None
+
+    def settle(self) -> None:
+        if (self.total is not None and not self.fut.done()
+                and all(seq in self.chunks for seq in range(self.total))):
+            self.fut.set_result(
+                [self.chunks[seq] for seq in range(self.total)])
+
+
 class BFTSupervisor:
     MAX_VOTE_NONCES = 4096   # `Suspect` nonces remembered, oldest out
 
@@ -116,6 +133,7 @@ class BFTSupervisor:
         # manifest collections in flight: request nonce -> (future,
         # sender -> StateDigest, target reply count)
         self._manifest_collects: dict[int, tuple] = {}
+        self._waking: dict[int, _Wake] = {}   # by the `Awake`'s session
         net.register(addr, self.handle)
 
     # ----------------------------------------------------------- life cycle
@@ -222,7 +240,19 @@ class BFTSupervisor:
                     self.quorum[replica] = set()
                     await self.recover(replica)
 
-            case M.State(_, _) | M.Complying():
+            case M.StateChunk(session, seq, kind="state"):
+                wake = self._waking.get(session)
+                if wake is not None and sender == wake.spare:
+                    wake.chunks[int(seq)] = msg
+                    wake.settle()
+
+            case M.State(session=session, total=total) if session in self._waking:
+                wake = self._waking[session]
+                if sender == wake.spare:
+                    wake.total = int(total)
+                    wake.settle()
+
+            case M.State() | M.Complying():
                 fut = self._pending.pop(f"{type(msg).__name__}:{sender}", None)
                 if fut is not None and not fut.done():
                     fut.set_result(msg)
@@ -310,7 +340,11 @@ class BFTSupervisor:
         votes: dict[str, M.StateDigest] = {}
         self._manifest_collects[nonce] = (fut, votes, target_count)
         for t in targets:
+            # one request a pass of the loop: a manifest is O(K) to list
+            # and sign and O(K) to verify here, and all of them asked at
+            # once would be one pass that long
             self.net.send(self.addr, t, M.StateDigestRequest(nonce))
+            await asyncio.sleep(0)
         try:
             await asyncio.wait_for(fut, self.cfg.manifest_timeout)
         except asyncio.TimeoutError:
@@ -342,6 +376,7 @@ class BFTSupervisor:
         self._manifest_collects[nonce] = (fut, votes, len(spares))
         for s in spares:
             self.net.send(self.addr, s, M.StateDigestRequest(nonce))
+            await asyncio.sleep(0)
         timeout = min(self.cfg.manifest_timeout,
                       self.cfg.sentinent_awake_timeout)
         try:
@@ -357,34 +392,56 @@ class BFTSupervisor:
                 )
         return fresh
 
-    async def _seed(self, dest: str, state: M.State, verified: tuple | None,
-                    timeout: float):
+    async def _wake(self, spare: str) -> list[M.StateChunk]:
+        """`Awake` the spare and await its state, as the chunks a seed
+        relays. With verified transfer the spare streams them itself
+        (`StateChunk`s of kind "state", then the `State` that counts them;
+        transports reorder, so completion is by count); without, its one
+        `State` is the reference's and becomes the one chunk of a
+        `Sleep`."""
+        timeout = self.cfg.sentinent_awake_timeout
+        if not self.cfg.verified_transfer:
+            state = await self._ask(spare, M.Awake(), "State", timeout)
+            return [M.StateChunk(0, 0, state.data, "state",
+                                 tuple(state.nonces))]
+        session = sigs.generate_nonce()
+        wake = self._waking[session] = _Wake(
+            asyncio.get_event_loop().create_future(), spare, {})
+        self.net.send(self.addr, spare, M.Awake(
+            session, max(1, self.cfg.state_chunk_keys)))
+        try:
+            return await asyncio.wait_for(wake.fut, timeout)
+        finally:
+            del self._waking[session]
+
+    async def _seed(self, dest: str, state: list[M.StateChunk],
+                    verified: tuple | None, timeout: float):
         """Reseed `dest` with the spare's state and await its Complying.
 
         Verified path: relay the collected manifest quorum in a SleepBegin
-        header, then stream the state as bounded StateChunk frames — the
-        node cross-checks every entry against the digest quorum, so the
-        spare's State is data, not truth. `verified=None` falls back to
-        the legacy single-frame Sleep (reference behavior)."""
+        header, then the spare's chunks as they came — the node
+        cross-checks every entry against the digest quorum, so the spare's
+        state is data, not truth, and takes each chunk in as it arrives.
+        `verified=None` falls back to the legacy single-frame Sleep
+        (reference behavior)."""
         if verified is None:
+            data, nonces = {}, []
+            for chunk in state:
+                data.update(chunk.entries)
+                nonces.extend(chunk.nonces)
             return await self._ask(
-                dest, M.Sleep(state.data, state.nonces), "Complying", timeout
+                dest, M.Sleep(data, nonces), "Complying", timeout
             )
         digests, support = verified
         session = sigs.generate_nonce()
-        items = sorted(state.data.items())
-        k = max(1, self.cfg.state_chunk_keys)
-        chunks = [dict(items[i:i + k]) for i in range(0, len(items), k)] or [{}]
         fut = self._expect(dest, "Complying")
         self.net.send(
             self.addr, dest,
-            M.SleepBegin(digests, session, len(chunks), support,
-                         list(state.nonces)),
+            M.SleepBegin(digests, session, len(state), support, []),
         )
-        for seq, chunk in enumerate(chunks):
-            self.net.send(self.addr, dest, M.StateChunk(session, seq, chunk))
-        tracer.event("supervisor.seed", dest=dest, chunks=len(chunks),
-                     keys=len(items), verified=True)
+        for seq, chunk in enumerate(state):
+            self.net.send(self.addr, dest, M.StateChunk(
+                session, seq, chunk.entries, "recovery", chunk.nonces))
         return await self._await_reply(dest, "Complying", fut, timeout)
 
     async def recover(self, byzantine: str) -> None:
@@ -411,11 +468,17 @@ class BFTSupervisor:
         self._idle.clear()
         spare = None
         tried: set[str] = set()
+        outcome = "no_spare"
         with tracer.span("supervisor.recover", victim=byzantine) as span:
             try:
                 verified = None
                 if self.cfg.verified_transfer:
-                    verified = await self._collect_manifests({byzantine})
+                    with tracer.span("recovery.manifests"):
+                        verified = await self._collect_manifests({byzantine})
+                        freshness = await self._probe_spares([
+                            s for s in self.sentinent
+                            if s not in self._recovering
+                        ])
                     if verified is None:
                         log.warning(
                             "verified state transfer degraded for %s: no "
@@ -427,10 +490,9 @@ class BFTSupervisor:
                             help="recoveries that fell back to single-spare "
                                  "trust (no manifest quorum)",
                         )
+                else:
+                    freshness = {}
                 span["verified"] = verified is not None
-                freshness = await self._probe_spares(
-                    [s for s in self.sentinent if s not in self._recovering]
-                ) if self.cfg.verified_transfer else {}
                 while True:
                     pool = [
                         s for s in self.sentinent
@@ -456,10 +518,8 @@ class BFTSupervisor:
                     tried.add(spare)
                     self._recovering.add(spare)
                     try:
-                        state = await self._ask(
-                            spare, M.Awake(), "State",
-                            self.cfg.sentinent_awake_timeout,
-                        )
+                        with tracer.span("recovery.wake", seeder=spare):
+                            state = await self._wake(spare)
                         self._strikes.pop(spare, None)
                         break
                     except asyncio.TimeoutError:
@@ -468,9 +528,16 @@ class BFTSupervisor:
                             self.sentinent.remove(spare)
                         spare = None
 
-                span["seeder"] = spare
+                span.update(
+                    seeder=spare,
+                    keys=sum(len(c.entries) for c in state),
+                    nonces=sum(len(c.nonces) for c in state),
+                )
                 tracer.event("supervisor.seeder", victim=byzantine,
                              seeder=spare, freshness=freshness.get(spare, 0))
+                outcome = ("swapped" if verified is not None
+                           or not self.cfg.verified_transfer
+                           else "unverified")
 
                 # promote the spare
                 self.sentinent.remove(spare)
@@ -481,10 +548,11 @@ class BFTSupervisor:
                 self.active = [r for r in self.active if r[0] != byzantine]
 
                 try:
-                    await self._seed(
-                        byzantine, state, verified,
-                        self.cfg.sentinent_awake_timeout,
-                    )
+                    with tracer.span("recovery.seed", chunks=len(state)):
+                        await self._seed(
+                            byzantine, state, verified,
+                            self.cfg.sentinent_awake_timeout,
+                        )
                     self._strikes.pop(byzantine, None)
                     self.sentinent.append(byzantine)
                     self.quorum[byzantine] = set()
@@ -496,6 +564,7 @@ class BFTSupervisor:
                         return
                     if self.cfg.debug:
                         log.info("replica %s crashed; rebooting", byzantine)
+                    outcome = "redeployed"
                     await self.redeploy(byzantine)
                     try:
                         await self._seed(
@@ -516,6 +585,11 @@ class BFTSupervisor:
                     self.sentinent.append(byzantine)
                     self.quorum[byzantine] = set()
             finally:
+                metrics.inc(
+                    "dds_recovery_rotations_total", outcome=outcome,
+                    help="recoveries the supervisor ran to an end, by how "
+                         "they ended",
+                )
                 self._recovering.discard(byzantine)
                 if spare is not None:
                     self._recovering.discard(spare)

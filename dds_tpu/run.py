@@ -478,6 +478,10 @@ async def _launch(cfg: DDSConfig) -> Deployment:
                 debug=cfg.debug,
             ),
             redeploy=redeploy,
+            # the draw of a spare comes from the deployment's one seed, as
+            # Trudy's victims do: a run's membership history is a function
+            # of its configuration
+            rng=random.Random(cfg.attacks.chaos_seed),
         )
         supervisor.start()
 
@@ -516,6 +520,7 @@ async def _launch(cfg: DDSConfig) -> Deployment:
             peers=cfg.proxy.remote_peers,
             keys_path=cfg.proxy.stored_keys_path,
             coalesce_window=cfg.proxy.coalesce_window,
+            replica_refresh_interval=cfg.proxy.replica_refresh_interval,
             supervisor=sup_addr,
             trace_route_enabled=cfg.debug or cfg.obs.trace_route,
             metrics_route_enabled=cfg.obs.metrics_route,
@@ -694,6 +699,7 @@ def proxy_config(cfg: DDSConfig, supervisor, ssl_server, ssl_client,
         crypto_backend=cfg.proxy.crypto_backend,
         keys_path=cfg.proxy.stored_keys_path,
         coalesce_window=cfg.proxy.coalesce_window,
+        replica_refresh_interval=cfg.proxy.replica_refresh_interval,
         supervisor=supervisor,
         trace_route_enabled=cfg.debug or cfg.obs.trace_route,
         metrics_route_enabled=cfg.obs.metrics_route,

@@ -129,6 +129,10 @@ class ProxySettings:
     breaker_threshold: int = 3
     breaker_reset: float = 2.0
     breaker_probe_timeout: float = 1.0
+    # how often the proxy asks the supervisor who is active
+    # (DDSRestServer.scala:139-147): the only way it learns of a spare the
+    # supervisor promoted, so it is read against recovery.interval
+    replica_refresh_interval: float = 5.0
     key_sync_enabled: bool = False
     key_sync_warm_up: float = 1.0
     key_sync_interval: float = 5.0
