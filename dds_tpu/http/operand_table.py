@@ -13,15 +13,23 @@ of no others (`resident/pool.rows_for`).
 
 An aggregate moves it by `apply([(position, entry)])` alone: O(changed
 rows) of list stores, `int()` parses and one copy of K pointers per
-patched column. What stays O(K) per changed version runs in C over whole
-lists: the copy of the tag vector and the join and hash of its fields for
-the replicas' tag round (`round_args`). In Python: one pass of tag
+patched column. A key set that only grew is the old table plus its new
+keys (`OperandTable.grown`): copies of the old lists with the new rows
+spliced in at their sorted positions, each kept column carried with only
+the new rows parsed, and its pool rows carried with only the new
+positions to look up. What stays O(K) per changed version runs in C over
+whole lists: the copy of the tag vector and the join and hash of its fields
+for the replicas' tag round (`round_args`). In Python: one pass of tag
 compares when the quorum saw a tag move (`stale`), and the `[(key,
 value)]` list of the routes that still read pairs (`pairs`), built when
 one of them asks.
 """
 
 from __future__ import annotations
+
+from bisect import bisect_left, bisect_right
+
+import numpy as np
 
 from dds_tpu.obs.metrics import metrics
 from dds_tpu.resident.pool import Operands, RowTrack
@@ -30,6 +38,18 @@ from dds_tpu.utils import sigs
 # columns kept per table: one per distinct `position` clients aggregate
 # over; past it the oldest goes (a request for it again parses it again)
 MAX_COLUMNS = 8
+
+
+def _spliced(old: list, at: list[int], items: list) -> list:
+    """A copy of `old` with `items[t]` put before `old[at[t]]` (`at`
+    ascending): slices in C and one append an item, `old` untouched."""
+    out, prev = [], 0
+    for b, item in zip(at, items):
+        out += old[prev:b]
+        out.append(item)
+        prev = b
+    out += old[prev:]
+    return out
 
 
 class OperandColumn:
@@ -52,7 +72,54 @@ class OperandColumn:
         self.where = where if len(ops) < len(entries) else None
         self.track = RowTrack()
         self.operands = Operands(ops, self.track)
-        self.shown: Operands | None = None   # the version last handed out
+        # the version last handed out; None: none of this column yet (it
+        # was carried from the table before, `grown`)
+        self.shown: Operands | None = None
+
+    def grown(self, at: list[int], added: list) -> OperandColumn | None:
+        """This column with the entries `added` put before table positions
+        `at` (ascending), as a new column: only their operands are parsed,
+        and this one is left as it is. None when an added row has no
+        operand here or holds what `int()` refuses, as `patch` says False.
+
+        The new column's `RowTrack` hands every pool that resolved this
+        one its index array with room made at the inserted positions, and
+        a log of this one's positions the slowest of those pools has yet
+        to look up, moved to where they are now, then the inserted ones:
+        `ResidentPool.rows_for` looks those up and no others."""
+        pos, parsed = self.pos, []
+        for e in added:
+            v = e[1] if e is not None else None
+            if v is None or pos >= len(v):
+                return None
+            try:
+                parsed.append(int(v[pos]))
+            except (TypeError, ValueError):
+                return None
+        old, where = self.operands, self.where
+        if where is None:
+            at_ops = at
+        else:
+            where, n = _spliced(where, at, [0] * len(at)), 0
+            for i, w in enumerate(where):
+                if w >= 0:
+                    where[i], n = n, n + 1
+            # each inserted operand's index, as among the old operands
+            at_ops = [where[b + t] - t for t, b in enumerate(at)]
+        col = object.__new__(OperandColumn)
+        col.pos, col.where, col.shown = pos, where, None
+        track = col.track = RowTrack()
+        known = list(old.track.rows.values())
+        if known:
+            base = min(r[2] for r in known)
+            track.log = [j + bisect_right(at_ops, j)
+                         for j in old.track.log[base:old.version]]
+            for pool, epoch, version, idx in known:
+                track.rows[id(pool)] = (
+                    pool, epoch, version - base, np.insert(idx, at_ops, 0))
+        track.log.extend(b + t for t, b in enumerate(at_ops))
+        col.operands = Operands(_spliced(old, at_ops, parsed), track)
+        return col
 
     def patch(self, updates: list) -> bool:
         """Take `[(table position, entry)]` in. False when a row gained or
@@ -88,28 +155,62 @@ class OperandColumn:
         return True
 
 
+def _tags_fields(entries: list) -> tuple[list, list]:
+    """Each entry's tag, and its field of the fingerprint."""
+    tags = [e[0] if e is not None else None for e in entries]
+    return tags, [sigs.tag_field(t) if t is not None else None for t in tags]
+
+
 class OperandTable:
-    def __init__(self, keys: list[str], cache: dict, stored_version: int):
+    def __init__(self, keys: list[str], cache: dict, stored_version: int,
+                 _lists: tuple | None = None):
         self.keys = keys                      # sorted, never changed
         self.stored_version = stored_version
-        self.index = {k: i for i, k in enumerate(keys)}
+        self.index = dict(zip(keys, range(len(keys))))
         # entries[i]: the cache's (tag, value) tuple of keys[i]; None while
         # the key was never read; (None, value) for a read the cache would
         # not keep (cache off, no tag): served this round, stale the next
-        self.entries: list = [cache.get(k) for k in keys]
-        self.uncached = sum(1 for e in self.entries if e is None)
-        # each entry's tag and its field of the fingerprint, patched with
-        # the entry: the tag round's vector is then a copy and a join
-        self.tags: list = [e[0] if e is not None else None
-                           for e in self.entries]
-        self.fields: list = [sigs.tag_field(t) if t is not None else None
-                             for t in self.tags]
+        # tags, fields: each entry's tag and its field of the fingerprint,
+        # patched with the entry: the tag round's vector is then a copy and
+        # a join
+        if _lists is None:    # `grown` brings its own
+            entries = [cache.get(k) for k in keys]
+            _lists = (entries, *_tags_fields(entries))
+        self.entries, self.tags, self.fields = _lists
+        self.uncached = self.entries.count(None)
         self.version = 0                      # bumps when an entry moves
         self.settled = -1     # the version an aggregate last patched up to
         self.columns: dict[int, OperandColumn] = {}
         self._digest: str | None = None
         self._round: tuple | None = None
         self._pairs: tuple | None = None
+
+    @classmethod
+    def grown(cls, old: OperandTable, added, cache: dict,
+              stored_version: int) -> OperandTable | None:
+        """The table of `old`'s keys and the keys `added` (none of them
+        `old`'s), equal to one built anew from a cache whose entries of
+        `old`'s keys are the ones `old` holds: copies of its lists with the
+        added keys' entries spliced in at their sorted positions, a new
+        index, and each column carried by `OperandColumn.grown` (one that
+        cannot take an added row is left out: the request that wants it
+        parses it whole). O(K) in C and O(added) in Python. `old`, its
+        lists and its columns are left as they are: an aggregate that
+        began on it finishes on it. None when `old` holds an entry without
+        a tag, which a table built anew would not hold."""
+        if old.uncached and old.uncached != old.entries.count(None):
+            return None
+        keys = sorted(added)
+        at = [bisect_left(old.keys, k) for k in keys]
+        entries = [cache.get(k) for k in keys]
+        tags, fields = _tags_fields(entries)
+        table = cls(_spliced(old.keys, at, keys), cache, stored_version, (
+            _spliced(old.entries, at, entries), _spliced(old.tags, at, tags),
+            _spliced(old.fields, at, fields)))
+        for pos, col in old.columns.items():
+            if (col := col.grown(at, entries)) is not None:
+                table.columns[pos] = col
+        return table
 
     def apply(self, updates: list) -> int:
         """Replace entries by `[(position, entry)]`, skipping those already
@@ -201,9 +302,10 @@ class OperandTable:
     def column(self, pos: int) -> tuple[Operands, str]:
         """(operands at `pos`, outcome): `reused` when it is the list
         handed out last time, `patched` when rows were parsed into it
-        since, `rebuilt` when every row had to be parsed (a first request
-        for `pos` on this table). `int()` raises here, for the request
-        that asked, as it always did."""
+        since, `grown` when it was carried from the table before this one
+        with the added rows parsed in, `rebuilt` when every row had to be
+        parsed (a first request for `pos` on a table built anew). `int()`
+        raises here, for the request that asked, as it always did."""
         col = self.columns.get(pos)
         if col is None:
             col = OperandColumn(pos, self.entries)
@@ -211,6 +313,8 @@ class OperandTable:
                 del self.columns[next(iter(self.columns))]
             self.columns[pos] = col
             outcome = "rebuilt"
+        elif col.shown is None:
+            outcome = "grown"
         else:
             outcome = "reused" if col.shown is col.operands else "patched"
         col.shown = col.operands

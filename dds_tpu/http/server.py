@@ -954,10 +954,16 @@ class DDSRestServer:
     def _sync_table(self) -> OperandTable:
         """The operand table an aggregate starts on: the current one with
         every cache entry that moved since it last looked taken in (O(moved
-        keys)), or one built anew (a sort of K keys and K cache probes)
-        when the key set changed, the cache was flushed or the cache is
-        off. `assembly.state` times it, with the tag list and fingerprint
-        of the round to come, whenever there is something to do."""
+        keys)); when the key set changed, the old table grown by its new
+        keys (`OperandTable.grown`: copies and splices of K pointers, the
+        added rows parsed) if none of its keys went and the added are at
+        most an eighth of them (`len(added) * 8 <= len(table.keys)`: past
+        that the splices cost what a sort does); else one built anew (a
+        sort of K keys and K cache probes): keys removed, too many added,
+        the cache flushed or off, no table yet. `assembly.state` times it,
+        with the tag list and fingerprint of the round to come, whenever
+        there is something to do; `built` says which, `added` how many of
+        the keys the table before did not have."""
         table = self._table
         build = (table is None
                  or table.stored_version != self._stored_version
@@ -965,17 +971,29 @@ class DDSRestServer:
         if not build and not self._dirty:
             return table
         with tracer.span("assembly.state") as sm:
+            built, added = "patched", ()
             if build:
-                table = self._table = OperandTable(
-                    sorted(self.stored_keys), self._cache,
-                    self._stored_version,
-                )
-                self._dirty.clear()
-            else:
-                self._take_dirty(table)
+                grown = None
+                if table is not None and self.cfg.aggregate_cache:
+                    added = self.stored_keys - table.index.keys()
+                    if (len(added) * 8 <= len(table.keys)
+                            and table.index.keys() <= self.stored_keys):
+                        grown = OperandTable.grown(
+                            table, added, self._cache, self._stored_version)
+                if grown is None:
+                    built, grown = "anew", OperandTable(
+                        sorted(self.stored_keys), self._cache,
+                        self._stored_version,
+                    )
+                    self._dirty.clear()   # its entries are the cache's
+                else:
+                    built = "grown"
+                table = self._table = grown
+            self._take_dirty(table)
             table.round_args()
             sm["k"] = len(table.keys)
             sm["cached"] = len(table.keys) - table.uncached
+            sm["built"], sm["added"] = built, len(added)
         return table
 
     def _take_dirty(self, table: OperandTable) -> None:
@@ -2546,8 +2564,8 @@ class DDSRestServer:
             metrics.inc(
                 "dds_operand_table_total", outcome=outcome,
                 help="aggregates by what their operand column cost: reused "
-                     "as it was, patched by the rows that moved, or parsed "
-                     "whole",
+                     "as it was, patched by the rows that moved, grown by "
+                     "the rows of new keys, or parsed whole (rebuilt)",
             )
             om["k"], om["memo"] = len(operands), outcome == "reused"
         if not operands:

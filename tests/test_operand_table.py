@@ -28,7 +28,7 @@ NSQR = ((1 << 61) - 1) ** 2        # SumAll's modulus here: column 2
 PUB = (1 << 89) - 1                # MultAll's: column 0
 SUM = f"/SumAll?position=2&nsqr={NSQR}"
 MULT = f"/MultAll?position=0&pubkey={PUB}"
-OUTCOMES = ("reused", "patched", "rebuilt")
+OUTCOMES = ("reused", "patched", "rebuilt", "grown")
 
 
 def product(rows, pos, mod):
@@ -458,7 +458,9 @@ def test_rebuild_events_answer_exactly(event, on_pool):
                 assert server._table is None      # flushed, not yet rebuilt
                 assert server._cache == {}
             else:
-                assert d["rebuilt"] == 1 and "patched" not in d
+                # a new key among 12 grows the table (test_growing_keyset)
+                built = "grown" if event == "putset" else "rebuilt"
+                assert d[built] == 1 and "patched" not in d
                 assert server._table is not table
             before = counters()
             assert await agg(server) == product(rows.values(), 2, NSQR)
