@@ -9,13 +9,10 @@ of a guess (r4 verdict #2): for each K it measures
                below-crossover request would pay, host<->device round
                trip included;
 - device-sus:  sustained per-fold time with R pipelined dispatches —
-               what concurrent serving pays per request;
-- coalesced:   per-request time when R concurrent K-wide folds share one
-               segmented dispatch (ops/foldmany) — the cross-request
-               batching path.
+               what concurrent serving pays per request.
 
 The printed curve is the BASELINE.md artifact; the crossover points are
-where device-lat / coalesced dip below host.
+where device-lat / device-sus dip below host.
 
 Usage: python -m benchmarks.crossover [--ks 32 64 ... ] [--r 8]
 """
@@ -62,7 +59,6 @@ def main(argv=None):
 
         from dds_tpu.models.backend import TpuBackend
         from dds_tpu.ops import bignum as bn
-        from dds_tpu.ops import foldmany
 
         be = TpuBackend(min_device_batch=0)
         kernel = be.kernel if be.pallas else "jnp"
@@ -90,14 +86,6 @@ def main(argv=None):
         lat_s = best_of(one_fold)
         sus_s = sustained_device(lambda: be.reduce_mul_device(ctx, dev), R=args.r)
 
-        folds = [cs] * args.r
-        foldmany.fold_many(folds, n2, kernel=kernel)  # warm/compile
-
-        def coal():
-            foldmany.fold_many(folds, n2, kernel=kernel)
-
-        coal_s = best_of(coal) / args.r
-
         rows.append(
             emit(
                 METRIC,
@@ -108,7 +96,6 @@ def main(argv=None):
                 host_ms=round(host_s * 1e3, 3),
                 device_latency_ms=round(lat_s * 1e3, 3),
                 device_sustained_ms=round(sus_s * 1e3, 3),
-                coalesced_ms_per_req=round(coal_s * 1e3, 3),
                 r=args.r,
                 kernel=kernel,
             )
@@ -127,7 +114,6 @@ def main(argv=None):
 
     print(f"# crossover (device latency < host): K >= {crossover('device_latency_ms')}")
     print(f"# crossover (sustained < host):      K >= {crossover('device_sustained_ms')}")
-    print(f"# crossover (coalesced < host):      K >= {crossover('coalesced_ms_per_req')}")
     return rows
 
 

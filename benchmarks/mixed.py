@@ -13,8 +13,7 @@ Two YCSB-faithful knobs added in r5 (the config-5 re-spec of r4 verdict
   realistic store size instead of the ~40 rows the 200-op mix happens to
   accumulate;
 - `--clients N`: N concurrent clients (the reference's `Main.scala:
-  166-170`), whose concurrent small SumAlls coalesce into shared device
-  dispatches (ops/foldmany).
+  166-170`), whose concurrent SumAlls fold on worker threads.
 
 Reports end-to-end aggregate client ops/s per crypto backend.
 

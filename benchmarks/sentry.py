@@ -37,8 +37,8 @@ Modes (all emit one JSON line to stdout):
         file, 0 when clean (including "nothing to compare": an empty
         baseline can never fail the gate, it just reports coverage 0).
 
-The probe workload drives `ops.foldmany` (the aggregate-fold kernel
-behind `SumAll`) at two fixed shapes; it runs on whatever jax backend is
+The probe workload drives `ops.foldmany` (the weighted fold behind the
+analytics routes) at two fixed shapes; it runs on whatever jax backend is
 available, so the same invocation gates CPU CI and TPU perf runs — each
 environment keeps its OWN baseline file (a CPU p50 is meaningless
 against a TPU one, which is why the kernel key includes shape but the
@@ -59,9 +59,9 @@ from dds_tpu.obs import sentry  # noqa: E402 — stdlib-only import
 
 
 def probe(repeats: int = 5) -> dict:
-    """Deterministic probe workload: a handful of foldmany dispatches at
-    two shapes, collected from a fresh tracer ring."""
-    from dds_tpu.ops.foldmany import fold_many
+    """Deterministic probe workload: a handful of weighted-fold dispatches
+    at two shapes, collected from a fresh tracer ring."""
+    from dds_tpu.ops.foldmany import fold_weighted
     from dds_tpu.utils.trace import tracer
 
     # a fixed odd modulus (Mersenne 127) keeps ModCtx shapes stable; the
@@ -69,14 +69,14 @@ def probe(repeats: int = 5) -> dict:
     # dispatch stats are steady-state — a cold compile is ~4x a warm
     # dispatch and would gate on cache temperature, not kernel speed
     n = (1 << 127) - 1
-    folds_small = [[3, 5, 7], [11, 13]]
-    folds_wide = [[3, 5, 7, 11, 13, 17, 19, 23]] * 4
-    fold_many(folds_small, n)
-    fold_many(folds_wide, n)
+    small = ([3, 5, 7], [[1, 1, 1], [1, 0, 1]])
+    wide = ([3, 5, 7, 11, 13, 17, 19, 23], [[1] * 8] * 4)
+    fold_weighted(*small, n)
+    fold_weighted(*wide, n)
     tracer.reset()
     for _ in range(max(1, repeats)):
-        fold_many(folds_small, n)
-        fold_many(folds_wide, n)
+        fold_weighted(*small, n)
+        fold_weighted(*wide, n)
     return sentry.collect()
 
 

@@ -32,13 +32,6 @@ decision loop, sitting at the REST edge BEFORE a Deadline is minted:
   `shed_hold` consecutive healthy evaluations — the hysteresis that
   keeps a marginal system from flapping. Every transition is
   flight-recorded and counted (`dds_admission_*`).
-- `AdaptiveCoalescer`: sizes the proxy's fold-coalescing window from the
-  OBSERVED fold arrival rate instead of a fixed knob — the BTS insight
-  (arxiv 2112.15479) that HE throughput comes from keeping batch shapes
-  full and steady: under load the window stretches until an expected
-  `target_folds` arrivals fit (so device batches stay full), and snaps
-  back to the base window when traffic goes idle (so a lone aggregate
-  never waits for company that is not coming).
 
 The controller imports no config tree and no SLO engine — the burn and
 breaker signals arrive as injected callables, and every class takes an
@@ -59,7 +52,7 @@ from dds_tpu.utils.trace import tracer
 
 __all__ = [
     "CLASSES", "route_class",
-    "TokenBucket", "Decision", "AdmissionController", "AdaptiveCoalescer",
+    "TokenBucket", "Decision", "AdmissionController",
 ]
 
 # Priority classes, highest first. The shed ratchet drops them from the
@@ -567,77 +560,3 @@ class AdmissionController:
                     "transitions": list(self.tenant_transitions[-8:]),
                 },
             }
-
-
-class AdaptiveCoalescer:
-    """Sizes the fold-coalescing window from observed arrival rate.
-
-    The proxy's coalescing window (ProxyConfig.coalesce_window) gathers
-    concurrent sub-crossover folds into one segmented device dispatch. A
-    fixed window is wrong at both ends: too short under load (batches
-    dispatch half-full, dispatch overhead per fold stays high) and pure
-    latency when sized for load but traffic is idle. This tracks a
-    time-decayed EWMA of the fold arrival rate (`note_fold`, called per
-    aggregate fold at the proxy) and answers `window()`:
-
-        idle (expected co-arrivals ~ 0)  -> base window (snap small)
-        loaded                           -> clamp(target_folds / rate,
-                                                 base, max_window)
-
-    so the window stretches exactly until ~`target_folds` arrivals are
-    expected to share the dispatch, and no further — full, steady batch
-    shapes, the property the HE-accelerator literature (BTS) gets its
-    throughput from."""
-
-    def __init__(self, base_window: float, max_window: float,
-                 target_folds: float = 8.0, half_life: float = 1.0,
-                 clock: Callable[[], float] = time.monotonic):
-        self.base_window = float(base_window)
-        self.max_window = max(float(max_window), self.base_window)
-        self.target_folds = float(target_folds)
-        self.half_life = float(half_life)
-        self._clock = clock
-        self._lock = threading.Lock()
-        self._ewma_rate = 0.0   # folds per second
-        self._last: float | None = None
-        self._folds = 0
-
-    def note_fold(self, width: int = 1) -> None:
-        """Record one fold arrival (the observed-load signal)."""
-        with self._lock:
-            self._folds += 1
-            now = self._clock()
-            if self._last is None:
-                self._last = now
-                return
-            dt = max(1e-6, now - self._last)
-            self._last = now
-            # time-decayed EWMA: one arrival every dt seconds is an
-            # instantaneous rate of 1/dt; weight by how much of the
-            # half-life elapsed so bursts and lulls both converge fast
-            alpha = 1.0 - math.exp(-dt / self.half_life)
-            self._ewma_rate += alpha * ((1.0 / dt) - self._ewma_rate)
-
-    def rate(self) -> float:
-        """Current folds/s estimate, decayed for elapsed idle time (a
-        burst an hour ago must not keep the window stretched)."""
-        with self._lock:
-            if self._last is None:
-                return 0.0
-            idle = max(0.0, self._clock() - self._last)
-            return self._ewma_rate * math.exp(-idle / self.half_life)
-
-    def window(self) -> float:
-        r = self.rate()
-        # fewer than one expected co-arrival even at the widest window:
-        # waiting buys nothing — snap to the base window
-        if r * self.max_window < 1.0:
-            return self.base_window
-        return min(self.max_window, max(self.base_window, self.target_folds / r))
-
-    def stats(self) -> dict:
-        return {
-            "rate": round(self.rate(), 3),
-            "window": round(self.window(), 6),
-            "folds": self._folds,
-        }

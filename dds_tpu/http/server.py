@@ -42,8 +42,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
-from dds_tpu.core.admission import (AdaptiveCoalescer, AdmissionController,
-                                    TokenBucket)
+from dds_tpu.core.admission import AdmissionController, TokenBucket
 from dds_tpu.core.errors import (
     AllBreakersOpenError,
     ByzantineError,
@@ -176,13 +175,6 @@ class ProxyConfig:
     key_sync_warmup: float = 1.0
     key_sync_interval: float = 5.0
     peers: list[str] = field(default_factory=list)  # "host:port"
-    # Cross-request fold coalescing: concurrent SumAll/MultAll folds that
-    # individually sit below the backend's device-batch crossover are
-    # gathered for coalesce_window seconds and dispatched as ONE segmented
-    # device fold (ops/foldmany), amortizing dispatch latency R ways. A
-    # group of one falls back to the plain host path, so the window only
-    # ever costs latency when there is something to gain. 0 disables.
-    coalesce_window: float = 0.002
     # stored_keys durability. The reference keeps the aggregate key set
     # in-memory only (`DDSRestServer.scala:70`), so a proxy restart makes
     # every aggregate silently shrink until re-population — flagged as a
@@ -285,7 +277,7 @@ class ProxyConfig:
 
 def _fold_after_wait(fold, operands: list[int], modulus: int,
                      t_call: float, t_done: list):
-    """The worker thread's side of `DDSRestServer._fold_on_worker`: note
+    """The worker thread's side of `DDSRestServer._fold`: note
     how long the call waited for this thread, fold, and leave in
     `t_done[0]` the instant the result was ready."""
     t_run = time.perf_counter()
@@ -382,17 +374,11 @@ class DDSRestServer:
         self._loop_sampler: LoopSampler | None = None
         self._keys_dirty = False
         self._keys_saver: asyncio.Task | None = None
-        # modulus -> [(enqueue_t, operands, future, waiter trace ctx)];
-        # drained by _drain_folds
-        self._fold_pending: dict[int, list] = {}
-        self._fold_drainer: asyncio.Task | None = None
-        self._folds_inflight = 0  # folds currently executing (any path)
         # Constellation: a ShardRouter (duck-typed via its shard_manager)
         # turns point routes into one-group ops and aggregates into
         # scatter-gather per-shard folds; a plain AbdClient leaves every
         # path exactly as before
         self._shards = getattr(abd, "shard_manager", None)
-        self._scatter_memo: tuple | None = None  # pairs identity -> shard operands
         self._owner_memo: tuple | None = None    # pairs identity -> (gid, ops)
         # Lodestone (dds_tpu/resident): per-group device-resident pools +
         # the fused single-dispatch sharded fold. Built from the
@@ -503,9 +489,8 @@ class DDSRestServer:
         self._column_memo: tuple | None = None  # pairs identity -> columns
         # Bulwark (core/admission): the admission gate + shed ratchet, fed
         # by the SLO engine's burn alerts and the storage layer's breaker
-        # census; and the adaptive coalescing window sized from observed
-        # fold arrivals. Both None when admission is off — every gate
-        # below is a cheap is-None check.
+        # census. None when admission is off — every gate below is a
+        # cheap is-None check.
         # Bastion (core/tenant + models/tenancy): tenancy makes the
         # validated x-dds-tenant header an isolation boundary. The server
         # holds NO tenant keys (the TenantKeyring is client-side, like the
@@ -523,18 +508,11 @@ class DDSRestServer:
         self._tenant_pairs_memo: dict[str, tuple] = {}
         acfg = self.cfg.admission
         self.admission: AdmissionController | None = None
-        self._coalescer: AdaptiveCoalescer | None = None
         if acfg is not None and getattr(acfg, "enabled", False):
             self.admission = AdmissionController.from_config(
                 acfg, alerts=self.slo.alerts, breakers=self._breaker_census,
                 tenancy=(tcfg if self._tenancy_enabled else None),
             )
-            if getattr(acfg, "adaptive_coalesce", True) and self.cfg.coalesce_window > 0:
-                self._coalescer = AdaptiveCoalescer(
-                    base_window=self.cfg.coalesce_window,
-                    max_window=getattr(acfg, "coalesce_max_window", 0.02),
-                    target_folds=getattr(acfg, "coalesce_target_folds", 8.0),
-                )
         # Heliograph (obs/heliograph): the prober itself starts in
         # start() (it needs the resolved listen port), but the canary
         # admission carve-out exists UNCONDITIONALLY: anything claiming
@@ -621,17 +599,6 @@ class DDSRestServer:
             except asyncio.CancelledError:
                 pass
         self._tasks.clear()
-        if self._fold_drainer is not None and not self._fold_drainer.done():
-            # resolve queued folds before teardown so no request future is
-            # orphaned and no task outlives the server
-            await _cancel_task(self._fold_drainer)
-            err = ConnectionError("proxy stopping")
-            for _, group in self._fold_pending.items():
-                for _, _, fut, _ in group:
-                    if not fut.done():
-                        fut.set_exception(err)
-            self._fold_pending.clear()
-            self._fold_drainer = None
         if self._ingest_task is not None:
             await _cancel_task(self._ingest_task)
             self._ingest_task = None
@@ -1072,7 +1039,7 @@ class DDSRestServer:
     def _note_resident_write(self, key: str, value) -> None:
         """Queue a committed write's ciphertext columns for resident-pool
         ingest (dds_tpu/resident) — OFF the request's critical path,
-        coalesced like folds — so a warm fleet's first post-write
+        debounced — so a warm fleet's first post-write
         aggregate gathers every row device-side with zero ingest.
         Content addressing keeps this unconditionally safe: the full
         quorum read still decides which ciphertexts fold; the pool only
@@ -2400,19 +2367,12 @@ class DDSRestServer:
                     help="stored aggregate keys per shard (proxy view)",
                 )
         # Bulwark admission surface: shed level is set at transition time
-        # too, but a scrape between transitions still deserves the truth;
-        # the coalescing window is pure scrape-time state
+        # too, but a scrape between transitions still deserves the truth
         if self.admission is not None:
             metrics.set(
                 "dds_admission_shed_level", self.admission.shed_level,
                 help="Bulwark shed level (0=none; higher sheds lower "
                      "priority classes first)",
-            )
-        if self._coalescer is not None:
-            metrics.set(
-                "dds_admission_coalesce_window_seconds",
-                self._coalescer.window(),
-                help="current adaptive fold-coalescing window",
             )
         if self._resident is not None:
             # Lodestone gauges: dds_resident_{rows,bytes,hit_ratio,
@@ -2427,16 +2387,9 @@ class DDSRestServer:
             # pending_ingest,...}, per group at scrape time
             self._search.export_gauges(metrics)
         # Chronoscope pipe profile (dds_pipe_*): per-route/per-stage
-        # critical-path self-times, plus the fold-coalescer's queue depth
-        # (entries parked awaiting the adaptive window)
+        # critical-path self-times
         from dds_tpu.obs.chronoscope import chronoscope
         chronoscope.export_gauges(metrics)
-        metrics.set(
-            "dds_queue_depth",
-            sum(len(g) for g in self._fold_pending.values()),
-            queue="fold-coalescer",
-            help="entries waiting in a bounded pipeline queue",
-        )
         # registry self-observation: label sets folded into `overflow`
         # across all families — attribution decays silently once this
         # moves, so dashboards must be able to alarm on it directly
@@ -2612,17 +2565,15 @@ class DDSRestServer:
             if result is not None:
                 return Response.json(J.value_result(str(result)))
             shard_ops = (
-                self._shard_operands(pairs, pos)
+                [g for _, g in self._owner_operands(pairs, pos)]
                 if self._shards is not None else None
             )
             if shard_ops is not None and len(shard_ops) > 1:
-                # Constellation scatter-gather: one coalescable fold per
-                # shard, dispatched CONCURRENTLY so they share a single
-                # segmented foldmany device dispatch (the coalescing
-                # window sees them in flight together), then the partials
-                # merge with the mesh plane's modular-product tail combine
-                # — all shards share one Paillier modulus, so the result
-                # is bit-identical to the unsharded fold.
+                # Constellation scatter-gather: one fold per shard, run
+                # CONCURRENTLY on worker threads, then the partials merge
+                # with the mesh plane's modular-product tail combine — all
+                # shards share one Paillier modulus, so the result is
+                # bit-identical to the unsharded fold.
                 from dds_tpu.parallel.mesh import combine_partials
 
                 with tracer.span("proxy.scatter_fold", k=len(operands),
@@ -2638,7 +2589,7 @@ class DDSRestServer:
                 # device-resident path when the backend has a cipher store:
                 # quorum/tag validation above is still authoritative; the
                 # store only memoizes limb conversion + transfer
-                # (ops/store.py). The fold runs in a worker thread so
+                # (resident/pool.py). The fold runs in a worker thread so
                 # concurrent aggregate requests overlap their device
                 # dispatches (and the event loop keeps serving) instead of
                 # serializing on a blocking fetch.
@@ -2728,75 +2679,17 @@ class DDSRestServer:
         self._owner_memo = (pairs, pos, out)
         return out
 
-    def _shard_operands(self, pairs, pos: int) -> list[list[int]]:
-        """Aggregate operands partitioned by owning shard group (memoized
-        per pairs-identity — between writes the partition is
-        state-identical)."""
-        memo = self._scatter_memo
-        if memo is not None and memo[0] is pairs and memo[1] == pos:
-            return memo[2]
-        out = [g for _, g in self._owner_operands(pairs, pos)]
-        self._scatter_memo = (pairs, pos, out)
-        return out
-
-    def _backend_fold_fn(self):
-        """The backend's single-aggregate fold entry point (the
-        device-store-aware variant when the backend has one)."""
-        return getattr(
+    async def _fold(self, operands: list[int], modulus: int):
+        """One aggregate's fold on a worker thread, by the backend's fold
+        (the device-store-aware variant where the backend has one), with
+        the two waits of the hop as spans: for a free thread
+        (`dispatch.thread_wait`, recorded by the worker at its first line;
+        `to_thread` copies the context, so it lands in the request's tree)
+        and for the event loop to take the coroutine up again once the
+        worker returned (`dispatch.resume_wait`)."""
+        fold = getattr(
             self.backend, "modmul_fold_resident", self.backend.modmul_fold
         )
-
-    async def _fold(self, operands: list[int], modulus: int):
-        """Dispatch one aggregate's fold: wide folds go straight to the
-        backend on a worker thread; small folds (below the device-batch
-        crossover, where dispatch latency beats the math) enter the
-        coalescing window so CONCURRENT small aggregates share one
-        segmented device dispatch (ProxyConfig.coalesce_window).
-
-        A small fold only enters the window when other folds are already
-        executing or queued — observed concurrency is the signal there is
-        something to coalesce with; a lone request pays zero extra latency."""
-        be = self.backend
-        fold = self._backend_fold_fn()
-        min_batch = getattr(be, "min_device_batch", 0)
-        if self._coalescer is not None:
-            # Bulwark adaptive coalescing: every fold arrival feeds the
-            # rate estimate the window is sized from, whichever path it
-            # takes below
-            self._coalescer.note_fold(len(operands))
-        concurrent = self._folds_inflight > 0 or bool(self._fold_pending)
-        if (
-            self.cfg.coalesce_window <= 0
-            or not hasattr(be, "modmul_fold_many")
-            or len(operands) >= min_batch
-            or not concurrent
-        ):
-            self._folds_inflight += 1
-            try:
-                return await self._fold_on_worker(fold, operands, modulus)
-            finally:
-                self._folds_inflight -= 1
-        loop = asyncio.get_running_loop()
-        fut = loop.create_future()
-        # carry the waiter's trace context + enqueue time into the drain:
-        # the dispatcher runs under the DRAINER task's context, so the
-        # per-waiter coalesce-wait/fold spans must be re-homed explicitly
-        self._fold_pending.setdefault(modulus, []).append(
-            (time.perf_counter(), operands, fut, obs_context.current())
-        )
-        if self._fold_drainer is None or self._fold_drainer.done():
-            self._fold_drainer = supervised_task(self._drain_folds(),
-                                                 name="proxy.fold_drainer")
-        return await fut
-
-    @staticmethod
-    async def _fold_on_worker(fold, operands: list[int], modulus: int):
-        """`fold` on a worker thread, with the two waits of the hop as
-        spans: for a free thread (`dispatch.thread_wait`, recorded by the
-        worker at its first line; `to_thread` copies the context, so it
-        lands in the request's tree) and for the event loop to take the
-        coroutine up again once the worker returned
-        (`dispatch.resume_wait`)."""
         t_call = time.perf_counter()
         t_done = [t_call]
         try:
@@ -2806,83 +2699,6 @@ class DDSRestServer:
             t_back = time.perf_counter()
             tracer.record("dispatch.resume_wait", (t_back - t_done[0]) * 1e3,
                           _ctx=obs_context.child(), _t_end=t_back)
-
-    def _coalesce_window(self) -> float:
-        """The gather window for this drain cycle: adaptive (sized from
-        observed fold arrival rate) when Bulwark armed it, else the
-        config constant."""
-        if self._coalescer is not None:
-            return self._coalescer.window()
-        return self.cfg.coalesce_window
-
-    async def _drain_folds(self) -> None:
-        await asyncio.sleep(self._coalesce_window())
-        while self._fold_pending:
-            # snapshot ALL pending groups and dispatch them concurrently:
-            # different moduli must overlap their dispatches (the whole
-            # point of folding in threads), and draining one at a time
-            # would let a continuously re-queued hot modulus starve others
-            groups = list(self._fold_pending.items())
-            self._fold_pending.clear()
-            await asyncio.gather(
-                *(self._dispatch_fold_group(m, g) for m, g in groups)
-            )
-
-    async def _dispatch_fold_group(self, modulus: int, group: list) -> None:
-        folds = [ops_ for _, ops_, _, _ in group]
-        futs = [f for _, _, f, _ in group]
-        t_start = time.perf_counter()
-        for t_enq, ops_, _, wctx in group:
-            # each waiter's sat-in-the-window time, in ITS OWN trace
-            tracer.record(
-                "proxy.coalesce_wait", (t_start - t_enq) * 1e3,
-                _ctx=obs_context.child(wctx) if wctx is not None else None,
-                batch=len(group), k=len(ops_),
-            )
-        self._folds_inflight += 1
-        try:
-            total = sum(len(f) for f in folds)
-            if len(folds) == 1 or total < getattr(
-                self.backend, "min_device_batch", 0
-            ):
-                # a lone fold, or a group whose COMBINED width is still
-                # below the device crossover: host folds win there. One
-                # worker thread per fold (not one serial loop): native
-                # host folds release the GIL, so group members overlap
-                # exactly as they would have without the window
-                fold = self._backend_fold_fn()
-                results = await asyncio.gather(
-                    *(asyncio.to_thread(fold, f, modulus) for f in folds)
-                )
-            else:
-                results = await asyncio.to_thread(
-                    self.backend.modmul_fold_many, folds, modulus
-                )
-            t_done = time.perf_counter()
-            for (_, ops_, _, wctx), _r in zip(group, results):
-                # the shared device dispatch, visible from every waiter's
-                # waterfall (self-time classifies as dispatch/execute)
-                tracer.record(
-                    "proxy.coalesced_fold", (t_done - t_start) * 1e3,
-                    _ctx=obs_context.child(wctx) if wctx is not None
-                    else None,
-                    batch=len(group), k=len(ops_),
-                )
-            for f, r in zip(futs, results):
-                if not f.cancelled():
-                    f.set_result(r)
-        except Exception as e:  # surface to every waiting request
-            for f in futs:
-                if not f.cancelled():
-                    f.set_exception(e)
-        finally:
-            self._folds_inflight -= 1
-            # a cancellation (e.g. stop() mid-dispatch) must not orphan
-            # the group: its futures are no longer in _fold_pending, so
-            # stop()'s sweep cannot see them — fail them here
-            for f in futs:
-                if not f.done():
-                    f.set_exception(ConnectionError("proxy stopping"))
 
     @staticmethod
     def _pos(req: Request) -> int:

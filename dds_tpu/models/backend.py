@@ -169,14 +169,14 @@ class TpuBackend:
         return native.fold(cs, modulus)
 
     def store_for(self, modulus: int):
-        """Per-modulus device-resident cipher store (ops/store.py)."""
+        """Per-modulus device-resident cipher store (resident/pool.py)."""
         with self._stores_lock:
             store = self._stores.get(modulus)
             if store is None:
-                from dds_tpu.ops.store import DeviceCipherStore
+                from dds_tpu.resident.pool import ResidentPool
 
                 ctx = ModCtx.make(modulus)
-                store = DeviceCipherStore(
+                store = ResidentPool(
                     modulus, reduce=lambda rows: self.reduce_mul_device(ctx, rows),
                     kernel=self.fold_kernel(),
                 )
@@ -199,7 +199,7 @@ class TpuBackend:
     def fold_kernel(self) -> str:
         """The single kernel-family rule (a family of ops/kernel) for the
         flat fold and every composite one — mesh-sharded (parallel/mesh),
-        coalesced (ops/foldmany) and resident-fused (dds_tpu/resident):
+        weighted (ops/foldmany) and resident-fused (dds_tpu/resident):
         v2 when pallas is on, the portable jnp scans otherwise, so
         scale-out and batching never silently run a slower kernel."""
         return "v2" if self.pallas else "jnp"
@@ -257,14 +257,6 @@ class TpuBackend:
         batch = bn.ints_to_batch(cs, ctx.L)
         out = self.reduce_mul_device(ctx, batch)
         return bn.limbs_to_int(np.asarray(out)[0])
-
-    def modmul_fold_many(self, folds: list[list[int]], modulus: int) -> list[int]:
-        """Fold R requests' operand lists in ONE device dispatch
-        (ops/foldmany): the cross-request batching for concurrent small
-        aggregates that individually sit below min_device_batch."""
-        from dds_tpu.ops import foldmany
-
-        return foldmany.fold_many(folds, modulus, kernel=self.fold_kernel())
 
     def matvec(
         self, cs: list[int], weights: list[list[int]], modulus: int,

@@ -10,9 +10,9 @@ per-tenant HMAC secret for transport signing) and three lifecycle verbs:
 - **keys_for(tenant)** — lazy generation on first touch. Every tenant
   gets its OWN Paillier modulus, so mixed-tenant folds can never share a
   ciphertext domain by accident; the fold planes group operands by
-  modulus (``_fold_pending`` is modulus-keyed), which means same-tenant
-  traffic still coalesces into the fused Lodestone dispatch while
-  cross-tenant operands land in separate groups by construction.
+  modulus, which means same-tenant traffic still shares the fused
+  Lodestone dispatch while cross-tenant operands land in separate
+  groups by construction.
 - **rotate(tenant)** — mint a new epoch; the previous epoch enters a
   *grace window* during which its ciphertexts still decrypt
   (`decrypt_any` walks active-then-grace epochs and reports which epoch

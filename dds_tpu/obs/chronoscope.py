@@ -65,7 +65,6 @@ log = logging.getLogger("dds.chronoscope")
 STAGES = (
     "admission",                # backpressure decision at the front door
     "assemble",                 # aggregate operand assembly (memo-miss work)
-    "coalesce-wait",            # sat in the proxy fold coalescer window
     "serialize",                # message <-> wire frame (+ MAC/sig)
     "quorum-rtt",               # ABD round: on the wire + remote queueing
     "hmac-verify",              # proxy-side reply signature validation
@@ -97,8 +96,6 @@ def classify(name: str, *, root: bool = False) -> str:
 def _stage_of(name: str) -> str:
     if name == "proxy.admission":
         return "admission"
-    if name == "proxy.coalesce_wait":
-        return "coalesce-wait"
     if name in ("net.serialize", "net.deserialize"):
         return "serialize"
     if name == "abd.verify":
@@ -135,8 +132,7 @@ def _stage_of(name: str) -> str:
         if name.endswith(".dispatch"):
             return "dispatch"
         return "device-execute"
-    if name in ("proxy.fold", "proxy.resident_fold", "proxy.scatter_fold",
-                "proxy.coalesced_fold"):
+    if name in ("proxy.fold", "proxy.resident_fold", "proxy.scatter_fold"):
         # fold orchestration: the kernel children claim their windows,
         # the marshaling remainder is host-side dispatch work
         return "dispatch"

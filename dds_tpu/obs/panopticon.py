@@ -844,7 +844,7 @@ class FleetCollector:
         metrics_text — zero wire-format changes) rolled up per route.
 
         Rollup semantics: a stage's fleet p95 is the MAX across hosts —
-        stages run on different processes (proxy coalesce vs replica
+        stages run on different processes (proxy fold vs replica
         apply vs group ingest), so the worst host's self-time is the
         fleet's bottleneck candidate, not an average that would dilute a
         single hot shard. `top` names the single (route, stage) pair with

@@ -1,29 +1,14 @@
-"""Segmented (multi-request) modular-product folds in ONE device dispatch.
+"""Weighted modular-product folds in ONE device dispatch.
 
-The small-aggregate regime problem (BASELINE.md config 5): a single
-SumAll over K < ~1k sets loses to a host fold because flat dispatch
-latency dominates. But a proxy serving CONCURRENT small aggregates can
-coalesce them — R requests' folds become one (P2*R, L) elem-major batch
-that tree-reduces in one dispatch, amortizing the latency R ways (the
-"consensus batch" idea of SURVEY.md §7 applied to the query plane;
-the reference folds each aggregate separately and sequentially,
-`dds/http/DDSRestServer.scala:397-446`).
+`fold_weighted` is a per-row product of operands raised to per-(row,
+operand) plaintext exponents: the plaintext-ciphertext matrix-
+multiplication kernel of the Prism analytics plane (dds_tpu/analytics,
+`TpuBackend.matvec`). It shares the compiled-fn cache, the kernel-family
+selection and the Montgomery contexts with the aggregate folds of
+`ops/kernel`. All rows of one call share one modulus.
 
-Layout: row elem*R + req, so level halving `x[:h*R] * x[h*R:2h*R]`
-multiplies elem i with elem i+h within every request at once. Each
-request pads to the shared P2 with the Montgomery identity; the per-
-request R^-(K_r-1) power is fixed with one final multiply by R^K_r
-(same accounting as ModCtx.reduce_mul). All requests share one modulus —
-the coalescer groups by modulus.
-
-Compiled executables retrace per (P2, R); both axes are bucketed to
-powers of two by the caller so the shape set stays tiny.
-
-`fold_weighted` extends the same machinery to weighted folds — per-row
-products of operands raised to per-(row, operand) plaintext exponents —
-the plaintext-ciphertext matrix-multiplication kernel of the Prism
-analytics plane (dds_tpu/analytics). It shares the compiled-fn cache,
-kernel-family selection, and Montgomery contexts with fold_many.
+Compiled executables retrace per (P2, Rp, D); the operand and row axes
+are bucketed to powers of two here so the shape set stays tiny.
 """
 
 from __future__ import annotations
@@ -38,29 +23,15 @@ from dds_tpu.ops.kernel import fn_cache, halving_tree, interpret_default, mont_m
 from dds_tpu.ops.montgomery import ModCtx
 
 
-def _fold_many_fn(ctx: ModCtx, kernel: str, R: int):
-    # interpret is baked into the multiply at trace time, so it is in the
-    # key: a backend flipped mid-process must not be served a stale trace
-    interpret = interpret_default()
-
-    def run(arr, fixes):
-        # arr: (P2*R, L) elem-major plain-domain; fixes: (R, L) = R^K_r
-        mul = mont_mul(ctx, kernel, interpret)
-        return mul(halving_tree(mul, arr, width=R), fixes)  # (R, L) plain
-
-    return fn_cache(
-        "foldmany", (ctx.n, kernel, R, interpret), lambda: jax.jit(run)
-    )
-
-
 _WINDOW = 4  # digit width of the weighted fold's ladder (16-entry tables)
 
 
 def _fold_weighted_fn(ctx: ModCtx, kernel: str):
     """Compiled weighted-fold kernel for (ctx, kernel family): shapes are
     NOT in the cache key — jit retraces per (P2, Rp, D) input shape under
-    one entry, like mesh's "reduce" keys — but the interpret flag is, for
-    the same stale-trace reason as _fold_many_fn."""
+    one entry, like mesh's "reduce" keys — but the interpret flag is: it
+    is baked into the multiply at trace time, and a backend flipped
+    mid-process must not be served a stale trace."""
     interpret = interpret_default()
     L = ctx.L
 
@@ -172,41 +143,5 @@ def fold_weighted(
         "fold_weighted",
         lambda: fn(jnp.asarray(arr), jnp.asarray(digits)),
         R=R_real, K=K, D=D,
-    )
-    return [bn.limbs_to_int(row) for row in np.asarray(out)[:R_real]]
-
-
-def fold_many(folds: list[list[int]], modulus: int, kernel: str = "jnp") -> list[int]:
-    """Modular product of each request's operand list, one device dispatch.
-
-    Pads every fold to the shared power-of-two width and the request axis
-    to a power of two (dummy folds of [1]) so compiled shapes stay few.
-    """
-    ctx = ModCtx.make(modulus)
-    R_real = len(folds)
-    Rp = 1 << max(0, (R_real - 1).bit_length())
-    Kmax = max(len(f) for f in folds)
-    P2 = 1 << max(0, (Kmax - 1).bit_length())
-
-    arr = np.empty((P2, Rp, ctx.L), np.uint32)
-    arr[:] = ctx.one_mont  # identity pads (elem pads + dummy requests)
-    for r, f in enumerate(folds):
-        arr[: len(f), r, :] = bn.ints_to_batch(f, ctx.L)
-    R_ = 1 << (bn.LIMB_BITS * ctx.L)
-    fixes = np.stack(
-        [
-            bn.int_to_limbs(pow(R_ % ctx.n, len(f), ctx.n), ctx.L)
-            for f in folds
-        ]
-        + [bn.int_to_limbs(R_ % ctx.n, ctx.L)] * (Rp - R_real)  # dummies: K=1
-    )
-    fn = _fold_many_fn(ctx, kernel, Rp)
-    # dispatch (trace+compile on a cold cache) vs device execute, timed
-    # separately (obs/kprof): the compile-vs-execute accounting GPU/TPU HE
-    # work sizes kernels by
-    out = kprof.profiled(
-        "foldmany",
-        lambda: fn(jnp.asarray(arr.reshape(P2 * Rp, ctx.L)), jnp.asarray(fixes)),
-        R=R_real, P2=P2,
     )
     return [bn.limbs_to_int(row) for row in np.asarray(out)[:R_real]]

@@ -24,7 +24,7 @@ prod * R^-(K-1); one final multiply by R^K mod n fixes the domain.
 
 The write-path ingest queue (`note_write` / `ingest_pending`) lets the
 proxy push committed ciphertexts into existing pools OFF the request's
-critical path, coalesced like folds — a warm fleet's first post-write
+critical path, debounced — a warm fleet's first post-write
 aggregate then pays zero ingest. Content addressing makes this safe: an
 ingested row is keyed by its value, so a racing aggregate either finds
 the row (identical bytes) or ingests it itself; nothing can go stale.
@@ -210,7 +210,7 @@ class ResidentPlane:
 
     def ingest_pending(self) -> int:
         """Drain the write-ingest queue into the matching pools (run on a
-        worker thread, coalesced by the proxy exactly like folds).
+        worker thread, debounced by the proxy).
         Returns rows newly ingested across all pools."""
         batch = self._pending.drain()
         if not batch:

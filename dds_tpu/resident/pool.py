@@ -1,10 +1,9 @@
 """Lodestone resident pools: per-group device-pinned ciphertext limb pools.
 
 A `ResidentPool` is the content-addressed `(rows, L)` uint32 limb buffer
-one shard group keeps in device memory for one modulus — the
-generalization of the single-store `ops/store.DeviceCipherStore` (which
-is now a thin alias of this class) into the per-group family the
-Constellation needs. Each distinct ciphertext *value* is ingested once
+one shard group keeps in device memory for one modulus: the unsharded
+backend keeps one a modulus (`TpuBackend.store_for`), the Constellation
+one a group. Each distinct ciphertext *value* is ingested once
 (int -> 16-bit limbs -> device row); every subsequent aggregate gathers
 resident rows on-device instead of re-marshaling host ints per fold —
 the memory-residency move the HE-accelerator literature scales by (BTS,

@@ -519,7 +519,6 @@ async def _launch(cfg: DDSConfig) -> Deployment:
             key_sync_interval=cfg.proxy.key_sync_interval,
             peers=cfg.proxy.remote_peers,
             keys_path=cfg.proxy.stored_keys_path,
-            coalesce_window=cfg.proxy.coalesce_window,
             replica_refresh_interval=cfg.proxy.replica_refresh_interval,
             supervisor=sup_addr,
             trace_route_enabled=cfg.debug or cfg.obs.trace_route,
@@ -698,7 +697,6 @@ def proxy_config(cfg: DDSConfig, supervisor, ssl_server, ssl_client,
         handler_timeout=cfg.proxy.handler_timeout,
         crypto_backend=cfg.proxy.crypto_backend,
         keys_path=cfg.proxy.stored_keys_path,
-        coalesce_window=cfg.proxy.coalesce_window,
         replica_refresh_interval=cfg.proxy.replica_refresh_interval,
         supervisor=supervisor,
         trace_route_enabled=cfg.debug or cfg.obs.trace_route,

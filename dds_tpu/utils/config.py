@@ -141,8 +141,8 @@ class ProxySettings:
     # lossy behavior); restarted proxies also pull keys from remote_peers
     # at start when key_sync_enabled
     stored_keys_path: str = ""
-    # gather window (s) for coalescing concurrent small aggregate folds
-    # into one device dispatch; 0 disables
+    # read by nothing since PR 48 (the fold coalescer went); stays until
+    # the harness's sample of a float setting is another (ROADMAP D13 j)
     coalesce_window: float = 0.002
 
 
@@ -468,13 +468,6 @@ class AdmissionConfig:
     # coordinators have open breakers and none will half-open within the
     # remaining budget, degrade instantly instead of burning the Deadline
     fast_fail: bool = True
-    # adaptive fold coalescing: size proxy.coalesce-window from the
-    # observed fold arrival rate — stretch toward coalesce-max-window
-    # until ~coalesce-target-folds arrivals share a dispatch under load,
-    # snap back to the base window when idle
-    adaptive_coalesce: bool = True
-    coalesce_max_window: float = 0.02
-    coalesce_target_folds: float = 8.0
 
 
 @dataclass

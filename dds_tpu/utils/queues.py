@@ -1,8 +1,8 @@
 """TimedQueue: the shared bounded hand-off queue with enqueue timestamps.
 
-Lodestone's write-ingest queue, Spyglass's index-ingest queue, and the
-proxy fold coalescer all share one shape: the request path appends work,
-a debounced worker drains it in batches. Before this helper each kept a
+Lodestone's write-ingest queue and Spyglass's index-ingest queue share
+one shape: the request path appends work, a debounced worker drains it
+in batches. Before this helper each kept a
 bare list/dict, so queue AGE — how long entries sat before the drain —
 was invisible (Chronoscope's ingest-queue-wait stage had nothing to
 attribute), and drops were counted ad-hoc (Lodestone dropped pool-less
@@ -21,9 +21,8 @@ longest wait in the batch) so the wait shows up in trace waterfalls when
 a drain happens to run under an active trace context; off-trace drains
 record the span unlinked, which still feeds `tracer.summary()`.
 
-`maxlen=None` means unbounded (the fold coalescer: entries carry
-futures, so rejecting them is not a drop but an error — the caller owns
-that policy). Bounded queues reject at `offer` time with reason="full".
+`maxlen=None` means unbounded. Bounded queues reject at `offer` time
+with reason="full".
 """
 
 from __future__ import annotations
@@ -115,8 +114,7 @@ class TimedQueue:
 
     def drain_entries(self) -> list[tuple[float, Any]]:
         """Like `drain` but returns (wait_seconds, item) pairs so callers
-        that need per-entry waits (the fold coalescer's per-waiter spans)
-        can attribute them individually."""
+        that need per-entry waits can attribute them individually."""
         now = self._clock()
         with self._lock:
             if not self._entries:

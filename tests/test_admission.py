@@ -1,7 +1,7 @@
 """Bulwark overload-control tests (ISSUE 7).
 
 The admission math — token-bucket refill/burst, priority ordering,
-shed/unshed hysteresis, adaptive coalescing — runs on FAKE clocks, so
+shed/unshed hysteresis — runs on FAKE clocks, so
 every ratchet step is deterministic. The storage-layer fast-fail and the
 REST surface (429/503 with derived Retry-After, exempt observability
 routes) run on small real stacks. The flagship drives a seeded ChaosNet
@@ -22,7 +22,6 @@ import pytest
 
 from dds_tpu.core.admission import (
     CLASSES,
-    AdaptiveCoalescer,
     AdmissionController,
     TokenBucket,
     route_class,
@@ -447,51 +446,6 @@ def test_degraded_retry_after_derived_from_breaker_eta():
             assert resp.headers["Retry-After"] == str(
                 max(1, round(server.cfg.retry_after_hint))
             )
-
-    asyncio.run(go())
-
-
-# ------------------------------------------------------ adaptive coalescing
-
-
-def test_adaptive_coalescer_fills_under_load_and_snaps_when_idle():
-    clk = FakeClock()
-    c = AdaptiveCoalescer(base_window=0.002, max_window=0.02,
-                          target_folds=8.0, clock=clk)
-    assert c.window() == pytest.approx(0.002)  # idle: base window
-    # sustained 1 kHz fold arrivals -> rate ~1000/s -> window ~ 8/1000
-    # (the EWMA time constant is half_life=1 s, so feed ~5 s of arrivals)
-    for _ in range(5000):
-        clk.advance(0.001)
-        c.note_fold()
-    assert c.rate() == pytest.approx(1000.0, rel=0.05)
-    assert c.window() == pytest.approx(0.008, rel=0.05)
-    # moderate load clamps at max_window (100/s -> 80 ms > 20 ms cap)
-    c2 = AdaptiveCoalescer(0.002, 0.02, target_folds=8.0, clock=clk)
-    for _ in range(200):
-        clk.advance(0.01)
-        c2.note_fold()
-    assert c2.window() == pytest.approx(0.02)
-    # going idle decays the estimate: the window snaps back to base
-    clk.advance(30.0)
-    assert c.window() == pytest.approx(0.002)
-    assert c2.window() == pytest.approx(0.002)
-
-
-def test_server_wires_adaptive_window():
-    acfg = AdmissionConfig(enabled=True, adaptive_coalesce=True,
-                           coalesce_max_window=0.05, eval_interval=1e9)
-
-    async def go():
-        async with admission_stack(acfg) as (server, _):
-            assert server._coalescer is not None
-            assert server._coalesce_window() == pytest.approx(
-                server.cfg.coalesce_window
-            )  # idle: the configured base
-            assert server._coalescer.max_window == pytest.approx(0.05)
-        async with admission_stack(None) as (server, _):
-            assert server._coalescer is None
-            assert server._coalesce_window() == server.cfg.coalesce_window
 
     asyncio.run(go())
 

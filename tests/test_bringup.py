@@ -181,9 +181,6 @@ def test_foldmany_entry_points_lower_for_tpu(kernel, monkeypatch):
     # foldmany picks interpret mode itself from the backend in use
     monkeypatch.setattr(foldmany, "interpret_default", lambda: False)
     lowers_to_mosaic(
-        foldmany._fold_many_fn(CTX, kernel, 2), u32(8, L), u32(2, L)
-    )
-    lowers_to_mosaic(
         foldmany._fold_weighted_fn(CTX, kernel), u32(4, L), i32(2, 2, 4)
     )
 

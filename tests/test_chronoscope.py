@@ -46,18 +46,18 @@ def S(name, start, end, span_id, parent_id=None, tid="t1", kind="span",
 
 
 def test_classify_is_closed_over_stages():
-    for name in ("proxy.admission", "proxy.coalesce_wait", "net.serialize",
+    for name in ("proxy.admission", "proxy.fold", "net.serialize",
                  "abd.verify", "abd.write", "abd.read_quorum",
                  "ingest.queue_wait", "ingest.h2d", "replica.handle",
-                 "antientropy.sync", "kernel.foldmany.compile",
-                 "kernel.foldmany.dispatch", "kernel.foldmany.execute",
-                 "proxy.coalesced_fold", "http.POST.PutSet",
+                 "antientropy.sync", "kernel.fold_weighted.compile",
+                 "kernel.fold_weighted.dispatch", "kernel.fold_weighted.execute",
+                 "proxy.scatter_fold", "http.POST.PutSet",
                  "proxy.get_set", "totally.unknown"):
         assert classify(name) in STAGES
     assert classify("abd.verify") == "hmac-verify"
     assert classify("abd.write") == "quorum-rtt"
-    assert classify("kernel.foldmany.compile") == "trace-compile"
-    assert classify("kernel.foldmany.execute") == "device-execute"
+    assert classify("kernel.fold_weighted.compile") == "trace-compile"
+    assert classify("kernel.fold_weighted.execute") == "device-execute"
     assert classify("ingest.h2d") == "host-to-device-transfer"
     assert classify("totally.unknown") == "other"
 

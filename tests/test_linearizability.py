@@ -532,15 +532,12 @@ def test_chaos_nemesis_mixed_attack_schedule():
     run(go())
 
 
-def test_coalesced_sumalls_see_old_or_new_never_mixed_garbage():
-    """Aggregate linearizability through the COALESCED fold path: while a
-    stored key's value is rewritten (v_old -> v_new), a storm of
-    concurrent SumAlls — small enough that they share coalesced device
-    dispatches — must each decrypt to sum_old or sum_new, never anything
-    else. Coalescing shares only the MATH of concurrent folds; each
-    request's operand snapshot still comes from its own quorum-validated
-    read, which this test pins down. A spy asserts the coalesced
-    dispatch genuinely ran (the claim is enforceable, not incidental)."""
+def test_concurrent_sumalls_under_a_write_see_the_old_or_the_new_total():
+    """Aggregate linearizability under concurrent folds: while a stored
+    key's value is rewritten (v_old -> v_new), a storm of concurrent
+    SumAlls must each decrypt to sum_old or sum_new, never anything
+    else. Each request's operand snapshot comes from its own
+    quorum-validated read, which this test pins down."""
     import json
 
     from dds_tpu.models import HEKeys
@@ -552,14 +549,7 @@ def test_coalesced_sumalls_see_old_or_new_never_mixed_garbage():
 
     async def go():
         async with rest_stack(n=4, quorum=3) as (server, _, _):
-            be = TpuBackend(pallas=False, min_device_batch=8)
-            calls = {"many": 0}
-            orig_many = be.modmul_fold_many
-            be.modmul_fold_many = lambda folds, mod: (
-                calls.__setitem__("many", calls["many"] + 1)
-                or orig_many(folds, mod)
-            )
-            server.backend = be
+            server.backend = TpuBackend(pallas=False, min_device_batch=8)
             base_vals = [10, 20, 30, 40]
             row_keys = []
             for v in base_vals:
@@ -594,7 +584,6 @@ def test_coalesced_sumalls_see_old_or_new_never_mixed_garbage():
             sums, _ = await asyncio.gather(storm(12), rewrite())
             allowed = {old_total, new_total}
             assert set(sums) <= allowed, (sums, allowed)
-            assert calls["many"] >= 1  # the coalesced path really ran
             # afterwards every aggregate sees the new value
             settled = await storm(4)
             assert set(settled) == {new_total}

@@ -354,12 +354,12 @@ def test_request_under_chaos_yields_single_trace_tree():
 def test_metrics_route_serves_parseable_prometheus_text():
     """Acceptance: GET /metrics is Prometheus exposition text covering
     route latency histograms, quorum RTT, and compile-cache hit rate."""
-    from dds_tpu.ops.foldmany import fold_many
+    from dds_tpu.ops.foldmany import fold_weighted
 
     # drive the instrumented kernel path so compile-cache series exist
     n = 7 * 11
-    assert fold_many([[2, 3], [4, 5]], n) == [6, 20 % n]
-    fold_many([[2, 3], [4, 5]], n)  # second call: cache hit
+    assert fold_weighted([2, 3], [[1, 1], [2, 1]], n) == [6, 12]
+    fold_weighted([2, 3], [[1, 1], [2, 1]], n)  # second call: cache hit
 
     async def go():
         net, server, _ = await _obs_rest_stack()
@@ -390,9 +390,9 @@ def test_metrics_route_serves_parseable_prometheus_text():
     # compile-cache accounting from the kernel path (1 miss, then hits)
     cache = series("dds_compile_cache_total")
     hits = sum(v for k, v in cache.items()
-               if 'cache="foldmany"' in k and 'outcome="hit"' in k)
+               if 'cache="fold_weighted"' in k and 'outcome="hit"' in k)
     misses = sum(v for k, v in cache.items()
-                 if 'cache="foldmany"' in k and 'outcome="miss"' in k)
+                 if 'cache="fold_weighted"' in k and 'outcome="miss"' in k)
     assert misses >= 1 and hits >= 1
     # scrape-time state gauges
     assert series("dds_trusted_replicas")

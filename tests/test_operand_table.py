@@ -413,10 +413,10 @@ def test_rebuild_events_answer_exactly(event, on_pool):
         async with rest_stack("tpu") as (server, _, _):
             rows = await load(server, 12)
             if event == "pool_reset":     # a pool of 16 rows for 12 operands
-                from dds_tpu.ops.store import DeviceCipherStore
+                from dds_tpu.resident.pool import ResidentPool
 
                 be = server.backend
-                pool = be._stores[NSQR] = DeviceCipherStore(
+                pool = be._stores[NSQR] = ResidentPool(
                     NSQR, reduce=be.store_for(NSQR).reduce,
                     initial_rows=16, max_rows=16)
             for _ in range(2):

@@ -519,122 +519,88 @@ def test_stored_keys_bootstrap_pull_from_peer_on_start():
     asyncio.run(go())
 
 
-def test_concurrent_small_sumalls_coalesce_into_one_dispatch():
-    """R concurrent below-crossover SumAlls must share ONE segmented device
-    dispatch (ops/foldmany) and still decrypt to the right totals — the
-    cross-request batching of r4 verdict #2."""
+def test_concurrent_narrow_sumalls_fold_apart_and_wait_for_no_wide_one():
+    """R concurrent SumAlls below the device crossover each take their own
+    fold and decrypt to the right total, and a narrow fold is answered
+    while a wide one is still in flight: nothing gathers folds."""
     from dds_tpu.models.backend import TpuBackend
 
     import threading
 
     async def go():
         async with rest_stack() as (server, _, _):
-            # each fold (K=6) is below the crossover (10) so requests enter
-            # the window; a group's combined width (>=2 x 6) clears it, so
-            # the coalesced dispatch goes to the device
+            # position 0 is in all 18 rows (wide: at or above the
+            # crossover of 10), position 1 in 6 of them (narrow)
             be = TpuBackend(pallas=False, min_device_batch=10)
-            calls = {"many": 0, "single": 0}
-            orig_many = be.modmul_fold_many
             orig_res = be.modmul_fold_resident
-            # Event-driven determinism (the old form raced the burst
-            # against a 2 ms window and hoped): the FIRST host fold — the
-            # direct path the first arrival takes — blocks on `coalesced`
-            # until a coalesced device dispatch has actually run, so the
-            # concurrency signal (folds in flight) deterministically holds
-            # open while the rest of the burst piles into the window. The
-            # drainer runs on the event loop, never behind this
-            # worker-thread wait, so the release is guaranteed; the wider
-            # window just keeps the burst in one drain cycle.
-            coalesced = threading.Event()
+            widths: list[int] = []
+            wide_started, release = threading.Event(), threading.Event()
 
-            def gated_single(cs, mod):
-                calls["single"] += 1
-                if calls["single"] == 1:
-                    assert coalesced.wait(30), "coalesced dispatch never ran"
+            def gated(cs, mod):
+                widths.append(len(cs))
+                if len(cs) >= be.min_device_batch:
+                    wide_started.set()
+                    assert release.wait(30), "the wide fold was never let go"
                 return orig_res(cs, mod)
 
-            def counting_many(folds, mod):
-                calls["many"] += 1
-                coalesced.set()
-                return orig_many(folds, mod)
-
-            be.modmul_fold_many = counting_many
-            be.modmul_fold_resident = gated_single
+            be.modmul_fold_resident = gated
             server.backend = be
-            server.cfg.coalesce_window = 0.05
             pk = KEYS.psse.public
-            vals = [rng.randrange(1 << 24) for _ in range(6)]
-            for v in vals:
-                await call(server, "POST", "/PutSet", {"contents": [str(pk.encrypt(v))]})
+            ones = [rng.randrange(1 << 24) for _ in range(12)]
+            pairs = [(rng.randrange(1 << 24), rng.randrange(1 << 24))
+                     for _ in range(6)]
+            for v in ones:
+                await call(server, "POST", "/PutSet",
+                           {"contents": [str(pk.encrypt(v))]})
+            for v, w in pairs:
+                await call(server, "POST", "/PutSet", {"contents": [
+                    str(pk.encrypt(v)), str(pk.encrypt(w))]})
+            narrow = f"/SumAll?position=1&nsqr={pk.nsquare}"
+            narrow_sum = sum(w for _, w in pairs)
 
-            # 5 concurrent SumAlls: the first (no observed concurrency)
-            # takes the host path and holds the in-flight signal; every
-            # later arrival sees it and coalesces into ONE device
-            # dispatch. Assert the shape: at least one coalesced dispatch
-            # happened, every result is correct, and dispatches never
-            # exceeded request count.
-            results = await asyncio.gather(*(
-                call(server, "GET", f"/SumAll?position=0&nsqr={pk.nsquare}")
-                for _ in range(5)
-            ))
-            for status, data in results:
+            def total(answer):
+                status, data = answer
                 assert status == 200
-                assert KEYS.psse.decrypt(int(json.loads(data)["result"])) == sum(vals)
-            assert calls["many"] >= 1
-            assert calls["many"] + calls["single"] < 5
+                return KEYS.psse.decrypt(int(json.loads(data)["result"]))
 
-            # a lone small aggregate pays NO window: straight host path
-            # (deterministic: nothing in flight, nothing pending)
-            before = dict(calls)
-            status, data = await call(
-                server, "GET", f"/SumAll?position=0&nsqr={pk.nsquare}"
-            )
-            assert status == 200
-            assert KEYS.psse.decrypt(int(json.loads(data)["result"])) == sum(vals)
-            assert calls["many"] == before["many"]
-            assert calls["single"] == before["single"] + 1
+            results = await asyncio.gather(
+                *(call(server, "GET", narrow) for _ in range(5)))
+            assert [total(r) for r in results] == [narrow_sum] * 5
+            assert widths == [6] * 5  # one fold a request, none merged
 
-            # window 0 disables coalescing entirely
-            server.cfg.coalesce_window = 0.0
-            before = dict(calls)
-            await asyncio.gather(*(
-                call(server, "GET", f"/SumAll?position=0&nsqr={pk.nsquare}")
-                for _ in range(3)
-            ))
-            assert calls["many"] == before["many"]
-            assert calls["single"] == before["single"] + 3
+            wide = asyncio.ensure_future(call(
+                server, "GET", f"/SumAll?position=0&nsqr={pk.nsquare}"))
+            assert await asyncio.to_thread(wide_started.wait, 30)
+            try:
+                answer = await asyncio.wait_for(
+                    call(server, "GET", narrow), timeout=15)
+                assert not wide.done()
+            finally:
+                release.set()
+            assert total(answer) == narrow_sum
+            assert total(await wide) == sum(ones) + sum(v for v, _ in pairs)
 
     asyncio.run(go())
 
 
-def test_coalesced_dispatch_failure_fails_all_waiters_cleanly():
-    """A failing coalesced device dispatch must surface as 500s to every
-    waiting request (never a hang) and leave the coalescer reusable for
-    the next, healthy, burst."""
+def test_a_fold_that_raises_fails_its_own_request_and_no_other():
+    """A fold that raises answers 500 to the request it belongs to (never
+    a hang); the folds beside it and the next burst succeed."""
     from dds_tpu.models.backend import TpuBackend
 
     async def go():
         async with rest_stack() as (server, _, _):
             be = TpuBackend(pallas=False, min_device_batch=10)
-            boom = {"on": True}
-            orig_many = be.modmul_fold_many
             orig_resident = be.modmul_fold_resident
+            calls = {"n": 0}
 
-            def maybe_boom(folds, mod):
-                if boom["on"]:
+            def second_one_booms(cs, mod):
+                calls["n"] += 1
+                if calls["n"] == 2:
                     raise RuntimeError("device fell off")
-                return orig_many(folds, mod)
-
-            def slow_host(cs, mod):
-                # hold the concurrency signal open so the rest of the burst
-                # deterministically piles into the coalescing window
-                import time as _time
-
-                _time.sleep(0.05)
                 return orig_resident(cs, mod)
 
-            be.modmul_fold_many = maybe_boom
-            be.modmul_fold_resident = slow_host
+            be.modmul_fold_resident = second_one_booms
             server.backend = be
             pk = KEYS.psse.public
             vals = [2, 3, 5, 7, 11, 13]
@@ -642,24 +608,17 @@ def test_coalesced_dispatch_failure_fails_all_waiters_cleanly():
                 await call(server, "POST", "/PutSet", {"contents": [str(pk.encrypt(v))]})
 
             target = f"/SumAll?position=0&nsqr={pk.nsquare}"
-            results = await asyncio.wait_for(
-                asyncio.gather(*(call(server, "GET", target) for _ in range(5))),
-                timeout=15,
-            )
-            statuses = sorted(st for st, _ in results)
-            # the first (host-path) request succeeds; the coalesced group
-            # all get the failure as 500s — nobody hangs
-            assert statuses[0] == 200 and statuses[-1] == 500
-            assert statuses.count(500) >= 1
-
-            # coalescer recovers once the backend is healthy again
-            boom["on"] = False
-            results = await asyncio.wait_for(
-                asyncio.gather(*(call(server, "GET", target) for _ in range(5))),
-                timeout=15,
-            )
-            for st, data in results:
-                assert st == 200
-                assert KEYS.psse.decrypt(int(json.loads(data)["result"])) == sum(vals)
+            for burst in range(2):
+                results = await asyncio.wait_for(
+                    asyncio.gather(*(call(server, "GET", target) for _ in range(5))),
+                    timeout=15,
+                )
+                failed = 1 if burst == 0 else 0
+                assert sorted(st for st, _ in results) == (
+                    [200] * (5 - failed) + [500] * failed)
+                for st, data in results:
+                    if st == 200:
+                        assert KEYS.psse.decrypt(
+                            int(json.loads(data)["result"])) == sum(vals)
 
     asyncio.run(go())

@@ -376,7 +376,7 @@ def test_scatter_gather_sumall_bit_for_bit_vs_single_shard():
                                ProxyConfig(port=0, crypto_backend="cpu"))
         await server.start()
         scatters = {"n": 0}
-        orig = server._shard_operands
+        orig = server._owner_operands
 
         def spy(pairs, pos):
             out = orig(pairs, pos)
@@ -384,7 +384,7 @@ def test_scatter_gather_sumall_bit_for_bit_vs_single_shard():
                 scatters["n"] += 1
             return out
 
-        server._shard_operands = spy
+        server._owner_operands = spy
         for row in rows:
             st, _ = await http_request(
                 "127.0.0.1", server.cfg.port, "POST", "/PutSet",

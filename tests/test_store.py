@@ -1,10 +1,10 @@
-"""Tests for the content-addressed device cipher store (ops/store.py)."""
+"""Tests for the content-addressed device cipher store (resident/pool.py)."""
 
 import random
 
 import pytest
 
-from dds_tpu.ops.store import DeviceCipherStore
+from dds_tpu.resident.pool import ResidentPool
 
 
 @pytest.fixture(scope="module")
@@ -22,7 +22,7 @@ def pyfold(cs, n):
 
 def test_fold_parity_and_residency(modulus):
     rng = random.Random(1)
-    store = DeviceCipherStore(modulus, initial_rows=8)
+    store = ResidentPool(modulus, initial_rows=8)
     cs = [rng.randrange(1, modulus) for _ in range(5)]
     assert store.fold(cs) == pyfold(cs, modulus)
     assert store.resident == 5
@@ -36,7 +36,7 @@ def test_fold_parity_and_residency(modulus):
 
 
 def test_duplicate_operands_fold_correctly(modulus):
-    store = DeviceCipherStore(modulus, initial_rows=8)
+    store = ResidentPool(modulus, initial_rows=8)
     c = 123456789
     assert store.fold([c, c, c]) == pyfold([c, c, c], modulus)
     assert store.resident == 1  # content-addressed: one row
@@ -44,7 +44,7 @@ def test_duplicate_operands_fold_correctly(modulus):
 
 def test_growth(modulus):
     rng = random.Random(2)
-    store = DeviceCipherStore(modulus, initial_rows=4)
+    store = ResidentPool(modulus, initial_rows=4)
     cs = [rng.randrange(1, modulus) for _ in range(19)]
     assert store.fold(cs) == pyfold(cs, modulus)
     assert store.capacity >= 19
@@ -53,7 +53,7 @@ def test_growth(modulus):
 
 def test_reset_over_max_rows(modulus):
     rng = random.Random(3)
-    store = DeviceCipherStore(modulus, initial_rows=4, max_rows=16)
+    store = ResidentPool(modulus, initial_rows=4, max_rows=16)
     cs = [rng.randrange(1, modulus) for _ in range(21)]
     # exceeds max_rows -> resets, then re-ingests what fits and still answers
     assert store.fold(cs[:10]) == pyfold(cs[:10], modulus)
@@ -63,7 +63,7 @@ def test_reset_over_max_rows(modulus):
 
 
 def test_empty_fold(modulus):
-    store = DeviceCipherStore(modulus)
+    store = ResidentPool(modulus)
     assert store.fold([]) == 1
 
 

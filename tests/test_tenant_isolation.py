@@ -3,9 +3,9 @@
 Small real stacks (InMemoryNet quorum + DDSRestServer) exercise the
 edges the unit suite can't: the `x-dds-tenant` header clamp answering
 typed 400s, cross-tenant key access answering typed 403s, per-tenant
-aggregate/order scoping, the mixed-tenant same-modulus fold still
-landing in ONE fused dispatch (isolation must not cost the batching
-win), and the tenant surfaces on /health and /metrics.
+aggregate/order scoping, two tenants' concurrent folds over one modulus
+each getting their own sum, and the tenant surfaces on /health and
+/metrics.
 
 The closing drill is the ISSUE's chaos acceptance: a client-side
 `TenantKeyring` rotates and then crypto-shreds one tenant's keys in the
@@ -21,6 +21,7 @@ import asyncio
 import contextlib
 import json
 import math
+import threading
 
 import pytest
 
@@ -37,8 +38,7 @@ pytestmark = pytest.mark.tenancy
 
 
 @contextlib.asynccontextmanager
-async def tenancy_stack(acfg: AdmissionConfig | None = None, n=4, quorum=3,
-                        **proxy_kw):
+async def tenancy_stack(acfg: AdmissionConfig | None = None, n=4, quorum=3):
     from dds_tpu.core.replica import BFTABDNode, ReplicaConfig
 
     net = InMemoryNet()
@@ -50,7 +50,7 @@ async def tenancy_stack(acfg: AdmissionConfig | None = None, n=4, quorum=3,
                     AbdClientConfig(request_timeout=2.0, quorum_size=quorum))
     server = DDSRestServer(abd, ProxyConfig(
         host="127.0.0.1", port=0, admission=acfg,
-        tenancy=TenancyConfig(enabled=True), **proxy_kw,
+        tenancy=TenancyConfig(enabled=True),
     ))
     await server.start()
     try:
@@ -177,69 +177,53 @@ def test_aggregates_and_order_are_tenant_scoped():
     asyncio.run(go())
 
 
-# ------------------------------- isolation must not break fold coalescing
+# ------------------------------- isolation scopes operands, not the fold
 
 
-class _FoldManyBackend:
-    """Fold backend with a device-batch crossover, recording every fused
-    dispatch so the test can prove mixed-tenant folds shared ONE."""
+class _MeetingFoldBackend:
+    """Fold backend whose folds wait for each other on a barrier, so the
+    test holds two tenants' folds in flight at the same instant."""
 
-    name = "stub-foldmany"
-    min_device_batch = 4  # alice(2) and bob(3) alone stay below; fused >= it
+    name = "stub-meeting"
 
     def __init__(self):
-        self.many_calls: list[list[int]] = []
+        self.meet = threading.Barrier(2, timeout=30)
+        self.widths: list[int] = []
 
     def modmul_fold(self, ops, modulus):
-        out = 1
-        for o in ops:
-            out = out * o % modulus
-        return out
-
-    def modmul_fold_many(self, folds, modulus):
-        self.many_calls.append(sorted(len(f) for f in folds))
-        return [self.modmul_fold(f, modulus) for f in folds]
+        self.widths.append(len(ops))
+        self.meet.wait()
+        return math.prod(ops) % modulus
 
 
-def test_mixed_tenant_same_modulus_folds_share_one_fused_dispatch():
-    """Acceptance: tenant isolation scopes the OPERANDS, not the device
-    batching — two tenants' folds over the same modulus coalesce into a
-    single `modmul_fold_many` dispatch (the `_fold_pending` group key is
-    the modulus alone), each receiving its own tenant-scoped result."""
+def test_two_tenants_concurrent_folds_over_one_modulus_get_their_own_sums():
+    """Acceptance: tenant isolation scopes the OPERANDS — two tenants'
+    folds over the same modulus, in flight together, each fold its own
+    tenant's rows and nothing of the other's."""
     M = (1 << 64) + 13
 
     async def go():
-        async with tenancy_stack(coalesce_window=0.05) as (server, _):
+        async with tenancy_stack() as (server, _):
             a_vals = [3, 5]
             b_vals = [7, 11, 13]
             for v in a_vals:
                 await _put(server, [str(v)], tenant="alice")
             for v in b_vals:
                 await _put(server, [str(v)], tenant="bob")
-            stub = server.backend = _FoldManyBackend()
+            stub = server.backend = _MeetingFoldBackend()
             tracer.reset()
-            # hold the inflight flag so BOTH folds take the coalescing
-            # window (a lone first fold would dispatch directly — correct
-            # in production, but here the fused path is the subject)
-            server._folds_inflight += 1
-            try:
-                results = await asyncio.gather(
-                    _get(server, "GET", f"/SumAll?position=0&nsqr={M}",
-                         tenant="alice"),
-                    _get(server, "GET", f"/SumAll?position=0&nsqr={M}",
-                         tenant="bob"),
-                )
-            finally:
-                server._folds_inflight -= 1
-            (st_a, body_a), (st_b, body_b) = results
+            (st_a, body_a), (st_b, body_b) = await asyncio.gather(
+                _get(server, "GET", f"/SumAll?position=0&nsqr={M}",
+                     tenant="alice"),
+                _get(server, "GET", f"/SumAll?position=0&nsqr={M}",
+                     tenant="bob"),
+            )
             assert st_a == 200 and st_b == 200
             assert json.loads(body_a)["result"] == str(math.prod(a_vals) % M)
             assert json.loads(body_b)["result"] == str(math.prod(b_vals) % M)
-            # ONE fused dispatch carried both tenants' folds
-            assert stub.many_calls == [[2, 3]]
-            spans = [e for e in tracer.events("proxy.coalesced_fold")]
-            assert len(spans) == 2
-            assert all(e.meta.get("batch") == 2 for e in spans)
+            # one fold a tenant, each as wide as that tenant's rows
+            assert sorted(stub.widths) == [2, 3]
+            spans = tracer.events("proxy.fold")
             assert sorted(e.meta.get("k") for e in spans) == [2, 3]
 
     asyncio.run(go())

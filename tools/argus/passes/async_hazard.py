@@ -75,8 +75,7 @@ BLOCKING_ATTRS = {
 
 # native/batched bignum entries: GIL-releasing but thread-blocking for a
 # full modexp — run them via asyncio.to_thread like server._fold does
-BLOCKING_COMPUTE = {"powmod", "powmod_batch", "fold", "modmul_fold",
-                    "modmul_fold_many"}
+BLOCKING_COMPUTE = {"powmod", "powmod_batch", "fold", "modmul_fold"}
 
 SPAWNERS = {"ensure_future", "create_task"}
 
