@@ -717,6 +717,24 @@ class TelemetryAck:
     error: str = ""
 
 
+@dataclass(frozen=True)
+class CountersRequest:
+    """Launcher -> a replica process's node-host agent: send your protocol
+    counters (`transport.replica_processes`, dds_tpu/hosts.py)."""
+
+
+@dataclass(frozen=True)
+class Counters:
+    """Node-host agent -> launcher: every series of the families in
+    `obs.metrics.PROTOCOL_FAMILIES` as this process has counted it since
+    it started, `[name, help, labels, value]` each. Cumulative, so a lost
+    report costs nothing: the launcher adds what grew since the last one.
+    Rides the authenticated transport like Redeploy; a process can lie
+    about its own counts and about nothing else."""
+
+    samples: list
+
+
 # --------------------------------------------------------------------------
 # fault injection backdoor (malicious/MaliciousAttack.scala:34)
 # --------------------------------------------------------------------------
@@ -757,7 +775,7 @@ _TYPES = {
         ShardMapInstall, ShardMapActivate, ShardMapAck,
         ShardExportRequest, ShardExport, ShardPruneRequest, ShardPruned,
         LeaseRequest, LeaseGrant, LeaseRevoke, LocalRead, LocalReadReply,
-        TelemetryBatch, TelemetryAck,
+        TelemetryBatch, TelemetryAck, CountersRequest, Counters,
     )
 }
 

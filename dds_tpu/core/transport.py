@@ -115,7 +115,12 @@ class TcpNet(Transport):
     Suspect). TLS contexts can be layered on top/instead.
 
     One listening socket per host serves all endpoints registered on it;
-    outbound connections are cached per destination host.
+    outbound connections are cached per destination process ("host:port"),
+    one a pair of processes and direction. A send to a peer that is not up
+    (yet, or any more) is a failed send: logged, counted by nobody here,
+    the sender's timeout and the breaker above it see it. A peer that goes
+    closes its end; the cached connection goes with it (`_watch`), so the
+    first frame after the peer is back opens a new one.
 
     What the wire costs is recorded per frame, never per key: a
     `net.serialize` span on the sending side (dict-encode, both JSON
@@ -148,6 +153,8 @@ class TcpNet(Transport):
         self._handlers: dict[str, Handler] = {}
         self._server: Optional[asyncio.AbstractServer] = None
         self._conns: dict[str, asyncio.StreamWriter] = {}
+        self._watchers: set[asyncio.Task] = set()
+        self._inbound: set[asyncio.StreamWriter] = set()
         self._ssl_server, self._ssl_client = ssl_server, ssl_client
         self._frame_secret = frame_secret
         # per-node identity (utils/nodeauth): node_key is THIS process's
@@ -222,11 +229,14 @@ class TcpNet(Transport):
             self.port = self._server.sockets[0].getsockname()[1]
 
     async def stop(self) -> None:
-        # close outbound connections first: the EOF unblocks server-side
-        # _serve loops, letting wait_closed() complete
-        for w in self._conns.values():
+        # close every connection first, outbound and inbound: a peer in
+        # another process keeps its end open for as long as it lives, and
+        # wait_closed() waits for every _serve loop
+        for w in [*self._conns.values(), *self._inbound]:
             w.close()
         self._conns.clear()
+        for t in list(self._watchers):
+            t.cancel()
         if self._server:
             self._server.close()
             try:
@@ -263,6 +273,7 @@ class TcpNet(Transport):
 
     async def _serve(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
         asyncio.current_task().set_name("tcp.serve")
+        self._inbound.add(writer)
         try:
             while True:
                 hdr = await reader.readexactly(4)
@@ -294,6 +305,7 @@ class TcpNet(Transport):
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
         finally:
+            self._inbound.discard(writer)
             writer.close()
 
     def _open_frame(self, frame: bytes):
@@ -366,6 +378,22 @@ class TcpNet(Transport):
         finally:
             obs_context.detach(token)
 
+    async def _watch(self, conn_key: str, reader: asyncio.StreamReader,
+                     writer: asyncio.StreamWriter) -> None:
+        """An outbound connection carries nothing back, so its reader ends
+        when the peer does: drop the connection then, and the next send
+        opens one to whoever listens there now. Without this the first
+        frame after a peer went is written into a dead socket and lost
+        without a sign."""
+        try:
+            while await reader.read(4096):
+                pass
+        except OSError:
+            pass
+        writer.close()
+        if self._conns.get(conn_key) is writer:
+            del self._conns[conn_key]
+
     def send(self, src: str, dest: str, msg: object) -> None:
         supervised_task(self._send(src, dest, msg),
                         name=f"tcp.send:{dest}")
@@ -377,8 +405,12 @@ class TcpNet(Transport):
             async with self._lock:
                 w = self._conns.get(conn_key)
                 if w is None or w.is_closing():
-                    _, w = await asyncio.open_connection(host, port, ssl=self._ssl_client)
+                    r, w = await asyncio.open_connection(host, port, ssl=self._ssl_client)
                     self._conns[conn_key] = w
+                    t = supervised_task(self._watch(conn_key, r, w),
+                                        name=f"tcp.watch:{conn_key}")
+                    self._watchers.add(t)
+                    t.add_done_callback(self._watchers.discard)
             t_ser = time.perf_counter()
             payload = M.to_dict(msg)
             obj = {"src": src, "dest": dest, "msg": payload}

@@ -15,6 +15,12 @@ Design notes:
   mid-flight would corrupt rate() queries.
 - every mutation takes one short lock; the hot-path cost is a dict lookup
   and a float add, matching the tracer's "one deque append" budget.
+- counters kept in other processes can be taken in (`counters` there,
+  `absorb` here): a launcher whose replicas run in processes of their own
+  (`transport.replica_processes`) reads, in its own registry, the sum of
+  what they counted about the protocol (`PROTOCOL_FAMILIES`). Families
+  that describe one process (the event loop's ledger, the collector's
+  pauses, the wire's frames) are never shipped.
 - label cardinality is BOUNDED per family (`max_series`, default 1024):
   once a family holds that many distinct label sets, new label sets fold
   into a single `overflow` series (every label value replaced by
@@ -33,8 +39,25 @@ from dataclasses import dataclass, field
 __all__ = [
     "Registry", "metrics",
     "LATENCY_BUCKETS", "SIZE_BUCKETS",
-    "OVERFLOW_LABEL", "OVERFLOW_COUNTER",
+    "OVERFLOW_LABEL", "OVERFLOW_COUNTER", "PROTOCOL_FAMILIES",
 ]
+
+# what replicas, their supervisor and their anti-entropy agents count about
+# the PROTOCOL: the same whichever process the replica runs in, so a
+# launcher sums them over its replica processes. Everything else a replica
+# process counts is about that process and stays there.
+PROTOCOL_FAMILIES = (
+    "dds_read_batch_keys_total",
+    "dds_replica_tag_vector_total", "dds_replica_tag_vector_keys_total",
+    "dds_replica_keyset_total", "dds_replica_rejected_total",
+    "dds_suspect_votes_total", "dds_suspicion_quorums_total",
+    "dds_recovery_rotations_total", "dds_recovery_unverified_total",
+    "dds_recovery_seeded_entries_total", "dds_recovery_rejected_entries_total",
+    "dds_antientropy_rounds_total", "dds_antientropy_timeouts_total",
+    "dds_antientropy_digest_mismatches_total",
+    "dds_antientropy_rejected_repairs_total",
+    "dds_antientropy_repaired_keys_total",
+)
 
 # seconds: 1ms .. 10s, the REST/quorum latency range under chaos schedules
 LATENCY_BUCKETS = (
@@ -87,6 +110,8 @@ class Registry:
         self._lock = threading.Lock()
         self._families: dict[str, _Family] = {}
         self.max_series = int(max_series)
+        # (source, family, label key) -> the value last absorbed from there
+        self._absorbed: dict[tuple, float] = {}
 
     # -------------------------------------------------------------- writes
 
@@ -159,7 +184,33 @@ class Registry:
             s[1] += value
             s[2] += 1
 
+    def absorb(self, source: str, samples: list) -> None:
+        """Take in another process's counters: `samples` as its `counters()`
+        gave them, cumulative since that process started. Each series here
+        grows by what it grew there since the last call for `source`, so
+        the series reads the sum over sources (and over this process's own
+        increments). A value below the last one is a process that started
+        again: it counts from nothing."""
+        for name, help, labels, value in samples:
+            at = (source, name, _label_key(labels))
+            last = self._absorbed.get(at, 0.0)
+            self._absorbed[at] = value
+            self.inc(name, value - last if value >= last else value,
+                     help=help, **labels)
+
     # --------------------------------------------------------------- reads
+
+    def counters(self, names) -> list:
+        """Every series of the counter families `names`, as `[name, help,
+        labels, value]`: what `absorb` takes, in a form JSON carries."""
+        with self._lock:
+            return [
+                [name, fam.help, dict(key), value]
+                for name in names
+                if (fam := self._families.get(name)) is not None
+                and fam.kind == "counter"
+                for key, value in fam.samples.items()
+            ]
 
     def value(self, name: str, **labels) -> float | None:
         """Current counter/gauge value of one series (tests/introspection)."""
@@ -206,6 +257,7 @@ class Registry:
     def reset(self) -> None:
         with self._lock:
             self._families.clear()
+            self._absorbed.clear()
 
     # ---------------------------------------------------------- exposition
 

@@ -131,6 +131,7 @@ _TASK_TENANT = {
     "http.conn": _REQUEST,       # http/miniserver: one REST connection
     "tcp.send": _TRANSPORT,      # core/transport.TcpNet: one frame out
     "tcp.serve": _TRANSPORT,     # ... and one inbound connection's frames
+    "tcp.watch": _TRANSPORT,     # ... and the wait for an outbound one's end
 }
 # a delivery task runs an endpoint's handler: the rest of its name is the
 # endpoint, whose role is the first of these words its name holds

@@ -20,7 +20,9 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
+import os
 import random
+import threading
 from dataclasses import dataclass, field
 
 from dds_tpu.clt.client import ClientConfig, DDSHttpClient
@@ -33,7 +35,9 @@ from dds_tpu.core.quorum_client import AbdClient, AbdClientConfig
 from dds_tpu.core.replica import BFTABDNode, ReplicaConfig
 from dds_tpu.core.supervisor import BFTSupervisor, SupervisorConfig
 from dds_tpu.core.transport import InMemoryNet, TcpNet
+from dds_tpu.hosts import NODEHOST, ReplicaProcesses
 from dds_tpu.http.server import DDSRestServer, ProxyConfig
+from dds_tpu.obs.metrics import PROTOCOL_FAMILIES, metrics
 from dds_tpu.obs.slo import SloEngine
 from dds_tpu.malicious.trudy import AttackType, Trudy, parse_attack
 from dds_tpu.models.facade import HomoProvider
@@ -61,8 +65,14 @@ class Deployment:
     # the victims of the attack `launch` fired itself (`attacks.at_launch`);
     # None when it fired none, and `run_workload` then fires its own
     launch_victims: list | None = None
+    # `transport.replica_processes`: the children that hold the replicas
+    # (`replicas` above is then empty and `supervisor` None)
+    hosts: ReplicaProcesses | None = None
 
     async def stop(self) -> None:
+        if self.hosts is not None:
+            # first: their last counters come over the transport
+            await self.hosts.stop()
         if self.constellation is not None:
             await self.constellation.stop()
         if self.supervisor is not None:
@@ -106,7 +116,13 @@ async def launch(cfg: DDSConfig | None = None) -> Deployment:
             "attacks.at_launch is set but attacks.enabled is not: the "
             "replicas would ignore the attack this launch is asked to fire"
         )
-    dep = await _launch(cfg)
+    started: list[ReplicaProcesses] = []
+    try:
+        dep = await _launch(cfg, started)
+    except BaseException:
+        for hosts in started:   # no child outlives a launch that failed
+            await hosts.stop()
+        raise
     if cfg.attacks.at_launch:
         try:
             await _attack_at_launch(dep)
@@ -158,7 +174,30 @@ async def _attack_at_launch(dep: Deployment) -> None:
     )
 
 
-async def _launch(cfg: DDSConfig) -> Deployment:
+def _replica_processes(cfg: DDSConfig, net, ssl_client) -> ReplicaProcesses:
+    """`transport.replica_processes`: refuse what this launch cannot
+    place; else the (not yet started) children of this launch."""
+    if cfg.shard.enabled:
+        raise ValueError(
+            "transport.replica_processes places one quorum group; a sharded "
+            "fleet is placed by its [fabric] roles"
+        )
+    if (cfg.replicas.addresses or cfg.replicas.local
+            or cfg.replicas.supervisor_address):
+        raise ValueError(
+            "transport.replica_processes chooses replicas.addresses, "
+            "replicas.local and replicas.supervisor_address itself: name "
+            "hosts with them, or let it place processes on this machine"
+        )
+    if cfg.security.node_public_keys:
+        raise ValueError(
+            "transport.replica_processes takes free ports at launch, so no "
+            "address can be in security.node_public_keys beforehand"
+        )
+    return ReplicaProcesses(cfg, net, ssl_client)
+
+
+async def _launch(cfg: DDSConfig, started: list) -> Deployment:
     stoppables = []
 
     # Atlas [retry]: the per-region deadline/backoff overrides for THIS
@@ -268,11 +307,25 @@ async def _launch(cfg: DDSConfig) -> Deployment:
             return f"{cfg.replicas.addresses.get(name, local_hostport)}/{name}"
 
     else:
+        if cfg.transport.replica_processes:
+            raise ValueError(
+                "transport.replica_processes needs transport.kind = \"tcp\": "
+                "processes meet over sockets"
+            )
         net = InMemoryNet()
         local_hostport = None
 
         def full(name: str) -> str:
             return name
+
+    hosts = None
+    if cfg.transport.replica_processes:
+        # `Main.scala:90-99` on one machine: every replica endpoint in a
+        # child process (dds_tpu/hosts.py). The children start now and are
+        # awaited once this process's own proxy stands.
+        hosts = _replica_processes(cfg, net, intranet_client)
+        started.append(hosts)   # whatever fails from here on, `launch` ends them
+        hosts.spawn()
 
     if cfg.attacks.chaos_enabled:
         # seeded fault fabric: every send traverses the ChaosNet schedule,
@@ -409,6 +462,9 @@ async def _launch(cfg: DDSConfig) -> Deployment:
     # NOT rebuilt (a stray/duplicate Redeploy must not wipe a live
     # replica's state); either way the agent acks so the supervisor's
     # reseed can proceed.
+    # It also answers a launcher that keeps its replicas in child processes
+    # (`transport.replica_processes`) with this process's protocol counters,
+    # and in that launcher takes the children's answers in.
     async def _nodehost(sender: str, msg) -> None:
         if isinstance(msg, M.Redeploy) and msg.endpoint in replicas:
             if net.has_endpoint(msg.endpoint):
@@ -418,9 +474,14 @@ async def _launch(cfg: DDSConfig) -> Deployment:
                     "nodehost rebuilding %s (asked by %s)", msg.endpoint, sender
                 )
                 _rebuild_local(msg.endpoint)
-            net.send(full("nodehost"), sender, M.Redeployed(msg.endpoint))
+            net.send(full(NODEHOST), sender, M.Redeployed(msg.endpoint))
+        elif isinstance(msg, M.CountersRequest):
+            net.send(full(NODEHOST), sender,
+                     M.Counters(metrics.counters(PROTOCOL_FAMILIES)))
+        elif isinstance(msg, M.Counters) and hosts is not None:
+            hosts.on_counters(sender, msg)
 
-    net.register(full("nodehost"), _nodehost)
+    net.register(full(NODEHOST), _nodehost)
 
     async def redeploy(endpoint: str) -> None:
         """Supervisor redeploy hook: rebuild locally when this process owns
@@ -447,7 +508,7 @@ async def _launch(cfg: DDSConfig) -> Deployment:
         net.register(tmp, on_ack)
         try:
             for _ in range(3):
-                net.send(tmp, f"{hostport}/nodehost", M.Redeploy(endpoint))
+                net.send(tmp, f"{hostport}/{NODEHOST}", M.Redeploy(endpoint))
                 try:
                     await asyncio.wait_for(asyncio.shield(ack), 1.0)
                     return
@@ -539,8 +600,23 @@ async def _launch(cfg: DDSConfig) -> Deployment:
         local_replicas=replicas,
         slo=SloEngine.from_obs(cfg.obs),
     )
+    if hosts is not None:
+        # the children came up while the backend above was made
+        await hosts.ready()
     await server.start()
     _log_backend(server)
+    if hosts is not None:
+        # ... and the supervisor, in the first child, has told this proxy
+        # who is active (`_replica_refresh_loop` asked when it started)
+        for _ in range(200):
+            if abd._preferred:
+                break
+            await asyncio.sleep(0.01)
+        else:
+            raise RuntimeError(
+                f"the supervisor at {sup_addr} never named the active "
+                "replicas"
+            )
 
     # Merkle anti-entropy loops: one pull agent per local replica, on a
     # jittered timer so the fleet's rounds spread out instead of thundering
@@ -564,7 +640,7 @@ async def _launch(cfg: DDSConfig) -> Deployment:
         trudy = Trudy(net, active, cfg.replicas.byz_max_faults,
                       addr=full("trudy"))
     dep = Deployment(cfg, net, replicas, supervisor, server, trudy, ssl_client,
-                     stoppables)
+                     stoppables, hosts=hosts)
 
     # per-process identity: the dds_process_info gauge on /metrics and the
     # flight recorder's incident headers (obs/panopticon correlates by it)
@@ -1042,6 +1118,22 @@ async def run_workload(dep: Deployment, provider: HomoProvider | None = None,
     return list(await asyncio.gather(*runs))
 
 
+def _die_with_parent() -> None:
+    """Exit when stdin reaches its end, however the process that held its
+    other end went (SIGKILL included: the kernel closes what it held). A
+    thread's blocking read, so a busy event loop delays nothing."""
+    def watch() -> None:
+        try:
+            while os.read(0, 4096):
+                pass
+        except OSError:
+            pass
+        os._exit(0)
+
+    threading.Thread(target=watch, name="dds-parent-watch",
+                     daemon=True).start()
+
+
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description="Run a DDS node + workload")
     ap.add_argument("--config", help="TOML/JSON config path")
@@ -1051,6 +1143,10 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=None)
     ap.add_argument("--serve", action="store_true", help="keep serving after workload")
     ap.add_argument("--role", help="override [fabric] role (all | proxy | group:N)")
+    ap.add_argument("--die-with-parent", action="store_true",
+                    help="exit when stdin ends: the launcher of a replica "
+                         "process (transport.replica_processes) holds its "
+                         "other end and nobody else does")
     ap.add_argument("--mint-node-keys", type=int, metavar="N",
                     help="provision N per-process Ed25519 node keys + the "
                          "security.node-public-keys TOML stanza, then exit")
@@ -1067,6 +1163,8 @@ def main(argv=None) -> None:
         print(mint_node_keys(args.mint_node_keys, args.mint_dir,
                              hosts or None), end="")
         return
+    if args.die_with_parent:
+        _die_with_parent()
     cfg = DDSConfig.load(args.config) if args.config else DDSConfig()
     if args.ops is not None:
         cfg.client.nr_of_operations = args.ops

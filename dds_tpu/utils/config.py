@@ -158,6 +158,13 @@ class TransportConfig:
     # address is missing from security.node_public_keys — peers could
     # never verify this process's frames.
     advertise: str = ""
+    # kind = "tcp" only: `launch` starts one child OS process per replica
+    # endpoint on this machine (dds_tpu/hosts.py: free loopback ports, each
+    # child the normal node entry with replicas.local = [its replica], the
+    # supervisor with the first), and keeps the proxy and no replica for
+    # itself. The per-host split of `dds-system.conf:113-128` on one
+    # machine; real hosts are named with replicas.addresses as before.
+    replica_processes: bool = False
 
 
 @dataclass

@@ -316,16 +316,26 @@ def test_a_cut_off_coordinator_is_no_users_to_try_while_another_stands(when):
     timeouts. From then on, open or half-open, every request goes through
     replica-1; what the proxy sends replica-0 is its own probe (the tags of
     no keys), which closes the breaker after the heal with no user's
-    request spent on finding out."""
+    request spent on finding out.
+
+    The breaker's 0.15 s of `breaker_reset` run on a clock the test
+    moves: a loaded machine cannot outrun the stretch in which the breaker
+    has to stay open (PR 48's run of six workers did), and after the heal
+    the clock goes with the wall clock the loop waits on."""
 
     async def go():
         from tests.test_core import Cluster
+        from tests.test_retry import FakeClock
 
         net = ChaosNet(InMemoryNet(), seed=9)
         c = Cluster(net=net)
         cfg = c.client.cfg
         cfg.request_timeout, cfg.breaker_reset = 0.1, 0.15
         cfg.breaker_probe_timeout = 0.05
+        clock = FakeClock()
+        c.client.breakers["replica-0"] = CircuitBreaker(
+            cfg.breaker_threshold, cfg.breaker_reset, clock=clock,
+            name="replica-0")
         c.client._preferred = ["replica-0", "replica-1"]
         sent = []
 
@@ -347,7 +357,7 @@ def test_a_cut_off_coordinator_is_no_users_to_try_while_another_stands(when):
         assert b.state == CircuitBreaker.OPEN
         assert c.client.replicas._strikes["replica-0"] == 0
         if when == "half_open":
-            await asyncio.sleep(0.16)
+            clock.advance(0.16)
             assert b.state == CircuitBreaker.HALF_OPEN
         for _ in range(20):
             assert await c.client.fetch_set("K") == ["row"]
@@ -359,6 +369,7 @@ def test_a_cut_off_coordinator_is_no_users_to_try_while_another_stands(when):
         for _ in range(40):
             if b.settled:
                 break
+            clock.advance(0.02)
             await asyncio.sleep(0.02)
         assert b.state == CircuitBreaker.CLOSED
         # found by the probe: nothing of a user's went there meanwhile
