@@ -85,6 +85,16 @@ def _differing(old: list, new: list) -> list[int]:
     return out
 
 
+class MergedTags(list):
+    """What `AbdClient.read_tags` returns when the quorum's max moved a
+    tag: the merged list, and in `moved` the positions at which it differs
+    from the caller's `cached_tags` (in no order), or None where the round
+    had no such list to hold it against. Everywhere else it holds
+    `cached_tags`' own objects."""
+
+    __slots__ = ("moved",)
+
+
 class _KeptVectors:
     """Per key set, what the proxy has verified from each replica: sender
     -> (fingerprint, diff), the vector that sender last attested, held
@@ -1354,6 +1364,18 @@ class AbdClient:
         and a delta is applied to its own sender's record only, so a liar
         can misstate no vote but its own, which it always could.
 
+        What comes back: `cached_tags` ITSELF, by identity, when the
+        quorum's max moved no tag of it (every vote "unchanged", or none
+        newer): the callers' all-fresh signal. Else a list of the K maxima
+        which, in a round with a fingerprint, is a `MergedTags` whose
+        `moved` names the positions at which it differs from `cached_tags`
+        (the ones the merge wrote, at no cost beyond an append each) and
+        which holds `cached_tags`' own objects everywhere else: a caller
+        that keeps its own record of what it moved (`OperandTable.stale`)
+        decides from those positions alone; one that compares all K still
+        can. A round without a fingerprint has no list of the caller's to
+        differ from: a plain list, or `moved` None.
+
         The request names the key set by `digest` (what its MAC covers)
         and carries the K keys only to a replica that has not yet answered
         for that digest with a verified vote (`_keyset_holders`: first
@@ -1486,17 +1508,22 @@ class AbdClient:
         `ref`, `ref`'s own tag elsewhere. The caller's own list BY IDENTITY
         when the max moves nothing (no vote differs, or those that do are
         older): callers use `result is cached_tags` as the all-fresh
-        signal."""
-        out = None
+        signal. Else a `MergedTags`: a copy of `ref` with the max written
+        at the positions it moved, which the copy names (`moved`) when
+        `ref` is the caller's list, so that the caller need not compare K
+        tags to find them."""
+        out, moved = None, []
         for i in set().union(*votes):
             at = ref[i]
             top = max(v.get(i, at) for v in votes)
             if top != at:
                 if out is None:
-                    out = list(ref)
+                    out = MergedTags(ref)
                 out[i] = top
+                moved.append(i)
         if out is None:
             return cached_tags if cached_tags is not None else list(ref)
+        out.moved = moved if ref is cached_tags else None
         return out
 
     def refresh_from(self, supervisor: str) -> None:

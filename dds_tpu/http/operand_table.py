@@ -19,15 +19,20 @@ spliced in at their sorted positions, each kept column carried with only
 the new rows parsed, and its pool rows carried with only the new
 positions to look up. What stays O(K) per changed version runs in C over
 whole lists: the copy of the tag vector and the join and hash of its fields
-for the replicas' tag round (`round_args`). In Python: one pass of tag
-compares when the quorum saw a tag move (`stale`), and the `[(key,
+for the replicas' tag round (`round_args`). In Python: the `[(key,
 value)]` list of the routes that still read pairs (`pairs`), built when
-one of them asks.
+one of them asks. Which rows the tag round left unconfirmed (`stale`) is
+decided at the positions that moved: the quorum's, which the round's
+reply names, and the table's own since the round was made, which it logs
+by version (`moved_since`). One pass of K tag compares is left for the
+rounds in which one of the two is not known.
 """
 
 from __future__ import annotations
 
 from bisect import bisect_left, bisect_right
+from collections import deque
+from itertools import chain, islice
 
 import numpy as np
 
@@ -38,6 +43,12 @@ from dds_tpu.utils import sigs
 # columns kept per table: one per distinct `position` clients aggregate
 # over; past it the oldest goes (a request for it again parses it again)
 MAX_COLUMNS = 8
+# versions back that a table remembers which positions each moved, for the
+# tag rounds in flight over it (`moved_since`; an aggregate bumps the
+# version up to four times), and the most positions one version may move
+# and be remembered: past either, `stale` compares all K tags
+MOVED_VERSIONS = 64
+MOVED_ROWS = 1024
 
 
 def _spliced(old: list, at: list[int], items: list) -> list:
@@ -180,6 +191,8 @@ class OperandTable:
         self.uncached = self.entries.count(None)
         self.version = 0                      # bumps when an entry moves
         self.settled = -1     # the version an aggregate last patched up to
+        # the positions each of the last versions moved, newest last
+        self._moved: deque[list[int]] = deque(maxlen=MOVED_VERSIONS)
         self.columns: dict[int, OperandColumn] = {}
         self._digest: str | None = None
         self._round: tuple | None = None
@@ -228,6 +241,10 @@ class OperandTable:
             tag = self.tags[i] = e[0] if e is not None else None
             self.fields[i] = sigs.tag_field(tag) if tag is not None else None
         self.version += 1
+        if len(moved) > MOVED_ROWS:
+            self._moved.clear()     # the log starts anew after this version
+        else:
+            self._moved.append([i for i, _ in moved])
         for pos in [p for p, c in self.columns.items() if not c.patch(moved)]:
             del self.columns[pos]
         if self.columns:
@@ -265,26 +282,67 @@ class OperandTable:
         self._round = (self.version, at, keys, digest, fp, tags)
         return self._round
 
-    def stale(self, sent: tuple | None, reply) -> list[int]:
-        """Positions whose entry the tag round did not confirm: those that
-        held no tag when the round `sent` was made, and those whose
-        quorum-max tag in `reply` (aligned with `sent`) is not the tag the
-        entry holds now, so that a write completed after the round is
-        re-read. One pass of comparisons; none when every vote said
-        "unchanged" (`reply` is the list sent): entries that moved since
-        come from completed operations and are newer than the round."""
+    def moved_since(self, version: int) -> list[int] | None:
+        """The positions `apply` moved since the table was at `version`
+        (one may come twice); None when the log no longer reaches back
+        that far."""
+        log = self._moved
+        n = self.version - version
+        if n > len(log):
+            return None
+        return list(chain.from_iterable(islice(log, len(log) - n, None)))
+
+    def stale(self, sent: tuple | None, reply) -> tuple[list[int], str]:
+        """(the positions whose entry the tag round did not confirm, the
+        path that found them): those that held no tag when the round `sent`
+        was made, and those whose quorum-max tag in `reply` (aligned with
+        `sent`) is not the tag the entry holds now, so that a write
+        completed after the round is re-read.
+
+        `unchanged`: none, at no cost, when every vote said "unchanged"
+        (`reply` is the list sent): entries that moved since come from
+        completed operations and are newer than the round. `positions`,
+        O(positions moved): the reply names where it differs from the list
+        sent (`MergedTags.moved`) and the log reaches back to the version
+        the round was made at (`moved_since`). At every other position the
+        entry is the one the round was made from and the reply holds that
+        entry's own tag, so the comparison is made at those positions
+        alone and finds what the pass over K would, in its order. `full`,
+        that pass, one comparison a row: no round or a failed one (`sent`
+        is None: everything is re-read), a round over some of the rows
+        (entries without a tag), a reply that names no positions (a round
+        without a fingerprint, one merged over shard groups), a log
+        trimmed past the round's version.
+        `dds_operand_table_validate_total{path}` counts the two that look
+        at entries."""
         entries = self.entries
         if sent is None:     # no round, or it failed: everything is re-read
-            return list(range(len(entries)))
-        _, at, _, _, _, tags = sent
-        out = [] if reply is tags else [
-            i for i, t in zip(range(len(entries)) if at is None else at, reply)
-            if (e := entries[i]) is None or e[0] is not t and e[0] != t
-        ]
-        if at is not None:
-            had = set(at)
-            out.extend(i for i in range(len(entries)) if i not in had)
-        return out
+            out, path = list(range(len(entries))), "full"
+        else:
+            version, at, _, _, _, tags = sent
+            if at is None and reply is tags:
+                return [], "unchanged"
+            voted = getattr(reply, "moved", None) if at is None else None
+            since = self.moved_since(version) if voted is not None else None
+            if since is not None:
+                rows, path = sorted({*voted, *since}), "positions"
+                held = zip(rows, map(reply.__getitem__, rows))
+            else:
+                path = "full"
+                held = zip(range(len(entries)) if at is None else at, reply)
+            out = [] if reply is tags else [
+                i for i, t in held
+                if (e := entries[i]) is None or e[0] is not t and e[0] != t
+            ]
+            if at is not None:
+                had = set(at)
+                out.extend(i for i in range(len(entries)) if i not in had)
+        metrics.inc(
+            "dds_operand_table_validate_total", path=path,
+            help="tag rounds held against the table, by how the "
+                 "unconfirmed rows were found",
+        )
+        return out, path
 
     def pairs(self) -> list[tuple[str, list]]:
         """`[(key, value)]` of the rows that hold a value, in key order,

@@ -1462,9 +1462,15 @@ class DDSRestServer:
         with 1 light round + reads for just the stale keys.
 
         What it costs. O(K): a copy of the tag list and a join and hash
-        of its kept fields when an entry moved since the last round, the
-        replicas' side of the round, and one pass of tag comparisons when
-        the quorum saw a tag move. O(rows that moved): everything else.
+        of its kept fields when an entry moved since the last round, and
+        the replicas' side of the round. O(rows that moved): everything
+        else, the tag comparisons included when the quorum saw a tag move:
+        the round's reply names the positions its max moved and the table
+        the ones it moved since the round was made, and
+        `OperandTable.stale` compares at those. One pass of K comparisons
+        is left for a round that cannot say (no fingerprint, a failed
+        round, entries without a tag, more than `MOVED_VERSIONS` versions
+        of the table since): `assembly.validate_tags` says which in `path`.
         Entries the proxy's own completed operations changed are taken
         from the cache by key (`_sync_table`), stale and audited keys are
         re-read through full quorums, and only those rows are parsed into
@@ -1512,7 +1518,7 @@ class DDSRestServer:
                 # a write completed during the round: take it in first, so
                 # that its entry is held to the round's tag like any other
                 self._take_dirty(table)
-                stale = table.stale(sent, reply)
+                stale, vm["path"] = table.stale(sent, reply)
                 vm["stale"] = len(stale)
             with tracer.span("assembly.pick_stale", k=len(keys)) as pm:
                 audit = _pick_outside(len(keys), set(stale),

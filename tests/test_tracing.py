@@ -225,7 +225,9 @@ def test_one_sumall_after_a_write_yields_one_span_per_step(monkeypatch):
     assert [r.meta["stretch"] for r in named["residency.lookup"]] == [1, 2]
     # counts, never a span per row
     assert named["assembly.state"][0].meta["k"] == rows
-    assert named["assembly.validate_tags"][0].meta == {"k": rows, "stale": 0}
+    # the write went through this proxy: the quorum's max moved no tag
+    assert named["assembly.validate_tags"][0].meta == {
+        "k": rows, "stale": 0, "path": "unchanged"}
     assert named["assembly.pairs"][0].meta["k"] == rows
     assert named["assembly.operands"][0].meta == {"k": rows, "memo": False}
     assert named["assembly.reread"][0].meta == {"stale": 0, "audit": 2}
